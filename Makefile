@@ -6,7 +6,7 @@ PY ?= python3
 .PHONY: all native test check ci bench bench-smoke status-smoke \
 	chaos-smoke tcp-smoke shard-smoke zone-smoke federation-smoke \
 	hostile-smoke verify-smoke balancer-smoke population-smoke \
-	real-tiers clean
+	chip-smoke real-tiers clean
 
 all: native
 
@@ -29,7 +29,7 @@ test: native
 # and run the fastio pytest suites against the ASan-built extension)
 check:
 	$(PY) -m compileall -q binder_tpu tests bench.py bench_impl.py \
-		__graft_entry__.py
+		chip_smoke.py __graft_entry__.py
 	$(PY) tools/lint.py
 	$(MAKE) -B -C native \
 		CXXFLAGS="-O2 -g -Wall -Wextra -Werror -std=c++17" \
@@ -162,6 +162,15 @@ balancer-smoke:
 # BINDER_POPULATION_SECONDS overrides the budget (make ci trims to 10)
 population-smoke:
 	$(PY) tools/population_smoke.py
+
+# the one command for the chip host (PERF.md "Chip host"): sees the
+# TPU in a child process or fails, rebuilds native/ from source, then
+# serves a 1M-name zone from --shards 4 in the production posture and
+# checks answers, read-your-writes on every worker, native-lane
+# counters and a clean drain.  In a sandbox without a chip:
+# `python3 chip_smoke.py --cpu --hosts 2000 --shards 2`
+chip-smoke:
+	$(PY) chip_smoke.py
 
 # serving-plane verification smoke: clean soak (zero violations while
 # the checker, audit and propagation tracer all do real work, RSS

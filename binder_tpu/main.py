@@ -94,6 +94,7 @@ async def run_supervisor(options: Dict[str, object]):
         "LOG_LEVEL", "info"))))
     log_event(log, logging.INFO, "starting shard supervisor", options={
         k: v for k, v in options.items() if k != "store"})
+    warn_if_no_fastio(log)
 
     port = int(options["port"])
     collector = MetricsCollector(static_labels={
@@ -200,14 +201,38 @@ async def run_supervisor(options: Dict[str, object]):
     return supervisor
 
 
+#: the reference runs at most 32 binder processes per zone
+#: (BASELINE.md, boot/setup.sh:17,78); every worker holds the whole
+#: mirror, so one per core on a 100-core host is not a plan
+MAX_AUTO_SHARDS = 32
+
+
 def resolve_shard_count(options: Dict[str, object]) -> int:
     """``shards: "auto"`` sizes the reuseport group to the machine —
-    one single-threaded worker per core is the sizing rule
-    (docs/operations.md "Sizing N")."""
+    one single-threaded worker per core this process is ALLOWED to run
+    on (a cpuset-restricted container may see 64 cores and own two),
+    up to the reference's per-zone process cap (docs/operations.md
+    "Sizing N")."""
     n = options.get("shards") or 0
     if n == "auto":
-        n = os.cpu_count() or 1
+        try:
+            cores = len(os.sched_getaffinity(0))
+        except (AttributeError, OSError):
+            cores = os.cpu_count() or 1
+        n = max(1, min(cores, MAX_AUTO_SHARDS))
     return int(n)
+
+
+def warn_if_no_fastio(log: logging.Logger) -> None:
+    """Said once per deployment (by the supervisor or the single
+    process, never by each worker): without the extension both
+    importers fall back without a word and every query is served by
+    the Python lanes, an order of magnitude slower."""
+    from binder_tpu import server
+    if server._fastio is None:
+        log.warning("native extension binder_tpu._binderfastio is not "
+                    "built (run `make -C native`): serving every query "
+                    "from the Python lanes")
 
 
 async def run(options: Dict[str, object]) -> BinderServer:
@@ -222,6 +247,8 @@ async def run(options: Dict[str, object]) -> BinderServer:
         "LOG_LEVEL", "info"))))
     log_event(log, logging.INFO, "starting with options", options={
         k: v for k, v in options.items() if k != "store"})
+    if shard_worker is None:
+        warn_if_no_fastio(log)
 
     port = int(options["port"])
     collector = MetricsCollector(static_labels={

@@ -11,9 +11,12 @@ One UNIX ``socketpair`` per shard carries two ordered streams:
   :class:`~binder_tpu.shard.replica.ReplicaStore` reproduces the
   owner's mirror exactly — which is why a respawned shard catches up
   by simply reading from the top (snapshot + replay on attach).
-- worker -> supervisor: one ``hello`` after the serve stack is up
-  (pid + bound ports), then 1 Hz ``stats`` frames the supervisor folds
-  into the aggregated ``binder_shard_*`` metrics and ``/status``.
+- worker -> supervisor: ``progress`` frames while the worker builds
+  its mirror (nothing else moves on the link then, and the supervisor
+  bounds a start by absence of progress), one ``hello`` after the
+  serve stack is up (pid + bound ports), then 1 Hz ``stats`` frames
+  the supervisor folds into the aggregated ``binder_shard_*`` metrics
+  and ``/status``.
 
 Framing is 4-byte big-endian length + UTF-8 JSON.  Node data rides as
 the owner mirror's *parsed* JSON (re-serialized), not raw znode bytes:
@@ -115,6 +118,13 @@ def state_frame(state: str, connected: bool,
 
 def snap_end_frame(nodes: int) -> dict:
     return {"op": "snap-end", "nodes": nodes}
+
+
+def progress_frame() -> dict:
+    """Worker -> supervisor: the worker's mirror bound more names since
+    the last one — the sign of life between snap-end and hello, when
+    nothing else moves on the link.  Older supervisors ignore the op."""
+    return {"op": "progress"}
 
 
 def hello_frame(shard: int, pid: int, udp_port: int, tcp_port: int,

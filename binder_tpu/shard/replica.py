@@ -63,6 +63,8 @@ class ReplicaStore(FakeStore):
         self._writer_armed = False
         self.frames_applied = 0
         self.snapshot_nodes = 0
+        # when the supervisor last heard that the mirror is binding
+        self._progress_sent = 0.0
         # supervisor-reported disconnect age + local receipt instant:
         # disconnected_seconds() keeps aging between heartbeats
         self._sup_disc_s: Optional[float] = None
@@ -127,6 +129,17 @@ class ReplicaStore(FakeStore):
             raise ShardLinkDown("supervisor closed the mutation log")
         self._rbuf.extend(chunk)
         return protocol.decode_frames(self._rbuf)
+
+    def bind_node(self, path: str, node) -> None:
+        """The worker's mirror binds every replayed name once between
+        snap-end and hello — 17 s at a million names with nothing on
+        the link.  Report it (at most every 250 ms) so the supervisor
+        can tell a worker that is building from one that is wedged."""
+        now = time.monotonic()
+        if now - self._progress_sent >= 0.25:
+            self._progress_sent = now
+            self.send(protocol.progress_frame())
+        super().bind_node(path, node)
 
     # -- steady state: non-blocking delta feed on the event loop --
 

@@ -72,10 +72,6 @@ RESPAWN_BACKOFF_MAX_S = 5.0
 #: do its job (bounded memory beats an unbounded replay queue)
 MAX_LINK_BUFFER = 256 << 20
 
-#: rolling upgrade: a replacement worker must hello AND report a
-#: ready replica within this window, else the step aborts with the
-#: old worker still serving
-ROLL_CONVERGE_S = 30.0
 #: bounded graceful-drain window for the outgoing incarnation (it
 #: quiesces and exits on SIGTERM; stragglers are KILLed)
 ROLL_DRAIN_S = 10.0
@@ -90,7 +86,7 @@ class ShardLink:
                  "hello", "stats", "stats_at", "last_requests",
                  "last_rrl_dropped", "last_shed",
                  "spawned_mono", "rbuf", "closed",
-                 "snap_queue", "snap_sent", "snap_started",
+                 "snap_queue", "snap_sent", "progress_at",
                  "dg", "skew_pending")
 
     def __init__(self, shard: int, proc: subprocess.Popen,
@@ -114,12 +110,15 @@ class ShardLink:
         self.spawned_mono = time.monotonic()
         self.closed = False
         # chunked attach-time snapshot state: the walk queue of owner
-        # mirror nodes still to frame (None once snap-end was sent),
-        # frames sent so far, and the start instant for the stall
-        # backstop
+        # mirror nodes still to frame (None once snap-end was sent)
+        # and frames sent so far
         self.snap_queue: Optional[object] = None
         self.snap_sent = 0
-        self.snap_started = 0.0
+        # last sign of progress from this incarnation: snapshot frames
+        # moving to the link, then the worker's own progress/hello
+        # frames — what the stall backstop and the start/roll waits
+        # measure quiet time against
+        self.progress_at = self.spawned_mono
         # replica-parity digest (ISSUE 16): the owner-side rolling
         # digest over this link's post-snapshot delta stream (None
         # until snap-end), and the chaos `skew-replica` counter of
@@ -159,7 +158,6 @@ class ShardSupervisor:
         self._consec_fail: Dict[int, int] = {i: 0 for i in range(self.n)}
         self._respawn_at: Dict[int, float] = {}
         self._requests_total: Dict[int, float] = {}
-        self._hello_futs: Dict[int, asyncio.Future] = {}
         self._draining = False
         self._tick_task: Optional[asyncio.Task] = None
         self._tmpdir: Optional[str] = None
@@ -303,14 +301,13 @@ class ShardSupervisor:
         self._loop = asyncio.get_running_loop()
         self._tmpdir = tempfile.mkdtemp(prefix="binder-shards-")
         self._spawn(0, self.port)
-        hello = await self._wait_hello(0)
+        hello = await self._wait_started(0)
         self.udp_port = int(hello["udp_port"])
         self.tcp_port = int(hello["tcp_port"])
         for i in range(1, self.n):
             self._spawn(i, self.udp_port)
-        if self.n > 1:
-            await asyncio.gather(*[self._wait_hello(i)
-                                   for i in range(1, self.n)])
+        for i in range(1, self.n):
+            await self._wait_started(i)
         self._tick_task = self._loop.create_task(self._tick_loop())
         self.log.info("all %d shard(s) serving (pids %s)", self.n,
                       ",".join(str(self._pid(i)) for i in
@@ -323,17 +320,38 @@ class ShardSupervisor:
         self.log.info("TCP DNS service started on %s:%d", self.host,
                       self.tcp_port)
 
-    async def _wait_hello(self, i: int, timeout: float = 30.0,
-                          link: Optional[ShardLink] = None) -> dict:
-        link = self.links[i] if link is None else link
-        if link.hello is not None:
-            return link.hello
-        fut = self._loop.create_future()
-        self._hello_futs[i] = fut
-        try:
-            return await asyncio.wait_for(fut, timeout)
-        finally:
-            self._hello_futs.pop(i, None)
+    #: a starting worker (first spawn, or a roll's replacement) is
+    #: given up on after this long WITHOUT PROGRESS, never on a total:
+    #: a million-name attach takes over half a minute per worker.
+    #: Progress is the attach snapshot moving to the link, then the
+    #: worker's ``progress`` frames while it builds its mirror in
+    #: silence (``ReplicaStore.bind_node``), then its hello.
+    WORKER_QUIET_S = 30.0
+
+    async def _wait_converged(self, link: ShardLink,
+                              need_ready: bool = False) -> Optional[str]:
+        """Wait for *link*'s worker to say hello (and, for a roll's
+        replacement, to report a ready replica over the stats feed).
+        Returns None once it has, else why it was given up on: the
+        process exited, or nothing moved for ``WORKER_QUIET_S``.
+        Stats frames are not progress — a live worker whose replica
+        never turns ready must still run out of window."""
+        while True:
+            if link.hello is not None and (
+                    not need_ready or (link.stats or {}).get("ready")):
+                return None
+            if link.closed or link.proc.poll() is not None:
+                return "worker exited before converging"
+            quiet = time.monotonic() - link.progress_at
+            if quiet > self.WORKER_QUIET_S:
+                return f"no progress for {quiet:.1f}s"
+            await asyncio.sleep(0.05)
+
+    async def _wait_started(self, i: int) -> dict:
+        reason = await self._wait_converged(self.links[i])
+        if reason is not None:
+            raise TimeoutError(f"shard {i} failed to start: {reason}")
+        return self.links[i].hello
 
     def _worker_config(self, port: int) -> str:
         """Write the resolved worker config once per port draw.  The
@@ -492,7 +510,7 @@ class ShardSupervisor:
                 self._dcs_path + "/" + dc, self._dcs_records[dc]))
         link.snap_queue = deque()
         link.snap_sent = 0
-        link.snap_started = time.monotonic()
+        link.progress_at = time.monotonic()
         root = self.cache.nodes.get(self.cache.domain)
         if root is not None:
             link.snap_queue.append(root)
@@ -516,7 +534,7 @@ class ShardSupervisor:
             link.snap_sent += 1
             n += 1
         if n:
-            link.snap_started = time.monotonic()   # progress
+            link.progress_at = time.monotonic()
         self._flush(link)
         if link.closed or link.snap_queue is None:
             return                      # flush may have severed the link
@@ -692,16 +710,16 @@ class ShardSupervisor:
             return
         for frame in frames:
             op = frame.get("op")
-            if op == "hello":
+            if op == "progress":
+                link.progress_at = time.monotonic()
+            elif op == "hello":
                 link.hello = frame
+                link.progress_at = time.monotonic()
                 self._consec_fail[link.shard] = 0
                 self.log.info(
                     "shard %d serving: pid %d udp %s tcp %s metrics %s",
                     link.shard, frame.get("pid"), frame.get("udp_port"),
                     frame.get("tcp_port"), frame.get("metrics_port"))
-                fut = self._hello_futs.get(link.shard)
-                if fut is not None and not fut.done():
-                    fut.set_result(frame)
             elif op == "stats":
                 self._fold_stats(link, frame)
             elif op == "digest-report":
@@ -808,10 +826,10 @@ class ShardSupervisor:
         # snapshot do its job
         for link in self._fanout_links():
             if (link.snap_queue is not None and not link.closed
-                    and now - link.snap_started > self.SNAP_STALL_S):
+                    and now - link.progress_at > self.SNAP_STALL_S):
                 self.log.error("shard %d: snapshot stalled %.0fs; "
                                "killing for respawn", link.shard,
-                               now - link.snap_started)
+                               now - link.progress_at)
                 self._kill_link(link)
         for i in range(self.n):
             if i in self._roll_links:
@@ -974,26 +992,7 @@ class ShardSupervisor:
         repl = self._spawn_link(i, self.udp_port, role="replacement")
         self._roll_links[i] = repl
         try:
-            reason = None
-            try:
-                await self._wait_hello(i, timeout=ROLL_CONVERGE_S,
-                                       link=repl)
-            except asyncio.TimeoutError:
-                reason = f"no hello within {ROLL_CONVERGE_S:.0f}s"
-            if reason is None:
-                deadline = time.monotonic() + ROLL_CONVERGE_S
-                while True:
-                    if repl.closed or repl.proc.poll() is not None:
-                        reason = "replacement died during catch-up"
-                        break
-                    stats = repl.stats
-                    if stats is not None and stats.get("ready"):
-                        break
-                    if time.monotonic() >= deadline:
-                        reason = ("replica not ready within "
-                                  f"{ROLL_CONVERGE_S:.0f}s")
-                        break
-                    await asyncio.sleep(0.05)
+            reason = await self._wait_converged(repl, need_ready=True)
             if reason is not None:
                 self.roll_aborts += 1
                 self._m_roll_aborts.inc()

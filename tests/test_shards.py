@@ -25,6 +25,7 @@ the production process topology, not a simulation.
 import asyncio
 import json
 import os
+import signal
 import socket
 import sys
 import tempfile
@@ -435,18 +436,142 @@ class TestLargeSnapshotAttach:
         asyncio.run(run())
 
 
+def _spawn_stopped(sup):
+    """A ``_spawn_link`` whose worker is SIGSTOPped at birth: alive,
+    never exits, never moves — the quiet worker."""
+    spawn = sup._spawn_link
+
+    def spawn_stopped(i, port, role="serving"):
+        link = spawn(i, port, role=role)
+        os.kill(link.proc.pid, signal.SIGSTOP)
+        return link
+
+    return spawn_stopped
+
+
+class TestStartBoundedByProgress:
+    """ISSUE 21 repair: the supervisor's wait for a worker's hello is
+    bounded by absence of progress, not by a total — a fixed 30 s made
+    ``--shards N`` unstartable over a million-name zone."""
+
+    NAMES = 50000
+
+    def _supervisor(self, names: int):
+        from binder_tpu.metrics.collector import MetricsCollector
+        from binder_tpu.shard.supervisor import ShardSupervisor
+        from binder_tpu.store import FakeStore, MirrorCache
+        from binder_tpu.store.fake import populate_synthetic
+
+        store = FakeStore()
+        populate_synthetic(store, DOMAIN, names)
+        cache = MirrorCache(store, DOMAIN)
+        store.start_session()
+        return ShardSupervisor(
+            options={"shards": 1, "host": "127.0.0.1", "port": 0,
+                     "dnsDomain": DOMAIN, "queryLog": False},
+            store=store, cache=cache, collector=MetricsCollector())
+
+    def test_attach_longer_than_the_window_still_starts(self):
+        async def run():
+            sup = self._supervisor(self.NAMES)
+            # well under the attach's total; a small high-water keeps
+            # the unapplied tail (the one stretch neither end reports)
+            # short, so the gaps are the 250 ms progress cadence
+            sup.WORKER_QUIET_S = 0.75
+            sup.SNAP_HIGH_WATER = 256 << 10
+            try:
+                await sup.start()
+                link = sup.links[0]
+                took = link.progress_at - link.spawned_mono
+                assert took > sup.WORKER_QUIET_S, took
+                racks = max(1, min(1024, self.NAMES // 512))
+                i = self.NAMES - 1
+                data = await ask_fresh(
+                    sup.udp_port,
+                    f"h{i:06d}.r{i % racks:04d}.zs.{DOMAIN}", Type.A,
+                    qid=61)
+                assert Message.decode(data).answers[0].address == \
+                    f"10.{i >> 16}.{(i >> 8) & 255}.{i & 255}"
+            finally:
+                await sup.drain()
+
+        asyncio.run(run())
+
+    def test_quiet_worker_still_fails_the_start(self):
+        async def run():
+            sup = self._supervisor(8)
+            sup._spawn_link = _spawn_stopped(sup)
+            sup.WORKER_QUIET_S = 0.5
+            t0 = time.monotonic()
+            try:
+                with pytest.raises(TimeoutError, match="no progress"):
+                    await sup.start()
+                assert time.monotonic() - t0 < 5.0
+            finally:
+                for link in list(sup.links.values()):
+                    sup._kill_link(link)
+                await sup.drain()
+
+        asyncio.run(run())
+
+    def test_dead_worker_fails_the_start(self):
+        async def run():
+            sup = self._supervisor(8)
+            spawn = sup._spawn_link
+
+            def spawn_dead(i, port, role="serving"):
+                link = spawn(i, port, role=role)
+                link.proc.kill()
+                return link
+
+            sup._spawn_link = spawn_dead
+            try:
+                with pytest.raises(TimeoutError, match="exited"):
+                    await sup.start()
+            finally:
+                await sup.drain()
+
+        asyncio.run(run())
+
+
 class TestShardAuto:
-    def test_auto_resolves_to_core_count(self):
+    def test_auto_resolves_to_allowed_cores_capped(self, monkeypatch):
         from binder_tpu.config.options import parse_options
-        from binder_tpu.main import resolve_shard_count
+        from binder_tpu.main import MAX_AUTO_SHARDS, resolve_shard_count
         opts = parse_options(["--shards", "auto", "-f",
                               "etc/config.json"])
         assert opts["shards"] == "auto"
-        n = resolve_shard_count(opts)
-        assert n == (os.cpu_count() or 1) and n >= 1
+        # the cores this process may run on, not the machine's
+        assert resolve_shard_count(opts) == \
+            len(os.sched_getaffinity(0)) >= 1
+        monkeypatch.setattr(os, "sched_getaffinity",
+                            lambda pid: {4, 5})
+        monkeypatch.setattr(os, "cpu_count", lambda: 64)
+        assert resolve_shard_count(opts) == 2
+        # a very wide host stops at the reference's per-zone cap
+        monkeypatch.setattr(os, "sched_getaffinity",
+                            lambda pid: set(range(208)))
+        assert resolve_shard_count(opts) == MAX_AUTO_SHARDS == 32
         # explicit counts and the unset default pass through untouched
         assert resolve_shard_count({"shards": 3}) == 3
         assert resolve_shard_count({}) == 0
+
+
+class TestMissingExtensionWarning:
+    def test_startup_says_once_when_fastio_is_absent(self, monkeypatch,
+                                                     caplog):
+        import logging
+
+        from binder_tpu import main, server
+        log = logging.getLogger("binder.test.nofastio")
+        with caplog.at_level(logging.WARNING, logger=log.name):
+            main.warn_if_no_fastio(log)     # built: nothing to say
+            assert not caplog.records
+            monkeypatch.setattr(server, "_fastio", None)
+            main.warn_if_no_fastio(log)
+        assert [r.levelname for r in caplog.records] == ["WARNING"]
+        assert "_binderfastio" in caplog.text
+        assert "Python lanes" in caplog.text
 
 
 class TestChaosShardKill:
@@ -589,18 +714,15 @@ class TestRollingOps:
         asyncio.run(run())
 
     def test_roll_abort_keeps_incumbent_serving(self, tmp_path):
-        """A replacement that never reports hello aborts the roll with
-        the incumbent untouched — a bad build or config must not take
-        down a serving shard."""
+        """A replacement that goes quiet before hello aborts the roll
+        with the incumbent untouched — a bad build or config must not
+        take down a serving shard."""
         async def run():
             sup = await boot(str(tmp_path), 1)
             try:
                 pid0 = sup._pid(0)
-
-                async def no_hello(i, timeout=0.0, link=None):
-                    raise asyncio.TimeoutError
-
-                sup._wait_hello = no_hello
+                sup._spawn_link = _spawn_stopped(sup)
+                sup.WORKER_QUIET_S = 0.5
                 assert not await sup.roll_shard(0)
                 assert sup.roll_aborts == 1 and sup.rolls[0] == 0
                 assert sup._pid(0) == pid0

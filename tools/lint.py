@@ -1185,7 +1185,8 @@ def collect(paths):
 
 def main(argv):
     paths = argv or ["binder_tpu", "tests", "bin", "tools",
-                     "bench.py", "bench_impl.py", "__graft_entry__.py"]
+                     "bench.py", "bench_impl.py", "chip_smoke.py",
+                     "__graft_entry__.py"]
     files = collect(paths)
     if not files:
         print("lint: no files found", file=sys.stderr)
