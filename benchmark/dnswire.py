@@ -1,0 +1,102 @@
+"""The benchmark's own DNS wire codec: query encoding and answer decoding.
+
+Nothing here comes from ``binder_tpu``: what the benchmark sends and how it
+reads what came back must not move when the program's codec does.  RFC 1035
+messages, RFC 6891 OPT in queries; the record types the zone serves (A, PTR,
+SRV, SOA) are decoded, anything else is kept as raw rdata.
+"""
+import socket
+import struct
+
+A, PTR, SOA, SRV, OPT = 1, 12, 6, 33, 41
+QTYPES = {"A": A, "PTR": PTR, "SRV": SRV}
+NOERROR, SERVFAIL, NXDOMAIN, NOTIMP, REFUSED = 0, 2, 3, 4, 5
+
+
+def encode_name(name: str) -> bytes:
+    out = b""
+    for label in name.rstrip(".").split("."):
+        raw = label.encode("ascii")
+        if not 0 < len(raw) < 64:
+            raise ValueError(f"bad label in {name!r}")
+        out += bytes([len(raw)]) + raw
+    return out + b"\0"
+
+
+def make_query(name: str, qtype: int, qid: int = 0, rd: bool = False,
+               edns_payload=None) -> bytes:
+    """One question; an OPT record advertising *edns_payload* if given."""
+    flags = 0x0100 if rd else 0
+    wire = struct.pack(">HHHHHH", qid, flags, 1, 0, 0,
+                       1 if edns_payload else 0)
+    wire += encode_name(name) + struct.pack(">HH", qtype, 1)
+    if edns_payload:
+        wire += b"\0" + struct.pack(">HHIH", OPT, edns_payload, 0, 0)
+    return wire
+
+
+def _name(wire: bytes, off: int):
+    """(name, offset after it), following compression pointers."""
+    labels, end, hops = [], None, 0
+    while True:
+        n = wire[off]
+        if n & 0xC0 == 0xC0:
+            if end is None:
+                end = off + 2
+            off = ((n & 0x3F) << 8) | wire[off + 1]
+            hops += 1
+            if hops > 64:
+                raise ValueError("compression loop")
+        elif n == 0:
+            return ".".join(labels), (end if end is not None else off + 1)
+        else:
+            labels.append(wire[off + 1:off + 1 + n].decode("ascii"))
+            off += 1 + n
+
+
+class Answer:
+    """A decoded response: header fields and the three sections as lists
+    of ``(name, type, ttl, rdata)``; rdata is an address, a name, a
+    ``(priority, weight, port, target)`` tuple, or bytes."""
+
+    __slots__ = ("qid", "tc", "rcode", "question", "answers", "authorities",
+                 "additionals")
+
+    def __init__(self, wire: bytes) -> None:
+        (self.qid, flags, qd, an, ns, ar) = struct.unpack(">HHHHHH",
+                                                          wire[:12])
+        if not flags & 0x8000:
+            raise ValueError("not a response")
+        self.tc = bool(flags & 0x0200)
+        self.rcode = flags & 0x0F
+        off = 12
+        self.question = None
+        for _ in range(qd):
+            qname, off = _name(wire, off)
+            qtype, _qclass = struct.unpack(">HH", wire[off:off + 4])
+            off += 4
+            self.question = (qname.lower(), qtype)
+        sections = []
+        for count in (an, ns, ar):
+            recs = []
+            for _ in range(count):
+                name, off = _name(wire, off)
+                rtype, _rclass, ttl, rdlen = struct.unpack(
+                    ">HHIH", wire[off:off + 10])
+                off += 10
+                rdata = wire[off:off + rdlen]
+                if len(rdata) != rdlen:
+                    raise ValueError("rdata runs past the message")
+                if rtype == A and rdlen == 4:
+                    rdata = socket.inet_ntoa(rdata)
+                elif rtype == PTR:
+                    rdata = _name(wire, off)[0].lower()
+                elif rtype == SRV:
+                    prio, weight, port = struct.unpack(">HHH", rdata[:6])
+                    rdata = (prio, weight, port,
+                             _name(wire, off + 6)[0].lower())
+                off += rdlen
+                if rtype != OPT:
+                    recs.append((name.lower(), rtype, ttl, rdata))
+            sections.append(recs)
+        self.answers, self.authorities, self.additionals = sections

@@ -1,0 +1,761 @@
+#!/usr/bin/env python3
+"""The benchmark's one command: one cell, one seed, one measured window.
+
+    python3 benchmark/run.py --workload <name> --seed <n> --seconds <s>
+                             --trace <0|1>
+
+A new process each time, nothing outliving it.  In order: the device child
+names the chip (the run fails without one; ``--cpu`` is the rehearsal mode
+and prints its numbers as a rehearsal, never as a result); ``make`` builds
+``native/`` and the generator where they are not built yet; the zone, the
+store fixture, the query templates, their sequence and the arrival schedule
+are made from ``--seed``; ``python -m binder_tpu.main --shards N`` is
+spawned in ``etc/config.json``'s production posture and waited for until it
+is *settled*; a seeded sample of the cell's own questions is asked from
+fresh sockets and compared with ``reference.py``, the chaos plan's write is
+read back from every worker; the generator warms up and then drives the
+window; the answers it kept are compared with the reference, the sample and
+the written names are asked once more; SIGTERM must end the group with exit
+0 and no orphan.  The last line of stdout is the result object.
+
+Everything that belongs to one configuration, traffic mix or per-layer
+metric is a file found by name: ``configs/<name>.json``,
+``workloads/<name>.json``, ``layer_metrics/<name>.py`` (README.md).
+"""
+import argparse
+import fcntl
+import importlib.util
+import json
+import os
+import re
+import signal
+import socket
+import struct
+import subprocess
+import sys
+import sysconfig
+import threading
+import time
+import urllib.request
+
+T_START = time.monotonic()
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+sys.path.insert(0, HERE)
+
+import dnswire  # noqa: E402
+import stats  # noqa: E402
+from reference import Zone, compare, rng_for  # noqa: E402
+from traffic import Traffic  # noqa: E402
+
+READY_TIMEOUT_S = 240.0
+SETTLE_TIMEOUT_S = 240.0
+#: the correctness asks' source: outside the RRL allowlist, so an ordinary
+#: client's path (RRL judging it) is what gets checked
+ASK_SOURCE = "127.0.1.1"
+ASKS = 96                   # seeded sample asked before and after the window
+GENERATOR = os.path.join(HERE, "loadgen", "build", "dnsblast")
+BREAKS = ("reference-address", "fixture-address", "skew-replica")
+
+
+def fail(phase: str, why: str) -> None:
+    sys.exit(f"benchmark: FAILED in {phase}: {why}")
+
+
+def say(msg: str) -> None:
+    print(f"benchmark: {msg}", flush=True)
+
+
+def load_json(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+# -- the device child --
+
+class DeviceChild:
+    """``device.py`` in its own process: the only one that imports jax."""
+
+    def __init__(self, cpu: bool, traced: bool) -> None:
+        env = dict(os.environ)
+        if cpu:
+            env["JAX_PLATFORMS"] = "cpu"
+        self.proc = subprocess.Popen(
+            [sys.executable, "-u", os.path.join(HERE, "device.py"), ROOT,
+             "cpu" if cpu else "tpu", "1" if traced else "0"],
+            env=env, cwd=ROOT, text=True, stdin=subprocess.PIPE,
+            stdout=subprocess.PIPE)
+
+    def _line(self, timeout: float) -> dict:
+        box = []
+        reader = threading.Thread(
+            target=lambda: box.append(self.proc.stdout.readline()),
+            daemon=True)
+        reader.start()
+        reader.join(timeout)
+        if not box or not box[0].strip():
+            self.kill()
+            fail("device", "the device child named no device (exit "
+                 f"{self.proc.poll()})")
+        return json.loads(box[0])
+
+    def device(self) -> dict:
+        return self._line(600.0)
+
+    def tell(self, word: str) -> None:
+        self.proc.stdin.write(word + "\n")
+        self.proc.stdin.flush()
+
+    def traced_window(self) -> dict:
+        return self._line(120.0)
+
+    def finish(self) -> None:
+        try:
+            rc = self.proc.wait(timeout=60)
+        except subprocess.TimeoutExpired:
+            self.kill()
+            fail("device", "the device child did not exit")
+        if rc != 0:
+            fail("device", f"the device child exited {rc}")
+
+    def kill(self) -> None:
+        if self.proc.poll() is None:
+            self.proc.kill()
+            self.proc.wait()
+
+
+# -- build --
+
+def build(out_dir: str) -> None:
+    """``make`` (no -B): the first run in a checkout builds, later runs
+    find the build.  No Python fallback: a missing extension or generator
+    fails the run."""
+    jobs = str(len(os.sched_getaffinity(0)))
+    with open(os.path.join(out_dir, "build.log"), "wb") as log:
+        for target in (os.path.join(ROOT, "native"),
+                       os.path.join(HERE, "loadgen")):
+            rc = subprocess.call(["make", "-j", jobs, "-C", target],
+                                 stdout=log, stderr=subprocess.STDOUT)
+            if rc != 0:
+                fail("build", f"make -C {target} exited {rc} (see "
+                     f"{log.name})")
+    ext = os.path.join(ROOT, "binder_tpu", "_binderfastio"
+                       + sysconfig.get_config_var("EXT_SUFFIX"))
+    for path in (ext, GENERATOR):
+        if not os.path.exists(path):
+            fail("build", f"{path} was not built")
+
+
+# -- the server --
+
+class Server:
+    """The supervisor process and what its output says.  The production
+    posture logs every query, the native lanes' from C too: 15 MB a second
+    at the cell's rate, on the stdout all workers share.  A reader that
+    falls behind fills the pipe and every worker blocks in its next log
+    write; a file would grow by half a gigabyte a window.  So the pipe is
+    made as large as the kernel allows and drained by a ``grep`` process
+    of its own, which drops the query lines; only the announce and chaos
+    lines reach this (Python) process."""
+
+    def __init__(self, config: str, shards: int, out_dir: str) -> None:
+        self.log_path = os.path.join(out_dir, "server.log")
+        self.spawned = time.monotonic()
+        # -u: the announce lines must not sit in a block buffer; own
+        # session: one killpg reaches the workers whatever happens
+        self.proc = subprocess.Popen(
+            [sys.executable, "-u", "-m", "binder_tpu.main", "-f", config,
+             "--shards", str(shards)],
+            cwd=ROOT, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+            start_new_session=True)
+        try:
+            fcntl.fcntl(self.proc.stdout, fcntl.F_SETPIPE_SZ, 1 << 20)
+        except OSError:
+            pass                        # the default 64 KiB then
+        self.filter = subprocess.Popen(
+            ["grep", "--line-buffered", "-v", "-F", '"msg": "DNS query"'],
+            stdin=self.proc.stdout, stdout=subprocess.PIPE)
+        self.proc.stdout.close()        # the filter holds it now
+        self.control = []               # parsed log records
+        self._lock = threading.Lock()
+        self._thread = threading.Thread(target=self._drain, daemon=True)
+        self._thread.start()
+
+    def _drain(self) -> None:
+        with open(self.log_path, "wb") as log:
+            for line in self.filter.stdout:
+                log.write(line)
+                try:
+                    rec = json.loads(line)
+                except ValueError:
+                    continue            # a traceback line: in the file
+                with self._lock:
+                    self.control.append(rec)
+
+    def wait_msg(self, pattern: str, timeout: float, what: str):
+        rx = re.compile(pattern)
+        deadline = time.monotonic() + timeout
+        while True:
+            with self._lock:
+                for rec in self.control:
+                    m = rx.search(str(rec.get("msg", "")))
+                    if m:
+                        return m
+            if self.proc.poll() is not None:
+                fail("serve", f"server exited {self.proc.returncode} "
+                     f"while waiting for {what} (see {self.log_path})")
+            if time.monotonic() > deadline:
+                fail("serve", f"no {what} within {timeout:.0f}s "
+                     f"(see {self.log_path})")
+            time.sleep(0.02)
+
+    def kill_group(self) -> None:
+        try:
+            os.killpg(self.proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+        self.proc.wait()
+        if self.filter.poll() is None:
+            self.filter.kill()
+        self.filter.wait()
+
+
+def http_get(port: int, path: str) -> bytes:
+    with urllib.request.urlopen(f"http://127.0.0.1:{port}{path}",
+                                timeout=60) as r:
+        return r.read()
+
+
+def scrape(port: int) -> dict:
+    return {"metrics": http_get(port, "/metrics").decode(),
+            "status": json.loads(http_get(port, "/status"))}
+
+
+def cpu_seconds(pid: int) -> float:
+    """User plus system time of a process so far (/proc/<pid>/stat)."""
+    with open(f"/proc/{pid}/stat") as f:
+        fields = f.read().rsplit(")", 1)[1].split()
+    return (int(fields[11]) + int(fields[12])) / os.sysconf("SC_CLK_TCK")
+
+
+def scrape_all(mport: int, workers: list) -> dict:
+    return {"supervisor": scrape(mport), "at": time.monotonic(),
+            "workers": [dict(scrape(w["metrics_port"]), pid=w["pid"],
+                             shard=w["shard"], cpu_s=cpu_seconds(w["pid"]))
+                        for w in workers]}
+
+
+def wait_settled(workers: list, hosts: int) -> None:
+    """Settled: every worker's precompile seed drained, the native zone
+    table filled, and its gauge the same on two readings half a second
+    apart (``chip_smoke.py wait_settled``)."""
+    deadline = time.monotonic() + SETTLE_TIMEOUT_S
+    last = None
+    while True:
+        seen = [scrape(w["metrics_port"]) for w in workers]
+        entries = [int(stats.total(s["metrics"], "binder_zone_entries"))
+                   for s in seen]
+        left = [s["status"]["precompile"]["seed_remaining"] for s in seen]
+        if not min(entries) and last is not None:
+            fail("serve", "a worker has no native zone table: the Python "
+                 "fallback is serving")
+        if entries == last and min(entries) >= hosts and not any(left):
+            return
+        if time.monotonic() > deadline:
+            fail("serve", f"not settled after {SETTLE_TIMEOUT_S:.0f}s: "
+                 f"zone entries {entries}, seed remaining {left}")
+        last = entries
+        time.sleep(0.5)
+
+
+# -- asks from fresh sockets --
+
+def ask_udp(port: int, wire: bytes):
+    """One ask on a fresh socket, a new 4-tuple, so the reuseport hash
+    draws a worker afresh."""
+    sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+    try:
+        sock.bind((ASK_SOURCE, 0))
+        sock.connect(("127.0.0.1", port))
+        sock.settimeout(2.0)
+        for _ in range(3):
+            sock.send(wire)
+            try:
+                return sock.recv(65535)
+            except socket.timeout:
+                continue
+        fail("serve", "an ask got no answer in 3 tries")
+    finally:
+        sock.close()
+
+
+def ask_tcp(port: int, wire: bytes) -> bytes:
+    with socket.create_connection(("127.0.0.1", port), timeout=10) as s:
+        s.sendall(len(wire).to_bytes(2, "big") + wire)
+        buf = b""
+        while len(buf) < 2 or len(buf) < 2 + int.from_bytes(buf[:2], "big"):
+            chunk = s.recv(65536)
+            if not chunk:
+                fail("serve", "TCP connection closed mid-answer")
+            buf += chunk
+        return buf[2:]
+
+
+class Verdict:
+    """Every number compared, beside its limit; ``correct`` is all of
+    them inside their limits."""
+
+    def __init__(self) -> None:
+        self.rows = []
+        self.examples = []
+
+    def hold(self, name: str, value, limit) -> None:
+        self.rows.append((name, value, limit))
+
+    def wrong(self, what: str, problems: list) -> int:
+        if problems and len(self.examples) < 8:
+            self.examples.append(f"{what}: {'; '.join(problems)}")
+        return 1 if problems else 0
+
+    @property
+    def correct(self) -> bool:
+        return all(value <= limit for _, value, limit in self.rows)
+
+    def show(self) -> None:
+        for name, value, limit in self.rows:
+            say(f"compared {name} = {value} (limit {limit})"
+                + ("" if value <= limit else "  <-- outside"))
+        for line in self.examples:
+            say(f"  e.g. {line}")
+
+
+def requests_completed(workers: list) -> list:
+    """Each worker's own count of requests completed.  Between settle
+    and the window, and after it, the harness is the only client: what a
+    worker's count grows by is the asks that worker served."""
+    return [stats.total(http_get(w["metrics_port"], "/metrics").decode(),
+                        "binder_requests_completed") for w in workers]
+
+
+def ask_sample(udp: int, tcp: int, zone, questions: list,
+               verdict: Verdict) -> int:
+    """Ask each question from a fresh socket (TC=1 retried over TCP, as a
+    stub does) and compare with the reference; how many mismatched."""
+    bad = 0
+    for n, (qname, qtype, wire) in enumerate(questions):
+        wire = bytes([n >> 8, n & 255]) + wire[2:]
+        answer = dnswire.Answer(ask_udp(udp, wire))
+        want = zone.expected(qname, qtype)
+        problems = compare(answer, qname, qtype, want, whole=not answer.tc)
+        if answer.tc and not problems:
+            problems = compare(dnswire.Answer(ask_tcp(tcp, wire)), qname,
+                               qtype, want)
+        bad += verdict.wrong(f"ask {qname}/{qtype}", problems)
+    return bad
+
+
+def read_back(udp: int, zone, workers: list, verdict: Verdict) -> tuple:
+    """Read-your-writes: the written names asked from fresh sockets until
+    every worker has served some of the asks; with every answer right,
+    every worker gave the written answer.  (workers that served none,
+    mismatching answers, asks made)."""
+    names = sorted(zone.written)
+    start = requests_completed(workers)
+    served, bad, asked = [0] * len(workers), 0, 0
+    while min(served) < 1 and asked < 64 * len(workers):
+        for _ in range(8):
+            qname = names[asked % len(names)]
+            reply = ask_udp(udp, dnswire.make_query(qname, dnswire.A,
+                                                    qid=asked))
+            bad += verdict.wrong(f"written {qname}", compare(
+                dnswire.Answer(reply), qname, dnswire.A,
+                zone.expected(qname, dnswire.A)))
+            asked += 1
+        served = [now - was for now, was
+                  in zip(requests_completed(workers), start)]
+    return sum(1 for n in served if n < 1), bad, asked
+
+
+def check_captures(path: str, traffic, zone, verdict: Verdict) -> int:
+    """The answers the generator kept from the window, each against the
+    reference.  Returns how many were compared."""
+    with open(path, "rb") as f:
+        raw = f.read()
+    off = compared = bad = longest = 0
+    while off < len(raw):
+        _pos, tmpl, _tcp, length = struct.unpack_from("<IIBH", raw, off)
+        off += 11
+        wire = raw[off:off + length]
+        off += length
+        qname, qtype = traffic.questions[tmpl]
+        try:
+            answer = dnswire.Answer(wire)
+            problems = compare(answer, qname, qtype,
+                               zone.expected(qname, qtype))
+            longest = max(longest, len(answer.answers))
+        except (ValueError, IndexError, struct.error) as e:
+            problems = [f"undecodable answer: {e}"]
+        bad += verdict.wrong(f"window {qname}/{qtype}", problems)
+        compared += 1
+    verdict.hold("window_answers_mismatching", bad, 0)
+    say(f"window answers compared: {compared}, the longest with "
+        f"{longest} records")
+    return compared
+
+
+# -- per-layer metrics: one reader file each --
+
+def layer_readers() -> dict:
+    out = {}
+    directory = os.path.join(HERE, "layer_metrics")
+    for fname in sorted(os.listdir(directory)):
+        if not fname.endswith(".py"):
+            continue
+        spec = importlib.util.spec_from_file_location(
+            "layer_metric_" + fname[:-3].replace("-", "_"),
+            os.path.join(directory, fname))
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        out[fname[:-3]] = module
+    return out
+
+
+def layer_values(manifest: dict, cell: str, ctx: dict) -> dict:
+    """The traced run's metrics: every reader that finds something to
+    read; for a cell that ``BENCHMARK.json`` lists, only the metrics it
+    lists for that cell."""
+    listed = any(w["name"] == cell for w in manifest["workloads"])
+    declared = {m["name"]: m for m in manifest["per_layer"]}
+    metrics = {}
+    for name, module in layer_readers().items():
+        if listed and (name not in declared or cell not in
+                       declared[name].get("workloads", [cell])):
+            continue
+        value = module.read(ctx)
+        if value is not None:
+            metrics[name] = {"value": value, "unit": module.UNIT}
+    return metrics
+
+
+def stage_seconds(before: dict, after: dict) -> list:
+    """Host time by the program's own query stages over the window:
+    the breakdown's ``idle_gaps`` (what the host did while the device
+    idled), at most ten."""
+    sums = {}
+    for b, a in zip(before["workers"], after["workers"]):
+        was = {lab.get("stage"): v for lab, v in stats.samples(
+            b["metrics"], "binder_query_stage_seconds_sum")}
+        for lab, v in stats.samples(a["metrics"],
+                                    "binder_query_stage_seconds_sum"):
+            stage = lab.get("stage")
+            sums[stage] = sums.get(stage, 0.0) + v - was.get(stage, 0.0)
+    top = sorted(sums.items(), key=lambda kv: -kv[1])[:10]
+    return [[str(stage), seconds] for stage, seconds in top if seconds > 0]
+
+
+def generator_argv(workload: dict, files: dict, udp: int, seconds: float,
+                   captures: str, gen_out: str) -> list:
+    argv = [GENERATOR, "-p", str(udp), "-d", str(seconds),
+            "-W", str(workload["warm_s"]), "-T", str(workload["timeout_s"]),
+            "-S", str(workload["sources"]),
+            "-C", str(workload.get("callers", workload["threads"])),
+            "-j", str(workload["threads"]), "-c", captures, "-o", gen_out]
+    if workload.get("tc_retry"):
+        argv.append("-R")
+    for flag, path in files.items():
+        argv += [flag, path]
+    return argv
+
+
+def describe_window(g: dict) -> None:
+    """Say what the generator counted, with the sample count behind the
+    percentiles."""
+    lat, bits = g["latency_ns"], g["hist_bits"]
+    say(f"window {g['window_s']:.1f}s {g['loop']} loop: sent {g['sent']}, "
+        f"ok {g['ok']}, failed {g['failed']} {g['fails']}, TC retries "
+        f"{g['tc_retries']}, unanswered at the end "
+        f"{g['unanswered_at_end']}")
+    if not lat:
+        fail("window", "no query was answered in the window")
+    p50_us, p99_us = (stats.hist_percentile(lat, bits, q) / 1e3
+                      for q in (50, 99))
+    n = stats.hist_count(lat)
+    say(f"latency p50 {p50_us:.1f} us, p99 {p99_us:.1f} us over {n} "
+        f"samples ({n // 100} beyond the 99th percentile)")
+    if g["late_ns"]:
+        say("sends left late by p50 %.1f us, p99 %.1f us" % tuple(
+            stats.hist_percentile(g["late_ns"], bits, q) / 1e3
+            for q in (50, 99)))
+    if g["loop"] == "open" and len(g["inflight"]) >= 6:
+        third = len(g["inflight"]) // 3
+        say(f"in flight, mean of the window's first third "
+            f"{sum(g['inflight'][:third]) / third:.1f}, of its last third "
+            f"{sum(g['inflight'][-third:]) / third:.1f} (a backlog that "
+            "grows shows here)")
+
+
+# -- one run --
+
+def write_server_config(config: dict, zone, out_dir: str, broken) -> str:
+    cfg = load_json(os.path.join(ROOT, config["base_config"]))
+    fixture = zone.fixture()
+    if broken == "fixture-address":
+        # the program's data altered under it: one member of the service
+        # with the largest answer gets another address than the reference
+        # knows
+        service = max(zone.services, key=lambda s: len(s.members))
+        base = "/" + "/".join(reversed(zone.domain.split(".")))
+        node = fixture[f"{base}/{service.label}/{service.members[0][0]}"]
+        node[service.kind]["address"] = "10.255.255.254"
+    fixture_path = os.path.join(out_dir, "fixture.json")
+    with open(fixture_path, "w") as f:
+        json.dump(fixture, f)
+    cfg["store"] = {"backend": "fake", "fixture": fixture_path,
+                    "synthetic": {"hosts": zone.hosts,
+                                  "racks": int(config.get("racks") or 0),
+                                  "subtree": zone.subtree}}
+    cfg["port"] = 0
+    for key, value in config.get("posture_overrides", {}).items():
+        if isinstance(value, dict) and isinstance(cfg.get(key), dict):
+            cfg[key].update(value)
+        else:
+            cfg[key] = value
+    at = float(config["chaos"]["mutate_at_s"])
+    plan = f"at {at} watch-storm n={int(config['chaos']['writes'])}"
+    if broken == "skew-replica":
+        # the control: one worker is cut off from the mutation log just
+        # before the write, so read-your-writes does not hold on it
+        plan = f"at {at - 0.5} skew-replica shard=0 frames=1000; " + plan
+    cfg["chaos"] = {"plan": plan}
+    path = os.path.join(out_dir, "config.json")
+    with open(path, "w") as f:
+        json.dump(cfg, f, indent=1)
+    return path
+
+
+def run(args) -> int:
+    cells = os.path.join(ROOT, args.dir)
+    workload = load_json(os.path.join(cells, "workloads",
+                                      args.workload + ".json"))
+    config = load_json(os.path.join(cells, "configs",
+                                    workload["config"] + ".json"))
+    manifest = load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    out_dir = os.path.join(HERE, "out", args.workload)
+    os.makedirs(out_dir, exist_ok=True)
+    traced = args.trace == 1
+
+    child = DeviceChild(args.cpu, traced)
+    server = None
+    try:
+        build(out_dir)
+        domain = load_json(os.path.join(
+            ROOT, config["base_config"]))["dnsDomain"]
+        zone = Zone(config, domain, args.seed)
+        shards = int(config["shards"])
+        server = Server(write_server_config(config, zone, out_dir,
+                                            args.break_), shards, out_dir)
+        if args.break_ == "reference-address":
+            service = max(zone.services, key=lambda s: len(s.members))
+            service.members[0] = (service.members[0][0], "10.255.255.254")
+        # the traffic is made while the server starts
+        traffic = Traffic(workload, zone, args.seed, args.seconds)
+        files = traffic.write(out_dir)
+        say(f"traffic: {len(traffic.templates)} templates, "
+            f"{len(traffic.sequence)} sequence entries"
+            + (f", {len(traffic.arrivals)} arrivals"
+               if traffic.arrivals is not None else ""))
+
+        udp = int(server.wait_msg(
+            r"^UDP DNS service started on [\d.]+:(\d+)$", READY_TIMEOUT_S,
+            "announce line").group(1))
+        ready_s = time.monotonic() - server.spawned
+        tcp = int(server.wait_msg(
+            r"^TCP DNS service started on [\d.]+:(\d+)$", 10,
+            "TCP announce line").group(1))
+        mport = int(server.wait_msg(
+            r"^metrics server started on port (\d+)$", 10,
+            "metrics announce line").group(1))
+        verdict = Verdict()
+
+        # the write has not happened yet: its names are not served
+        before_write = 0
+        for qname in sorted(zone.written)[:2]:
+            before_write += verdict.wrong(
+                f"before the write {qname}", compare(
+                    dnswire.Answer(ask_udp(udp, dnswire.make_query(
+                        qname, dnswire.A, qid=1))), qname, dnswire.A,
+                    zone.expected(qname, dnswire.A)))
+        workers = json.loads(http_get(mport, "/status"))["shards"]["workers"]
+        pids = sorted(w["pid"] for w in workers)
+        if len(set(pids)) != shards:
+            fail("serve", f"{len(set(pids))} worker pids for {shards} shards")
+
+        wait_settled(workers, zone.hosts)
+        seed_s = time.monotonic() - server.spawned - ready_s
+        say(f"{shards} shards ready in {ready_s:.1f}s, settled "
+            f"{seed_s:.1f}s later")
+        server.wait_msg(r"^chaos: injected watch-storm",
+                        float(config["chaos"]["mutate_at_s"]) + 30,
+                        "chaos watch-storm")
+        zone.writes_done = True
+
+        # a seeded sample of the cell's own questions, before the window
+        picks = rng_for(args.seed, 6).choice(len(traffic.sequence),
+                                             size=ASKS, replace=False)
+        sample = []
+        for pos in picks:
+            tmpl = int(traffic.sequence[pos]) & 0x7FFFFFFF
+            sample.append(traffic.questions[tmpl]
+                          + (traffic.templates[tmpl][0],))
+
+        def asks_and_read_back() -> tuple:
+            start = requests_completed(workers)
+            bad = ask_sample(udp, tcp, zone, sample, verdict)
+            unseen = sum(1 for now, was in zip(requests_completed(workers),
+                                               start) if now <= was)
+            return (bad, unseen) + read_back(udp, zone, workers, verdict)
+
+        bad, unseen, silent, stale, asked = asks_and_read_back()
+        say(f"before the window: {ASKS} sampled asks, the write read back "
+            f"in {asked} asks")
+
+        device = child.device()
+        if not traced:
+            child.finish()
+        scrape_before = scrape_after = None
+        gen_out = os.path.join(out_dir, "generator.json")
+        captures = os.path.join(out_dir, "captures.bin")
+        argv = generator_argv(workload, files, udp, args.seconds,
+                              captures, gen_out)
+        setup_s = time.monotonic() - T_START + float(workload["warm_s"])
+        say(f"set-up {setup_s:.1f}s with the warm-up; the device child "
+            f"named {device['kind']}")
+        if traced:
+            # the first scrape is taken before the warm-up, so that it
+            # does not fall into the window (a scrape holds a worker's loop
+            # for tens of milliseconds): the deltas cover warm-up and window
+            scrape_before = scrape_all(mport, workers)
+        gen = subprocess.Popen(argv, cwd=out_dir)
+        try:
+            rc = gen.wait(timeout=args.seconds + 60)
+        finally:
+            if gen.poll() is None:
+                gen.kill()
+                gen.wait()
+        if rc != 0:
+            fail("window", f"the generator exited {rc}")
+        if traced:
+            child.tell("stop")
+            time.sleep(1.5)     # the workers report to the supervisor at 1 Hz
+            scrape_after = scrape_all(mport, workers)
+            device.update(child.traced_window())
+            child.finish()
+        g = load_json(gen_out)
+        if traced:
+            between = scrape_after["at"] - scrape_before["at"]
+            say("worker CPU between the scrapes, % of a core: " + ", ".join(
+                f"{100 * (a['cpu_s'] - b['cpu_s']) / between:.0f}"
+                for b, a in zip(scrape_before["workers"],
+                                scrape_after["workers"]))
+                + "; generator threads: " + ", ".join(
+                    f"{100 * (user + system) / g['window_s']:.0f}"
+                    for user, system in g["thread_cpu_s"]))
+
+        # after the window: the kept answers, the sample and the write again
+        compared = check_captures(captures, traffic, zone, verdict)
+        bad2, unseen2, silent2, stale2, _ = asks_and_read_back()
+        verdict.hold("asks_mismatching_before_window", bad, 0)
+        verdict.hold("asks_mismatching_after_window", bad2, 0)
+        verdict.hold("workers_not_seen_answering", max(unseen, unseen2), 0)
+        verdict.hold("written_names_served_before_the_write",
+                     before_write, 0)
+        verdict.hold("workers_not_serving_the_write",
+                     max(silent, silent2), 0)
+        verdict.hold("written_names_mismatching", stale + stale2, 0)
+        verdict.hold("window_answers_wrong_rcode_or_count",
+                     g["fails"]["rcode"] + g["fails"]["ancount"], 0)
+        verdict.hold("window_answers_not_compared",
+                     0 if compared >= min(
+                         200, int(workload["capture_answers"]) // 4) else 1,
+                     0)
+
+        # SIGTERM: the supervisor exits 0 and no worker survives
+        server.proc.send_signal(signal.SIGTERM)
+        try:
+            rc = server.proc.wait(timeout=60)
+        except subprocess.TimeoutExpired:
+            fail("serve", "supervisor ignored SIGTERM for 60s")
+        time.sleep(0.2)
+        orphans = [p for p in pids if os.path.exists(f"/proc/{p}")]
+        verdict.hold("supervisor_exit_code", rc, 0)
+        verdict.hold("orphan_processes", len(orphans), 0)
+    finally:
+        if server is not None:
+            server.kill_group()
+        child.kill()
+
+    if "jax" in sys.modules:
+        fail("summary", "the parent process imported jax")
+    say(f"whole run {time.monotonic() - T_START:.1f}s")
+    verdict.show()
+    describe_window(g)
+    values = {"setup_s": (setup_s, "s"),
+              "answers_per_s": (g["ok_in_window"] / g["window_s"],
+                                "answers/s")}
+    for q in (50, 90, 99):
+        values[f"p{q}_us"] = (stats.hist_percentile(
+            g["latency_ns"], g["hist_bits"], q) / 1e3, "us")
+    if traced:
+        metrics = layer_values(manifest, args.workload, {
+            "before": scrape_before, "after": scrape_after, "generator": g,
+            "workload": workload,
+            "harness": {"ready_s": ready_s, "seed_s": seed_s,
+                        "setup_s": setup_s}})
+    else:
+        metrics = {name: {"value": values[name][0], "unit": values[name][1]}
+                   for name in workload["end_to_end"]}
+    result = {"correct": verdict.correct, "attempted": g["sent"],
+              "failed": g["failed"] + g["unanswered_at_end"],
+              "metrics": metrics, "device": device}
+    if traced:
+        result["breakdown"] = {
+            "device_ops": device.pop("device_ops", []),
+            "idle_gaps": stage_seconds(scrape_before, scrape_after)}
+    if args.cpu:
+        # a rehearsal: no number of a CPU run goes out under a device
+        # metric's name, and no result line
+        say("REHEARSAL on the CPU (not a measurement): "
+            + json.dumps(result))
+        return 0 if verdict.correct else 1
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), required=True)
+    ap.add_argument("--cpu", action="store_true",
+                    help="rehearsal on the CPU platform: refused as a "
+                    "measurement, prints no result line")
+    ap.add_argument("--dir", default="benchmark",
+                    help="where configs/ and workloads/ are looked up "
+                    "(the tests keep a tiny cell of their own)")
+    ap.add_argument("--break", dest="break_", choices=BREAKS,
+                    help="a deliberately wrong run, for the tests and the "
+                    "control: correct must come out false")
+    args = ap.parse_args()
+    for needed in ("BENCHMARK.json", "binder_tpu/main.py", "native/Makefile",
+                   "etc/config.json", "__graft_entry__.py"):
+        if not os.path.exists(os.path.join(ROOT, needed)):
+            fail("start", f"{needed} is missing: not a binder checkout")
+    # a `timeout` or the driver ends us with SIGTERM: leave through the
+    # finally blocks, so the server group is killed too
+    signal.signal(signal.SIGTERM, lambda *_: fail("run", "got SIGTERM"))
+    sys.exit(run(args))
+
+
+if __name__ == "__main__":
+    main()

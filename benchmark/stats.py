@@ -1,0 +1,148 @@
+"""The benchmark's arithmetic: percentiles from the generator's histograms,
+sums and histogram deltas from Prometheus text, spreads of runs."""
+import re
+import statistics
+
+
+def bucket_bounds(idx: int, bits: int):
+    """[low, high) of one bucket of the generator's log-linear histogram:
+    values under 2**(bits+1) have a bucket each, above that every power of
+    two is cut into 2**bits buckets."""
+    if idx < (1 << (bits + 1)):
+        return idx, idx + 1
+    shift = (idx >> bits) - 1
+    low = (idx - (shift << bits)) << shift
+    return low, low + (1 << shift)
+
+
+def hist_count(hist: list) -> int:
+    return sum(count for _, count in hist)
+
+
+def hist_percentile(hist: list, bits: int, q: float) -> float:
+    """The q-th percentile (0-100) of ``[[bucket, count], ...]``, linear
+    inside the bucket that holds it; the rank is the nearest-rank one
+    (the smallest value with at least q% of the samples at or under it)."""
+    total = hist_count(hist)
+    if total == 0:
+        raise ValueError("percentile of an empty histogram")
+    rank = max(1.0, q / 100.0 * total)
+    seen = 0
+    for idx, count in sorted(hist):
+        if seen + count >= rank:
+            low, high = bucket_bounds(idx, bits)
+            return low + (high - low) * (rank - seen) / count
+        seen += count
+    raise AssertionError("unreachable")
+
+
+SAMPLE_RX = re.compile(
+    r"^([a-zA-Z_:][a-zA-Z0-9_:]*)(?:\{([^}]*)\})? ([0-9.eE+-]+|NaN)$", re.M)
+LABEL_RX = re.compile(r'([a-zA-Z_][a-zA-Z0-9_]*)="((?:[^"\\]|\\.)*)"')
+
+
+def samples(text: str, name: str) -> list:
+    """Every sample of one metric in Prometheus text, as
+    ``(labels dict, value)``."""
+    return [(dict(LABEL_RX.findall(m.group(2) or "")), float(m.group(3)))
+            for m in SAMPLE_RX.finditer(text) if m.group(1) == name]
+
+
+def total(text: str, name: str) -> float:
+    return sum(value for _, value in samples(text, name))
+
+
+def histogram_delta(before: str, after: str, name: str) -> list:
+    """``[(upper bound, count in the window), ...]`` of a Prometheus
+    histogram, summed over its other labels, from two scrapes."""
+    def buckets(text):
+        out = {}
+        for labels, value in samples(text, name + "_bucket"):
+            le = float(labels["le"].replace("+Inf", "inf"))
+            out[le] = out.get(le, 0.0) + value
+        return out
+
+    b, a = buckets(before), buckets(after)
+    cumulative = sorted((le, a[le] - b.get(le, 0.0)) for le in a)
+    out, prev = [], 0.0
+    for le, c in cumulative:
+        out.append((le, c - prev))
+        prev = c
+    return out
+
+
+def bucketed_percentile(buckets: list, q: float):
+    """Upper bound of the bucket that holds the q-th percentile (what a
+    fixed-bucket histogram can say), or None when it counted nothing."""
+    count = sum(c for _, c in buckets)
+    if count <= 0:
+        return None
+    rank, seen = q / 100.0 * count, 0.0
+    for le, c in buckets:
+        seen += c
+        if seen >= rank:
+            return le
+    return buckets[-1][0]
+
+
+def spread(values: list) -> float:
+    """Distance between the first and third quartile over the median."""
+    q1, _, q3 = statistics.quantiles(values, n=4)
+    return (q3 - q1) / statistics.median(values)
+
+
+# -- helpers for the per-layer readers (layer_metrics/*.py) --
+
+def window_delta(ctx: dict, name: str) -> list:
+    """Per worker, how much a counter of its own ``/metrics`` grew
+    between the two scrapes of a traced run."""
+    return [total(a["metrics"], name) - total(b["metrics"], name)
+            for b, a in zip(ctx["before"]["workers"],
+                            ctx["after"]["workers"])]
+
+
+def native_serve_percent(ctx: dict):
+    """Share of the window's answers that the C lanes gave: zone-table
+    serves plus the answer-cache hits that the Python AnswerCache
+    (``/status``) did not count itself, over requests completed.  The
+    subtraction is ``chip_smoke.py``'s, until the program splits the
+    counter."""
+    served = sum(window_delta(ctx, "binder_requests_completed"))
+    if served <= 0:
+        return None
+    native = sum(window_delta(ctx, "binder_zone_serves")) \
+        + sum(window_delta(ctx, "binder_answer_cache_hits")) \
+        - sum(a["status"]["answer_cache"]["hits"]
+              - b["status"]["answer_cache"]["hits"]
+              for b, a in zip(ctx["before"]["workers"],
+                              ctx["after"]["workers"]))
+    return 100.0 * native / served
+
+
+def shard_balance(ctx: dict):
+    """Least over greatest per-worker share of the requests between the
+    scrapes, from the supervisor's ``binder_shard_requests``."""
+    def by_shard(scrape):
+        return {labels.get("shard"): value for labels, value in samples(
+            scrape["supervisor"]["metrics"], "binder_shard_requests")}
+
+    before, after = by_shard(ctx["before"]), by_shard(ctx["after"])
+    grew = [after[shard] - before.get(shard, 0.0) for shard in after]
+    if not grew or max(grew) <= 0:
+        return None
+    return min(grew) / max(grew)
+
+
+def loop_lag_p99_ms(ctx: dict):
+    """Event-loop lag between the scrapes, 99th percentile on the worst
+    worker, from ``binder_loop_lag_seconds`` (a bucket's upper edge)."""
+    worst = None
+    for b, a in zip(ctx["before"]["workers"], ctx["after"]["workers"]):
+        buckets = histogram_delta(b["metrics"], a["metrics"],
+                                  "binder_loop_lag_seconds")
+        p99 = bucketed_percentile(buckets, 99)
+        if p99 == float("inf"):         # past the last edge: say that edge
+            p99 = max(le for le, _ in buckets if le != float("inf"))
+        if p99 is not None:
+            worst = p99 if worst is None else max(worst, p99)
+    return None if worst is None else worst * 1e3
