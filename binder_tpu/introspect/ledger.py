@@ -1,0 +1,128 @@
+"""The worker's time ledger: leaf spans of ``binder_query_stage_seconds``.
+
+The per-query stages (``QueryCtx.stamp``) name what a Python-lane query
+spent; they cover a few percent of a worker's second.  The ledger adds
+the rest as *leaf* spans, timed where the work happens, that overlap
+neither each other nor the per-query stages, so that their sums over a
+window add up to the worker's wall time less a remainder a reader can
+report as such:
+
+==============  =================  ====================================
+stage           observed per       where the clock is read
+==============  =================  ====================================
+``loop-idle``   ``select`` call    :class:`TimingSelector`, inside select
+``udp-recv``    ``recvmmsg`` call  C (``native/fastio``), EAGAIN included
+``native-serve``  batch            C, after recvmmsg to before sendmmsg
+``udp-send``    ``sendmmsg`` call  C
+``log-write``   ring drain         ``BinderServer._drain_native_log``
+``log-line``    logged query       ``BinderServer._on_after``
+==============  =================  ====================================
+
+Always on, like the stage histogram: no switch, option or environment
+variable.  Every span reads ``CLOCK_MONOTONIC``.  The Python spans
+observe straight into their stage's child of the histogram; the C spans
+accumulate sum, count and cells on the stage grid in ``fastio_io`` and
+are folded in by deltas at scrape, as the native per-qtype latency is
+(``HistogramChild.merge``).
+"""
+from __future__ import annotations
+
+import asyncio
+import selectors
+import threading
+import time
+from typing import Optional, Sequence
+
+from binder_tpu.metrics.collector import (DEFAULT_STAGE_BUCKETS,
+                                          HistogramChild)
+
+METRIC_STAGE_HISTOGRAM = "binder_query_stage_seconds"
+STAGE_HISTOGRAM_HELP = "per-stage decomposition of request processing time"
+
+#: the ledger's leaf stages (docs/observability.md); the per-query
+#: stages beside them are whatever ``QueryCtx.stamp`` was given
+LEAF_STAGES = ("loop-idle", "udp-recv", "native-serve", "udp-send",
+               "log-write", "log-line")
+
+
+def stage_child(collector, stage: str) -> HistogramChild:
+    """The stage histogram's child for one stage."""
+    return collector.histogram(
+        METRIC_STAGE_HISTOGRAM, STAGE_HISTOGRAM_HELP,
+        buckets=DEFAULT_STAGE_BUCKETS).labelled({"stage": stage})
+
+
+class SpanFold:
+    """Folds one monotone span source kept in C into its stage's child
+    by the delta since the last fold.  A source that stepped back (a
+    test's ``io_stats(True)``, a grid handed over anew) is taken as the
+    new baseline and that fold is skipped, never folded as negative."""
+
+    def __init__(self, collector, stage: str) -> None:
+        self.child = stage_child(collector, stage)
+        self.skipped = 0
+        self._cells: Sequence[int] = ()
+        self._sum = 0.0
+        self._lock = threading.Lock()   # scrapes run on their own threads
+
+    def fold(self, cells: Sequence[int], total: float) -> None:
+        with self._lock:
+            cells = list(cells)
+            last = self._cells or [0] * len(cells)
+            delta = [c - p for c, p in zip(cells, last)]
+            stepped_back = (len(last) != len(cells) or min(delta) < 0
+                            or total < self._sum)
+            self._cells, prev_sum, self._sum = cells, self._sum, total
+            if stepped_back:
+                self.skipped += 1
+            elif any(delta):
+                self.child.merge(delta, total - prev_sum)
+
+
+class TimingSelector(selectors.DefaultSelector):
+    """The loop's selector with the wait timed: ``loop-idle`` is the
+    time inside ``select()``, its count the ``epoll_wait`` calls.  A
+    loop is made before its process has a collector, so the waits are
+    timed from ``install_loop_idle`` on."""
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.observe = None     # the `loop-idle` child's, once installed
+
+    def select(self, timeout=None):
+        observe = self.observe
+        if observe is None:
+            return super().select(timeout)
+        t0 = time.monotonic()
+        try:
+            return super().select(timeout)
+        finally:
+            observe(time.monotonic() - t0)
+
+
+def timed_loop() -> asyncio.AbstractEventLoop:
+    """``loop_factory`` for ``asyncio.Runner``: a selector loop that
+    carries its timing selector as ``ledger_selector``."""
+    selector = TimingSelector()
+    loop = asyncio.SelectorEventLoop(selector)
+    loop.ledger_selector = selector
+    return loop
+
+
+def run(main):
+    """``asyncio.run(main)`` on a ``timed_loop`` (``asyncio.run`` takes
+    a ``loop_factory`` from Python 3.12 only, ``Runner`` from 3.11)."""
+    with asyncio.Runner(loop_factory=timed_loop) as runner:
+        return runner.run(main)
+
+
+def install_loop_idle(collector) -> Optional[HistogramChild]:
+    """Time the running loop's waits into ``collector``'s ``loop-idle``
+    (workers, the supervisor and the single process alike).  A loop
+    that ``timed_loop`` did not make has nothing to time."""
+    selector = getattr(asyncio.get_running_loop(), "ledger_selector", None)
+    if selector is None:
+        return None
+    child = stage_child(collector, "loop-idle")
+    selector.observe = child.observe
+    return child

@@ -132,6 +132,7 @@ class Introspector:
             "mirror": self._mirror_section(),
             "answer_cache": self._cache_section(),
             "tcp": self._tcp_section(),
+            "io": self._io_section(),
             "inflight": self._inflight_section(),
             "recursion": self._recursion_section(),
             "federation": self._federation_section(),
@@ -244,6 +245,15 @@ class Introspector:
                 "idle_timeouts": 0, "slow_reader_drops": 0,
                 "coalesced_writes": 0, "coalesced_frames": 0,
                 "half_closes": 0, "rst_drops": 0}
+
+    def _io_section(self) -> Optional[dict]:
+        """What the batched socket calls and the query log moved since
+        start (null without a server): the counts behind the time
+        ledger's ``udp-recv`` / ``udp-send`` / ``log-write`` /
+        ``log-line`` stages (docs/observability.md)."""
+        if self.server is None:
+            return None
+        return self.server.io_introspect()
 
     def _inflight_section(self) -> dict:
         queries = []

@@ -11,11 +11,19 @@ callback experienced in the same window.
 Samples land in the ``binder_loop_lag_seconds`` histogram; a sample
 over ``stall_threshold`` also fires a ``loop-stall`` flight-recorder
 event carrying the measured lag.
+
+A sample of ``STALL_RING_LAG`` or more is also kept with its instant on
+``CLOCK_MONOTONIC`` (``time.monotonic()``, the clock every process of
+the machine shares) in a ring of ``STALL_RING_SIZE``: a freeze of the
+whole sandbox hits every worker at the same instant and a pause of the
+program hits one, so a reader that lines the workers' rings up can
+tell them apart (``/status`` ``loop.stalls``).
 """
 from __future__ import annotations
 
 import asyncio
 import time
+from collections import deque
 from typing import Optional
 
 #: Lag grid: the loop's normal jitter is sub-millisecond; anything in
@@ -25,6 +33,10 @@ DEFAULT_LAG_BUCKETS = (
     1.0, 2.5, 5.0)
 
 METRIC_LOOP_LAG = "binder_loop_lag_seconds"
+
+#: a sample whose lag reaches this is kept with its instant
+STALL_RING_LAG = 0.05
+STALL_RING_SIZE = 256
 
 
 class LoopLagWatchdog:
@@ -39,6 +51,9 @@ class LoopLagWatchdog:
         self.last_lag = 0.0
         self.max_lag = 0.0
         self.last_sample_mono: Optional[float] = None
+        # (t_mono, lag_s) of the samples that reached STALL_RING_LAG;
+        # t_mono is when the late wakeup ran, the block's end
+        self.stall_ring: deque = deque(maxlen=STALL_RING_SIZE)
         self._task: Optional[asyncio.Task] = None
         self._hist_child = None
         if collector is not None:
@@ -47,10 +62,6 @@ class LoopLagWatchdog:
                 "event-loop scheduling lag sampled by the watchdog "
                 "(how late a timer callback ran)",
                 buckets=DEFAULT_LAG_BUCKETS).labelled()
-            collector.gauge(
-                "binder_loop_lag_max_seconds",
-                "largest event-loop lag observed since start"
-            ).set_function(lambda: self.max_lag)
 
     def start(self) -> None:
         if self._task is None:
@@ -77,6 +88,8 @@ class LoopLagWatchdog:
             self.max_lag = lag
         if self._hist_child is not None:
             self._hist_child.observe(lag)
+        if lag >= STALL_RING_LAG:
+            self.stall_ring.append((now, lag))
         if lag >= self.stall_threshold and self.recorder is not None:
             self.stalls += 1
             self.recorder.record("loop-stall", lag_s=round(lag, 6),
@@ -87,7 +100,9 @@ class LoopLagWatchdog:
             "interval_seconds": self.interval,
             "stall_threshold_seconds": self.stall_threshold,
             "samples": self.samples,
-            "stalls": self.stalls,
+            "stall_events": self.stalls,
+            "stalls": [{"t_mono": t, "lag_s": lag}
+                       for t, lag in list(self.stall_ring)],
             "last_lag_seconds": self.last_lag,
             "max_lag_seconds": self.max_lag,
         }

@@ -19,6 +19,7 @@ from typing import Dict
 from binder_tpu.config.options import ConfigError, parse_options
 from binder_tpu.introspect import (BalancerStatsFold, FlightRecorder,
                                    Introspector, LoopLagWatchdog)
+from binder_tpu.introspect import ledger
 from binder_tpu.metrics.collector import MetricsCollector, MetricsServer
 from binder_tpu.server import BinderServer
 from binder_tpu.store import FakeStore, MirrorCache
@@ -194,6 +195,7 @@ async def run_supervisor(options: Dict[str, object]):
 
     watchdog = LoopLagWatchdog(collector=collector, recorder=recorder)
     watchdog.start()
+    ledger.install_loop_idle(collector)    # the owner's loop has a ledger too
     recorder.install_sigusr2(loop, path=options.get("flightRecorderDump"))
     supervisor.watchdog = watchdog
     supervisor.metrics = metrics
@@ -464,6 +466,7 @@ async def run(options: Dict[str, object]) -> BinderServer:
     loop = asyncio.get_running_loop()
     watchdog = LoopLagWatchdog(collector=collector, recorder=recorder)
     watchdog.start()
+    ledger.install_loop_idle(collector)    # the time ledger's idle wait
     introspector = Introspector(server=server, recorder=recorder,
                                 watchdog=watchdog, collector=collector,
                                 name=NAME)
@@ -576,7 +579,9 @@ def main(argv=None) -> None:
         await asyncio.Event().wait()  # serve forever
 
     try:
-        asyncio.run(_run())
+        # the loop's selector times its own wait: the time ledger's
+        # `loop-idle` span (introspect/ledger.py), always on
+        ledger.run(_run())
     except KeyboardInterrupt:
         pass
     except ConfigError as e:

@@ -43,6 +43,11 @@ from binder_tpu.dns.wire import (
     patch_answer_wire,
     reverse_name_for_ip,
 )
+from binder_tpu.introspect.ledger import (
+    METRIC_STAGE_HISTOGRAM,
+    STAGE_HISTOGRAM_HELP,
+    SpanFold,
+)
 from binder_tpu.metrics.collector import (
     DEFAULT_SIZE_BUCKETS,
     DEFAULT_STAGE_BUCKETS,
@@ -64,12 +69,16 @@ from binder_tpu.verify import Verifier
 METRIC_REQUEST_COUNTER = "binder_requests_completed"
 METRIC_LATENCY_HISTOGRAM = "binder_request_latency_seconds"
 METRIC_SIZE_HISTOGRAM = "binder_response_size_bytes"
-# per-stage attribution: one histogram, labeled by stage, fed from the
-# QueryCtx phase stamps at after-hook time — the scrapeable form of the
-# query log's `timers` dict (same stage names)
-METRIC_STAGE_HISTOGRAM = "binder_query_stage_seconds"
+# per-stage attribution (METRIC_STAGE_HISTOGRAM): one histogram, labeled
+# by stage, fed from the QueryCtx phase stamps at after-hook time — the
+# scrapeable form of the query log's `timers` dict (same stage names) —
+# and from the time ledger's leaf spans (introspect/ledger.py)
 
 SLOW_QUERY_MS = 1000.0  # log at warn above this (lib/server.js:511-514)
+
+# binder_udp_batch_size's upper bounds: the log2 cells of
+# fastio_io.recv_cells (1, 2-3, 4-7, ..., >=128 as the +Inf cell)
+UDP_BATCH_BUCKETS = (1, 3, 7, 15, 31, 63, 127)
 
 # byte values a name label may contain for the native fast path; names
 # outside this set are still served, just never through the C cache
@@ -211,7 +220,14 @@ class BinderServer:
             intern=getattr(zk_cache, "canon", None))
         self.cache_hit_counter = self.collector.counter(
             "binder_answer_cache_hits", "encoded-answer cache hits")
-        self._cache_hit_child = self.cache_hit_counter.labelled()
+        # one child per tier: the Python lanes' hits are counted where
+        # they happen, the C lanes' are folded in at scrape
+        self._cache_hit_child = self.cache_hit_counter.labelled(
+            {"tier": "python"})
+        self._cache_hit_native_child = self.cache_hit_counter.labelled(
+            {"tier": "native"})
+        self._cache_hit_child.inc(0)        # both series from the start
+        self._cache_hit_native_child.inc(0)
         self._fp_inval_total = 0   # C-side drops, updated at each fold
         self.collector.gauge(
             "binder_answer_cache_invalidations",
@@ -228,14 +244,51 @@ class BinderServer:
             METRIC_SIZE_HISTOGRAM, "size in bytes of Binder responses",
             buckets=DEFAULT_SIZE_BUCKETS)
         self.stage_histogram = self.collector.histogram(
-            METRIC_STAGE_HISTOGRAM,
-            "per-stage decomposition of request processing time",
+            METRIC_STAGE_HISTOGRAM, STAGE_HISTOGRAM_HELP,
             buckets=DEFAULT_STAGE_BUCKETS)
         # per-qtype pre-resolved metric handles (label-sort once, not
         # per query); key is the numeric qtype
         self._metric_children: dict = {}
         # per-stage pre-resolved histogram handles, keyed by stage name
         self._stage_children: dict = {}
+
+        # The time ledger (introspect/ledger.py): the query log's two
+        # leaf spans are timed here, straight into their stage's
+        # child; the socket calls and the native serve loop are timed
+        # in C and fold into the stage histogram at scrape.
+        self._log_write_child = self.stage_histogram.labelled(
+            {"stage": "log-write"})
+        self._log_line_child = self.stage_histogram.labelled(
+            {"stage": "log-line"})
+        self._log_bytes = self.collector.counter(
+            "binder_query_log_bytes",
+            "bytes of query-log lines written (native ring drains and "
+            "Python-lane lines)")
+        self._log_bytes_child = self._log_bytes.labelled()
+        self._log_bytes_child.inc(0)
+        self._json_formatters = [h.formatter
+                                 for h in self._find_json_handlers()]
+        self._io_folds: dict = {}
+        self._io_folded: dict = {}
+        self._io_fold_lock = threading.Lock()
+        if _fastio is not None and hasattr(_fastio, "io_span_grid"):
+            _fastio.io_span_grid(
+                [float(b) for b in self.stage_histogram.buckets])
+            self._io_folds = {
+                stage: SpanFold(self.collector, stage)
+                for stage in ("udp-recv", "native-serve", "udp-send")}
+            datagrams = self.collector.counter(
+                "binder_udp_datagrams",
+                "UDP datagrams moved by the batched socket calls")
+            self._udp_in_child = datagrams.labelled({"dir": "in"})
+            self._udp_out_child = datagrams.labelled({"dir": "out"})
+            self._udp_in_child.inc(0)
+            self._udp_out_child.inc(0)
+            self._udp_batch_child = self.collector.histogram(
+                "binder_udp_batch_size",
+                "datagrams a recvmmsg call returned (calls that "
+                "returned any)", buckets=UDP_BATCH_BUCKETS).labelled()
+        self.collector.on_expose(self._fold_ledger)
 
         # USDT analog: provider 'binder', probes op-req-start/op-req-done
         # fired with the query context (lib/server.js:24-29,472-474,516-518)
@@ -657,7 +710,8 @@ class BinderServer:
                 query.response.rcode = wire[3] & 0x0F  # for metrics/logs
                 query.log_ctx["cached"] = True
                 query.cached_summary = (ans, add)
-                query.stamp("cache-hit")   # decode→probe→serve, whole hit
+                query.stamp("cache-hit")   # context→probe; the respond
+                # and the native promotion below land in `log-after`
                 query.respond_raw(wire)
                 # promote-on-first-hit: a repeat proves the name is hot,
                 # so hand the entry to the C fast path NOW (resolve-time
@@ -1939,7 +1993,7 @@ class BinderServer:
             last = self._fp_folded
             hits_delta = stats["hits"] - last.get("hits", 0)
             if hits_delta > 0:
-                self._cache_hit_child.inc(hits_delta)
+                self._cache_hit_native_child.inc(hits_delta)
             last["hits"] = stats["hits"]
             zone_delta = stats.get("zone_hits", 0) - last.get("zone_hits", 0)
             if zone_delta > 0:
@@ -1961,6 +2015,56 @@ class BinderServer:
                          for i, c in enumerate(s["size_cells"])],
                         s["size_sum"] - (prev["size_sum"] if prev else 0.0))
                 last[qtype] = s
+
+    def _fold_ledger(self) -> None:
+        """Fold the time ledger's C spans and the socket counters
+        (``_fastio.io_stats``, process-wide) into the collectors, by
+        deltas against the last fold.  A counter that stepped back (a
+        test's ``io_stats(True)``) restarts the baseline and is skipped,
+        never folded as negative."""
+        if not self._io_folds:
+            return
+        with self._io_fold_lock:
+            io = _fastio.io_stats()
+            for stage, fold in self._io_folds.items():
+                span = io["spans"][stage]
+                fold.fold(span["cells"], span["sum"])
+            last = self._io_folded
+            self._io_folded = io
+            cells = [c - p for c, p in zip(
+                io["recv_cells"],
+                last.get("recv_cells") or [0] * len(io["recv_cells"]))]
+            msgs_in = io["recv_msgs"] - last.get("recv_msgs", 0)
+            msgs_out = io["send_msgs"] - last.get("send_msgs", 0)
+            if min(cells) < 0 or msgs_in < 0 or msgs_out < 0:
+                return
+            self._udp_in_child.inc(msgs_in)
+            self._udp_out_child.inc(msgs_out)
+            self._udp_batch_child.merge(cells, msgs_in)
+
+    def io_introspect(self) -> dict:
+        """The ``io`` section of ``/status``: what the socket calls and
+        the query log moved since the process started, beside the
+        ledger's spans on ``/metrics`` (docs/observability.md)."""
+        out = {"log_writes": self.stage_histogram.count(
+                   {"stage": "log-write"}),
+               "log_lines": self.stage_histogram.count(
+                   {"stage": "log-line"}),
+               "log_bytes": int(self._log_bytes.value()),
+               "recv_calls": 0, "recv_empty": 0, "recv_datagrams": 0,
+               "recv_batch_cells": [0] * (len(UDP_BATCH_BUCKETS) + 1),
+               "send_calls": 0, "send_datagrams": 0}
+        if self._io_folds:
+            io = _fastio.io_stats()
+            out.update(
+                recv_calls=io["spans"]["udp-recv"]["count"],
+                recv_empty=(io["spans"]["udp-recv"]["count"]
+                            - io["recv_calls"]),
+                recv_datagrams=io["recv_msgs"],
+                recv_batch_cells=io["recv_cells"],
+                send_calls=io["spans"]["udp-send"]["count"],
+                send_datagrams=io["send_msgs"])
+        return out
 
     def _children_for(self, qtype: int):
         """Pre-resolved (counter, latency, size) metric handles for a
@@ -2047,6 +2151,7 @@ class BinderServer:
         if not block:
             return
         text = None
+        t0 = time.monotonic()
         for h in self._log_json_handlers:
             try:
                 h.acquire()
@@ -2073,10 +2178,12 @@ class BinderServer:
                             text = block.decode("utf-8", "replace")
                         h.stream.write(text)
                         h.flush()
+                    self._log_bytes_child.inc(len(block))
                 finally:
                     h.release()
             except Exception:
                 pass   # a dead log sink must never take down serving
+        self._log_write_child.observe(time.monotonic() - t0)
 
     async def _log_flush_loop(self) -> None:
         try:
@@ -2129,6 +2236,12 @@ class BinderServer:
             ans = [self._summarize(r) for r in query.response.answers]
             add = [self._summarize(r) for r in query.response.additionals
                    if not isinstance(r, OPTRecord)]
+        # log-line: the line's format and its write + flush, timed
+        # around the call; the line is out by the time it is known, so
+        # it goes to the histogram only, not into `timers`
+        fmts = self._json_formatters
+        wrote = sum(f.bytes_out for f in fmts)
+        t0 = time.monotonic()
         log_event(
             self.log, level, "DNS query",
             # request envelope built here, not per-query in _on_query:
@@ -2146,6 +2259,8 @@ class BinderServer:
             latency=lat_ms,
             timers=query.times,
         )
+        self._log_line_child.observe(time.monotonic() - t0)
+        self._log_bytes_child.inc(sum(f.bytes_out for f in fmts) - wrote)
 
     def _summarize(self, rec) -> object:
         if isinstance(rec, SRVRecord):

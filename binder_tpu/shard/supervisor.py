@@ -137,6 +137,7 @@ class ShardSupervisor:
         self.cache = cache
         self.collector = collector
         self.recorder = recorder
+        self.watchdog = None    # set by main.py once the loop-lag task runs
         self.log = log or logging.getLogger("binder.shard")
         self.name = name
         self.n = max(1, int(options.get("shards") or 1))
@@ -1164,5 +1165,9 @@ class ShardSupervisor:
                 "digest_violations": self.digest_violations,
                 "workers": workers,
             },
+            # the owner's own loop: its ring of stall instants lines
+            # up against the workers' on the shared monotonic clock
+            "loop": (self.watchdog.snapshot()
+                     if self.watchdog is not None else None),
             "flight_recorder": intro._recorder_section(),
         }

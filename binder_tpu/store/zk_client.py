@@ -127,10 +127,6 @@ class ZKClient(SessionStateMixin, StoreClient):
                 "binder_zk_connected",
                 "1 while the ZooKeeper session is live"
             ).set_function(lambda: 1.0 if self._connected else 0.0)
-            collector.gauge(
-                "binder_zk_outstanding_requests",
-                "requests awaiting a ZooKeeper response"
-            ).set_function(lambda: len(self._pending))
 
         self._session_cbs: List[Callable[[], None]] = []
         self._watchers: Dict[str, _ZKWatcher] = {}

@@ -35,6 +35,9 @@ class JsonFormatter(logging.Formatter):
         super().__init__()
         self.name = name
         self.hostname = socket.gethostname()
+        #: bytes of the lines formatted so far, newlines included (the
+        #: JSON is ASCII); read by the server's binder_query_log_bytes
+        self.bytes_out = 0
 
     def format(self, record: logging.LogRecord) -> str:
         entry = {
@@ -57,7 +60,9 @@ class JsonFormatter(logging.Formatter):
                 "name": record.exc_info[0].__name__,
                 "message": str(record.exc_info[1]),
             }
-        return json.dumps(entry, default=str)
+        line = json.dumps(entry, default=str)
+        self.bytes_out += len(line) + 1
+        return line
 
 
 def make_logger(name: str = "binder", level: str = "info",

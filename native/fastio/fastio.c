@@ -36,12 +36,30 @@
 #include <netinet/in.h>
 #include <string.h>
 #include <sys/socket.h>
+#include <time.h>
 
 #include "fastpath.h"
 
 unsigned char fastio_shared_bufs[FASTIO_BATCH][FASTIO_DGRAM_MAX];
 
 fastio_io_t fastio_io;
+double fastio_span_grid[FASTIO_SPAN_MAX_BUCKETS];
+int fastio_span_grid_n;
+
+/* CLOCK_MONOTONIC in seconds: fp_now() of fpcore.h, which this file
+ * does not include */
+static inline double
+fastio_now(void)
+{
+    struct timespec ts;
+    clock_gettime(CLOCK_MONOTONIC, &ts);
+    return (double)ts.tv_sec + (double)ts.tv_nsec * 1e-9;
+}
+
+/* stage label of each span, in the enum's order */
+static const char *const fastio_span_names[FASTIO_N_SPANS] = {
+    "udp-recv", "native-serve", "udp-send",
+};
 
 PyObject *
 fastio_addr_to_tuple(const struct sockaddr_storage *ss)
@@ -142,7 +160,12 @@ fastio_recv_batch(PyObject *self, PyObject *args)
         msgs[i].msg_hdr.msg_namelen = sizeof(addrs[i]);
     }
 
+    double t0 = fastio_now();
     int n = recvmmsg(fd, msgs, (unsigned)max_n, MSG_DONTWAIT, NULL);
+    int recv_errno = errno;
+    /* an empty-handed call costs the same kernel crossing: it counts */
+    fastio_span_note(FASTIO_SPAN_RECV, fastio_now() - t0);
+    errno = recv_errno;
 
     if (n < 0) {
         if (errno == EAGAIN || errno == EWOULDBLOCK || errno == EINTR)
@@ -230,11 +253,16 @@ fastio_send_batch(PyObject *self, PyObject *args)
         int off = 0;
         int blocked = 0;
         while (off < n) {
-            int sent;
+            int sent, send_errno;
+            double t0 = fastio_now(), t1;
             Py_BEGIN_ALLOW_THREADS
             sent = sendmmsg(fd, msgs + off, (unsigned)(n - off),
                             MSG_DONTWAIT);
+            send_errno = errno;
+            t1 = fastio_now();
             Py_END_ALLOW_THREADS
+            fastio_span_note(FASTIO_SPAN_SEND, t1 - t0);
+            errno = send_errno;
             if (sent >= 0) {
                 /* a short count means msgs[off+sent] hit an error; the
                  * next pass re-sends from there and classifies it */
@@ -274,6 +302,23 @@ fail:
 }
 
 static PyObject *
+fastio_cells_list(const unsigned long long *cells, int n)
+{
+    PyObject *out = PyList_New(n);
+    if (out == NULL)
+        return NULL;
+    for (int i = 0; i < n; i++) {
+        PyObject *v = PyLong_FromUnsignedLongLong(cells[i]);
+        if (v == NULL) {
+            Py_DECREF(out);
+            return NULL;
+        }
+        PyList_SET_ITEM(out, i, v);
+    }
+    return out;
+}
+
+static PyObject *
 fastio_io_stats(PyObject *self, PyObject *args)
 {
     int reset = 0;
@@ -281,29 +326,87 @@ fastio_io_stats(PyObject *self, PyObject *args)
 
     if (!PyArg_ParseTuple(args, "|p", &reset))
         return NULL;
-    PyObject *cells = PyList_New(FASTIO_IO_CELLS);
-    if (cells == NULL)
+    PyObject *spans = PyDict_New();
+    if (spans == NULL)
         return NULL;
-    for (int i = 0; i < FASTIO_IO_CELLS; i++) {
-        PyObject *v = PyLong_FromUnsignedLongLong(fastio_io.recv_cells[i]);
-        if (v == NULL) {
-            Py_DECREF(cells);
+    for (int i = 0; i < FASTIO_N_SPANS; i++) {
+        const fastio_span_t *sp = &fastio_io.spans[i];
+        PyObject *cells = fastio_cells_list(sp->cells,
+                                            fastio_span_grid_n + 1);
+        PyObject *d = cells == NULL ? NULL : Py_BuildValue(
+            "{s:d,s:K,s:N}", "sum", sp->sum, "count", sp->count,
+            "cells", cells);
+        int rc = d == NULL ? -1
+            : PyDict_SetItemString(spans, fastio_span_names[i], d);
+        Py_XDECREF(d);
+        if (rc < 0) {
+            Py_DECREF(spans);
             return NULL;
         }
-        PyList_SET_ITEM(cells, i, v);
+    }
+    PyObject *cells = fastio_cells_list(fastio_io.recv_cells,
+                                        FASTIO_IO_CELLS);
+    if (cells == NULL) {
+        Py_DECREF(spans);
+        return NULL;
     }
     PyObject *d = Py_BuildValue(
-        "{s:K,s:K,s:K,s:K,s:N}",
+        "{s:K,s:K,s:K,s:K,s:N,s:N}",
         "recv_calls", fastio_io.recv_calls,
         "recv_msgs", fastio_io.recv_msgs,
         "send_calls", fastio_io.send_calls,
         "send_msgs", fastio_io.send_msgs,
-        "recv_cells", cells);
+        "recv_cells", cells,
+        "spans", spans);
     if (d == NULL)
         return NULL;
     if (reset)
         memset(&fastio_io, 0, sizeof(fastio_io));
     return d;
+}
+
+static PyObject *
+fastio_io_span_grid(PyObject *self, PyObject *args)
+{
+    PyObject *seq;
+    (void)self;
+
+    if (!PyArg_ParseTuple(args, "O", &seq))
+        return NULL;
+    PyObject *fast = PySequence_Fast(seq, "grid must be a sequence");
+    if (fast == NULL)
+        return NULL;
+    Py_ssize_t n = PySequence_Fast_GET_SIZE(fast);
+    double grid[FASTIO_SPAN_MAX_BUCKETS];
+    if (n > FASTIO_SPAN_MAX_BUCKETS) {
+        Py_DECREF(fast);
+        PyErr_Format(PyExc_ValueError, "too many span buckets (max %d)",
+                     FASTIO_SPAN_MAX_BUCKETS);
+        return NULL;
+    }
+    for (Py_ssize_t i = 0; i < n; i++) {
+        grid[i] = PyFloat_AsDouble(PySequence_Fast_GET_ITEM(fast, i));
+        if ((grid[i] == -1.0 && PyErr_Occurred())
+                || (i > 0 && grid[i] <= grid[i - 1])) {
+            Py_DECREF(fast);
+            if (!PyErr_Occurred())
+                PyErr_SetString(PyExc_ValueError,
+                                "span buckets must be strictly "
+                                "increasing");
+            return NULL;
+        }
+    }
+    Py_DECREF(fast);
+    /* every server of a process hands over the same grid; only a
+     * different one restarts the cells (a fold skips the step back) */
+    if ((int)n != fastio_span_grid_n
+            || memcmp(grid, fastio_span_grid,
+                      (size_t)n * sizeof(double)) != 0) {
+        memcpy(fastio_span_grid, grid, (size_t)n * sizeof(double));
+        fastio_span_grid_n = (int)n;
+        memset(fastio_io.spans, 0, sizeof(fastio_io.spans));
+    }
+    Py_RETURN_NONE;
 }
 
 static PyMethodDef fastio_methods[] = {
@@ -313,8 +416,12 @@ static PyMethodDef fastio_methods[] = {
      "send_batch(fd, msgs) -> int sent"},
     {"io_stats", fastio_io_stats, METH_VARARGS,
      "io_stats(reset=False) -> dict of process-wide batched-I/O "
-     "counters (recvmmsg/sendmmsg calls, messages, and the recvmmsg "
-     "batch-size log2 histogram)"},
+     "counters (recvmmsg/sendmmsg calls, messages, the recvmmsg "
+     "batch-size log2 histogram) and the time ledger's spans "
+     "(udp-recv, native-serve, udp-send: sum, count, cells)"},
+    {"io_span_grid", fastio_io_span_grid, METH_VARARGS,
+     "io_span_grid(buckets) -> None; the stage histogram's upper "
+     "bounds, handed over once at start"},
     {"fastpath_new", fastpath_new, METH_VARARGS,
      "fastpath_new(size, expiry_ms, lat_buckets, size_buckets) -> capsule"},
     {"fastpath_put", fastpath_put, METH_VARARGS,

@@ -547,8 +547,12 @@ bal_flush(int fd, struct mmsghdr *omsgs, int n_hits)
 {
     int off = 0;
     while (off < n_hits) {
+        double t0 = fp_now();
         int sent = sendmmsg(fd, omsgs + off, (unsigned)(n_hits - off),
                             MSG_DONTWAIT);
+        int send_errno = errno;
+        fastio_span_note(FASTIO_SPAN_SEND, fp_now() - t0);
+        errno = send_errno;
         if (sent >= 0) {
             fastio_io_note_send(sent);
             off += sent > 0 ? sent : 1;
@@ -780,8 +784,16 @@ fastpath_drain(PyObject *self, PyObject *args)
         msgs[i].msg_hdr.msg_namelen = sizeof(addrs[i]);
     }
 
+    /* the time ledger's three leaf spans of a batch: udp-recv is the
+     * recvmmsg (an EAGAIN costs the same crossing and counts),
+     * native-serve runs from there to the flush, udp-send is each
+     * sendmmsg; the clock is read where one ends and the next begins */
     double t0 = fp_now();
     int n = recvmmsg(fd, msgs, (unsigned)max_n, MSG_DONTWAIT, NULL);
+    int recv_errno = errno;
+    double t_recv = fp_now();
+    fastio_span_note(FASTIO_SPAN_RECV, t_recv - t0);
+    errno = recv_errno;
     if (n < 0) {
         if (errno == EAGAIN || errno == EWOULDBLOCK || errno == EINTR) {
             PyObject *empty = PyList_New(0);
@@ -863,13 +875,24 @@ fastpath_drain(PyObject *self, PyObject *args)
         batch_qtype_counts[(int)(qs - c->qstats)]++;
     }
 
+    double t_sent = t_recv;
+    if (n > 0) {
+        t_sent = fp_now();
+        fastio_span_note(FASTIO_SPAN_SERVE, t_sent - t_recv);
+    }
+
     /* flush hits; per-destination errors skip one datagram and continue
      * (same policy as send_batch — one unreachable client must not drop
      * other clients' responses) */
     int off = 0;
     while (off < n_hits) {
+        double t_send = t_sent;     /* where the last span ended */
         int sent = sendmmsg(fd, omsgs + off, (unsigned)(n_hits - off),
                             MSG_DONTWAIT);
+        int send_errno = errno;
+        t_sent = fp_now();
+        fastio_span_note(FASTIO_SPAN_SEND, t_sent - t_send);
+        errno = send_errno;
         if (sent >= 0) {
             fastio_io_note_send(sent);
             off += sent > 0 ? sent : 1;
@@ -890,7 +913,7 @@ fastpath_drain(PyObject *self, PyObject *args)
     /* latency: the whole batch window, attributed to each hit — an
      * upper bound (a hit waited at most recv..send of its batch) */
     if (n_hits > 0) {
-        double elapsed = fp_now() - t0;
+        double elapsed = t_sent - t0;
         int li = fp_bucket_index(c->lat_buckets, c->n_lat_buckets,
                                  elapsed);
         for (int s = 0; s < FP_MAX_QTYPES; s++) {

@@ -17,19 +17,59 @@
 PyObject *fastio_addr_to_tuple(const struct sockaddr_storage *ss);
 
 /* Process-wide I/O accounting shared by every batched entry point
- * (recv_batch, send_batch, fastpath_drain, fastpath_serve_balancer).
+ * (recv_batch, send_batch, fastpath_drain, fastpath_serve_balancer),
+ * read by BinderServer's scrape fold (binder_udp_datagrams,
+ * binder_udp_batch_size) and /status `io`.
  * The batch-size histogram is the observable for "sampling must not
  * defeat batching": if the duty-cycle sampler serialized the drain,
  * every cell above recv_cells[0] would empty out. */
 #define FASTIO_IO_CELLS 8   /* log2 cells: 1, 2-3, 4-7, ..., >=128 */
+
+/* The worker's time ledger, C half: leaf spans of
+ * binder_query_stage_seconds timed where the work happens (stage names
+ * in fastio_span_names, fastio.c).  Each keeps sum, count and
+ * non-cumulative cells on the stage grid Python hands over once at
+ * start (io_span_grid); the scrape folds them in by deltas like the
+ * per-qtype latency.  All on CLOCK_MONOTONIC. */
+#define FASTIO_SPAN_MAX_BUCKETS 24
+enum {
+    FASTIO_SPAN_RECV = 0,   /* udp-recv: one recvmmsg, EAGAIN included */
+    FASTIO_SPAN_SERVE,      /* native-serve: after recvmmsg to before
+                             * sendmmsg, per batch with a datagram */
+    FASTIO_SPAN_SEND,       /* udp-send: one sendmmsg */
+    FASTIO_N_SPANS
+};
+typedef struct {
+    double sum;
+    unsigned long long count;
+    unsigned long long cells[FASTIO_SPAN_MAX_BUCKETS + 1];
+} fastio_span_t;
+
 typedef struct {
     unsigned long long recv_calls;   /* recvmmsg calls that returned >0 */
     unsigned long long recv_msgs;
     unsigned long long recv_cells[FASTIO_IO_CELLS];
     unsigned long long send_calls;   /* sendmmsg calls that sent >0 */
     unsigned long long send_msgs;
+    fastio_span_t spans[FASTIO_N_SPANS];
 } fastio_io_t;
 extern fastio_io_t fastio_io;
+/* the stage grid outlives io_stats(reset=True) */
+extern double fastio_span_grid[FASTIO_SPAN_MAX_BUCKETS];
+extern int fastio_span_grid_n;
+
+static inline void
+fastio_span_note(int which, double seconds)
+{
+    fastio_span_t *s = &fastio_io.spans[which];
+    /* first bound >= v, the +Inf cell last: collector.py's bisect_left */
+    int i = 0;
+    while (i < fastio_span_grid_n && fastio_span_grid[i] < seconds)
+        i++;
+    s->sum += seconds;
+    s->count++;
+    s->cells[i]++;
+}
 
 static inline void
 fastio_io_note_recv(int n)

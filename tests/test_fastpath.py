@@ -401,7 +401,10 @@ class TestFastpathIntegration:
                         in text)
                 assert ('binder_response_size_bytes_count{type="A"} 5'
                         in text)
-                assert 'binder_answer_cache_hits 4' in text
+                # one Python-lane hit (the promoting one), three native:
+                # the tier label splits what was one series of 4
+                assert 'binder_answer_cache_hits{tier="python"} 1' in text
+                assert 'binder_answer_cache_hits{tier="native"} 3' in text
                 # folding is delta-based: a second scrape must not
                 # double-count
                 text = server.collector.expose()

@@ -661,6 +661,7 @@ class TestSnapshotValidator:
                     "rst_drops": 0},
             "recursion": None, "precompile": None, "loop": None,
             "flight_recorder": None, "policy": None, "verify": None,
+            "io": None,
         }
         assert validate_status_snapshot(good) == []
         bad = json.loads(json.dumps(good))

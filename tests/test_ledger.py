@@ -1,0 +1,575 @@
+"""The worker's time ledger (ISSUE 24): leaf spans of
+``binder_query_stage_seconds`` timed where the work happens, the socket
+and log counters beside them, and the loop-lag watchdog's ring of stall
+instants.
+
+What this pins:
+
+- the spans are leaves: over a driven burst on a loopback server
+  ``loop-idle`` plus the named stages never exceed the wall time (they
+  would if two of them overlapped) and come within 25% of it;
+- ``udp-recv`` counts a ``recvmmsg`` that returns EAGAIN, in
+  ``recv_batch`` and in ``fastpath_drain``; a served batch gives one
+  ``native-serve`` and one ``udp-send``;
+- the C spans fold into the stage histogram by deltas, and a counter
+  that stepped back (``io_stats(True)``) is skipped, not folded;
+- ``binder_answer_cache_hits`` is split by ``tier`` and its children add
+  up to what the one series counted; the whole exposition still passes
+  ``tools/lint.py``;
+- ``log-write`` / ``log-line`` time the log, and
+  ``binder_query_log_bytes`` counts the bytes that reached the stream;
+- the watchdog's ring keeps a forced block with its instant, caps at
+  256, and ``/status`` carries ``io`` and ``loop.stalls``.
+"""
+import asyncio
+import io
+import socket
+import threading
+import time
+
+import pytest
+
+from binder_tpu.dns import Type, make_query
+from binder_tpu.introspect import Introspector, LoopLagWatchdog
+from binder_tpu.introspect import ledger
+from binder_tpu.introspect.watchdog import STALL_RING_SIZE
+from binder_tpu.metrics.collector import (DEFAULT_STAGE_BUCKETS,
+                                          MetricsCollector)
+from binder_tpu.server import BinderServer
+from binder_tpu.store import FakeStore, MirrorCache
+from binder_tpu.utils.jsonlog import make_logger
+from tests.test_server import udp_ask
+from tools.lint import (validate_exposition, validate_ledger_metrics,
+                        validate_status_snapshot)
+
+try:
+    from binder_tpu import _binderfastio as fastio
+except ImportError:
+    fastio = None
+
+needs_native = pytest.mark.skipif(
+    fastio is None or not hasattr(fastio, "io_span_grid"),
+    reason="native extension with the time ledger not built")
+
+DOMAIN = "foo.com"
+HOSTS = 40
+
+
+def fixture_cache():
+    store = FakeStore()
+    cache = MirrorCache(store, DOMAIN)
+    for i in range(HOSTS):
+        store.put_json(f"/com/foo/h{i}", {
+            "type": "host", "host": {"address": f"10.0.{i // 250}.{i % 250 + 1}"}})
+    store.start_session()
+    return cache
+
+
+async def start_logged_server(stream, **kw):
+    log = make_logger("binder-ledger-test", stream=stream)
+    server = BinderServer(zk_cache=fixture_cache(), dns_domain=DOMAIN,
+                          datacenter_name="coal", host="127.0.0.1",
+                          port=0, collector=MetricsCollector(), log=log,
+                          query_log=True, **kw)
+    await server.start()
+    return server
+
+
+def stage_sums(collector, part="sum"):
+    """{stage: seconds or observations} of the stage histogram."""
+    collector.fold()
+    hist = collector.get("binder_query_stage_seconds")
+    out = {}
+    for key, cells in hist._counts.items():
+        stage = dict(key)["stage"]
+        out[stage] = (hist._sums.get(key, 0.0) if part == "sum"
+                      else sum(cells))
+    return out
+
+
+def span(name):
+    return fastio.io_stats()["spans"][name]
+
+
+# -- the Python half: the fold of the C spans, the timing selector --
+
+def test_the_selector_times_nothing_before_a_collector_is_installed():
+    """A loop is made before its process has a collector: until
+    ``install_loop_idle`` the selector waits untimed, and from then on
+    every wait lands in the collector's ``loop-idle``."""
+    collector = MetricsCollector()
+
+    async def nap():
+        selector = asyncio.get_running_loop().ledger_selector
+        assert selector.observe is None
+        await asyncio.sleep(0.02)
+        assert collector.get("binder_query_stage_seconds") is None
+        child = ledger.install_loop_idle(collector)
+        assert selector.observe == child.observe
+        await asyncio.sleep(0.02)
+
+    ledger.run(nap())
+    assert stage_sums(collector)["loop-idle"] >= 0.015
+
+
+def test_span_fold_merges_deltas_once():
+    collector = MetricsCollector()
+    fold = ledger.SpanFold(collector, "udp-send")
+    fold.fold([0, 2, 0], 0.003)
+    fold.fold([0, 2, 0], 0.003)    # nothing new: nothing folded twice
+    fold.fold([0, 2, 1], 0.007)
+    assert sum(fold.child._cells) == 3
+    assert stage_sums(collector)["udp-send"] == pytest.approx(0.007)
+    assert fold.skipped == 0
+
+
+def test_span_fold_skips_a_source_that_stepped_back():
+    """A negative delta (a test's ``io_stats(True)``) restarts the
+    baseline; it is never folded."""
+    collector = MetricsCollector()
+    fold = ledger.SpanFold(collector, "udp-recv")
+    fold.fold([0, 5, 0], 0.5)
+    fold.fold([0, 1, 0], 0.1)      # the source was reset in between
+    assert fold.skipped == 1
+    assert sum(fold.child._cells) == 5
+    fold.fold([0, 3, 0], 0.3)      # and grows again from its new base
+    assert sum(fold.child._cells) == 7
+    assert stage_sums(collector)["udp-recv"] == pytest.approx(0.7)
+    assert validate_exposition(collector.expose()) == []
+
+
+def test_timing_selector_times_the_loops_wait():
+    collector = MetricsCollector()
+
+    async def nap():
+        ledger.install_loop_idle(collector)
+        t0 = time.monotonic()
+        await asyncio.sleep(0.2)
+        return time.monotonic() - t0
+
+    napped = ledger.run(nap())
+    assert stage_sums(collector, "count")["loop-idle"] >= 1
+    assert 0.15 <= stage_sums(collector)["loop-idle"] <= napped + 0.05
+
+
+def test_loop_idle_is_exported_in_the_stage_histogram():
+    collector = MetricsCollector()
+
+    async def nap():
+        assert ledger.install_loop_idle(collector) is not None
+        await asyncio.sleep(0.05)
+
+    ledger.run(nap())
+    assert stage_sums(collector, "count")["loop-idle"] >= 1
+    assert 'stage="loop-idle"' in collector.expose()
+
+
+def test_a_loop_of_another_make_exports_no_idle_span():
+    collector = MetricsCollector()
+
+    async def plain():
+        return ledger.install_loop_idle(collector)
+
+    assert asyncio.run(plain()) is None
+    assert collector.get("binder_query_stage_seconds") is None
+
+
+# -- the C half: the socket calls and the serve loop --
+
+def bound_udp():
+    sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+    sock.bind(("127.0.0.1", 0))
+    sock.setblocking(False)
+    return sock
+
+
+@needs_native
+def test_udp_recv_counts_an_eagain_call_of_recv_batch():
+    fastio.io_span_grid(list(DEFAULT_STAGE_BUCKETS))
+    sock = bound_udp()
+    try:
+        before, calls = span("udp-recv"), fastio.io_stats()["recv_calls"]
+        assert fastio.recv_batch(sock.fileno(), 64) == []
+        after = span("udp-recv")
+        assert after["count"] == before["count"] + 1
+        assert after["sum"] > before["sum"]
+        assert sum(after["cells"]) == after["count"]
+        # ... and it is no call that "returned any"
+        assert fastio.io_stats()["recv_calls"] == calls
+    finally:
+        sock.close()
+
+
+@needs_native
+def test_udp_recv_counts_an_eagain_call_of_the_drain():
+    fastio.io_span_grid(list(DEFAULT_STAGE_BUCKETS))
+    cache = fastio.fastpath_new(16, 60000, [0.001, 1.0], [512.0])
+    sock = bound_udp()
+    try:
+        recv, serve = span("udp-recv"), span("native-serve")
+        assert fastio.fastpath_drain(cache, sock.fileno(), 1, 64) == ([], 0)
+        assert span("udp-recv")["count"] == recv["count"] + 1
+        # no datagram, no batch: the serve loop did not run
+        assert span("native-serve")["count"] == serve["count"]
+    finally:
+        sock.close()
+
+
+@needs_native
+def test_send_batch_times_each_sendmmsg():
+    fastio.io_span_grid(list(DEFAULT_STAGE_BUCKETS))
+    rx, tx = bound_udp(), bound_udp()
+    try:
+        before = span("udp-send")
+        sent = fastio.send_batch(tx.fileno(), [
+            (b"x" * 20, rx.getsockname()) for _ in range(5)])
+        after = span("udp-send")
+        assert sent == 5
+        assert after["count"] == before["count"] + 1
+        assert after["sum"] > before["sum"]
+        got = fastio.recv_batch(rx.fileno(), 64)
+        assert len(got) == 5
+    finally:
+        rx.close()
+        tx.close()
+
+
+@needs_native
+def test_the_same_grid_keeps_the_cells_and_another_restarts_them():
+    fastio.io_span_grid(list(DEFAULT_STAGE_BUCKETS))
+    sock = bound_udp()
+    try:
+        fastio.recv_batch(sock.fileno(), 64)
+        count = span("udp-recv")["count"]
+        assert count >= 1
+        fastio.io_span_grid(list(DEFAULT_STAGE_BUCKETS))
+        assert span("udp-recv")["count"] == count
+        fastio.io_span_grid([0.001, 0.01])
+        assert span("udp-recv") == {"sum": 0.0, "count": 0,
+                                    "cells": [0, 0, 0]}
+        with pytest.raises(ValueError):
+            fastio.io_span_grid([0.01, 0.001])
+    finally:
+        fastio.io_span_grid(list(DEFAULT_STAGE_BUCKETS))
+        sock.close()
+
+
+# -- a served burst: leaves that add up, counters that agree --
+
+def drive_burst(port, n, pause_s):
+    """n A queries from a thread with blocking sockets: host names in
+    turn, which the C lanes serve, and every fifth a name of its own
+    that the zone lacks, which only the Python lanes can refuse;
+    returns the answers received."""
+    got = 0
+    sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+    sock.settimeout(2.0)
+    try:
+        for i in range(n):
+            name = f"nx{i}" if i % 5 == 4 else f"h{i % HOSTS}"
+            wire = make_query(f"{name}.{DOMAIN}", Type.A,
+                              qid=1 + i % 60000).encode()
+            sock.sendto(wire, ("127.0.0.1", port))
+            try:
+                sock.recvfrom(4096)
+                got += 1
+            except socket.timeout:
+                pass
+            if pause_s:
+                time.sleep(pause_s)
+    finally:
+        sock.close()
+    return got
+
+
+@needs_native
+def test_leaf_spans_do_not_overlap_and_cover_the_wall_time():
+    """Idle plus every named stage stays under the wall time of the
+    burst (two overlapping spans would push it over) and within 25% of
+    it: what is left is the loop's own Python."""
+    async def run():
+        server = await start_logged_server(io.StringIO())
+        idle_fold = ledger.install_loop_idle(server.collector)
+        assert idle_fold is not None
+        try:
+            await asyncio.sleep(0.05)
+            base, t0 = stage_sums(server.collector), time.monotonic()
+            got = await asyncio.get_running_loop().run_in_executor(
+                None, drive_burst, server.udp_port, 600, 0.0005)
+            wall = time.monotonic() - t0
+            now = stage_sums(server.collector)
+            return got, wall, {k: v - base.get(k, 0.0)
+                               for k, v in now.items()}
+        finally:
+            await server.stop()
+
+    got, wall, grew = ledger.run(run())
+    assert got == 600
+    for stage in ledger.LEAF_STAGES:
+        assert grew.get(stage, 0.0) > 0.0, (stage, grew)
+    overlay = ("await", "upstream", "upstream-rtt", "loop-wait")
+    named = sum(v for k, v in grew.items() if k not in overlay)
+    assert named <= wall * 1.001, (named, wall, grew)
+    assert named >= 0.75 * wall, (named, wall, grew)
+
+
+@needs_native
+def test_socket_counters_agree_with_the_spans_and_the_lint_catalog():
+    async def run():
+        server = await start_logged_server(io.StringIO())
+        ledger.install_loop_idle(server.collector)
+        try:
+            server.collector.fold()
+            before = stage_sums(server.collector, "count")
+            dgrams = server.collector.get("binder_udp_datagrams")
+            was_in = dgrams.value({"dir": "in"})
+            was_out = dgrams.value({"dir": "out"})
+            for i in range(12):
+                r = await udp_ask(server.udp_port, f"h{i % 3}.{DOMAIN}",
+                                  Type.A, qid=100 + i)
+                assert r.answers
+            text = server.collector.expose()
+            after = stage_sums(server.collector, "count")
+            assert dgrams.value({"dir": "in"}) - was_in == 12
+            assert dgrams.value({"dir": "out"}) - was_out == 12
+            batches = server.collector.get("binder_udp_batch_size")
+            assert batches.count() >= 1
+            # a datagram at a time here: a recvmmsg and a sendmmsg each
+            assert after["udp-recv"] - before["udp-recv"] >= 12
+            assert after["udp-send"] - before["udp-send"] == 12
+            assert after["native-serve"] - before["native-serve"] >= 1
+            return text
+        finally:
+            await server.stop()
+
+    text = ledger.run(run())
+    assert validate_ledger_metrics(text) == []
+    assert 'binder_udp_batch_size_bucket{le="1"}' in text
+
+
+@needs_native
+def test_a_negative_io_stats_delta_is_skipped_not_folded():
+    async def run():
+        server = await start_logged_server(io.StringIO())
+        try:
+            for i in range(6):
+                await udp_ask(server.udp_port, f"h1.{DOMAIN}", Type.A,
+                              qid=200 + i)
+            server.collector.fold()
+            dgrams = server.collector.get("binder_udp_datagrams")
+            seen = dgrams.value({"dir": "in"})
+            recv = stage_sums(server.collector, "count")["udp-recv"]
+            assert seen >= 6
+            fastio.io_stats(True)          # what test_hostile.py does
+            await udp_ask(server.udp_port, f"h1.{DOMAIN}", Type.A, qid=300)
+            server.collector.fold()        # the step back: skipped
+            assert dgrams.value({"dir": "in"}) == seen
+            assert stage_sums(server.collector,
+                              "count")["udp-recv"] == recv
+            assert server._io_folds["udp-recv"].skipped == 1
+            await udp_ask(server.udp_port, f"h1.{DOMAIN}", Type.A, qid=301)
+            server.collector.fold()        # and counting goes on
+            assert dgrams.value({"dir": "in"}) == seen + 1
+            assert validate_exposition(server.collector.expose()) == []
+        finally:
+            await server.stop()
+
+    asyncio.run(run())
+
+
+@needs_native
+def test_tier_children_add_up_to_the_old_total():
+    """One series became two: Python-lane hits are counted where they
+    happen, native hits folded from C; their sum is what
+    ``native_serve_share``'s subtraction reads."""
+    async def run():
+        server = await start_logged_server(io.StringIO(),
+                                           zone_precompile=False)
+        try:
+            # zone table off: resolve, a Python hit (promotes), natives
+            for i in range(6):
+                await udp_ask(server.udp_port, f"h2.{DOMAIN}", Type.A,
+                              qid=400 + i)
+            text = server.collector.expose()
+            hits = server.collector.get("binder_answer_cache_hits")
+            python = hits.value({"tier": "python"})
+            native = hits.value({"tier": "native"})
+            assert python == server.answer_cache.stats()["hits"] == 1
+            assert native == fastio.fastpath_stats(
+                server._fastpath)["hits"] == 4
+            assert hits.total() == python + native == 5
+            assert hits.value() == 0            # no unlabelled series
+            return text
+        finally:
+            await server.stop()
+
+    text = asyncio.run(run())
+    assert 'binder_answer_cache_hits{tier="python"} 1' in text
+    assert 'binder_answer_cache_hits{tier="native"} 4' in text
+    assert validate_exposition(text) == []
+
+
+# -- the query log's two spans --
+
+@needs_native
+def test_log_write_times_the_ring_drain_and_counts_its_bytes():
+    async def run():
+        stream = io.StringIO()
+        server = await start_logged_server(stream)
+        try:
+            start = len(stream.getvalue())
+            for i in range(5):
+                await udp_ask(server.udp_port, f"h3.{DOMAIN}", Type.A,
+                              qid=500 + i)
+            server._drain_native_log()
+            wrote = len(stream.getvalue()) - start
+            counts = stage_sums(server.collector, "count")
+            sums = stage_sums(server.collector)
+            assert counts["log-write"] >= 1 and sums["log-write"] > 0
+            assert counts["log-line"] == 0      # all five served in C
+            nbytes = server.collector.get("binder_query_log_bytes")
+            assert nbytes.total() == wrote > 0
+            # an empty ring is no write
+            n = counts["log-write"]
+            server._drain_native_log()
+            assert stage_sums(server.collector,
+                              "count")["log-write"] == n
+        finally:
+            await server.stop()
+
+    asyncio.run(run())
+
+
+def test_log_line_times_a_python_lane_line_outside_its_timers():
+    """``log-line`` wraps ``log_event``; it reaches the histogram and the
+    byte counter, never the line's own ``timers``."""
+    async def run():
+        stream = io.StringIO()
+        server = await start_logged_server(stream, cache_size=0)
+        try:
+            start = len(stream.getvalue())
+            for i in range(4):
+                await udp_ask(server.udp_port, f"h4.{DOMAIN}", Type.A,
+                              qid=600 + i)
+            lines = stream.getvalue()[start:]
+            counts = stage_sums(server.collector, "count")
+            assert counts["log-line"] == 4
+            assert counts["log-after"] == 4
+            assert stage_sums(server.collector)["log-line"] > 0
+            assert "log-line" not in lines and "log-after" in lines
+            nbytes = server.collector.get("binder_query_log_bytes")
+            assert nbytes.total() == len(lines)
+        finally:
+            await server.stop()
+
+    asyncio.run(run())
+
+
+# -- stall instants on the shared clock --
+
+def test_ring_keeps_a_forced_block_with_its_instant():
+    async def run():
+        dog = LoopLagWatchdog(interval=0.005)
+        dog.start()
+        await asyncio.sleep(0.03)
+        start = time.monotonic()
+        time.sleep(0.06)                       # the loop is held
+        end = time.monotonic()
+        await asyncio.sleep(0.03)
+        dog.stop()
+        return dog, start, end
+
+    dog, start, end = asyncio.run(run())
+    ring = dog.snapshot()["stalls"]
+    assert len(ring) == 1, ring
+    assert ring[0]["lag_s"] >= 0.05
+    # the instant is the late wake-up: at the block's end, on the clock
+    # every process of the machine shares
+    assert start <= ring[0]["t_mono"] <= end + 0.02
+    assert dog.snapshot()["stall_events"] == 0      # under 0.25 s
+
+
+def test_ring_leaves_out_what_is_under_50ms_and_caps_at_256():
+    dog = LoopLagWatchdog(collector=MetricsCollector())
+    dog._observe(0.049, 10.0)
+    assert dog.snapshot()["stalls"] == []
+    for i in range(STALL_RING_SIZE + 40):
+        dog._observe(0.05 + i * 1e-4, 100.0 + i)
+    ring = dog.snapshot()["stalls"]
+    assert len(ring) == STALL_RING_SIZE == 256
+    assert ring[0]["t_mono"] == 140.0 and ring[-1]["t_mono"] == 395.0
+    assert dog.samples == STALL_RING_SIZE + 41     # the histogram's view
+
+
+@needs_native
+def test_status_carries_io_and_the_stall_ring():
+    async def run():
+        server = await start_logged_server(io.StringIO())
+        dog = LoopLagWatchdog(collector=server.collector)
+        dog._observe(0.07, time.monotonic())
+        intro = Introspector(server=server, watchdog=dog,
+                             collector=server.collector)
+        try:
+            for i in range(3):
+                await udp_ask(server.udp_port, f"h5.{DOMAIN}", Type.A,
+                              qid=700 + i)
+            server._drain_native_log()
+            return intro.snapshot()
+        finally:
+            await server.stop()
+
+    snap = asyncio.run(run())
+    assert validate_status_snapshot(snap) == []
+    assert snap["io"]["recv_datagrams"] >= 3
+    assert snap["io"]["send_datagrams"] >= 3
+    assert snap["io"]["recv_calls"] \
+        >= snap["io"]["recv_empty"] + 3
+    assert sum(snap["io"]["recv_batch_cells"]) >= 3
+    assert snap["io"]["log_writes"] >= 1 and snap["io"]["log_bytes"] > 0
+    assert [s["lag_s"] for s in snap["loop"]["stalls"]] == [0.07]
+    # a snapshot whose ring lost its shape is refused
+    snap["loop"]["stalls"] = 1
+    assert any("loop.stalls" in e for e in validate_status_snapshot(snap))
+
+
+def test_bstat_renders_the_io_line_and_the_ring():
+    import importlib.machinery
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bin", "bstat")
+    loader = importlib.machinery.SourceFileLoader("bstat", path)
+    bstat = importlib.util.module_from_spec(
+        importlib.util.spec_from_loader("bstat", loader))
+    loader.exec_module(bstat)
+
+    async def run():
+        server = await start_logged_server(io.StringIO())
+        dog = LoopLagWatchdog()
+        dog._observe(0.3, time.monotonic())
+        intro = Introspector(server=server, watchdog=dog)
+        try:
+            await udp_ask(server.udp_port, f"h6.{DOMAIN}", Type.A, qid=800)
+            return intro.snapshot()
+        finally:
+            await server.stop()
+
+    text = bstat.render(asyncio.run(run()))
+    assert "io: recvmmsg" in text and "query log" in text
+    assert "1 instant(s) of 50ms+ kept" in text
+
+
+def test_scrape_thread_and_loop_fold_without_double_counting():
+    """Scrapes run on their own threads beside the 1 Hz fold on the
+    loop: two folds of one delta must count it once."""
+    collector = MetricsCollector()
+    fold = ledger.SpanFold(collector, "udp-recv")
+    cells = [0] * (len(DEFAULT_STAGE_BUCKETS) + 1)
+    cells[3] = 1000
+    threads = [threading.Thread(target=fold.fold, args=(cells, 0.02))
+               for _ in range(8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert sum(fold.child._cells) == 1000

@@ -298,7 +298,7 @@ class TestLaneBehavior:
         ask_raw(srv, wire)   # second one is a lane cache hit
         text = srv.collector.expose()
         assert 'binder_requests_completed{type="A"} 2' in text
-        assert "binder_answer_cache_hits 1" in text
+        assert 'binder_answer_cache_hits{tier="python"} 1' in text
 
     def test_balancer_protocol_lane(self):
         """Lane handles balancer-framed queries; TCP client transport

@@ -37,7 +37,8 @@ import pytest
 from binder_tpu.chaos import ChaosDriver, FaultPlan
 from binder_tpu.dns import Message, Rcode, Type, make_query
 from binder_tpu.main import run as binder_run
-from tools.lint import validate_shard_metrics
+from tools.lint import (validate_ledger_metrics, validate_shard_metrics,
+                        validate_status_snapshot)
 
 DOMAIN = "shard.test"
 
@@ -208,6 +209,45 @@ class TestShardServing:
                 assert snap["shards"]["count"] == 2
                 assert len(snap["shards"]["workers"]) == 2
                 assert all(w["pid"] for w in snap["shards"]["workers"])
+            finally:
+                await sup.drain()
+
+        asyncio.run(run())
+
+
+    def test_the_time_ledger_and_the_stall_rings_reach_every_process(
+            self, tmp_path):
+        """main.py hands every loop the timing selector: a worker's
+        scrape carries the whole ledger (``loop-idle`` with it) and its
+        ``/status`` ``io`` and ``loop.stalls``; the supervisor, which
+        serves no query, still times its own loop's wait and keeps a
+        ring, so the rings line up on the shared clock."""
+        async def run():
+            sup = await boot(str(tmp_path), 2)
+            try:
+                for s in range(8):
+                    await ask_fresh(sup.udp_port, "w0.shard.test", Type.A,
+                                    qid=900 + s)
+                loop = asyncio.get_running_loop()
+                for shard in range(2):
+                    mport = sup.links[shard].hello["metrics_port"]
+
+                    def scrape(port=mport):
+                        with urllib.request.urlopen(
+                                f"http://127.0.0.1:{port}/metrics",
+                                timeout=5) as r:
+                            return r.read().decode()
+
+                    text = await loop.run_in_executor(None, scrape)
+                    assert validate_ledger_metrics(text) == []
+                    status = await loop.run_in_executor(
+                        None, worker_status, sup, shard)
+                    assert validate_status_snapshot(status) == []
+                    assert isinstance(status["loop"]["stalls"], list)
+                    assert status["io"]["recv_calls"] >= 0
+                snap = sup.snapshot()
+                assert isinstance(snap["loop"]["stalls"], list)
+                assert snap["loop"]["samples"] >= 0
             finally:
                 await sup.drain()
 

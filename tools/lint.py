@@ -482,6 +482,14 @@ _SNAPSHOT_SCHEMA = {
         "rst_drops": (int, False),
     },
 }
+# the counts behind the time ledger's socket and log stages
+_IO_SCHEMA = {
+    "recv_calls": (int, False), "recv_empty": (int, False),
+    "recv_datagrams": (int, False), "recv_batch_cells": (list, False),
+    "send_calls": (int, False), "send_datagrams": (int, False),
+    "log_writes": (int, False), "log_lines": (int, False),
+    "log_bytes": (int, False),
+}
 _SESSION_STATES = ("never-connected", "connected", "degraded", "expired",
                    "closed")
 _INFLIGHT_KEYS = ("trace", "name", "type", "client", "protocol",
@@ -520,7 +528,7 @@ def validate_status_snapshot(snap):
     # nullable top-level sections must still be PRESENT (consumers key
     # on them to know the feature is off, not mistyped)
     for section in ("recursion", "precompile", "verify", "loop",
-                    "flight_recorder", "policy"):
+                    "flight_recorder", "policy", "io"):
         if section not in snap:
             errs.append(f"{section}: key must be present (null when "
                         "the subsystem is off)")
@@ -555,10 +563,28 @@ def validate_status_snapshot(snap):
     loop = snap.get("loop")
     if isinstance(loop, dict):
         for key in ("interval_seconds", "stall_threshold_seconds",
-                    "samples", "stalls", "last_lag_seconds",
-                    "max_lag_seconds"):
+                    "samples", "stall_events", "stalls",
+                    "last_lag_seconds", "max_lag_seconds"):
             if key not in loop:
                 errs.append(f"loop: missing {key!r}")
+        # the ring of stall instants on the shared monotonic clock
+        stalls = loop.get("stalls")
+        if not isinstance(stalls, list):
+            errs.append("loop.stalls: expected a list of instants")
+        else:
+            for i, st in enumerate(stalls):
+                if not (isinstance(st, dict)
+                        and isinstance(st.get("t_mono"), _NUM)
+                        and isinstance(st.get("lag_s"), _NUM)):
+                    errs.append(f"loop.stalls[{i}]: expected "
+                                "{t_mono, lag_s} numbers")
+            times = [st["t_mono"] for st in stalls
+                     if isinstance(st, dict) and "t_mono" in st]
+            if times != sorted(times):
+                errs.append("loop.stalls: t_mono not ascending")
+    io = snap.get("io")
+    if isinstance(io, dict):
+        _check_keys(io, _IO_SCHEMA, "io", errs)
     fr = snap.get("flight_recorder")
     if isinstance(fr, dict):
         for key in ("capacity", "recorded", "dropped", "by_type",
@@ -1152,6 +1178,72 @@ def validate_verify_metrics(text):
         if stage not in have:
             errs.append(f"binder_propagation_seconds: missing pinned "
                         f"series stage={stage!r}")
+    return errs
+
+
+# -- the time ledger (ISSUE 24, docs/observability.md) ----------------
+#
+# A worker's second is accounted for by the leaf stages of
+# binder_query_stage_seconds (loop-idle, udp-recv, native-serve,
+# udp-send, log-write, log-line) beside the per-query stages, with the
+# socket and log counters that give them their denominators.  The
+# benchmark's per-layer readers (benchmark/layer_metrics/) key on these
+# names and labels, so each family must carry the right TYPE, and the
+# pinned label values must be there.  Wired into tier-1 via
+# tests/test_ledger.py.
+
+_LEDGER_FAMILIES = {
+    "binder_query_stage_seconds": "histogram",
+    "binder_udp_datagrams": "counter",
+    "binder_udp_batch_size": "histogram",
+    "binder_answer_cache_hits": "counter",
+    "binder_query_log_bytes": "counter",
+}
+_LEDGER_STAGES = ("loop-idle", "udp-recv", "native-serve", "udp-send",
+                  "log-write", "log-line")
+_LEDGER_LABELS = {
+    "binder_udp_datagrams": ("dir", ("in", "out")),
+    "binder_answer_cache_hits": ("tier", ("native", "python")),
+}
+
+
+def validate_ledger_metrics(text):
+    """Validate that a Prometheus exposition carries the time ledger:
+    the families with their TYPEs, every leaf stage as a series of the
+    stage histogram, and the pinned ``dir`` / ``tier`` label values.
+    Returns error strings; empty == valid."""
+    errs = list(validate_exposition(text))
+    types = {}
+    labels_seen = {}
+    for line in text.splitlines():
+        parts = line.split()
+        if line.startswith("# TYPE") and len(parts) >= 4:
+            types[parts[2]] = parts[3]
+        elif line and not line.startswith("#") and parts:
+            name, _, labels = parts[0].partition("{")
+            for pair in labels.partition("}")[0].split(","):
+                key, _, val = pair.partition("=")
+                if key:
+                    labels_seen.setdefault(name, {}).setdefault(
+                        key, set()).add(val.strip('"'))
+    for family, kind in _LEDGER_FAMILIES.items():
+        if family not in types:
+            errs.append(f"{family}: missing # TYPE declaration")
+        elif types[family] != kind:
+            errs.append(f"{family}: declared {types[family]!r}, "
+                        f"expected {kind!r}")
+    have = labels_seen.get(
+        "binder_query_stage_seconds_count", {}).get("stage", set())
+    for stage in _LEDGER_STAGES:
+        if stage not in have:
+            errs.append(f"binder_query_stage_seconds: missing leaf "
+                        f"stage={stage!r}")
+    for family, (label, values) in _LEDGER_LABELS.items():
+        have = labels_seen.get(family, {}).get(label, set())
+        for value in values:
+            if value not in have:
+                errs.append(f"{family}: missing pinned series "
+                            f"{label}={value!r}")
     return errs
 
 
