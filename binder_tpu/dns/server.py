@@ -221,6 +221,7 @@ class BalancerLink:
         buf = self._rbuf
         out: list = []
         self._direct_box[0] = out
+        engine.log_flush_owed = True
         try:
             fp = engine.fastpath
             if (fp is not None and _fp_serve_balancer is not None
@@ -247,13 +248,6 @@ class BalancerLink:
                                                   from_native=True):
                             self.close()
                             return
-                    log_flush = engine.fastpath_log_flush
-                    if log_flush is not None:
-                        try:
-                            log_flush()
-                        except Exception:
-                            self.log.exception(
-                                "query-log ring drain failed")
             # Python lane: whatever the native pass left behind —
             # everything, when there is no cache / no passed fd / the
             # gate is closed; only a trailing partial or garbage frame
@@ -279,6 +273,7 @@ class BalancerLink:
             if out and not self._closed:
                 self._send_direct_batch(out)
             self._flush()
+            engine._flush_log()
 
     def _handle_frame(self, frame: bytes,
                       from_native: bool = False) -> bool:
@@ -572,10 +567,18 @@ class DnsServer:
         self.fastpath = None
         self.fastpath_gen: Optional[Callable[[], int]] = None
         self.fastpath_gate: Optional[Callable[[], bool]] = None
-        # Drains the native query-log ring (installed by BinderServer in
-        # the logged posture); called once per UDP drain pass so ring
-        # writes amortize over a whole batch of serves.
-        self.fastpath_log_flush: Optional[Callable[[], None]] = None
+        # The query log's writer (installed by BinderServer where lines
+        # are rendered ahead of their write: the native ring's and the
+        # Python lanes' direct ones); a lane calls it once a readiness
+        # event, after the batch's responses are sent, so one stream
+        # write carries a whole batch of lines (_flush_log).
+        self.log_flush: Optional[Callable[[], None]] = None
+        # True while a lane callback runs that ends in _flush_log: a
+        # line rendered meanwhile is left to it
+        self.log_flush_owed = False
+        # the native log ring is armed: the socket-free serve entries
+        # take the client's address for the line (_fp_call)
+        self.fastpath_logged = False
         # Balancer answer-cache support: control frames let the balancer
         # cache responses with backend-driven invalidation.
         # `gen_source` supplies the current generation/epoch;
@@ -762,12 +765,24 @@ class DnsServer:
             return None
         try:
             gen = self.fastpath_gen() if self.fastpath_gen else 0
-            if self.fastpath_log_flush is not None:
+            if self.fastpath_logged:
                 return entry(self.fastpath, payload, gen, src[0], src[1],
                              protocol)
             return entry(self.fastpath, payload, gen)
         except (TypeError, ValueError):
             return None
+
+    def _flush_log(self) -> None:
+        """The end of a lane's readiness callback: write the query-log
+        lines it produced, the native ring's and the Python lanes'
+        alike, in one write."""
+        self.log_flush_owed = False
+        flush = self.log_flush
+        if flush is not None:
+            try:
+                flush()
+            except Exception:
+                self.log.exception("query-log write failed")
 
     def _serve_frames_bulk(self, buf: bytes, src):
         """Bulk native TCP-frame serve (``fastpath_serve_frames``):
@@ -906,7 +921,7 @@ class DnsServer:
         if _fastio is not None:
             on_readable = self._batched_udp_reader(sock)
         else:
-            def on_readable() -> None:
+            def drain() -> None:
                 for _ in range(burst):
                     try:
                         data, addr = recvfrom(65535)
@@ -927,6 +942,13 @@ class DnsServer:
                                       _addr, e)
 
                     handle_raw(data, (addr[0], addr[1]), "udp", send)
+
+            def on_readable() -> None:
+                self.log_flush_owed = True
+                try:
+                    drain()
+                finally:
+                    self._flush_log()
 
         loop.add_reader(sock.fileno(), on_readable)
         self._udp_socks.append((loop, sock))
@@ -1040,6 +1062,7 @@ class DnsServer:
         def on_readable() -> None:
             out: list = []
             batch_out[0] = out
+            self.log_flush_owed = True
             # fast path on/off is decided once per readiness event — the
             # gate (query-log / probe state) can flip at runtime
             fp = self.fastpath
@@ -1109,12 +1132,9 @@ class DnsServer:
                                           len(out) - sent)
                     except OSError as e:
                         log.error("batched UDP send failed: %s", e)
-                log_flush = self.fastpath_log_flush
-                if use_fp and log_flush is not None:
-                    try:
-                        log_flush()
-                    except Exception:
-                        log.exception("query-log ring drain failed")
+                # after the batch's responses: no answer waits behind
+                # a log write, and the batch's lines share one
+                self._flush_log()
 
         return on_readable
 

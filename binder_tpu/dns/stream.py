@@ -150,7 +150,13 @@ class TcpConn:
             self.promoted = True
             self.srv.tcp_stats.promotions += 1
             self._arm_nodelay()
-        self._feed(chunk)
+        # one query-log write a readiness event, after the responses
+        # the feed produced are handed to the socket
+        self.srv.log_flush_owed = True
+        try:
+            self._feed(chunk)
+        finally:
+            self.srv._flush_log()
 
     def _arm_nodelay(self) -> None:
         """TCP_NODELAY, the moment a SECOND response write becomes
@@ -207,12 +213,6 @@ class TcpConn:
                                 "unhandled error processing TCP frame "
                                 "from %s", self.peer[0])
                     off = consumed
-                    if resp and srv.fastpath_log_flush is not None:
-                        try:
-                            srv.fastpath_log_flush()
-                        except Exception:
-                            srv.log.exception(
-                                "query-log ring drain failed")
             n = len(buf)
             while n - off >= 2:
                 length = (buf[off] << 8) | buf[off + 1]

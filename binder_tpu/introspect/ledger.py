@@ -14,8 +14,8 @@ stage           observed per       where the clock is read
 ``udp-recv``    ``recvmmsg`` call  C (``native/fastio``), EAGAIN included
 ``native-serve``  batch            C, after recvmmsg to before sendmmsg
 ``udp-send``    ``sendmmsg`` call  C
-``log-write``   ring drain         ``BinderServer._drain_native_log``
-``log-line``    logged query       ``BinderServer._on_after``
+``log-write``   log write          ``BinderServer._write_log``
+``log-line``    Python-lane line   ``BinderServer._on_after``
 ==============  =================  ====================================
 
 Always on, like the stage histogram: no switch, option or environment

@@ -165,6 +165,12 @@ def _fastpath_key_parts(rd: bool, edns: bool, payload: int, qtype: int,
             + qclass.to_bytes(2, "big") + qname_wire)
 
 
+#: a log line's own fields, dumped as JsonFormatter dumps them; one
+#: encoder, because json.dumps builds a new one a call when handed a
+#: ``default``
+_LOG_ENCODE = _json.JSONEncoder(default=str).encode
+
+
 class BinderServer:
     def __init__(self, *, zk_cache, dns_domain: str,
                  datacenter_name: str = "",
@@ -266,6 +272,16 @@ class BinderServer:
             "Python-lane lines)")
         self._log_bytes_child = self._log_bytes.labelled()
         self._log_bytes_child.inc(0)
+        # how often the direct render engages (_on_after); the native
+        # lanes' lines are fastpath_stats' log_lines
+        self._log_lines = log_lines = self.collector.counter(
+            "binder_query_log_lines",
+            "Python-lane query-log lines, by the path that rendered "
+            "them: straight to bytes, or through logging")
+        self._log_direct_child = log_lines.labelled({"path": "direct"})
+        self._log_logging_child = log_lines.labelled({"path": "logging"})
+        self._log_direct_child.inc(0)
+        self._log_logging_child.inc(0)
         self._json_formatters = [h.formatter
                                  for h in self._find_json_handlers()]
         self._io_folds: dict = {}
@@ -553,36 +569,49 @@ class BinderServer:
             self.engine.fastpath_gate = self._fastpath_active
             self.collector.on_expose(self._fold_fastpath_metrics)
 
-        # Native query-log ring: with per-query logging ON (the
-        # reference's always-on posture, lib/server.js:537-591) the fast
-        # path previously stood down completely, forfeiting ~9x
-        # throughput.  Instead, entries now carry pre-rendered JSON log
-        # fragments, the C serve path appends one complete bunyan-style
-        # line per serve to a byte ring, and Python drains the ring in
-        # batches onto the SAME stream the JSON logger writes to — one
-        # stream write per batch instead of one formatting pass per
-        # query.  A serve that cannot produce its line (ring full, no
-        # fragment) DECLINES to the Python path, which logs normally:
-        # pressure degrades throughput, never drops log records.
-        # Armed only when the server's logger actually ends in a
-        # JsonFormatter stream (the production logger from make_logger);
-        # otherwise the old stand-down gating applies unchanged.
+        # The query log's one writer (_write_log).  With per-query
+        # logging ON (the reference's always-on posture,
+        # lib/server.js:537-591) and a logger that ends in a
+        # JsonFormatter stream (the production logger from
+        # make_logger), a line is rendered straight to bytes behind a
+        # prefix rendered once: by C into a byte ring, one complete
+        # bunyan-style line per native serve, and by _on_after into
+        # _log_pending for a Python-lane query.  Ring and pending lines
+        # go out in ONE stream write a readiness event, after the
+        # batch's responses, onto the same stream the JSON logger
+        # writes to.  Before the ring the fast path stood down
+        # completely under logging, forfeiting ~9x throughput.  A
+        # serve that cannot produce its line (ring full, no fragment)
+        # DECLINES to the Python path, which logs: pressure degrades
+        # throughput, never drops log records.  With any other logger
+        # every line goes through `logging` and the old stand-down
+        # gating applies unchanged.
         self._log_ring = False
         self._log_json_handlers: list = []
+        self._log_prefix = b""
+        #: rendered Python-lane lines awaiting the write; appended and
+        #: taken under _log_lock (a record of another thread flushes
+        #: them too, _before_record)
+        self._log_pending: list = []
+        self._log_lock = threading.RLock()
+        self._log_soon = False       # a call_soon'd write is armed
+        self._log_sec = -1           # the second _log_sec_head renders
+        self._log_sec_head = b""
         self._log_flush_task: Optional[asyncio.Task] = None
-        if (self.query_log and self._fastpath is not None
-                and hasattr(_fastio, "fastpath_log_enable")
-                and self.log.isEnabledFor(logging.INFO)):
+        if self.query_log and self.log.isEnabledFor(logging.INFO):
             self._log_json_handlers = self._find_json_handlers()
-            if self._log_json_handlers:
+        if self._log_json_handlers:
+            self._log_prefix = self._native_log_prefix()
+            self.engine.log_flush = self._write_log
+            if (self._fastpath is not None
+                    and hasattr(_fastio, "fastpath_log_enable")):
                 try:
                     _fastio.fastpath_log_enable(
-                        self._fastpath, self._native_log_prefix(),
-                        1 << 20)
+                        self._fastpath, self._log_prefix, 1 << 20)
                     self._log_ring = True
-                    self.engine.fastpath_log_flush = self._drain_native_log
+                    self.engine.fastpath_logged = True
                 except ValueError:
-                    self._log_json_handlers = []
+                    pass    # no ring: the fast path stands down
 
         # Zone precompilation (fpcore.h zone table): finished answer
         # bodies for the dominant record shapes (host A, PTR) are pushed
@@ -2050,6 +2079,8 @@ class BinderServer:
                    {"stage": "log-write"}),
                "log_lines": self.stage_histogram.count(
                    {"stage": "log-line"}),
+               "log_lines_direct": int(self._log_lines.value(
+                   {"path": "direct"})),
                "log_bytes": int(self._log_bytes.value()),
                "recv_calls": 0, "recv_empty": 0, "recv_datagrams": 0,
                "recv_batch_cells": [0] * (len(UDP_BATCH_BUCKETS) + 1),
@@ -2094,12 +2125,12 @@ class BinderServer:
                 and (not self.query_log or self._log_ring)
                 and (self._rrl is None or not self._rrl.hot()))
 
-    # -- native query-log ring plumbing --
+    # -- query-log plumbing: the ring, the pending lines, one writer --
 
     def _find_json_handlers(self) -> list:
         """StreamHandlers with a JsonFormatter reachable from this
         server's logger (walking propagation like logging does) — the
-        sinks the ring's pre-formatted lines are written to."""
+        sinks the pre-rendered lines are written to."""
         handlers = []
         lg: Optional[logging.Logger] = self.log
         while lg is not None:
@@ -2114,9 +2145,10 @@ class BinderServer:
         return handlers
 
     def _native_log_prefix(self) -> bytes:
-        """Constant head of every native log line, up to and including
-        ``"time": "`` — rendered once from the logger's identity, so
-        ring lines carry the same envelope as JsonFormatter's."""
+        """Constant head of every pre-rendered log line, up to and
+        including ``"time": "`` — rendered once from the logger's
+        identity, so these lines carry the same envelope as
+        JsonFormatter's."""
         fmt = self._log_json_handlers[0].formatter
         head = {"name": fmt.name, "hostname": fmt.hostname,
                 "pid": _os.getpid(), "level": 30,
@@ -2139,59 +2171,127 @@ class BinderServer:
             return None
         return frag if 0 < len(frag) <= 4096 else None
 
-    def _drain_native_log(self) -> None:
-        """Write the ring's accumulated complete lines to the JSON log
-        stream(s).  Called from the UDP drain loop (amortized over each
-        batch) and from a periodic flusher covering the TCP/balancer
-        lanes and idle tails."""
+    @staticmethod
+    def _byte_sink(h: logging.StreamHandler):
+        """The binary layer under a handler's stream, where bytes
+        written to it are what the text layer would have produced
+        (UTF-8-family encoding, no newline translation); else None:
+        pre-rendered and formatter lines would mix encodings or line
+        endings in one file."""
+        stream = h.stream
+        enc = (getattr(stream, "encoding", "") or "") \
+            .lower().replace("-", "")
+        if (enc in ("utf8", "ascii", "usascii")
+                and getattr(stream, "newlines", None) in (None, "\n")):
+            return getattr(stream, "buffer", None)
+        return None
+
+    def _log_direct(self) -> bool:
+        """Whether a Python-lane INFO line may be rendered straight to
+        bytes: read from the logger, exactly where the ring's byte
+        path is sound."""
+        handlers = self._log_json_handlers
+        if not handlers or not self.log.isEnabledFor(logging.INFO):
+            return False
+        for h in handlers:
+            if self._byte_sink(h) is None:
+                return False
+        return True
+
+    def _render_log_line(self, fields: dict) -> bytes:
+        """One complete ``DNS query`` line: the prefix, the time at
+        microsecond resolution (the seconds part rendered once a
+        second, as C does), ``"v": 0`` and the line's own fields, as
+        JsonFormatter would have dumped them."""
+        sec, ns = divmod(time.time_ns(), 1_000_000_000)
+        if sec != self._log_sec:
+            self._log_sec = sec
+            self._log_sec_head = time.strftime(
+                "%Y-%m-%dT%H:%M:%S.", time.gmtime(sec)).encode()
+        return b'%b%b%06dZ", "v": 0, %b\n' % (
+            self._log_prefix, self._log_sec_head, ns // 1000,
+            _LOG_ENCODE(fields)[1:].encode())
+
+    def _log_owed(self) -> None:
+        """A line went into ``_log_pending``: see that it is written
+        before the loop next blocks.  A UDP drain writes in its own
+        ``finally``, after its ``send_batch``; every other lane's line
+        arms one ``call_soon``."""
+        if self._log_soon or self.engine.log_flush_owed:
+            return
         try:
-            block = _fastio.fastpath_log_drain(self._fastpath)
-        except (TypeError, ValueError):
+            loop = asyncio.get_running_loop()
+        except RuntimeError:
+            self._write_log()
             return
-        if not block:
-            return
-        text = None
-        t0 = time.monotonic()
-        for h in self._log_json_handlers:
-            try:
-                h.acquire()
+        self._log_soon = True
+        loop.call_soon(self._write_log_soon)
+
+    def _write_log_soon(self) -> None:
+        self._log_soon = False
+        self._write_log()
+
+    def _before_record(self, record: logging.LogRecord) -> bool:
+        """Filter on the JSON handlers while the server runs: a record
+        that goes out through ``logging`` does not overtake the lines
+        rendered before it."""
+        if self._log_pending:
+            self._write_log()
+        return True
+
+    def _write_log(self) -> None:
+        """The query log's one writer: the native ring's complete lines
+        and the pending Python-lane lines, in one write per handler.
+        Called once a readiness event by the lane that served it, in
+        its ``finally`` after the responses are sent
+        (``DnsServer._flush_log``), by ``_log_owed``'s ``call_soon``,
+        by a record on its way through ``logging``, and by the
+        periodic flusher that nets idle tails."""
+        with self._log_lock:
+            block = b""
+            if self._log_ring:
                 try:
-                    buf = getattr(h.stream, "buffer", None)
-                    # bytes straight through ONLY when the text layer
-                    # would have produced the same bytes: UTF-8-family
-                    # encoding and no newline translation — otherwise
-                    # ring lines and formatter lines would mix
-                    # encodings/line-endings in one file
-                    enc = (getattr(h.stream, "encoding", "") or "") \
-                        .lower().replace("-", "")
-                    nl = getattr(h.stream, "newlines", None)
-                    if (buf is not None
-                            and enc in ("utf8", "ascii", "usascii")
-                            and nl in (None, "\n")):
-                        # (flush the text layer first so lines the
-                        # Python formatter wrote stay ordered)
-                        h.stream.flush()
-                        buf.write(block)
-                        buf.flush()
-                    else:
-                        if text is None:
-                            text = block.decode("utf-8", "replace")
-                        h.stream.write(text)
-                        h.flush()
-                    self._log_bytes_child.inc(len(block))
-                finally:
-                    h.release()
-            except Exception:
-                pass   # a dead log sink must never take down serving
-        self._log_write_child.observe(time.monotonic() - t0)
+                    block = _fastio.fastpath_log_drain(
+                        self._fastpath) or b""
+                except (TypeError, ValueError):
+                    pass
+            if self._log_pending:
+                block += b"".join(self._log_pending)
+                self._log_pending = []
+            if not block:
+                return
+            text = None
+            t0 = time.monotonic()
+            for h in self._log_json_handlers:
+                try:
+                    h.acquire()
+                    try:
+                        buf = self._byte_sink(h)
+                        if buf is not None:
+                            # (flush the text layer first so lines the
+                            # Python formatter wrote stay ordered)
+                            h.stream.flush()
+                            buf.write(block)
+                            buf.flush()
+                        else:
+                            if text is None:
+                                text = block.decode("utf-8", "replace")
+                            h.stream.write(text)
+                            h.flush()
+                        self._log_bytes_child.inc(len(block))
+                    finally:
+                        h.release()
+                except Exception:
+                    pass   # a dead log sink must never take down serving
+            self._log_write_child.observe(time.monotonic() - t0)
 
     async def _log_flush_loop(self) -> None:
         try:
             while True:
                 await asyncio.sleep(0.1)
-                self._drain_native_log()
+                self._write_log()
         except asyncio.CancelledError:
-            self._drain_native_log()
+            self._write_log()
             raise
 
     # -- after hook: metrics + query log (lib/server.js:509-591) --
@@ -2209,8 +2309,8 @@ class BinderServer:
                 "stages": {k: round(v, 3)
                            for k, v in query.times.items()},
             })
-        level = logging.WARNING if lat_ms > SLOW_QUERY_MS else logging.INFO
-        if lat_ms > SLOW_QUERY_MS and self.recorder is not None:
+        slow = lat_ms > SLOW_QUERY_MS
+        if slow and self.recorder is not None:
             self.recorder.record(
                 "slow-query", trace=query.trace_id, name=query.name(),
                 qtype=query.qtype_name(), rcode=Rcode.name(query.rcode()),
@@ -2228,7 +2328,7 @@ class BinderServer:
                     self.stage_histogram.labelled({"stage": stage})
             child.observe(ms / 1000.0)
 
-        if not self.query_log and lat_ms <= SLOW_QUERY_MS:
+        if not self.query_log and not slow:
             return
         if query.cached_summary is not None:
             ans, add = query.cached_summary
@@ -2236,31 +2336,43 @@ class BinderServer:
             ans = [self._summarize(r) for r in query.response.answers]
             add = [self._summarize(r) for r in query.response.additionals
                    if not isinstance(r, OPTRecord)]
-        # log-line: the line's format and its write + flush, timed
-        # around the call; the line is out by the time it is known, so
-        # it goes to the histogram only, not into `timers`
+        # request envelope built here, not per-query in _on_query: most
+        # queries never log (queryLog off / fast), so the dict work
+        # happens only on the slow/logged path
+        fields = {
+            "trace": query.trace_id,
+            "req_id": query.request.id,
+            "client": query.src[0],
+            "port": f"{query.src[1]}/{query.protocol}",
+            "edns": query.request.edns is not None,
+            **query.log_ctx,
+            "rcode": Rcode.name(query.rcode()),
+            "answers": ans,
+            "additional": add,
+            "latency": lat_ms,
+            "timers": query.times,
+        }
+        # log-line: the line's render (direct) or its trip through
+        # logging (every other logger, and the slow-query warning),
+        # timed around it; it goes to the histogram only, not into
+        # `timers`, which the line has already rendered
+        t0 = time.monotonic()
+        if not slow and self._log_direct():
+            line = self._render_log_line(fields)
+            with self._log_lock:
+                self._log_pending.append(line)
+            self._log_owed()
+            self._log_line_child.observe(time.monotonic() - t0)
+            self._log_direct_child.inc()
+            return
         fmts = self._json_formatters
         wrote = sum(f.bytes_out for f in fmts)
-        t0 = time.monotonic()
-        log_event(
-            self.log, level, "DNS query",
-            # request envelope built here, not per-query in _on_query:
-            # most queries never log (queryLog off / fast), so the dict
-            # work happens only on the slow/logged path
-            trace=query.trace_id,
-            req_id=query.request.id,
-            client=query.src[0],
-            port=f"{query.src[1]}/{query.protocol}",
-            edns=query.request.edns is not None,
-            **query.log_ctx,
-            rcode=Rcode.name(query.rcode()),
-            answers=ans,
-            additional=add,
-            latency=lat_ms,
-            timers=query.times,
-        )
+        log_event(self.log,
+                  logging.WARNING if slow else logging.INFO,
+                  "DNS query", **fields)
         self._log_line_child.observe(time.monotonic() - t0)
         self._log_bytes_child.inc(sum(f.bytes_out for f in fmts) - wrote)
+        self._log_logging_child.inc()
 
     def _summarize(self, rec) -> object:
         if isinstance(rec, SRVRecord):
@@ -2336,9 +2448,12 @@ class BinderServer:
                 self.engine.announce_udp(self.host, udp_port)
                 self.engine.announce_tcp(self.host, self.tcp_port)
             break
-        if self._log_ring and self._log_flush_task is None:
-            # periodic drain for the lanes without a C drain loop of
-            # their own (TCP/balancer serves) and for idle tails
+        if self._log_json_handlers and self._log_flush_task is None:
+            for h in self._log_json_handlers:
+                h.addFilter(self._before_record)
+            # a net for tails (a native serve on a lane that did not
+            # write, a line whose write failed); every lane writes its
+            # own lines within the turn that served them
             self._log_flush_task = asyncio.get_running_loop().create_task(
                 self._log_flush_loop())
         if self._policy is not None and self._policy_task is None:
@@ -2364,9 +2479,11 @@ class BinderServer:
             except asyncio.CancelledError:
                 pass
             self._log_flush_task = None
-        if self._log_ring:
-            self._drain_native_log()
         await self.engine.close()
+        # queries the close served out have rendered their lines
+        self._write_log()
+        for h in self._log_json_handlers:
+            h.removeFilter(self._before_record)
 
 
 def create_server(**kwargs) -> BinderServer:

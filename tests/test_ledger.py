@@ -38,6 +38,7 @@ from binder_tpu.metrics.collector import (DEFAULT_STAGE_BUCKETS,
 from binder_tpu.server import BinderServer
 from binder_tpu.store import FakeStore, MirrorCache
 from binder_tpu.utils.jsonlog import make_logger
+from tests.test_log_ring import byte_stream as log_ring_byte_stream
 from tests.test_server import udp_ask
 from tools.lint import (validate_exposition, validate_ledger_metrics,
                         validate_status_snapshot)
@@ -421,7 +422,7 @@ def test_log_write_times_the_ring_drain_and_counts_its_bytes():
             for i in range(5):
                 await udp_ask(server.udp_port, f"h3.{DOMAIN}", Type.A,
                               qid=500 + i)
-            server._drain_native_log()
+            server._write_log()
             wrote = len(stream.getvalue()) - start
             counts = stage_sums(server.collector, "count")
             sums = stage_sums(server.collector)
@@ -431,7 +432,7 @@ def test_log_write_times_the_ring_drain_and_counts_its_bytes():
             assert nbytes.total() == wrote > 0
             # an empty ring is no write
             n = counts["log-write"]
-            server._drain_native_log()
+            server._write_log()
             assert stage_sums(server.collector,
                               "count")["log-write"] == n
         finally:
@@ -440,25 +441,88 @@ def test_log_write_times_the_ring_drain_and_counts_its_bytes():
     asyncio.run(run())
 
 
-def test_log_line_times_a_python_lane_line_outside_its_timers():
-    """``log-line`` wraps ``log_event``; it reaches the histogram and the
-    byte counter, never the line's own ``timers``."""
+def text_of(stream):
+    """What reached a test's log stream: a StringIO's text, or the
+    bytes under a text layer."""
+    if isinstance(stream, io.StringIO):
+        return stream.getvalue()
+    return stream.buffer.getvalue().decode("utf-8")
+
+
+def byte_stream():
+    return log_ring_byte_stream()[0]
+
+
+@pytest.mark.parametrize("make_stream", [io.StringIO, byte_stream],
+                         ids=["logging", "direct"])
+def test_log_line_times_a_python_lane_line_outside_its_timers(make_stream):
+    """``log-line`` times a Python-lane line once, its render straight
+    to bytes or its trip through ``log_event``; it reaches the
+    histogram and the byte counter, never the line's own ``timers``."""
     async def run():
-        stream = io.StringIO()
+        stream = make_stream()
         server = await start_logged_server(stream, cache_size=0)
         try:
-            start = len(stream.getvalue())
+            start = len(text_of(stream))
             for i in range(4):
                 await udp_ask(server.udp_port, f"h4.{DOMAIN}", Type.A,
                               qid=600 + i)
-            lines = stream.getvalue()[start:]
+            lines = text_of(stream)[start:]
             counts = stage_sums(server.collector, "count")
             assert counts["log-line"] == 4
             assert counts["log-after"] == 4
             assert stage_sums(server.collector)["log-line"] > 0
             assert "log-line" not in lines and "log-after" in lines
+            assert lines.count('"msg": "DNS query"') == 4
             nbytes = server.collector.get("binder_query_log_bytes")
             assert nbytes.total() == len(lines)
+        finally:
+            await server.stop()
+
+    asyncio.run(run())
+
+
+@needs_native
+def test_log_lines_by_path_add_up_to_log_line_and_bytes_to_the_stream(
+        monkeypatch):
+    """Native lines, direct lines and one slow-query warning through
+    ``logging`` on one stream: ``binder_query_log_lines{path}`` sums to
+    ``log-line``'s count, ``log-write`` carried the first two kinds,
+    and ``binder_query_log_bytes`` is the stream's growth."""
+    import binder_tpu.server as binder_server
+
+    async def run():
+        stream = byte_stream()
+        server = await start_logged_server(stream)
+        try:
+            start = len(text_of(stream))
+            for i in range(5):          # the zone table answers in C
+                await udp_ask(server.udp_port, f"h7.{DOMAIN}", Type.A,
+                              qid=900 + i)
+            for i in range(3):          # out of zone: the Python lanes
+                await udp_ask(server.udp_port, f"nope{i}.example.com",
+                              Type.A, qid=910 + i)
+            monkeypatch.setattr(binder_server, "SLOW_QUERY_MS", -1.0)
+            await udp_ask(server.udp_port, "slow.example.com", Type.A,
+                          qid=920)
+            monkeypatch.undo()
+            server._write_log()
+            lines = text_of(stream)[start:]
+            by_path = server.collector.get("binder_query_log_lines")
+            direct = by_path.value({"path": "direct"})
+            logged = by_path.value({"path": "logging"})
+            assert (direct, logged) == (3, 1)
+            counts = stage_sums(server.collector, "count")
+            assert counts["log-line"] == direct + logged
+            assert lines.count('"msg": "DNS query"') == 9
+            assert lines.count('"level": 40') == 1
+            nbytes = server.collector.get("binder_query_log_bytes")
+            assert nbytes.total() == len(lines)
+            snap = server.io_introspect()
+            assert snap["log_lines"] == 4 and snap["log_lines_direct"] == 3
+            text = server.collector.expose()
+            assert 'binder_query_log_lines{path="direct"} 3' in text
+            assert 'binder_query_log_lines{path="logging"} 1' in text
         finally:
             await server.stop()
 
@@ -513,7 +577,7 @@ def test_status_carries_io_and_the_stall_ring():
             for i in range(3):
                 await udp_ask(server.udp_port, f"h5.{DOMAIN}", Type.A,
                               qid=700 + i)
-            server._drain_native_log()
+            server._write_log()
             return intro.snapshot()
         finally:
             await server.stop()

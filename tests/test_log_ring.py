@@ -12,15 +12,33 @@ down entirely.  These tests pin the round-5 contract:
   the resolve-shape ``query`` object);
 - lanes without a C drain (TCP) log through the same ring;
 - without a JSON stream logger the old stand-down gating is unchanged.
+
+And the contract of ISSUE 25, one log writer a readiness event:
+
+- a Python-lane line rendered straight to bytes parses to the object
+  JsonFormatter gives for the same query (UDP, TCP, and the slow-query
+  warning, which stays on ``logging``);
+- every answered query leaves exactly one line, also when the server
+  stops or the flusher is cancelled with lines pending;
+- a batch's responses are handed to ``send_batch`` before any of its
+  log bytes reach the stream, and one write carries the batch's lines;
+- a record that goes through ``logging`` does not overtake the lines
+  rendered before it;
+- any other logger or stream keeps every line on ``logging``.
 """
 import asyncio
 import io
 import json
 import logging
+import socket
+import sys
+import threading
 
 import pytest
 
-from binder_tpu.dns import Rcode, Type
+import binder_tpu.dns.server as dns_server
+import binder_tpu.server as binder_server
+from binder_tpu.dns import Message, Rcode, Type, make_query
 from binder_tpu.metrics.collector import MetricsCollector
 from binder_tpu.server import BinderServer
 from binder_tpu.store import FakeStore, MirrorCache
@@ -75,7 +93,7 @@ async def udp_ask(port, name, qtype, qid=4242, payload=1232):
 
 
 def log_lines(server, stream):
-    server._drain_native_log()
+    server._write_log()
     return [json.loads(ln) for ln in stream.getvalue().splitlines()]
 
 
@@ -277,3 +295,352 @@ class TestLogRing:
                 await quiet.stop()
 
         asyncio.run(run())
+
+
+# -- one log writer a readiness event (ISSUE 25) --
+
+def byte_stream(**kw):
+    """A stream as ``sys.stdout`` is one: a text layer over a binary
+    one, whose bytes the test reads back."""
+    raw = io.BytesIO()
+    kw.setdefault("encoding", "utf-8")
+    kw.setdefault("newline", "\n")
+    return io.TextIOWrapper(raw, write_through=True, **kw), raw
+
+
+def query_lines(raw):
+    text = raw.getvalue()
+    if isinstance(text, bytes):
+        text = text.decode("utf-8")
+    return [json.loads(ln) for ln in text.splitlines()
+            if '"msg": "DNS query"' in ln]
+
+
+def lines_by_path(server):
+    counter = server.collector.get("binder_query_log_lines")
+    return (int(counter.value({"path": "direct"})),
+            int(counter.value({"path": "logging"})))
+
+
+async def python_lane_server(stream, **kw):
+    """Every query surfaces to the Python lanes: no zone table, no
+    answer cache for the native tier to be fed from."""
+    store, cache = fixture_store()
+    return await start_logged_server(cache, stream, cache_size=0,
+                                     zone_precompile=False, **kw)
+
+
+#: what differs between two askings of one query
+VOLATILE = ("time", "latency", "timers", "trace", "port")
+
+
+@pytest.mark.parametrize("lane", ["udp", "tcp", "slow"])
+def test_direct_line_parses_to_the_formatters_object(lane, monkeypatch):
+    """The same query against a server whose logger takes bytes and one
+    whose logger does not (a StringIO has no binary layer): equal
+    objects, key for key in the same order, but for the values that
+    differ between any two askings, whose types are equal."""
+    if lane == "slow":
+        monkeypatch.setattr(binder_server, "SLOW_QUERY_MS", -1.0)
+
+    async def ask(server):
+        if lane == "tcp":
+            r = await tcp_ask(server.tcp_port, "web.foo.com", Type.A,
+                              qid=900)
+        else:
+            r = await udp_ask(server.udp_port, "web.foo.com", Type.A,
+                              qid=900)
+        assert r.rcode == Rcode.NOERROR
+
+    async def one(stream, want):
+        # one after the other: the two share the logger's name, which
+        # the line carries, and make_logger hands it one stream
+        server = await python_lane_server(stream)
+        try:
+            await ask(server)
+        finally:
+            await server.stop()
+        assert lines_by_path(server) == want
+
+    async def run():
+        stream, raw = byte_stream()
+        # the slow-query warning goes through logging on both
+        await one(stream, (0, 1) if lane == "slow" else (1, 0))
+        text = io.StringIO()
+        await one(text, (0, 1))
+        return query_lines(raw), query_lines(text)
+
+    (a,), (b,) = asyncio.run(run())
+    assert list(a) == list(b)
+    assert a["level"] == b["level"] == (40 if lane == "slow" else 30)
+    for key in a:
+        if key in VOLATILE:
+            assert type(a[key]) is type(b[key]), key
+        else:
+            assert a[key] == b[key], key
+    assert a["port"].endswith("/tcp" if lane == "tcp" else "/udp")
+    assert set(a["timers"]) == set(b["timers"]) and a["timers"]
+    # microsecond resolution, as datetime.isoformat() gives it
+    assert len(a["time"]) == len(b["time"]) == len(
+        "2026-01-01T00:00:00.000000Z")
+    assert a["time"][10] == "T" and a["time"].endswith("Z")
+
+
+@pytest.mark.parametrize("how", ["stop", "cancelled-flusher"])
+def test_every_answered_query_leaves_one_line(how):
+    """Lines that no lane wrote (here: the lanes' writer is taken away
+    while the queries are served) are written by ``stop()`` and by the
+    flusher's cancel path."""
+    n = 7
+
+    async def run():
+        stream, raw = byte_stream()
+        server = await python_lane_server(stream)
+        stopped = False
+        try:
+            server.engine.log_flush = None
+            for i in range(n):
+                await udp_ask(server.udp_port, "web.foo.com", Type.A,
+                              qid=1000 + i)
+            assert lines_by_path(server) == (n, 0)
+            assert query_lines(raw) == []        # all pending
+            if how == "stop":
+                await server.stop()
+                stopped = True
+            else:
+                server._log_flush_task.cancel()
+                with pytest.raises(asyncio.CancelledError):
+                    await server._log_flush_task
+            return query_lines(raw)
+        finally:
+            if not stopped:
+                await server.stop()
+
+    lines = asyncio.run(run())
+    assert [ln["req_id"] for ln in lines] == [1000 + i for i in range(n)]
+
+
+def test_sampled_drain_sends_its_batch_before_one_log_write(monkeypatch):
+    """RRL configured, gate open: every eighth drain goes through the
+    Python lanes.  Its batch's responses reach ``send_batch`` before
+    any of its log bytes reach the stream, and one ``log-write``
+    carries the batch's lines."""
+    k = 5
+    sends = []
+    real_send = fastio.send_batch
+
+    async def run():
+        stream, raw = byte_stream()
+
+        def send_batch(fd, out):
+            sends.append((len(out), len(query_lines(raw))))
+            return real_send(fd, out)
+
+        monkeypatch.setattr(dns_server._fastio, "send_batch", send_batch)
+        store, cache = fixture_store()
+        server = await start_logged_server(
+            cache, stream,
+            rrl={"responsesPerSecond": 100000, "burst": 100000})
+        loop = asyncio.get_running_loop()
+        rounds = []
+        try:
+            assert server._fastpath_active()
+            for r in range(2 * server._rrl.FASTPATH_SAMPLE_EVERY):
+                sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+                sock.setblocking(False)
+                sock.connect(("127.0.0.1", server.udp_port))
+                # k datagrams queued before the server's reader runs:
+                # one readiness event drains them as one batch
+                for i in range(k):
+                    sock.send(make_query("web.foo.com", Type.A,
+                                         qid=100 * r + i).encode())
+                before = (lines_by_path(server)[0], len(sends),
+                          server.io_introspect()["log_writes"],
+                          len(query_lines(raw)))
+                for i in range(k):
+                    data = await asyncio.wait_for(
+                        loop.sock_recv(sock, 4096), 5)
+                    assert Message.decode(data).rcode == Rcode.NOERROR
+                sock.close()
+                await asyncio.sleep(0)
+                rounds.append((before, (
+                    lines_by_path(server)[0], len(sends),
+                    server.io_introspect()["log_writes"],
+                    len(query_lines(raw)))))
+            return rounds
+        finally:
+            await server.stop()
+
+    rounds = asyncio.run(run())
+    sampled = [(b, a) for b, a in rounds if a[0] - b[0] == k]
+    assert len(sampled) == 2, rounds       # every eighth of sixteen
+    for before, after in sampled:
+        # the whole batch in one send_batch, no line out yet ...
+        assert after[1] - before[1] == 1
+        assert sends[before[1]] == (k, before[3])
+        # ... then one write with the batch's k lines
+        assert after[2] - before[2] == 1
+        assert after[3] - before[3] == k
+    # the other rounds are the native lanes': no direct line, the
+    # ring's lines in one write each
+    for before, after in rounds:
+        if (before, after) not in sampled:
+            assert after[0] == before[0]
+            assert after[2] - before[2] == 1 and after[3] - before[3] == k
+
+
+def test_direct_engages_without_the_extension(monkeypatch):
+    """The render is read from the logger, not from the extension: the
+    plain ``recvfrom`` reader writes its drain's lines in its own
+    ``finally`` too."""
+    monkeypatch.setattr(binder_server, "_fastio", None)
+    monkeypatch.setattr(dns_server, "_fastio", None)
+
+    async def run():
+        stream, raw = byte_stream()
+        server = await python_lane_server(stream)
+        try:
+            assert server._fastpath is None and not server._log_ring
+            for i in range(3):
+                await udp_ask(server.udp_port, "web.foo.com", Type.A,
+                              qid=i)
+                # answered, so its drain has ended: the line is out
+                assert len(query_lines(raw)) == i + 1
+            assert lines_by_path(server) == (3, 0)
+            assert server.io_introspect()["log_writes"] == 3
+        finally:
+            await server.stop()
+
+    asyncio.run(run())
+
+
+def test_a_logging_record_does_not_overtake_pending_lines():
+    async def run():
+        stream, raw = byte_stream()
+        server = await python_lane_server(stream)
+        try:
+            server.engine.log_flush = None      # nobody writes ...
+            await udp_ask(server.udp_port, "web.foo.com", Type.A,
+                          qid=1)
+            assert query_lines(raw) == []
+            # ... until a record of the same logger goes out
+            server.log.warning("in between")
+            await udp_ask(server.udp_port, "web.foo.com", Type.A,
+                          qid=2)
+        finally:
+            await server.stop()
+        return [json.loads(ln) for ln in
+                raw.getvalue().decode().splitlines()]
+
+    lines = asyncio.run(run())
+    order = [ln.get("req_id", ln["msg"]) for ln in lines
+             if ln["msg"] in ("DNS query", "in between")]
+    assert order == [1, "in between", 2]
+
+
+def test_records_of_another_thread_lose_and_tear_no_line():
+    """A thread that logs through the same handler writes the pending
+    lines too (the handler's filter): under a shortened switch interval
+    every query still leaves exactly one whole line."""
+    n = 300
+
+    async def run():
+        stream, raw = byte_stream()
+        server = await python_lane_server(stream)
+        stop = threading.Event()
+        noise = [0]
+
+        def chatter():
+            while not stop.is_set():
+                server.log.info("noise")
+                noise[0] += 1
+
+        threads = [threading.Thread(target=chatter) for _ in range(3)]
+        was = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            for i in range(n):
+                await udp_ask(server.udp_port, "web.foo.com", Type.A,
+                              qid=i)
+        finally:
+            stop.set()
+            for t in threads:
+                t.join(10)
+            sys.setswitchinterval(was)
+            await server.stop()
+        assert not any(t.is_alive() for t in threads)
+        assert lines_by_path(server) == (n, 0)
+        return raw.getvalue().decode(), noise[0]
+
+    text, noise = asyncio.run(run())
+    lines = [json.loads(ln) for ln in text.splitlines()]    # none torn
+    assert [ln["req_id"] for ln in lines
+            if ln["msg"] == "DNS query"] == list(range(n))
+    assert sum(ln["msg"] == "noise" for ln in lines) == noise > 0
+
+
+class ReportsTranslation(io.TextIOWrapper):
+    """A text layer that says it translates newlines."""
+    newlines = "\r\n"
+
+
+def _plain_logger(stream):
+    plain = logging.getLogger("binder-logring-plain-stream")
+    plain.setLevel(logging.INFO)
+    plain.propagate = False
+    handler = logging.StreamHandler(stream)
+    handler.setFormatter(logging.Formatter("%(message)s"))
+    plain.handlers = [handler]
+    return plain
+
+
+@pytest.mark.parametrize("kind", ["non-json", "latin-1", "translating",
+                                  "no-binary-layer"])
+def test_other_loggers_and_streams_stay_on_logging(kind):
+    n = 3
+
+    async def run():
+        raw = io.BytesIO()
+        kw = {}
+        if kind == "latin-1":
+            stream = io.TextIOWrapper(raw, encoding="latin-1",
+                                      write_through=True)
+        elif kind == "translating":
+            stream = ReportsTranslation(raw, encoding="utf-8",
+                                        newline="\r\n",
+                                        write_through=True)
+        elif kind == "no-binary-layer":
+            stream = raw = io.StringIO()
+        else:
+            stream, raw = byte_stream()
+            kw["log"] = _plain_logger(stream)
+        store, cache = fixture_store()
+        if "log" in kw:
+            server = BinderServer(
+                zk_cache=cache, dns_domain=DOMAIN, datacenter_name="coal",
+                host="127.0.0.1", port=0, collector=MetricsCollector(),
+                query_log=True, cache_size=0, zone_precompile=False, **kw)
+            await server.start()
+        else:
+            server = await start_logged_server(
+                cache, stream, cache_size=0, zone_precompile=False)
+        try:
+            for i in range(n):
+                await udp_ask(server.udp_port, "web.foo.com", Type.A,
+                              qid=i)
+            assert server._log_pending == []
+        finally:
+            await server.stop()
+        assert lines_by_path(server) == (0, n)
+        text = raw.getvalue()
+        return text if isinstance(text, str) else text.decode("latin-1")
+
+    text = asyncio.run(run())
+    if kind == "non-json":
+        assert text.count("DNS query") == n
+    else:
+        assert text.count('"msg": "DNS query"') == n
+    if kind == "translating":
+        assert text.count("\r\n") == text.count("\n") >= n

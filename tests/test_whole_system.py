@@ -224,7 +224,7 @@ def test_everything_at_once(tmp_path, query_log, json_log):
                     if s._fastpath is None:
                         continue
                     assert s._log_ring, "log ring failed to arm"
-                    s._drain_native_log()
+                    s._write_log()
                     import binder_tpu.server as _srv
                     stats = _srv._fastio.fastpath_stats(s._fastpath)
                     native_lines += stats["log_lines"]

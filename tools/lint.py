@@ -488,7 +488,7 @@ _IO_SCHEMA = {
     "recv_datagrams": (int, False), "recv_batch_cells": (list, False),
     "send_calls": (int, False), "send_datagrams": (int, False),
     "log_writes": (int, False), "log_lines": (int, False),
-    "log_bytes": (int, False),
+    "log_lines_direct": (int, False), "log_bytes": (int, False),
 }
 _SESSION_STATES = ("never-connected", "connected", "degraded", "expired",
                    "closed")
@@ -1198,19 +1198,22 @@ _LEDGER_FAMILIES = {
     "binder_udp_batch_size": "histogram",
     "binder_answer_cache_hits": "counter",
     "binder_query_log_bytes": "counter",
+    "binder_query_log_lines": "counter",
 }
 _LEDGER_STAGES = ("loop-idle", "udp-recv", "native-serve", "udp-send",
                   "log-write", "log-line")
 _LEDGER_LABELS = {
     "binder_udp_datagrams": ("dir", ("in", "out")),
     "binder_answer_cache_hits": ("tier", ("native", "python")),
+    "binder_query_log_lines": ("path", ("direct", "logging")),
 }
 
 
 def validate_ledger_metrics(text):
     """Validate that a Prometheus exposition carries the time ledger:
     the families with their TYPEs, every leaf stage as a series of the
-    stage histogram, and the pinned ``dir`` / ``tier`` label values.
+    stage histogram, and the pinned ``dir`` / ``tier`` / ``path`` label
+    values.
     Returns error strings; empty == valid."""
     errs = list(validate_exposition(text))
     types = {}
