@@ -75,6 +75,10 @@ CTL_INVALIDATE = 1   # backend→balancer: dependency-tag invalidate
 CTL_DIRECT = 2
 
 
+def _no_span(seconds: float) -> None:
+    """Where no ledger is attached, a stream-lane span goes nowhere."""
+
+
 def pack_balancer_frame(family: int, addr: str, port: int,
                         payload: bytes,
                         transport: int = TRANSPORT_UDP) -> bytes:
@@ -524,6 +528,12 @@ class DnsServer:
         # coalesce economics, drop reasons) — folded into binder_tcp_*
         # at scrape time by BinderServer
         self.tcp_stats = TcpStats()
+        # the time ledger's four stream-lane spans (introspect/ledger.py
+        # `tcp-*`): each takes the seconds one kernel crossing of the
+        # lane took.  BinderServer hands in its stage children's
+        # `observe`; an engine on its own times and drops them.
+        self.span_accept = self.span_recv = self.span_send = \
+            self.span_close = _no_span
         # cap-refusal accounting: a connect flood at the cap must not
         # become a log flood, so refusals log at most once per interval
         # (with the count of everything refused since the last line)
@@ -1207,13 +1217,19 @@ class DnsServer:
     def _on_accept_ready(self, lsock: socket.socket, loop) -> None:
         stats = self.tcp_stats
         for _ in range(self._ACCEPT_BURST):
+            # tcp-accept: one span an accept call, the EAGAIN that ends
+            # the burst included, with the new socket's setblocking
+            t0 = time.monotonic()
             try:
                 sock, peer = lsock.accept()
+                sock.setblocking(False)
             except (BlockingIOError, InterruptedError):
                 return
             except OSError as e:
                 self.log.error("TCP accept failed: %s", e)
                 return
+            finally:
+                self.span_accept(time.monotonic() - t0)
             stats.accepts += 1
             if len(self._tcp_conns) >= self.max_tcp_conns:
                 # at the connection cap: refuse the newcomer outright
@@ -1221,7 +1237,6 @@ class DnsServer:
                 # slowloris herd can't pin the front end shut for long)
                 self._refuse_at_cap(sock, peer, loop)
                 continue
-            sock.setblocking(False)
             # (TCP_NODELAY is armed lazily by TcpConn — at promotion,
             # or as soon as a second write becomes possible.  A
             # one-shot client gets exactly one response write on a
@@ -1243,10 +1258,12 @@ class DnsServer:
                 self.max_tcp_conns, self._cap_log_pending, peer[0])
             self._cap_log_last = now
             self._cap_log_pending = 0
+        t0 = time.monotonic()
         try:
             sock.close()
         except OSError:
             pass
+        self.span_close(time.monotonic() - t0)
 
     def _sweep_idle_tcp(self, loop, interval: float) -> None:
         self._tcp_sweep_handle = None

@@ -38,12 +38,17 @@ the shared event loop:
 
 Every transition feeds :class:`TcpStats`, folded into the
 ``binder_tcp_*`` Prometheus family at scrape time and surfaced in the
-``/status`` ``tcp`` section (docs/observability.md).
+``/status`` ``tcp`` section (docs/observability.md).  The lane's kernel
+crossings are leaf spans of the time ledger (``introspect/ledger.py``):
+``tcp-recv`` a ``recv`` call, ``tcp-send`` a ``send``/``sendmsg`` call,
+``tcp-close`` a connection closed (``tcp-accept`` is the accept path's,
+``dns/server.py``); the serve between them is not theirs.
 """
 from __future__ import annotations
 
 import socket
 import struct
+from time import monotonic
 
 #: scatter-gather ceiling per sendmsg (POSIX IOV_MAX is 1024 on Linux);
 #: a flush carrying more frames sends the first window and lets the
@@ -83,7 +88,7 @@ class TcpConn:
                  "out", "out_nframes", "wbuf", "flush_scheduled",
                  "reader_on", "writer_on", "deadline", "promoted",
                  "served", "q_out", "eof", "closed", "grace", "in_feed",
-                 "nodelay")
+                 "nodelay", "close_s")
 
     def __init__(self, srv, sock, peer, loop) -> None:
         self.srv = srv
@@ -111,6 +116,7 @@ class TcpConn:
         self.grace = None            # half-close drain deadline handle
         self.in_feed = False
         self.nodelay = False
+        self.close_s = 0.0           # unregistration done ahead of close
 
     def start(self) -> None:
         srv = self.srv
@@ -130,11 +136,18 @@ class TcpConn:
     def _on_readable(self) -> None:
         if self.closed:
             return
+        # tcp-recv: one span a recv call, whatever it brought
+        t0 = monotonic()
         try:
             chunk = self.sock.recv(65536)
         except (BlockingIOError, InterruptedError):
-            return
+            chunk = None
         except OSError:
+            chunk = False
+        self.srv.span_recv(monotonic() - t0)
+        if chunk is None:
+            return
+        if chunk is False:
             # RST, possibly mid-frame: shed this connection; the rest
             # of the table (and any partial frame state) dies with it
             self.srv.tcp_stats.rst_drops += 1
@@ -260,16 +273,19 @@ class TcpConn:
     def _on_eof(self) -> None:
         srv = self.srv
         self.eof = True
+        if self.q_out == 0 and not self.out and self.wbuf is None:
+            self._maybe_finish()    # closes; the reader goes with it
+            return
         # no more data will arrive; a level-triggered reader would spin
+        # (the unregistration is tcp-close's, observed with the close)
         if self.reader_on:
+            t0 = monotonic()
             try:
                 self.loop.remove_reader(self.fd)
             except (OSError, ValueError):
                 pass
             self.reader_on = False
-        if self.q_out == 0 and not self.out and self.wbuf is None:
-            self._maybe_finish()
-            return
+            self.close_s += monotonic() - t0
         # half-close with responses still owed (send-then-SHUT_WR is a
         # legitimate RFC 7766 client shape): serve them out under a
         # bounded grace, so a query that never answers (malformed drop)
@@ -339,6 +355,8 @@ class TcpConn:
         total = 0
         for framed in out:
             total += len(framed)
+        # tcp-send: one span a send or sendmsg call
+        t0 = monotonic()
         try:
             if nframes == 1:
                 sent = self.sock.send(out[0])
@@ -350,6 +368,9 @@ class TcpConn:
         except (BlockingIOError, InterruptedError):
             sent = 0
         except OSError:
+            sent = None
+        self.srv.span_send(monotonic() - t0)
+        if sent is None:
             out.clear()
             self.close()
             return
@@ -380,11 +401,15 @@ class TcpConn:
         if self.closed:
             return
         wbuf = self.wbuf
+        t0 = monotonic()
         try:
             sent = self.sock.send(wbuf)
         except (BlockingIOError, InterruptedError):
-            return
+            sent = 0
         except OSError:
+            sent = None
+        self.srv.span_send(monotonic() - t0)
+        if sent is None:
             self.close()
             return
         del wbuf[:sent]
@@ -437,17 +462,22 @@ class TcpConn:
         kernel send buffer instead of draining it toward a peer that
         has stopped reading."""
         if not self.closed:
+            t0 = monotonic()
             try:
                 self.sock.setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
                                      struct.pack("ii", 1, 0))
             except OSError:
                 pass
+            self.close_s += monotonic() - t0
         self.close()
 
     def close(self) -> None:
         if self.closed:
             return
         self.closed = True
+        # tcp-close: one span a connection closed, with its selector
+        # unregistrations (and what abort or a half-close did ahead)
+        t0 = monotonic()
         if self.grace is not None:
             self.grace.cancel()
             self.grace = None
@@ -472,3 +502,4 @@ class TcpConn:
             self.sock.close()
         except OSError:
             pass
+        self.srv.span_close(self.close_s + monotonic() - t0)

@@ -16,7 +16,22 @@ stage           observed per       where the clock is read
 ``udp-send``    ``sendmmsg`` call  C
 ``log-write``   log write          ``BinderServer._write_log``
 ``log-line``    Python-lane line   ``BinderServer._on_after``
+``tcp-accept``  ``accept`` call    ``DnsServer._on_accept_ready``, EAGAIN
+                                   included; with the new socket's
+                                   ``setblocking``
+``tcp-recv``    ``recv`` call      ``TcpConn._on_readable``, the EOF and
+                                   EAGAIN reads included
+``tcp-send``    ``send``/          ``TcpConn._flush`` and
+                ``sendmsg`` call   ``_on_writable``
+``tcp-close``   connection closed  ``TcpConn.close`` (and ``abort``): the
+                                   selector unregistrations and ``close``
 ==============  =================  ====================================
+
+The four ``tcp-*`` spans are the stream lane's kernel crossings
+(``dns/stream.py``); the frames' serve between them is the per-query
+stages' and ``native-serve`` has no part in it (the bulk frame serve is
+not timed).  A connection's reader *registration* after its first serve
+is the one crossing of a one-shot leg that no span names.
 
 Always on, like the stage histogram: no switch, option or environment
 variable.  Every span reads ``CLOCK_MONOTONIC``.  The Python spans
@@ -39,10 +54,13 @@ from binder_tpu.metrics.collector import (DEFAULT_STAGE_BUCKETS,
 METRIC_STAGE_HISTOGRAM = "binder_query_stage_seconds"
 STAGE_HISTOGRAM_HELP = "per-stage decomposition of request processing time"
 
+#: the stream lane's four (dns/stream.py), in the order a one-shot leg
+#: passes them
+TCP_STAGES = ("tcp-accept", "tcp-recv", "tcp-send", "tcp-close")
 #: the ledger's leaf stages (docs/observability.md); the per-query
 #: stages beside them are whatever ``QueryCtx.stamp`` was given
 LEAF_STAGES = ("loop-idle", "udp-recv", "native-serve", "udp-send",
-               "log-write", "log-line")
+               "log-write", "log-line") + TCP_STAGES
 
 
 def stage_child(collector, stage: str) -> HistogramChild:

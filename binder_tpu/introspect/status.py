@@ -237,8 +237,13 @@ class Introspector:
         slow / shedding" section the runbook keys on
         (docs/operations.md)."""
         if self.server is not None:
-            return self.server.engine.tcp_introspect()
-        return {"open_conns": 0, "max_conns": 0,
+            out = self.server.engine.tcp_introspect()
+            # what sends clients to the lane: UDP answers that left
+            # with TC=1 (binder_truncated_responses over all types)
+            out["udp_truncated"] = int(
+                self.server.truncated_counter.total())
+            return out
+        return {"udp_truncated": 0, "open_conns": 0, "max_conns": 0,
                 "idle_timeout_seconds": 0.0, "max_write_buffer": 0,
                 "cap_refusals": 0, "accepts": 0, "fast_serves": 0,
                 "promotions": 0, "oneshot_closes": 0,

@@ -44,6 +44,7 @@ from binder_tpu.dns.wire import (
     Type,
     ip_from_reverse_name,
 )
+from binder_tpu.resolver.precompile import Precompiler
 from binder_tpu.store.cache import MirrorCache
 from binder_tpu.store.names import rec_parts as _rec_parts
 
@@ -130,6 +131,12 @@ class AnswerPlan:
         #: resolved from a stale mirror (degradation policy: session
         #: down, within maxStalenessSeconds, TTLs clamped)
         self.stale = False
+
+    def records(self) -> int:
+        """Answer and additional records over all groups: what the
+        precompiler holds against ``MAX_SET_RECORDS``."""
+        return sum(len(answers) + len(additionals)
+                   for answers, additionals in self.groups)
 
     @property
     def negative(self) -> bool:
@@ -440,8 +447,14 @@ class Resolver:
             query.dep_domain = plan.dep_domain
         if plan.stale:
             query.log_ctx["stale"] = True
-        # decode→policy→mirror probe→plan, on the attribution timeline
-        query.stamp("store-lookup")
+        # a set the precompiler declines as oversize is rendered here,
+        # at query time, whole: its plan, its records and its encode go
+        # under one stage of their own, `lazy-render`, stamped after the
+        # respond in place of `store-lookup` and `pre-resp`
+        lazy = plan.rotatable and plan.records() > Precompiler.MAX_SET_RECORDS
+        if not lazy:
+            # decode→policy→mirror probe→plan, on the attribution timeline
+            query.stamp("store-lookup")
         if plan.miss and self.recursion is not None and query.rd():
             adm = self.admission
             if adm is not None and not adm.allow_recursion(query.src[0]):
@@ -474,8 +487,11 @@ class Resolver:
                 query.add_additional(rec)
         for rec in plan.authorities:
             query.add_authority(rec)
-        query.stamp("pre-resp")
+        if not lazy:
+            query.stamp("pre-resp")
         query.respond()
+        if lazy:
+            query.stamp("lazy-render")
 
     # -- reverse resolution (lib/server.js:67-134) --
 

@@ -385,8 +385,7 @@ class Precompiler:
         the verify layer's compiled-bytes check, which re-renders and
         compares byte-for-byte (``verify/checker.py``)."""
         groups = plan.groups
-        if sum(len(g[0]) + len(g[1]) for g in groups) \
-                > self.MAX_SET_RECORDS:
+        if plan.records() > self.MAX_SET_RECORDS:
             return None                 # oversize answer set: lazy
         nv = min(len(groups), self.VARIANTS_CAP) if plan.rotatable else 1
         variants = []

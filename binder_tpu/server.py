@@ -46,6 +46,7 @@ from binder_tpu.dns.wire import (
 from binder_tpu.introspect.ledger import (
     METRIC_STAGE_HISTOGRAM,
     STAGE_HISTOGRAM_HELP,
+    TCP_STAGES,
     SpanFold,
 )
 from binder_tpu.metrics.collector import (
@@ -69,6 +70,7 @@ from binder_tpu.verify import Verifier
 METRIC_REQUEST_COUNTER = "binder_requests_completed"
 METRIC_LATENCY_HISTOGRAM = "binder_request_latency_seconds"
 METRIC_SIZE_HISTOGRAM = "binder_response_size_bytes"
+METRIC_TRUNCATED_COUNTER = "binder_truncated_responses"
 # per-stage attribution (METRIC_STAGE_HISTOGRAM): one histogram, labeled
 # by stage, fed from the QueryCtx phase stamps at after-hook time — the
 # scrapeable form of the query log's `timers` dict (same stage names) —
@@ -252,6 +254,15 @@ class BinderServer:
         self.stage_histogram = self.collector.histogram(
             METRIC_STAGE_HISTOGRAM, STAGE_HISTOGRAM_HELP,
             buckets=DEFAULT_STAGE_BUCKETS)
+        # UDP answers that left with TC=1, for the client to fetch
+        # again over TCP (RRL's slips are binder_rrl_slipped_total's);
+        # A and SRV, the types whose sets grow, from scrape 1
+        self.truncated_counter = self.collector.counter(
+            METRIC_TRUNCATED_COUNTER,
+            "UDP responses sent truncated (TC=1), by query type")
+        for qtype in (Type.A, Type.SRV):
+            self.truncated_counter.labelled(
+                {"type": Type.name(qtype)}).inc(0)
         # per-qtype pre-resolved metric handles (label-sort once, not
         # per query); key is the numeric qtype
         self._metric_children: dict = {}
@@ -471,6 +482,12 @@ class BinderServer:
         self.engine.recorder = flight_recorder
         self.engine.admission = self._admission
         self.engine.rrl = self._rrl
+        # the ledger's stream-lane spans: timed in dns/stream.py and the
+        # accept path, observed straight into their stage's child
+        for stage, slot in zip(TCP_STAGES, ("span_accept", "span_recv",
+                                            "span_send", "span_close")):
+            setattr(self.engine, slot, self.stage_histogram.labelled(
+                {"stage": stage}).observe)
         # the engine's cap-refusal log line is rate-limited, so the
         # counter is the only complete record — surface it in the scrape
         self._cap_refusal_child = self.collector.counter(
@@ -2043,6 +2060,13 @@ class BinderServer:
                         [c - (prev["size_cells"][i] if prev else 0)
                          for i, c in enumerate(s["size_cells"])],
                         s["size_sum"] - (prev["size_sum"] if prev else 0.0))
+                    # an answer-cache entry promoted from a truncated
+                    # answer leaves C truncated (an extension built
+                    # before the count has none)
+                    cut = s.get("truncated", 0) - (
+                        prev.get("truncated", 0) if prev else 0)
+                    if cut > 0:
+                        children[3].inc(cut)
                 last[qtype] = s
 
     def _fold_ledger(self) -> None:
@@ -2108,7 +2132,8 @@ class BinderServer:
                       else Type.name(qtype)}
             children = (self.request_counter.labelled(labels),
                         self.latency_histogram.labelled(labels),
-                        self.size_histogram.labelled(labels))
+                        self.size_histogram.labelled(labels),
+                        self.truncated_counter.labelled(labels))
             self._metric_children[qtype] = children
         return children
 
@@ -2321,6 +2346,8 @@ class BinderServer:
         children[0].inc()
         children[1].observe(lat_ms / 1000.0)
         children[2].observe(query.bytes_sent)
+        if query.udp_semantics and query.wire[2] & 0x02:
+            children[3].inc()
         for stage, ms in query.times.items():
             child = self._stage_children.get(stage)
             if child is None:

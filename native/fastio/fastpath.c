@@ -686,6 +686,8 @@ fastpath_serve_balancer(PyObject *self, PyObject *args)
             fp_qstat_t *qs = fp_qstat(c, qtype);
             double elapsed = fp_now() - t0;
             qs->count++;
+            /* a cached wire that was truncated for this posture */
+            qs->truncated += (outs[n_hits - 1][2] & 0x02) != 0;
             qs->lat_sum += elapsed;
             qs->lat_cells[fp_bucket_index(c->lat_buckets,
                                           c->n_lat_buckets, elapsed)]++;
@@ -868,6 +870,9 @@ fastpath_drain(PyObject *self, PyObject *args)
         n_hits++;
 
         fp_qstat_t *qs = fp_qstat(c, entry_qtype);
+        /* the answer cache's entries are keyed by posture: one promoted
+         * from a truncated answer is served truncated */
+        qs->truncated += (out[2] & 0x02) != 0;
         qs->size_sum += (double)wlen;
         qs->size_cells[fp_bucket_index(c->size_buckets,
                                        c->n_size_buckets,
@@ -1067,8 +1072,9 @@ fastpath_stats(PyObject *self, PyObject *args)
             PyTuple_SET_ITEM(sz, b,
                              PyLong_FromUnsignedLongLong(s->size_cells[b]));
         PyObject *d = Py_BuildValue(
-            "{s:K,s:d,s:N,s:d,s:N}",
+            "{s:K,s:K,s:d,s:N,s:d,s:N}",
             "count", (unsigned long long)s->count,
+            "truncated", (unsigned long long)s->truncated,
             "lat_sum", s->lat_sum, "lat_cells", lat,
             "size_sum", s->size_sum, "size_cells", sz);
         if (d == NULL) {

@@ -106,6 +106,7 @@ typedef struct {
 typedef struct {
     uint16_t qtype;
     uint64_t count;
+    uint64_t truncated;     /* served to a UDP client with TC=1 */
     double lat_sum;
     double size_sum;
     uint64_t lat_cells[FP_MAX_BUCKETS + 1];

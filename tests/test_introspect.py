@@ -658,7 +658,7 @@ class TestSnapshotValidator:
                     "oneshot_closes": 0, "idle_timeouts": 0,
                     "slow_reader_drops": 0, "coalesced_writes": 0,
                     "coalesced_frames": 0, "half_closes": 0,
-                    "rst_drops": 0},
+                    "rst_drops": 0, "udp_truncated": 0},
             "recursion": None, "precompile": None, "loop": None,
             "flight_recorder": None, "policy": None, "verify": None,
             "io": None,

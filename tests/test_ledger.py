@@ -5,9 +5,12 @@ instants.
 
 What this pins:
 
-- the spans are leaves: over a driven burst on a loopback server
-  ``loop-idle`` plus the named stages never exceed the wall time (they
-  would if two of them overlapped) and come within 25% of it;
+- the spans are leaves: over a driven burst on a loopback server, UDP
+  with one-shot TCP legs among it, ``loop-idle`` plus the named stages
+  never exceed the wall time (they would if two of them overlapped)
+  and come within 25% of it;
+- the stream lane's four (``tcp-accept``, ``tcp-recv``, ``tcp-send``,
+  ``tcp-close``) observe once per call;
 - ``udp-recv`` counts a ``recvmmsg`` that returns EAGAIN, in
   ``recv_batch`` and in ``fastpath_drain``; a served batch gives one
   ``native-serve`` and one ``udp-send``;
@@ -24,6 +27,7 @@ What this pins:
 import asyncio
 import io
 import socket
+import struct
 import threading
 import time
 
@@ -257,10 +261,25 @@ def test_the_same_grid_keeps_the_cells_and_another_restarts_them():
 
 # -- a served burst: leaves that add up, counters that agree --
 
-def drive_burst(port, n, pause_s):
+def tcp_oneshot(port, wire):
+    """One query over a connection of its own, as a truncation retry
+    makes it: connect, send, read the answer, close."""
+    with socket.create_connection(("127.0.0.1", port), timeout=2.0) as s:
+        s.sendall(struct.pack(">H", len(wire)) + wire)
+        buf = b""
+        while len(buf) < 2 or len(buf) < 2 + int.from_bytes(buf[:2], "big"):
+            chunk = s.recv(65536)
+            if not chunk:
+                break
+            buf += chunk
+    return buf[2:]
+
+
+def drive_burst(port, n, pause_s, tcp_port=None):
     """n A queries from a thread with blocking sockets: host names in
     turn, which the C lanes serve, and every fifth a name of its own
-    that the zone lacks, which only the Python lanes can refuse;
+    that the zone lacks, which only the Python lanes can refuse; with
+    *tcp_port* every seventh goes over a one-shot TCP connection;
     returns the answers received."""
     got = 0
     sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
@@ -270,6 +289,9 @@ def drive_burst(port, n, pause_s):
             name = f"nx{i}" if i % 5 == 4 else f"h{i % HOSTS}"
             wire = make_query(f"{name}.{DOMAIN}", Type.A,
                               qid=1 + i % 60000).encode()
+            if tcp_port is not None and i % 7 == 6:
+                got += 1 if tcp_oneshot(tcp_port, wire) else 0
+                continue
             sock.sendto(wire, ("127.0.0.1", port))
             try:
                 sock.recvfrom(4096)
@@ -296,7 +318,8 @@ def test_leaf_spans_do_not_overlap_and_cover_the_wall_time():
             await asyncio.sleep(0.05)
             base, t0 = stage_sums(server.collector), time.monotonic()
             got = await asyncio.get_running_loop().run_in_executor(
-                None, drive_burst, server.udp_port, 600, 0.0005)
+                None, drive_burst, server.udp_port, 600, 0.0005,
+                server.tcp_port)
             wall = time.monotonic() - t0
             now = stage_sums(server.collector)
             return got, wall, {k: v - base.get(k, 0.0)
@@ -527,6 +550,129 @@ def test_log_lines_by_path_add_up_to_log_line_and_bytes_to_the_stream(
             await server.stop()
 
     asyncio.run(run())
+
+
+# -- the stream lane's four spans, the lazy render --
+
+#: what one one-shot leg observes: the accept and the EAGAIN that ends
+#: its burst, the frame's recv and the EOF's, one send, one close
+TCP_LEG_CALLS = {"tcp-accept": 2, "tcp-recv": 2, "tcp-send": 1,
+                 "tcp-close": 1}
+LEGS = 8
+
+
+@pytest.fixture(scope="module")
+def tcp_legs():
+    """``LEGS`` one-shot TCP legs, one after the other, half of them for
+    a name only the Python lanes can refuse (per-query stages) and half
+    for a host (the bulk frame serve); what every stage grew by, in
+    seconds and in observations, and the wall time beside."""
+    def closes(server):
+        return stage_sums(server.collector, "count").get("tcp-close", 0)
+
+    async def run():
+        server = await start_logged_server(io.StringIO())
+        assert ledger.install_loop_idle(server.collector) is not None
+        loop = asyncio.get_running_loop()
+        try:
+            await asyncio.sleep(0.05)
+            sums = stage_sums(server.collector)
+            counts = stage_sums(server.collector, "count")
+            t0 = time.monotonic()
+            for i in range(LEGS):
+                name = f"nx{i}" if i % 2 else f"h{i}"
+                assert await loop.run_in_executor(
+                    None, tcp_oneshot, server.tcp_port, make_query(
+                        f"{name}.{DOMAIN}", Type.A, qid=900 + i).encode())
+                # the leg's EOF lands a turn later; the next leg starts
+                # after it, so that every accept ends its own burst
+                for _ in range(200):
+                    if closes(server) - counts.get("tcp-close", 0) > i:
+                        break
+                    await asyncio.sleep(0.005)
+            wall = time.monotonic() - t0
+            return ({k: v - sums.get(k, 0.0) for k, v
+                     in stage_sums(server.collector).items()},
+                    {k: v - counts.get(k, 0) for k, v
+                     in stage_sums(server.collector, "count").items()},
+                    wall)
+        finally:
+            await server.stop()
+
+    return ledger.run(run())
+
+
+@pytest.mark.parametrize("stage", ledger.TCP_STAGES)
+def test_a_tcp_span_observes_once_per_call(tcp_legs, stage):
+    sums, counts, _wall = tcp_legs
+    assert counts[stage] == TCP_LEG_CALLS[stage] * LEGS
+    assert sums[stage] > 0.0
+
+
+def test_the_tcp_spans_overlap_no_per_query_stage(tcp_legs):
+    """The legs' Python-lane queries stamped their stages between the
+    lane's crossings: idle, the four spans and every other stage stay
+    under the wall time, which a span around the serve would pass."""
+    sums, counts, wall = tcp_legs
+    assert counts["log-after"] == LEGS // 2     # the refused names
+    assert sums["store-lookup"] > 0.0
+    overlay = ("await", "upstream", "upstream-rtt", "loop-wait")
+    named = sum(v for k, v in sums.items() if k not in overlay)
+    assert named <= wall * 1.001, (named, wall, sums)
+    assert sum(sums[k] for k in ledger.TCP_STAGES) < wall - sums["loop-idle"]
+
+
+def service_cache(members):
+    store = FakeStore()
+    cache = MirrorCache(store, DOMAIN)
+    store.put_json("/com/foo/big", {
+        "type": "service",
+        "service": {"srvce": "_http", "proto": "_tcp", "port": 80}})
+    for i in range(members):
+        store.put_json(f"/com/foo/big/m{i}", {
+            "type": "load_balancer",
+            "load_balancer": {"address": f"10.9.{i // 250}.{i % 250 + 1}"}})
+    store.start_session()
+    return cache
+
+
+@pytest.mark.parametrize("qname,qtype,members,lazy", [
+    # an A set: one record a member
+    (f"big.{DOMAIN}", Type.A, 64, False),
+    (f"big.{DOMAIN}", Type.A, 65, True),
+    # an SRV set with glue: two records a member
+    (f"_http._tcp.big.{DOMAIN}", Type.SRV, 32, False),
+    (f"_http._tcp.big.{DOMAIN}", Type.SRV, 33, True),
+], ids=["A-64", "A-65", "SRV-64", "SRV-66"])
+def test_lazy_render_fires_above_64_records_and_not_at_64(
+        qname, qtype, members, lazy):
+    """A set the precompiler declines (``MAX_SET_RECORDS``) is rendered
+    at query time under a stage of its own, in place of ``store-lookup``
+    and ``pre-resp``; a set of 64 records keeps those two."""
+    async def run():
+        server = BinderServer(
+            zk_cache=service_cache(members), dns_domain=DOMAIN,
+            datacenter_name="coal", host="127.0.0.1", port=0,
+            collector=MetricsCollector(), query_log=False,
+            zone_precompile=False)
+        await server.start()
+        try:
+            loop = asyncio.get_running_loop()
+            wire = make_query(qname, qtype, qid=77).encode()
+            answer = await loop.run_in_executor(
+                None, tcp_oneshot, server.tcp_port, wire)
+            return answer, stage_sums(server.collector, "count")
+        finally:
+            await server.stop()
+
+    answer, counts = asyncio.run(run())
+    assert int.from_bytes(answer[6:8], "big") == members
+    if lazy:
+        assert counts["lazy-render"] == 1
+        assert "store-lookup" not in counts and "pre-resp" not in counts
+    else:
+        assert "lazy-render" not in counts
+        assert counts["store-lookup"] == counts["pre-resp"] == 1
 
 
 # -- stall instants on the shared clock --

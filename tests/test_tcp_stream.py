@@ -16,7 +16,10 @@ Pins the serving contracts the rewritten lane must keep:
   metric advanced, never buffered unboundedly;
 - **observability** — the ``binder_tcp_*`` exposition passes
   ``tools/lint.py validate_tcp_metrics`` (this is the family's tier-1
-  wiring) and the ``/status`` ``tcp`` section is schema-complete;
+  wiring) and the ``/status`` ``tcp`` section is schema-complete; a
+  one-shot leg observes the time ledger's four ``tcp-*`` spans once a
+  call, and ``binder_truncated_responses`` counts the UDP answers that
+  send clients to the lane, and nothing else;
 - **chaos** — the stream-fault DSL actions drive a live server and the
   table re-converges to empty.
 """
@@ -30,6 +33,7 @@ from binder_tpu.dns import Message, Rcode, Type, make_query
 from binder_tpu.dns.server import DnsServer
 from binder_tpu.dns.wire import ARecord
 from binder_tpu.introspect import Introspector
+from binder_tpu.introspect.ledger import TCP_STAGES
 from binder_tpu.metrics.collector import MetricsCollector
 from binder_tpu.server import BinderServer
 from binder_tpu.store import FakeStore, MirrorCache
@@ -467,6 +471,100 @@ class TestObservability:
         assert tcp["accepts"] >= 1
         assert tcp["max_conns"] == DnsServer.MAX_TCP_CONNS
         assert tcp["max_write_buffer"] == DnsServer.MAX_TCP_WRITE_BUFFER
+
+
+    def test_a_oneshot_legs_span_counts(self):
+        """One truncation retry as the time ledger sees it: the accept
+        and the EAGAIN that ends its burst, the frame's recv and the
+        EOF's, one send, one close (docs/observability.md)."""
+        async def run():
+            store, cache = fixture_store()
+            server = await start_server(cache)
+            hist = server.collector.get("binder_query_stage_seconds")
+
+            def counts():
+                return {stage: hist.count({"stage": stage})
+                        for stage in TCP_STAGES}
+
+            before = counts()
+            await tcp_oneshot_raw(
+                server.tcp_port,
+                make_query("_pg._tcp.svc.foo.com", Type.SRV, qid=3,
+                           edns_payload=None).encode())
+            await wait_until(
+                lambda: counts()["tcp-close"] > before["tcp-close"])
+            after = counts()
+            stats = server.engine.tcp_stats.snapshot()
+            await server.stop()
+            return {k: after[k] - before[k] for k in after}, stats
+
+        grew, stats = asyncio.run(run())
+        assert grew == {"tcp-accept": 2, "tcp-recv": 2, "tcp-send": 1,
+                        "tcp-close": 1}
+        assert stats["accepts"] == stats["oneshot_closes"] == 1
+
+    def test_truncated_responses_counts_a_tc_answer_and_nothing_else(self):
+        """``binder_truncated_responses{type}``: a UDP answer that left
+        with TC=1, by query type; not its retry over TCP, not a UDP
+        answer that fit, not an answer EDNS made room for."""
+        async def run():
+            store, cache = fixture_store()
+            # (no query log: the native lanes stand down for a logger
+            # that is no JSON stream)
+            server = await start_server(cache, query_log=False)
+            trunc = server.collector.get("binder_truncated_responses")
+
+            def seen():
+                return {t: trunc.value({"type": t})
+                        for t in ("A", "SRV", "PTR")}
+
+            steps = [seen()]
+            for name, qtype, payload, over_tcp in (
+                    ("_pg._tcp.svc.foo.com", Type.SRV, None, False),
+                    ("_pg._tcp.svc.foo.com", Type.SRV, None, True),
+                    ("svc.foo.com", Type.A, None, False),
+                    ("svc.foo.com", Type.A, 1232, False),
+                    ("web.foo.com", Type.A, None, False),
+                    ("1.0.168.192.in-addr.arpa", Type.PTR, None, False)):
+                wire = make_query(name, qtype, qid=9,
+                                  edns_payload=payload).encode()
+                raw = await (tcp_oneshot_raw(server.tcp_port, wire)
+                             if over_tcp
+                             else udp_ask_raw(server.udp_port, wire))
+                steps.append((bool(raw[2] & 0x02), seen()))
+            text = server.collector.expose()
+            snap = Introspector(server=server).snapshot()
+            # asked often enough, the truncated answer is promoted to
+            # the native answer cache, which is keyed by posture and
+            # replays it truncated: counted there too
+            wire = make_query("_pg._tcp.svc.foo.com", Type.SRV, qid=10,
+                              edns_payload=None).encode()
+            for _ in range(14):
+                raw = await udp_ask_raw(server.udp_port, wire)
+                assert raw[2] & 0x02
+            server.collector.fold()
+            native = 0
+            if server._fastpath is not None:
+                native = server.collector.get(
+                    "binder_answer_cache_hits").value({"tier": "native"})
+                assert native >= 1
+            repeats = trunc.value({"type": "SRV"})
+            await server.stop()
+            return steps, text, snap, repeats
+
+        steps, text, snap, repeats = asyncio.run(run())
+        assert repeats == 1 + 14
+        zero = {"A": 0, "SRV": 0, "PTR": 0}
+        assert steps[0] == zero                     # and A, SRV from scrape 1
+        assert steps[1] == (True, {**zero, "SRV": 1})    # 40 SRV + glue
+        assert steps[2] == (False, {**zero, "SRV": 1})   # its TCP retry
+        assert steps[3] == (True, {"A": 1, "SRV": 1, "PTR": 0})
+        assert steps[4][0] is False and steps[4][1] == steps[3][1]
+        assert steps[5][0] is False and steps[6][0] is False
+        assert steps[6][1] == {"A": 1, "SRV": 1, "PTR": 0}
+        assert 'binder_truncated_responses{type="SRV"} 1' in text
+        assert validate_tcp_metrics(text) == []
+        assert snap["tcp"]["udp_truncated"] == 2
 
 
 class TestChaosStreamFaults:

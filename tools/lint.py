@@ -479,7 +479,7 @@ _SNAPSHOT_SCHEMA = {
         "slow_reader_drops": (int, False),
         "coalesced_writes": (int, False),
         "coalesced_frames": (int, False), "half_closes": (int, False),
-        "rst_drops": (int, False),
+        "rst_drops": (int, False), "udp_truncated": (int, False),
     },
 }
 # the counts behind the time ledger's socket and log stages
@@ -803,6 +803,8 @@ _TCP_FAMILIES = {
     "binder_tcp_rst_drops": "counter",
     "binder_tcp_cap_refusals": "counter",
     "binder_tcp_open_conns": "gauge",
+    # what sends clients to the lane: UDP answers that left with TC=1
+    "binder_truncated_responses": "counter",
 }
 
 
@@ -1185,7 +1187,8 @@ def validate_verify_metrics(text):
 #
 # A worker's second is accounted for by the leaf stages of
 # binder_query_stage_seconds (loop-idle, udp-recv, native-serve,
-# udp-send, log-write, log-line) beside the per-query stages, with the
+# udp-send, log-write, log-line and the stream lane's tcp-accept,
+# tcp-recv, tcp-send, tcp-close) beside the per-query stages, with the
 # socket and log counters that give them their denominators.  The
 # benchmark's per-layer readers (benchmark/layer_metrics/) key on these
 # names and labels, so each family must carry the right TYPE, and the
@@ -1201,7 +1204,8 @@ _LEDGER_FAMILIES = {
     "binder_query_log_lines": "counter",
 }
 _LEDGER_STAGES = ("loop-idle", "udp-recv", "native-serve", "udp-send",
-                  "log-write", "log-line")
+                  "log-write", "log-line",
+                  "tcp-accept", "tcp-recv", "tcp-send", "tcp-close")
 _LEDGER_LABELS = {
     "binder_udp_datagrams": ("dir", ("in", "out")),
     "binder_answer_cache_hits": ("tier", ("native", "python")),
