@@ -29,6 +29,7 @@ import struct
 from typing import Dict, Optional, Tuple
 
 from binder_tpu.chaos.plan import FaultPlan
+from binder_tpu.dns.server import bind_port_pair
 
 
 def _parse_question(data: bytes) -> Optional[Tuple[str, int, int]]:
@@ -188,12 +189,24 @@ class ChaosUpstream:
     async def start(self, address: str = "127.0.0.1",
                     port: int = 0) -> int:
         loop = asyncio.get_running_loop()
-        self._udp_transport, _ = await loop.create_datagram_endpoint(
-            lambda: self._Proto(self), local_addr=(address, port))
-        self.port = self._udp_transport.get_extra_info("sockname")[1]
+
+        async def bind_udp():
+            self._udp_transport, _ = await loop.create_datagram_endpoint(
+                lambda: self._Proto(self), local_addr=(address, port))
+            return self._udp_transport.get_extra_info("sockname")[1]
+
+        async def bind_tcp(tcp_port):
+            self._tcp_server = await asyncio.start_server(
+                self._tcp_conn, address, tcp_port)
+            return tcp_port
+
+        def release_udp(_port):
+            self._udp_transport.close()
+            self._udp_transport = None
+
         # TCP shares the UDP port number (binder peers serve both)
-        self._tcp_server = await asyncio.start_server(
-            self._tcp_conn, address, self.port)
+        self.port, _ = await bind_port_pair(port, bind_udp, bind_tcp,
+                                            release_udp)
         return self.port
 
     async def stop(self) -> None:

@@ -497,6 +497,33 @@ class BalancerLink:
             pass
 
 
+#: redraws of a kernel-chosen port pair before giving up; each failure
+#: means the drawn UDP port was taken on TCP, so consecutive failures
+#: are near-independent draws from the ephemeral range
+PAIR_BIND_ATTEMPTS = 16
+
+
+async def bind_port_pair(port: int, bind_udp, bind_tcp, release_udp):
+    """UDP and TCP on one port number, ``(udp_port, tcp_port)``.  With
+    *port* 0 the kernel picks the UDP port (``await bind_udp()`` gives
+    it) and any unrelated socket may hold that number on TCP: the draw
+    is released (``release_udp(udp_port)``) and made again instead of
+    failing.  A fixed port that is taken is a real error, and a failed
+    draw is released before any raise: callers treat a start as atomic.
+    errno is None when asyncio aggregates several bind failures
+    (multi-address hosts) into one OSError; a colliding draw redraws in
+    that shape too."""
+    for attempt in range(PAIR_BIND_ATTEMPTS):
+        udp_port = await bind_udp()
+        try:
+            return udp_port, await bind_tcp(port or udp_port)
+        except OSError as e:
+            release_udp(udp_port)
+            if not (port == 0 and e.errno in (errno.EADDRINUSE, None)
+                    and attempt < PAIR_BIND_ATTEMPTS - 1):
+                raise
+
+
 class DnsServer:
     #: Bounds for the TCP front (the reference's mname engine had none;
     #: a DNS front end that one slow peer can fd-starve is not done).

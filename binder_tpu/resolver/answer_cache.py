@@ -19,7 +19,9 @@ Correctness properties:
   exactly the tags it touched (``MirrorCache.invalidate``), so one
   churning record no longer evicts every cached answer;
 - round-robin is preserved: each miss stores another shuffle variant (up
-  to ``variants_cap``), and hits cycle through the collected variants;
+  to ``variants_cap``), and hits cycle through the collected variants; a
+  truncated UDP answer (TC=1: header, question, OPT echo) shows no
+  rotation and is one variant, served from its second sight on;
 - entries expire after ``expiry_ms`` regardless (defense in depth);
 - negative answers (NXDOMAIN, and NODATA — NOERROR with no answers) are
   cached like positives but accounted separately (``negative`` flag,
@@ -129,16 +131,23 @@ class AnswerCache:
     def put(self, key, epoch: int, value: object,
             rotatable: bool = False, tag: Optional[str] = None,
             negative: bool = False, qkey: Optional[tuple] = None) -> bool:
-        """Record a freshly resolved value.  ``tag`` is the store name
-        the answer depends on (defaults handled by the caller);
+        """Record a freshly resolved value.  ``rotatable`` says that
+        another resolve of this key may give other bytes (the wire
+        carries a set of several records, shuffled a resolve): such an
+        entry serves no hit before it holds ``variants_cap`` of them.  A
+        wire that left truncated carries no record, whatever set was
+        rendered for it, and ``BinderServer._on_query`` stores it not
+        rotatable: one variant, complete from its first sight.  ``tag`` is
+        the store name the answer depends on (defaults handled by the
+        caller);
         ``negative`` marks NXDOMAIN/NODATA answers for the separate
         accounting (never SERVFAIL — callers must not put those at
         all); ``qkey`` is the ``(qtype, qname)`` question identity, kept
         so tag invalidation can tell the precompiler exactly which
         question shapes it dropped.  Returns True exactly when the entry
         just became *complete* (non-rotatable, or the full variant set
-        collected) — the signal the server uses to push the entry to the
-        native fast path (see BinderServer._on_query)."""
+        collected): from then on ``get`` serves it, and its first hit
+        promotes it to the native fast path (``take_push``)."""
         if self.size <= 0:
             return False
         e = self._entries.get(key)

@@ -9,7 +9,6 @@ socket (``lib/server.js:609-653``).
 from __future__ import annotations
 
 import asyncio
-import errno as _errno
 import json as _json
 import logging
 import os as _os
@@ -27,7 +26,7 @@ except ImportError:
     _fastio = None
 
 from binder_tpu.dns.query import QueryCtx
-from binder_tpu.dns.server import DnsServer
+from binder_tpu.dns.server import DnsServer, bind_port_pair
 from binder_tpu.dns.wire import (
     MAX_EDNS_PAYLOAD,
     MAX_UDP_PAYLOAD,
@@ -71,6 +70,7 @@ METRIC_REQUEST_COUNTER = "binder_requests_completed"
 METRIC_LATENCY_HISTOGRAM = "binder_request_latency_seconds"
 METRIC_SIZE_HISTOGRAM = "binder_response_size_bytes"
 METRIC_TRUNCATED_COUNTER = "binder_truncated_responses"
+METRIC_TRUNCATED_RENDERS = "binder_truncated_renders"
 # per-stage attribution (METRIC_STAGE_HISTOGRAM): one histogram, labeled
 # by stage, fed from the QueryCtx phase stamps at after-hook time — the
 # scrapeable form of the query log's `timers` dict (same stage names) —
@@ -263,6 +263,15 @@ class BinderServer:
         for qtype in (Type.A, Type.SRV):
             self.truncated_counter.labelled(
                 {"type": Type.name(qtype)}).inc(0)
+        # of those, the ones a resolve of the Python lanes rendered the
+        # whole set for, for the encode to drop it: what the answer
+        # cache did not hold (it holds a truncated wire from its first
+        # sight)
+        self._tc_render_child = self.collector.counter(
+            METRIC_TRUNCATED_RENDERS,
+            "truncated UDP answers that cost a resolve: the set was "
+            "rendered and the encode dropped it").labelled()
+        self._tc_render_child.inc(0)   # series exists from scrape 1
         # per-qtype pre-resolved metric handles (label-sort once, not
         # per query); key is the numeric qtype
         self._metric_children: dict = {}
@@ -776,8 +785,16 @@ class BinderServer:
 
         pending = self.resolver.handle(query)
 
-        if (pending is None and key is not None and query.responded
-                and query.wire is not None and not query.no_store
+        answered = (pending is None and query.responded
+                    and query.wire is not None)
+        # a wire that left truncated (only a UDP answer does) is its
+        # header, whatever set the resolve rendered for the encode to
+        # drop
+        truncated = answered and bool(query.wire[2] & 0x02)
+        if truncated:
+            self._tc_render_child.inc()
+
+        if (answered and key is not None and not query.no_store
                 and query.rcode() != Rcode.SERVFAIL):
             ans = [self._summarize(r) for r in query.response.answers]
             add = [self._summarize(r) for r in query.response.additionals
@@ -797,7 +814,11 @@ class BinderServer:
             rcode = query.rcode()
             self.answer_cache.put(
                 key, epoch, (query.wire, ans, add),
-                rotatable=len(query.response.answers) > 1, tag=tag,
+                # a truncated wire has one variant: no rotation of the
+                # set shows in a header, so the entry is complete from
+                # its first sight
+                rotatable=(len(query.response.answers) > 1
+                           and not truncated), tag=tag,
                 # negative answers (NXDOMAIN / NODATA) cache like
                 # positives but are accounted separately; SERVFAIL is
                 # excluded above — the never-cache rule
@@ -877,7 +898,8 @@ class BinderServer:
         exact key so repeats take the plain hit path (and promote to the
         native fast path on their first hit, same economics as lazy
         entries).  Declines (False) when the table has no entry or the
-        wire would need UDP truncation — the generic path owns those."""
+        wire would need UDP truncation: the generic path owns those,
+        and ``_on_query`` stores the header it sends as one variant."""
         if q0.qclass != 1:
             return False
         epoch = self.zk_cache.epoch
@@ -2415,11 +2437,6 @@ class BinderServer:
 
     # -- lifecycle (lib/server.js:609-657) --
 
-    #: ephemeral pair-bind redraws before giving up; each failure means
-    #: the kernel-chosen UDP port was taken on TCP, so consecutive
-    #: failures are near-independent draws from the ephemeral range
-    _PAIR_BIND_ATTEMPTS = 16
-
     async def start(self) -> None:
         if self._precompiler is not None:
             # compile the already-mirrored names (mirrors built before
@@ -2429,52 +2446,33 @@ class BinderServer:
         self._zone_fill()
         if self.balancer_socket:
             await self.engine.listen_balancer(self.balancer_socket)
-        # UDP and TCP must share one port number (the reference serves
-        # both on the same port, lib/server.js:643-653).  With port=0
-        # the kernel picks the UDP port and any unrelated socket may
-        # already hold that number on TCP — so the pair bind is a retry
-        # loop: release the UDP draw and redraw instead of failing
-        # (the observed CI flake: EADDRINUSE on the UDP-chosen port).
-        for attempt in range(self._PAIR_BIND_ATTEMPTS):
-            # announce only once the PAIR is secured: harnesses watch
-            # the "service started" lines for the port, and a line
-            # printed for a draw that is then released and redrawn
-            # advertises a dead port (observed as a CI dnsblast
-            # connection-refused failure)
-            try:
-                udp_port = await self.engine.listen_udp(
+        # UDP and TCP share one port number (the reference serves both
+        # on the same port, lib/server.js:643-653); a kernel-chosen
+        # draw that is taken on TCP is made again (the observed CI
+        # flake: EADDRINUSE on the UDP-chosen port).  Announce only
+        # once the PAIR is secured: harnesses watch the "service
+        # started" lines for the port, and a line printed for a draw
+        # that is then released advertises a dead port (observed as a
+        # CI dnsblast connection-refused failure)
+        try:
+            self.udp_port, self.tcp_port = await bind_port_pair(
+                self.port,
+                lambda: self.engine.listen_udp(
                     self.host, self.port, announce=False,
-                    reuse_port=self.reuse_port)
-            except OSError:
-                # a UDP bind failure (fixed port taken) must release
-                # the balancer listener opened above, like the TCP path
-                await self.engine.close()
-                raise
-            try:
-                self.tcp_port = await self.engine.listen_tcp(
-                    self.host, self.port if self.port else udp_port,
-                    announce=False, reuse_port=self.reuse_port)
-            except OSError as e:
-                # the failed draw must be released even when re-raising:
-                # callers treat start() as atomic and won't stop() a
-                # server that never started
-                self.engine.close_udp_listener(udp_port)
-                # errno is None when asyncio aggregates several bind
-                # failures (multi-address hosts) into one OSError — a
-                # colliding draw must redraw in that shape too
-                if (self.port == 0
-                        and e.errno in (_errno.EADDRINUSE, None)
-                        and attempt < self._PAIR_BIND_ATTEMPTS - 1):
-                    continue
-                # failed for good: release the balancer listener opened
-                # above so the raise leaves no socket behind
-                await self.engine.close()
-                raise
-            self.udp_port = udp_port
-            if self.announce:
-                self.engine.announce_udp(self.host, udp_port)
-                self.engine.announce_tcp(self.host, self.tcp_port)
-            break
+                    reuse_port=self.reuse_port),
+                lambda port: self.engine.listen_tcp(
+                    self.host, port, announce=False,
+                    reuse_port=self.reuse_port),
+                self.engine.close_udp_listener)
+        except OSError:
+            # failed for good (a fixed port taken on UDP or on TCP):
+            # release the balancer listener opened above so the raise
+            # leaves no socket behind
+            await self.engine.close()
+            raise
+        if self.announce:
+            self.engine.announce_udp(self.host, self.udp_port)
+            self.engine.announce_tcp(self.host, self.tcp_port)
         if self._log_json_handlers and self._log_flush_task is None:
             for h in self._log_json_handlers:
                 h.addFilter(self._before_record)

@@ -182,6 +182,51 @@ class TestChaosUpstream:
         self.run(go())
 
 
+    def test_a_drawn_port_taken_on_tcp_is_redrawn(self):
+        """The kernel draws the UDP port and TCP shares its number: where
+        another socket holds that number on TCP (a client's, in
+        TIME_WAIT) the pair is drawn again, as ``BinderServer.start``
+        does; a port the caller fixed is not."""
+        async def go():
+            import socket
+            blocker = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+            blocker.bind(("127.0.0.1", 0))
+            blocker.listen(1)
+            taken = blocker.getsockname()[1]
+            real = asyncio.start_server
+            ports = []
+
+            async def first_draw_collides(cb, host, port, **kw):
+                ports.append(port)
+                return await real(cb, host,
+                                  taken if len(ports) == 1 else port, **kw)
+
+            asyncio.start_server = first_draw_collides
+            up = ChaosUpstream(FaultPlan(), hosts={"w.foo.com": "10.1.1.1"})
+            try:
+                port = await up.start()
+                client = DnsClient(timeout=0.5)
+                try:
+                    recs = await client.lookup("w.foo.com", Type.A,
+                                               [f"127.0.0.1:{port}"])
+                finally:
+                    client.close()
+                await up.stop()
+                fixed = ChaosUpstream(FaultPlan(), hosts={})
+                asyncio.start_server = real
+                with pytest.raises(OSError):
+                    await fixed.start(port=taken)
+                assert fixed._udp_transport is None
+            finally:
+                asyncio.start_server = real
+                blocker.close()
+            return ports, port, [r.address for r in recs]
+
+        ports, port, addresses = self.run(go())
+        assert len(ports) == 2 and port == ports[1]
+        assert addresses == ["10.1.1.1"]
+
+
 # ---------------------------------------------------------------------------
 # circuit breakers + hedging
 

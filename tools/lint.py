@@ -1202,6 +1202,7 @@ _LEDGER_FAMILIES = {
     "binder_answer_cache_hits": "counter",
     "binder_query_log_bytes": "counter",
     "binder_query_log_lines": "counter",
+    "binder_truncated_renders": "counter",
 }
 _LEDGER_STAGES = ("loop-idle", "udp-recv", "native-serve", "udp-send",
                   "log-write", "log-line",
