@@ -3,7 +3,7 @@
 
 PY ?= python3
 
-.PHONY: all native test check ci bench bench-smoke status-smoke \
+.PHONY: all native test check ci status-smoke \
 	chaos-smoke tcp-smoke shard-smoke zone-smoke federation-smoke \
 	hostile-smoke verify-smoke balancer-smoke population-smoke \
 	chip-smoke real-tiers clean
@@ -28,8 +28,7 @@ test: native
 # a warning), smoke the sanitizer-built fuzzers over the native parsers,
 # and run the fastio pytest suites against the ASan-built extension)
 check:
-	$(PY) -m compileall -q binder_tpu tests bench.py bench_impl.py \
-		chip_smoke.py __graft_entry__.py
+	$(PY) -m compileall -q binder_tpu tests chip_smoke.py __graft_entry__.py
 	$(PY) tools/lint.py
 	$(MAKE) -B -C native \
 		CXXFLAGS="-O2 -g -Wall -Wextra -Werror -std=c++17" \
@@ -39,8 +38,8 @@ check:
 
 # the reference's Jenkins pipeline as one invocable unit
 # (Jenkinsfile:25-41: checkout -> check -> [test]); extended with the
-# gates the reference leaves to production: full test suite + bench
-# smoke.  Explicitly sequential: check's ASan extension swap must not
+# gates the reference leaves to production: full test suite + the
+# smokes.  Explicitly sequential: check's ASan extension swap must not
 # race test's pytest import under `make -j`.
 # ci turns the glibc stub-resolver tier on when running as root (it
 # rewrites /etc/resolv.conf and binds 127.0.0.1:53, so plain `make
@@ -52,7 +51,6 @@ ci:
 	$(MAKE) check
 	$(MAKE) test CONFORMANCE_STRICT=--strict \
 		BINDER_LIBC_CONFORMANCE="$${BINDER_LIBC_CONFORMANCE-$$([ "$$(id -u)" = 0 ] && echo 1)}"
-	$(MAKE) bench-smoke
 	BINDER_CHAOS_SECONDS=10 $(MAKE) chaos-smoke
 	$(MAKE) tcp-smoke
 	BINDER_SHARD_SECONDS=10 $(MAKE) shard-smoke
@@ -63,21 +61,6 @@ ci:
 	BINDER_BALANCER_SECONDS=10 $(MAKE) balancer-smoke
 	BINDER_POPULATION_SECONDS=10 $(MAKE) population-smoke
 	@echo "ci: all gates passed"
-
-# one fast reduced-iteration bench pass proving the measured paths still
-# run end to end (its numbers are not comparable: small samples, and the
-# baseline write is diverted); the driver runs the full bench.py separately
-bench-smoke: native
-	@mkdir -p .scratch
-	BENCH_QUERIES=5000 BENCH_PASSES=1 BENCH_MISS_QUERIES=2000 \
-		BENCH_RECURSION_QUERIES=2000 BENCH_TCP1_QUERIES=1500 \
-		BENCH_TC_FLOWS=300 BENCH_SHARD_NS=1,2 \
-		BENCH_POPULATION_SECONDS=8 \
-		BENCH_BASELINE_FILE=.scratch/bench_smoke_baseline.json \
-		$(PY) bench.py
-
-bench: native
-	$(PY) bench.py
 
 # introspection end-to-end smoke: boot a fake-store server, fetch the
 # /status snapshot over HTTP, run the snapshot-schema and Prometheus

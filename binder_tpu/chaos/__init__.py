@@ -9,8 +9,9 @@
   dead-peer).
 
 Consumed by tests/test_chaos.py, ``tools/chaos_smoke.py`` (the
-``make chaos-smoke`` target), the bench's degraded axis, and — via the
-``chaos`` config block — a live server under test (``main.py``).
+``make chaos-smoke`` target) and — via the ``chaos`` config block — a
+live server under test (``main.py``; the benchmark's deployments script
+their store write so).
 """
 from binder_tpu.chaos.plan import ChaosDriver, FaultPlan, UpstreamFaults
 from binder_tpu.chaos.upstream import ChaosUpstream

@@ -6,8 +6,8 @@ failure.  A :class:`FaultPlan` is a timeline of fault actions plus the
 live upstream-fault state, injectable into the fake store, the ZK test
 server, and the chaos upstream (``chaos/upstream.py``), and scriptable
 from three places: unit tests (build it in code), ``make chaos-smoke``
-(the DSL below), and the bench's degraded axis (a ``chaos`` config
-block, ``main.py``).
+(the DSL below), and a live server's ``chaos`` config block
+(``main.py``).
 
 DSL — one action per line (``;`` also separates), ``#`` comments::
 

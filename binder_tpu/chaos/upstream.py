@@ -17,8 +17,7 @@ The response is built by patching the *request* wire — id and question
 echoed byte-verbatim — so the chaos upstream is transparent to the
 client's dns0x20 validation, exactly like a real binder peer.
 
-Used by tests/test_chaos.py, ``tools/chaos_smoke.py``, and the bench's
-degraded axis.
+Used by tests/test_chaos.py and ``tools/chaos_smoke.py``.
 """
 from __future__ import annotations
 

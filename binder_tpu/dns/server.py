@@ -588,13 +588,6 @@ class DnsServer:
         # handlers stuck in read
         self._conns: set = set()
         self._decode_cache: dict = {}
-        # Raw resolve lane (installed by BinderServer): handles the
-        # dominant query shape (single A/IN question) by direct wire
-        # assembly, skipping Message decode/encode.  Returns True when it
-        # fully handled the packet (response sent, metrics recorded);
-        # anything it can't prove simple falls through to the generic
-        # path below.
-        self.raw_lane: Optional[Callable] = None
         # Native fast-path cache (installed by BinderServer when the
         # _binderfastio extension is built): answer-cache hits are served
         # inside the C drain loop and never surface here.  `fastpath_gen`
@@ -657,7 +650,7 @@ class DnsServer:
         # Direct-return negotiation switch: announce the capability on
         # every balancer link so a capable balancer passes its client
         # socket.  BINDER_NO_DIRECT_RETURN=1 keeps the classic pure
-        # relay — the A/B lever for tests and the bench's relay arm.
+        # relay — the A/B lever for tests and mixed-version fleets.
         self.balancer_direct_return = os.environ.get(
             "BINDER_NO_DIRECT_RETURN", "") not in ("1", "true", "yes")
 
@@ -880,15 +873,6 @@ class DnsServer:
                 except OSError:
                     pass
                 return
-        lane = self.raw_lane
-        if lane is not None:
-            try:
-                if lane(data, src, protocol, send, client_transport):
-                    return
-            except Exception:
-                # the lane assembles before it sends, so falling through
-                # re-processes the query from scratch safely
-                self.log.exception("raw lane failed; using generic path")
         try:
             request = self._decode_query(data)
         except WireError as e:

@@ -15,8 +15,8 @@ the shared event loop:
 - **Accept fast path** — the listener arms ``TCP_DEFER_ACCEPT``, so
   accept-readiness normally fires with the client's first frame already
   in the socket buffer.  The accept callback reads it, serves every
-  complete frame through the same native-bulk/raw-lane/generic ladder
-  the old protocol used, and answers with one vectored write — accept,
+  complete frame through the same native-bulk/generic ladder the old
+  protocol used, and answers with one vectored write — accept,
   read, serve, and respond in a single loop iteration, no task, no
   streams.  A one-shot client's close lands as EOF on a later readiness
   callback and tears the state down; only clients that keep sending get

@@ -205,7 +205,7 @@ class Introspector:
             "domain": zc.domain,
             "generation": zc.gen,
             "epoch": zc.epoch,
-            # zone scale (ISSUE 7): every bench/status reading carries
+            # zone scale (ISSUE 7): every status reading carries
             # the size it was measured at ("nodes" kept as the
             # historical alias of the name count)
             "nodes": len(zc.nodes),

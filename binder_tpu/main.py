@@ -53,7 +53,7 @@ def make_store(options: Dict[str, object], log: logging.Logger,
                     store.put_json(path, obj)
         synthetic = store_cfg.get("synthetic")
         if synthetic:
-            # zone_scale bench / smoke surface: generate a
+            # zone-scale smoke and benchmark surface: generate a
             # production-scale zone procedurally instead of shipping a
             # hundred-MB fixture file through JSON twice
             from binder_tpu.store.fake import populate_synthetic
@@ -419,7 +419,7 @@ async def run(options: Dict[str, object]) -> BinderServer:
                  len(cache.nodes))
 
     # fault injection (chaos) — ONLY when configured, for soaks and the
-    # bench's degraded axis: a scripted FaultPlan drives session loss /
+    # benchmark's store write: a scripted FaultPlan drives session loss /
     # watch storms / loop stalls inside the live process
     # (binder_tpu/chaos, docs/degradation.md).  In shard mode the
     # supervisor owns chaos (it has the store and the kill switch).

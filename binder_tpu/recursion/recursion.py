@@ -344,7 +344,7 @@ class Recursion:
         # vs sitting in the local event loop waiting for this callback?
         # The client stamps the datagram's arrival on the future
         # (binder_recv_t); the two spans are recorded separately so the
-        # stage histograms/bench can name the owner.
+        # stage histograms can name the owner.
         now = time.monotonic()
         recv_t = getattr(fut, "binder_recv_t", None)
         if sent_at is not None and recv_t is not None:

@@ -28,7 +28,6 @@ except ImportError:
 from binder_tpu.dns.query import QueryCtx
 from binder_tpu.dns.server import DnsServer, bind_port_pair
 from binder_tpu.dns.wire import (
-    MAX_EDNS_PAYLOAD,
     MAX_UDP_PAYLOAD,
     ARecord,
     OPTRecord,
@@ -97,11 +96,6 @@ def strip_suffix(suffix: str, s: str) -> str:
     return s
 
 
-# Pre-encoded EDNS echo for the raw lane: name 0, TYPE OPT(41),
-# CLASS=payload 1232, TTL 0, RDLEN 0 — byte-identical to the generic
-# path's _ECHO_OPT (dns/query.py) encoding.
-_OPT_ECHO_WIRE = b"\x00" + struct.pack(">HHIH", 41, 1232, 0, 0)
-
 # one label of a registered srvce/proto pair, exactly what one group of
 # the engine's SRV_RE can match — zone SRV entries are only pushed for
 # qnames the engine would parse back to the same service
@@ -112,10 +106,10 @@ _SRV_LABEL_RE = re.compile(r"^_[^_.]*$")
 # accepts would be silently rejected and the name never precompiled
 _FP_MAX_VARIANTS = 8
 
-# Record types the raw lane may answer directly: exactly the host-likes
-# the resolver maps to a single A record (resolver/engine.py:213-216).
-# 'service' (rotation, SRV) and 'database' (URL parse) take the generic
-# path.
+# Record types the zone table answers with one A record: exactly the
+# host-likes the resolver maps to a single A record
+# (resolver/engine.py:213-216).  'service' (rotation, SRV) and 'database'
+# (URL parse) have their own zone pushes.
 _LANE_HOST_TYPES = frozenset({
     "db_host", "host", "load_balancer", "moray_host", "redis_host",
     "ops_host", "rr_host",
@@ -138,9 +132,9 @@ def _rec_ttl(rec: tuple) -> int:
 def _lane_ttl(record: dict, sub) -> Optional[int]:
     """Deepest-object-wins TTL (the one policy, engine._record_ttl:
     sub-record TTL wins, else record TTL, else default); None means the
-    store value is garbage and the lane must decline to the generic
-    path.  Shared by the A and PTR lane branches so the precedence
-    cannot drift between them."""
+    store value is garbage and the zone table must leave the name to
+    the Python lanes.  Shared by the zone's A and PTR pushes so the
+    precedence cannot drift between them."""
     ttl = record.get("ttl")
     sttl = sub.get("ttl") if type(sub) is dict else None
     if sttl is not None:
@@ -155,7 +149,8 @@ def _fastpath_key_parts(rd: bool, edns: bool, payload: int, qtype: int,
     """The native answer-cache key, from its components.
 
     SINGLE SOURCE OF THE LAYOUT on the Python side — both
-    ``BinderServer._fastpath_key`` and the raw lane build through here.
+    ``BinderServer._fastpath_key`` and the native installs build
+    through here.
     Must stay byte-for-byte with ``fp_build_key`` in
     native/fastio/fastpath.c and the balancer's copy (see
     docs/balancer-protocol.md):
@@ -201,8 +196,8 @@ class BinderServer:
                  announce: bool = True) -> None:
         self.log = log or logging.getLogger("binder.server")
         # introspection flight recorder (binder_tpu/introspect):
-        # slow-query events from the after hook and lane, resolver
-        # errors from the engine's error path
+        # slow-query events from the after hook, resolver errors from
+        # the engine's error path
         self.recorder = flight_recorder
         self.host = host
         self.port = port
@@ -550,13 +545,11 @@ class BinderServer:
         ).set_function(lambda: float(len(self.engine._tcp_conns)))
         self.collector.on_expose(self._fold_engine_counters)
 
-        # Raw resolve lane: direct wire assembly for single-question A/IN
-        # queries (see _raw_lane).  Policy strings mirror Resolver.resolve
-        # exactly; the lane declines anything it can't prove simple.
+        # The zone table's dnsDomain suffix policy (_zone_suffix_ok):
+        # the strings Resolver.resolve compares against, built once.
         dd = self.resolver.dns_domain
         self._lane_suffix = ("." + dd) if dd else None
         self._lane_dcsuff = dd + "." + self.resolver.datacenter_name
-        self.engine.raw_lane = self._raw_lane
 
         # Native fast path: answer-cache hits served inside the C UDP
         # drain (native/fastio/fastpath.c).  Python remains the source of
@@ -646,8 +639,8 @@ class BinderServer:
         # never surfaces to Python.  The reference resolves every cold
         # name per query (lib/server.js:136); this is the rebuild's
         # NSD/Knot-style answer to that.  `zonePrecompile: false`
-        # disables it (the bench uses that to keep an honest measurement
-        # of the Python resolve path).
+        # disables it (the tests' reference servers use that to make
+        # every answer a resolve).
         self._zone_enabled = (
             zone_precompile and self._fastpath is not None
             and hasattr(_fastio, "fastpath_zone_put"))
@@ -953,8 +946,8 @@ class BinderServer:
         the zone RE-PUSHES are refill work and are deferred to a
         bounded dirty-set drain between serving batches, so a mutation
         burst can't stall the hot loop (VERDICT r4 weak 5).  Until a
-        name's refresh runs, its queries resolve through the raw lane /
-        generic path — slower, never stale."""
+        name's refresh runs, its queries resolve through the Python
+        lanes (_on_query) — slower, never stale."""
         wires = []
         # question shapes the drops touched — the precompiler's exact
         # re-render work list (concrete negative SRV qnames, postures)
@@ -1038,7 +1031,7 @@ class BinderServer:
         mirror currently resolves it to a shape the zone table serves.
         Stale entries were already dropped by tag invalidation; absent
         or ineligible names simply stay un-pushed and resolve through
-        the raw lane / generic path."""
+        the Python lanes (_on_query)."""
         ctx = self._zone_trace.pop(name, None)
         try:
             if name.endswith(".in-addr.arpa") or name.endswith(".ip6.arpa"):
@@ -1119,10 +1112,12 @@ class BinderServer:
         return ip
 
     def _zone_host_shape(self, node):
-        """(record, sub, packed_addr, ttl) when `node` is a host-like
-        record the raw lane would answer, else None — the eligibility
-        rules are _raw_lane's, verbatim, so the zone table can never
-        answer a shape the lane would decline."""
+        """(record, sub, packed_addr, ttl) when ``Resolver.resolve``
+        answers `node` with exactly one A record, else None.  The rules:
+        a type in _LANE_HOST_TYPES, a dict sub-record, a canonical
+        dotted-quad address, int TTLs; anything else stays un-pushed,
+        so the zone table never answers what the resolver would answer
+        otherwise (invalid records, store garbage it SERVFAILs on)."""
         rec = node.rec
         if type(rec) is tuple:
             # compact host-like: the only decline left is the address
@@ -1191,8 +1186,8 @@ class BinderServer:
 
     def _zone_push_a(self, name: str, node) -> None:
         """Precompile the A answer for a host-like or database record
-        (the raw lane's A branch plus engine.resolve's database branch,
-        done once at mutation time instead of per query)."""
+        (``Resolver.resolve``'s host-like and database branches, done
+        once at mutation time instead of per query)."""
         if not self._zone_suffix_ok(name):
             return
         rec = node.rec
@@ -1227,8 +1222,9 @@ class BinderServer:
             self.log.debug("zone A push skipped for %s: %s", name, e)
 
     def _zone_suffix_ok(self, name: str) -> bool:
-        """The raw lane's dnsDomain suffix policy (a doubled suffix is
-        REFUSED, never answered) — shared by every forward zone push."""
+        """``Resolver.resolve``'s dnsDomain suffix policy (a name
+        outside the suffix, or with it doubled up, is REFUSED, never
+        answered) — shared by every forward zone push."""
         dd_suffix = self._lane_suffix
         if dd_suffix is None or not name.endswith(dd_suffix):
             return False
@@ -1458,16 +1454,18 @@ class BinderServer:
             self.log.debug("zone SRV push skipped for %s: %s", name, e)
 
     def _zone_push_ptr(self, rev_name: str, owner) -> None:
-        """Precompile the PTR answer for a reverse name (the raw lane's
-        PTR branch; NO dnsDomain suffix policy on the reverse tree,
-        lib/server.js:67-134)."""
+        """Precompile the PTR answer for a reverse name
+        (``Resolver.resolve_ptr``'s answer; NO dnsDomain suffix policy
+        on the reverse tree, lib/server.js:67-134)."""
         shape = self._zone_host_shape(owner)
         if shape is None:
             return
         _record, _sub, _packed, ttl = shape
         target = owner.domain
         if target.endswith(".arpa"):
-            return                      # parity with the lane's decline
+            # the resolver's encoder could compress such a target
+            # against the reverse qname; the push's fixed body cannot
+            return
         tw = self._qname_wire(target)
         if tw is None:
             return
@@ -1516,7 +1514,7 @@ class BinderServer:
         semantics); at zone scale the walk moves to a time-budgeted
         background task so serving starts immediately and the fill
         streams in behind it (un-filled names resolve through the
-        raw lane / generic path — slower, never wrong)."""
+        Python lanes, _on_query — slower, never wrong)."""
         if not self._zone_enabled:
             return
         try:
@@ -1650,378 +1648,6 @@ class BinderServer:
         return _fastpath_key_parts(req.rd, req.edns is not None,
                                    req.max_udp_payload(), q0.qtype,
                                    q0.qclass, raw[12:off].lower())
-
-    def _raw_lane(self, data: bytes, src, protocol: str, send,
-                  client_transport: Optional[str] = None) -> bool:
-        """Direct-assembly resolve for the dominant query shapes: one
-        A/IN or PTR/IN question, optionally with a bare EDNS OPT.
-
-        The generic path costs ~60µs per cold name (Message decode,
-        QueryCtx, resolver, Message encode); this lane answers the same
-        shapes in a few µs by patching the request wire: header rewrite,
-        verbatim question echo, one compression-pointer A or PTR record.
-        It mirrors ``Resolver.resolve`` / ``Resolver.resolve_ptr``
-        policy exactly for the shapes it accepts — suffix /
-        doubled-suffix REFUSED (forward only; the reverse tree has no
-        suffix policy), store-down SERVFAIL, TTL precedence,
-        REFUSED-not-NXDOMAIN on misses (lib/server.js:227-241) — and is
-        differential-tested against the generic path
-        (tests/test_raw_lane.py).  Everything else — other qtypes, EDNS
-        options, service/database records, the recursion handoff,
-        invalid records, responses that would need UDP truncation,
-        query-log/probes active — returns False and takes the generic
-        path, so divergence is impossible for declined shapes.
-
-        The question section is echoed with the requester's original
-        case (dns0x20), matching the generic path's echo in
-        QueryCtx._echo_question_case.
-        """
-        if (self.query_log or self.p_req_start.enabled
-                or self.p_req_done.enabled):
-            return False
-        if self._policy is not None and self._policy.mode() != "fresh":
-            # degraded serving (TTL clamp, withhold-past-cap) is the
-            # generic path's job; the lane declines rather than
-            # duplicating the policy matrix (docs/degradation.md)
-            return False
-        dd_suffix = self._lane_suffix
-        if dd_suffix is None:
-            return False
-        n = len(data)
-        if n < 17:
-            return False
-        # header: QR / opcode / TC must be clear; QD=1; AN=NS=0; AR<=1
-        if data[2] & 0xFA:
-            return False
-        if (data[4] or data[5] != 1 or data[6] or data[7] or data[8]
-                or data[9] or data[10] or data[11] > 1):
-            return False
-        start = time.monotonic()
-        # question name: case-preserving walk, charset-validated (the
-        # charset equals the resolver's NAME_RE alphabet, so names the
-        # lane declines here are exactly the generic path's
-        # invalid-name REFUSED shapes plus non-ASCII oddities)
-        labels = []
-        off = 12
-        ok = _FP_NAME_OK.issuperset
-        while True:
-            ll = data[off]
-            if ll == 0:
-                off += 1
-                break
-            if ll & 0xC0:
-                return False           # compressed qname
-            end = off + 1 + ll
-            if end + 1 > n:
-                return False
-            if not ok(data[off + 1:end]):
-                return False
-            labels.append(data[off + 1:end])
-            off = end
-            if off - 12 > 255:
-                return False
-        if off + 4 > n:
-            return False
-        qtype_b = data[off:off + 4]
-        if qtype_b == b"\x00\x01\x00\x01":       # A / IN
-            qtype_val = 1
-        elif qtype_b == b"\x00\x0c\x00\x01":     # PTR / IN
-            qtype_val = 12
-        else:
-            return False
-        q_end = off + 4
-        edns = False
-        payload = MAX_UDP_PAYLOAD
-        if data[11]:
-            # exactly one bare OPT: root name, TYPE 41, version 0, no
-            # RDATA (EDNS options vary per packet and take the generic
-            # path; so do nonzero versions)
-            if q_end + 11 != n or data[q_end] != 0:
-                return False
-            otype, ocls = struct.unpack_from(">HH", data, q_end + 1)
-            if otype != 41 or data[q_end + 6] != 0:
-                return False
-            if data[q_end + 9] or data[q_end + 10]:
-                return False
-            # same floor/clamp as Message.max_udp_payload — shared
-            # constants so the copies cannot drift
-            if ocls >= MAX_UDP_PAYLOAD:
-                payload = min(ocls, MAX_EDNS_PAYLOAD)
-            edns = True
-        elif q_end != n:
-            return False               # trailing bytes
-        try:
-            name = b".".join(labels).lower().decode("ascii")
-        except UnicodeDecodeError:
-            return False
-
-        rd_flag = data[2] & 0x01
-        udp_sem = (protocol == "udp"
-                   or (protocol == "balancer" and client_transport != "tcp"))
-        # the key layout must stay byte-for-byte with _on_query's
-        key = (udp_sem, bool(rd_flag), qtype_val, 1, name, edns, payload)
-        cache = self.zk_cache
-        epoch = cache.epoch
-        hit = self.answer_cache.get(key, epoch)
-        if hit is not None:
-            cached = hit[0]
-            # patch in this requester's id AND question bytes: cached
-            # wires store the question lowercased (see the put below), so
-            # echoing the requester's own bytes keeps dns0x20 validators
-            # happy; same name/qtype keyed -> identical section length
-            wire = (data[:2] + cached[2:12] + data[12:q_end]
-                    + cached[q_end:])
-            send(wire)
-            try:
-                self._cache_hit_child.inc()
-                self._lane_finish(data, src, protocol, start, wire,
-                                  wire[3] & 0x0F, edns, hit[1], hit[2],
-                                  qtype=qtype_val, cached=True)
-                # promote-on-first-hit: the repeat proves the name hot;
-                # hand it to the C fast path so the next repeat never
-                # surfaces to Python
-                if (udp_sem and self._fastpath is not None
-                        and self._fastpath_active()):
-                    claimed = self.answer_cache.take_push(key, epoch)
-                    if claimed is not None:
-                        qname_low = data[12:q_end - 4].lower()
-                        ckey = _fastpath_key_parts(
-                            bool(rd_flag), edns, payload, qtype_val, 1,
-                            qname_low)
-                        try:
-                            _fastio.fastpath_put(
-                                self._fastpath, ckey, qtype_val, epoch,
-                                [v[0] for v in claimed[0]],
-                                int(self.answer_cache.expiry_s * 1000),
-                                qname_low)
-                        except (TypeError, ValueError, MemoryError) as e:
-                            self.log.debug("fastpath push skipped: %s",
-                                           e)
-            except Exception:
-                # response already sent: never fall through to the
-                # generic path (it would answer a second time)
-                self.log.exception("raw lane post-send bookkeeping failed")
-            return True
-
-        # Mutation-time precompiled probe (the lane edition of
-        # _serve_compiled): a dict probe + RD patch + the same id/case
-        # splice as the hit path above, instead of the inline resolve
-        # below.  Declines to the resolve on truncation overflow.
-        comp = self.answer_cache.get_compiled(qtype_val, name, epoch)
-        if comp is not None:
-            (w0, w1, ans, add), rotatable, tag, negative = comp
-            cw = w1 if edns else w0
-            if not (udp_sem and len(cw) > payload):
-                if rd_flag:
-                    cw = patch_answer_wire(cw, rd=True)
-                wire = (data[:2] + cw[2:12] + data[12:q_end]
-                        + cw[q_end:])
-                send(wire)
-                try:
-                    self._precompile_serve_child.inc()
-                    self._lane_finish(data, src, protocol, start, wire,
-                                      wire[3] & 0x0F, edns, ans, add,
-                                      qtype=qtype_val, cached=True)
-                    self.answer_cache.put(
-                        key, epoch, (cw, ans, add), rotatable=rotatable,
-                        tag=tag, negative=negative,
-                        qkey=(qtype_val, name))
-                except Exception:
-                    # response already sent: never fall through to the
-                    # generic path (it would answer a second time)
-                    self.log.exception(
-                        "raw lane post-send bookkeeping failed")
-                return True
-
-        # -- resolution --
-        body = b""
-        ancount = 0
-        ans = []
-        if qtype_val == 1:
-            # mirrors Resolver.resolve ordering exactly
-            rcode = 0
-            node = None
-            if not name.endswith(dd_suffix):
-                rcode = Rcode.REFUSED  # not within dns domain suffix
-            else:
-                stripped = name[:-len(dd_suffix)]
-                dd = self.resolver.dns_domain
-                if (stripped == dd or stripped.endswith(dd_suffix)
-                        or stripped == self._lane_dcsuff
-                        or stripped.endswith("." + self._lane_dcsuff)):
-                    rcode = Rcode.REFUSED  # doubled-up dns domain suffix
-                elif not cache.is_ready():
-                    self.log.error("no coordination-store session")
-                    rcode = Rcode.SERVFAIL
-                else:
-                    node = cache.lookup(name)
-                    if node is None:
-                        if (self.resolver.recursion is not None
-                                and rd_flag):
-                            return False  # recursion handoff: generic
-                        rcode = Rcode.REFUSED
-
-            if rcode == 0 and node is not None:
-                rec = node.rec
-                if type(rec) is tuple:
-                    # compact host-like (store/names.py): address and
-                    # int TTLs by invariant, canonicality still checked
-                    if rec[0] not in _LANE_HOST_TYPES:
-                        return False
-                    addr = rec[1]
-                    ttl = _rec_ttl(rec)
-                else:
-                    rt = rec.get("type") if type(rec) is dict else None
-                    if rt not in _LANE_HOST_TYPES:
-                        return False   # service/database/invalid record
-                    sub = rec.get(rt)
-                    if type(sub) is not dict:
-                        return False
-                    addr = sub.get("address")
-                    if type(addr) is not str:
-                        return False
-                    ttl = _lane_ttl(rec, sub)
-                    if ttl is None:
-                        return False   # store garbage: generic path
-                try:
-                    packed = _socket.inet_aton(addr)
-                except (OSError, TypeError):
-                    return False       # generic path SERVFAILs
-                if _socket.inet_ntoa(packed) != addr:
-                    return False       # non-canonical dotted quad
-                body = (b"\xc0\x0c\x00\x01\x00\x01"
-                        + struct.pack(">IH", ttl & 0xFFFFFFFF, 4)
-                        + packed)
-                ancount = 1
-                # same string _summarize(ARecord) renders, through the
-                # one redaction helper, without the record-object round
-                # trip
-                ans = [f"{strip_suffix(dd_suffix, name)} A {addr}"]
-        else:
-            # PTR: mirrors Resolver.resolve_ptr exactly — note there is
-            # NO dnsDomain suffix policy on the reverse tree
-            # (lib/server.js:67-134)
-            rcode = 0
-            ip = None
-            parts = name.split(".")
-            if len(parts) >= 2 and parts[-1] == "arpa" \
-                    and parts[-2] == "ip6":
-                # IPv6 reverse: strict canonical nibble parse (the
-                # reverse map is keyed by canonical address strings);
-                # malformed ip6.arpa names miss below
-                ip = ip_from_reverse_name(name)
-                if ip is None:
-                    rcode = Rcode.REFUSED
-            elif len(parts) < 2 or parts[-1] != "arpa" \
-                    or parts[-2] != "in-addr":
-                rcode = Rcode.REFUSED  # not an ip reverse name
-            if rcode == 0 and not cache.is_ready():
-                self.log.error("no coordination-store session")
-                rcode = Rcode.SERVFAIL
-            elif rcode == 0:
-                if ip is None:
-                    # no octet validation: an invalid address simply
-                    # misses (comment at lib/server.js:79-83)
-                    ip = ".".join(reversed(parts[:-2]))
-                node = cache.reverse_lookup(ip)
-                if node is None:
-                    if self.resolver.recursion is not None and rd_flag:
-                        return False   # recursion handoff: generic path
-                    rcode = Rcode.REFUSED
-                else:
-                    rec = node.rec
-                    if type(rec) is tuple:
-                        ttl = _rec_ttl(rec)
-                    else:
-                        record = rec if type(rec) is dict else {}
-                        rt = record.get("type")
-                        sub = record.get(rt) if type(rt) is str else None
-                        ttl = _lane_ttl(record, sub)
-                        if ttl is None:
-                            return False   # store garbage: generic path
-                    target = node.domain
-                    if target.endswith(".arpa"):
-                        # the generic encoder could compress the target
-                        # against the reverse qname; keep parity by
-                        # declining the (absurd) overlap case
-                        return False
-                    # the one real name encoder enforces the label and
-                    # 255-byte total bounds the generic path would
-                    # SERVFAIL on; unencodable targets decline
-                    tw = self._qname_wire(target)
-                    if tw is None:
-                        return False
-                    body = (b"\xc0\x0c\x00\x0c\x00\x01"
-                            + struct.pack(">IH", ttl & 0xFFFFFFFF,
-                                          len(tw)) + tw)
-                    ancount = 1
-                    # the dict _summarize renders for PTR records,
-                    # without the record-object round trip
-                    ans = [{"type": "PTR", "name": name, "ttl": ttl,
-                            "target": target}]
-
-        flags_out = 0x8400 | (0x0100 if rd_flag else 0) | rcode
-        wire = (data[:2]
-                + struct.pack(">HHHHH", flags_out, 1, ancount, 0,
-                              1 if edns else 0)
-                + data[12:q_end] + body
-                + (_OPT_ECHO_WIRE if edns else b""))
-        if udp_sem and len(wire) > payload:
-            # a long reverse qname + long target can exceed the UDP
-            # ceiling; the generic path owns truncation semantics
-            return False
-        send(wire)
-        try:
-            self._lane_finish(data, src, protocol, start, wire, rcode,
-                              edns, ans, [], qtype=qtype_val)
-            if rcode != Rcode.SERVFAIL:
-                # cache entries carry a lowercased question so hits can
-                # splice in each requester's own case (generic hits do
-                # the same via QueryCtx._echo_question_case).  The
-                # native push happens at the entry's first hit above
-                # (promote-on-first-hit), never on this cold path.
-                q_sec = data[12:q_end]
-                q_low = q_sec.lower()
-                cache_wire = (wire if q_sec == q_low
-                              else wire[:12] + q_low + wire[q_end:])
-                # lane answers (hit, miss-REFUSED, suffix-REFUSED) all
-                # depend on exactly this name; the qname doubles as the
-                # dependency tag.  qkey carries the question identity as
-                # re-render evidence — without it, churn on a name served
-                # only by this lane would never reach the precompiler
-                # (or the propagation tracer's render/install stages)
-                self.answer_cache.put(
-                    key, epoch, (cache_wire, ans, []), rotatable=False,
-                    tag=name, qkey=(qtype_val, name))
-        except Exception:
-            # response already sent: never fall through to the generic
-            # path (it would answer a second time)
-            self.log.exception("raw lane post-send bookkeeping failed")
-        return True
-
-    def _lane_finish(self, data, src, protocol: str, start: float,
-                     wire: bytes, rcode: int, edns: bool, ans, add,
-                     qtype: int = 1, cached: bool = False) -> None:
-        """Metrics + the slow-query warn for a lane-handled query
-        (the lane equivalent of _on_after with queryLog off)."""
-        lat_s = time.monotonic() - start
-        ch = self._children_for(qtype)
-        ch[0].inc()
-        ch[1].observe(lat_s)
-        ch[2].observe(len(wire))
-        lat_ms = lat_s * 1000.0
-        if lat_ms > SLOW_QUERY_MS:
-            if self.recorder is not None:
-                self.recorder.record(
-                    "slow-query", trace=None, name="(raw-lane)",
-                    qtype=Type.name(qtype), rcode=Rcode.name(rcode),
-                    latency_ms=round(lat_ms, 3), stages={})
-            log_event(self.log, logging.WARNING, "DNS query",
-                      req_id=(data[0] << 8) | data[1], client=src[0],
-                      port=f"{src[1]}/{protocol}", edns=edns,
-                      cached=cached, rcode=Rcode.name(rcode),
-                      answers=ans, additional=add, latency=lat_ms,
-                      timers={})
 
     def _fold_engine_counters(self) -> None:
         # scrapes run on ThreadingHTTPServer threads: fold under the

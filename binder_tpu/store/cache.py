@@ -384,7 +384,7 @@ class MirrorCache:
         # chunked-rebuild state: the walk queue (None when no rebuild
         # is in flight), a generation guard so a session churning
         # mid-rebuild restarts the walk instead of interleaving two,
-        # and the introspection counters the zone-scale bench reads
+        # and the introspection counters the zone-scale smoke reads
         self._rebuild_queue: Optional[deque] = None
         self._rebuild_gen = 0
         self._rebuild_started: Optional[float] = None

@@ -261,7 +261,7 @@ def populate_synthetic(store: FakeStore, domain: str, hosts: int,
                        racks: int = 0,
                        subtree: str = "zs") -> int:
     """Bulk-build a synthetic production-scale zone directly into the
-    store tree (bench/smoke surface, ISSUE 7 zone_scale axis): ``hosts``
+    store tree (smoke and benchmark surface, ISSUE 7 zone_scale axis): ``hosts``
     host records spread across ``racks`` service-style parents under
     ``<subtree>.<domain>``, with deterministic unique addresses.
 

@@ -6,7 +6,7 @@ needs a live ZooKeeper).  The rebuild defines this narrow interface instead,
 with two implementations:
 
 - ``binder_tpu.store.fake.FakeStore`` — in-memory, synchronous; used by
-  tests and ``bench.py``.
+  tests, the smokes and the benchmark's deployments.
 - ``binder_tpu.store.zk_client.ZKClient`` — real ZooKeeper wire protocol
   (jute) over asyncio.
 

@@ -80,12 +80,12 @@ constexpr size_t kMaxRemotes = 65536;
 
 int g_verbose = 0;
 /* -D: keep every reply on the relay lane even for capable backends
- * (the bench A/B arm, and an operator escape hatch) */
+ * (the tests' A/B arm, and an operator escape hatch) */
 int g_no_direct = 0;
 /* packet-path syscall count (epoll_wait, recvmmsg, sendmmsg, read,
- * writev, accept4, the fd-pass sendmsg): with direct return the bench
- * divides this by queries to prove the per-query kernel-crossing floor
- * actually dropped, not just the cycle shares */
+ * writev, accept4, the fd-pass sendmsg): divided by queries it shows
+ * whether direct return dropped the per-query kernel-crossing floor,
+ * not just the cycle shares */
 uint64_t g_syscalls = 0;
 
 void logmsg(const char *fmt, ...) {
@@ -132,7 +132,7 @@ uint64_t now_ms() {
  * Counters are raw TSC cycles on x86 (CLOCK_MONOTONIC ns elsewhere);
  * one pair of reads per region ~10ns, cheap enough to stay always-on.
  * `cycles_per_us` is calibrated over process lifetime at stats-read
- * time, so consumers (balstat, bench) convert without knowing the TSC
+ * time, so consumers (balstat) convert without knowing the TSC
  * rate.  Nested regions subtract out: a stage's cycles are exclusive,
  * so the four cells sum to the balancer's total attributable work and
  * a share-of-total per stage is meaningful. */
@@ -1805,7 +1805,7 @@ int main(int argc, char **argv) {
      * With -p 0 the kernel picks the UDP port — a number any unrelated
      * socket may already hold on TCP — so the rebind is a retry loop:
      * release the draw and redraw instead of dying (observed as a
-     * transient bench startup death, "bind tcp: Address already in
+     * transient startup death under load, "bind tcp: Address already in
      * use"; the backend's ephemeral pair bind handles the same race
      * the same way). */
     if (g_bal.port == 0) {

@@ -188,7 +188,7 @@ typedef struct {
 } fp_cache_t;
 
 /* EDNS OPT echoed on zone serves: root name, type 41, payload 1232,
- * no flags/options — byte-for-byte server.py _OPT_ECHO_WIRE */
+ * no flags/options — byte-for-byte dns/query.py _ECHO_OPT's encoding */
 static const uint8_t fp_opt_echo[11] = {
     0x00, 0x00, 0x29, 0x04, 0xD0, 0x00, 0x00, 0x00, 0x00, 0x00, 0x00
 };

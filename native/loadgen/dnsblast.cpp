@@ -3,13 +3,13 @@
  *
  * The reference repo ships no load tool; its tests shell out to dig(1)
  * (reference test/dig.js:109-134), which cannot measure server capacity.
- * bench_impl.py previously drove load from Python, but on a single-core
- * machine the Python client's per-packet interpreter cost competes with
- * the server for the same CPU and caps the measurement.  This native
- * client keeps the measurement overhead at ~1-2us/query so the reported
- * number is server capacity, not client capacity.
+ * Load driven from Python competes, on a single-core machine, with the
+ * server for the same CPU: the client's per-packet interpreter cost caps
+ * the measurement.  This native client keeps the measurement overhead at
+ * ~1-2us/query so the reported number is server capacity, not client
+ * capacity.
  *
- * Protocol behavior mirrors bench_impl.BenchClient exactly:
+ * Protocol behavior:
  *   - window of W queries in flight over one connected UDP socket;
  *   - query wires are templates cycled round-robin with the 2-byte id
  *     rewritten per send (ids unique across the whole run, N <= 65536);

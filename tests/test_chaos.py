@@ -509,7 +509,6 @@ class TestAdmission:
                 return wait()
 
             server.resolver.handle = slow_handle
-            server.engine.raw_lane = None
             server.engine.fastpath = None
             try:
                 loop = asyncio.get_running_loop()

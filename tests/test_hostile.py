@@ -6,8 +6,8 @@ Pins the contracts the hostile plane must keep:
 - **fuzz-clean parsing** — every frame in the committed malformed
   corpus (``tests/data/malformed_corpus.bin``, generated by
   ``tools/hostile.py --write-corpus``) produces FORMERR-or-drop on
-  every serve lane — the UDP ingress (raw lane enabled), the TCP
-  generic path, and the live TCP stream lane — never an exception and
+  every serve lane — the UDP ingress, the TCP generic path, and the
+  live TCP stream lane — never an exception and
   never a deposit into the decode cache, answer cache, or compiled
   table;
 - **RRL mechanics** — slip replies are TC=1 echoes no larger than the
@@ -101,12 +101,10 @@ class TestCorpus:
         assert len(committed) >= 300
 
     def test_udp_lane_formerr_or_drop_no_deposits(self):
-        """Every corpus frame through the UDP ingress (raw lane
-        enabled, the production shape): FORMERR-or-drop, no exception,
-        no cache deposit on any lane."""
+        """Every corpus frame through the UDP ingress: FORMERR-or-drop,
+        no exception, no cache deposit on any lane."""
         store, cache = fixture_store()
         server = new_server(cache)
-        assert server.engine.raw_lane is not None
         for label, frame in read_corpus(CORPUS):
             out = []
             server.engine._handle_raw(frame, ("192.0.2.7", 5353),

@@ -90,9 +90,8 @@ async def udp_ask(port, name, qtype, qid=1, timeout=5.0):
 
 def via_generic_path(server):
     """Force every query through the generic Python resolve path: the
-    raw lane and native fast path would otherwise answer simple A/IN
-    shapes before the (test-instrumented) resolver ever runs."""
-    server.engine.raw_lane = None
+    native fast path would otherwise answer simple A/IN shapes before
+    the (test-instrumented) resolver ever runs."""
     server.engine.fastpath = None
 
 

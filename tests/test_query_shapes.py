@@ -1,19 +1,23 @@
-"""Differential tests for the raw resolve lane (BinderServer._raw_lane).
+"""The Python lanes' answer for every query shape, held to a reference.
 
-The lane re-implements the single-question A/IN resolve by direct wire
-assembly; these tests prove it cannot diverge from the generic path:
+A datagram that the native lanes did not answer takes one path:
+``_decode_query`` then ``_on_query`` (answer cache, compiled table,
+resolver).  These tests drive that path with the query log off (so no
+log line stands between a query and its answer):
 
-- every query shape is driven through BOTH paths over the same store
-  fixture and the response wires must be byte-identical (the request
-  wires here are lowercase, so the lane's case-preserving question echo
-  matches the generic encoder's output exactly);
-- answer-cache entries created by one path must be served by the other
-  (key-layout parity both directions);
-- shapes the lane must decline (other qtypes, EDNS options, compressed
-  qnames, service/database records, recursion handoffs, garbage) fall
-  back and still produce the generic path's answer.
+- every shape of ``QUERY_SHAPES``, the store-down shape and two
+  malformed-looking shapes are asked twice of a server with its caches
+  on (the first sight is a resolve, the second an answer-cache hit
+  wherever the answer may be cached) and each answer must be byte for
+  byte what a reference server renders (no answer cache, no compiled
+  table, no zone table: every answer a resolve), the id apart;
+- what the caches must keep: each requester's own question case, a
+  mutation's invalidation, rotation of service answers, metrics with
+  the log off, one cache key a transport.
 """
 import random
+
+import pytest
 
 from binder_tpu.dns import Message, Rcode, Type, make_query
 from binder_tpu.dns.query import QueryCtx
@@ -57,24 +61,36 @@ def make_fixture():
     return store, cache
 
 
-def new_server(cache, lane: bool, **kw):
+def new_server(cache, **kw):
     srv = BinderServer(zk_cache=cache, dns_domain=DOMAIN,
                        datacenter_name="coal",
                        collector=MetricsCollector(), query_log=False, **kw)
-    # deterministic shuffle so both servers' service answers rotate
+    # deterministic shuffle so two servers' service answers rotate
     # identically (the differential compares exact bytes)
     srv.resolver.rng = random.Random(42)
-    if not lane:
-        srv.engine.raw_lane = None
     return srv
+
+
+def reference_server(cache):
+    """A server whose every answer is a resolve: no answer cache, no
+    compiled table, no zone table."""
+    return new_server(cache, cache_size=0, answer_precompile=False,
+                      zone_precompile=False)
+
+
+def responses(server, wire: bytes, protocol: str = "udp",
+              client_transport=None) -> list:
+    """Push one request wire through the engine; return what it sent."""
+    out = []
+    server.engine._handle_raw(wire, ("192.0.2.9", 1234), protocol,
+                              out.append, client_transport=client_transport)
+    return out
 
 
 def ask_raw(server, wire: bytes, protocol: str = "udp",
             client_transport=None):
-    """Push one request wire through the engine; return the response."""
-    out = []
-    server.engine._handle_raw(wire, ("192.0.2.9", 1234), protocol,
-                              out.append, client_transport=client_transport)
+    """The one response to one request wire."""
+    out = responses(server, wire, protocol, client_transport)
     assert len(out) == 1, f"expected one response, got {len(out)}"
     return out[0]
 
@@ -97,9 +113,9 @@ QUERY_SHAPES = [
     ("short.foo.com", Type.A, False, 1232),      # non-canonical address
     ("noaddr.foo.com", Type.A, False, 1232),     # record without address
     ("badrec.foo.com", Type.A, False, 1232),     # invalid record shape
-    ("db.foo.com", Type.A, False, 1232),         # database type (declined)
-    ("svc.foo.com", Type.A, False, 1232),        # service A (declined)
-    ("_pg._tcp.svc.foo.com", Type.SRV, False, 1232),   # SRV (declined)
+    ("db.foo.com", Type.A, False, 1232),         # database type
+    ("svc.foo.com", Type.A, False, 1232),        # service A: a set that rotates
+    ("_pg._tcp.svc.foo.com", Type.SRV, False, 1232),   # SRV
     ("1.0.168.192.in-addr.arpa", Type.PTR, False, 1232),  # PTR hit
     ("1.0.168.192.in-addr.arpa", Type.PTR, False, None),  # PTR, no EDNS
     ("1.0.168.192.in-addr.arpa", Type.PTR, True, 1232),   # PTR, RD set
@@ -113,70 +129,87 @@ QUERY_SHAPES = [
 ]
 
 
+def make_down_fixture():
+    # no session ever established: the mirror never becomes ready, so
+    # resolution must SERVFAIL
+    store = FakeStore()
+    return store, MirrorCache(store, DOMAIN)
+
+
+def shape_wire(name, qtype, rd, payload):
+    return lambda qid: make_query(name, qtype, qid=qid, rd=rd,
+                                  edns_payload=payload).encode()
+
+
+def cookie_wire(qid):
+    """An OPT that carries an option (a DNS cookie)."""
+    wire = make_query("web.foo.com", Type.A, qid=qid,
+                      edns_payload=1232).encode()
+    cookie = b"\x00\x0a\x00\x08" + b"\x01" * 8
+    assert wire.endswith(b"\x00\x00")   # RDLEN 0
+    return wire[:-2] + len(cookie).to_bytes(2, "big") + cookie
+
+
+def compressed_qname_wire(qid):
+    """A qname that is a (self-referential, invalid) compression
+    pointer: dropped as malformed, with a FORMERR or in silence."""
+    return (qid.to_bytes(2, "big")
+            + b"\x00\x00\x00\x01\x00\x00\x00\x00\x00\x00"
+            + b"\xc0\x0c\x00\x01\x00\x01")
+
+
+#: (fixture, wire of a qid)
+SHAPE_CASES = [
+    pytest.param(
+        make_fixture, shape_wire(name, qtype, rd, payload),
+        id=f"{name}-{Type.name(qtype)}-rd{int(rd)}-edns{payload}")
+    for name, qtype, rd, payload in QUERY_SHAPES
+] + [
+    pytest.param(make_down_fixture,
+                 shape_wire("web.foo.com", Type.A, False, None),
+                 id="store-down"),
+    pytest.param(make_fixture, cookie_wire, id="opt-with-option"),
+    pytest.param(make_fixture, compressed_qname_wire,
+                 id="compressed-qname"),
+]
+
+
 class TestDifferential:
-    def test_wire_identical_across_paths(self):
-        """Every shape must produce byte-identical responses from the
-        lane-enabled and generic-only servers (ids patched equal)."""
-        for name, qtype, rd, payload in QUERY_SHAPES:
-            _, cache_a = make_fixture()
-            _, cache_b = make_fixture()
-            # fresh servers per shape: no cross-shape cache pollution
-            srv_lane = new_server(cache_a, lane=True)
-            srv_gen = new_server(cache_b, lane=False)
-            wire = make_query(name, qtype, qid=77, rd=rd,
-                              edns_payload=payload).encode()
-            got_lane = ask_raw(srv_lane, wire)
-            got_gen = ask_raw(srv_gen, wire)
-            assert got_lane == got_gen, (
-                f"{name}/{Type.name(qtype)} rd={rd} edns={payload}: "
-                f"lane={got_lane.hex()} generic={got_gen.hex()}")
-
-    def test_store_down_servfail_identical(self):
-        for lane in (True, False):
-            # no session ever established: the mirror never becomes
-            # ready, so resolution must SERVFAIL on both paths
-            store = FakeStore()
-            cache = MirrorCache(store, DOMAIN)
-            srv = new_server(cache, lane=lane)
-            wire = make_query("web.foo.com", Type.A, qid=5).encode()
-            resp = Message.decode(ask_raw(srv, wire))
-            assert resp.rcode == Rcode.SERVFAIL
-
-    def test_cache_key_parity_lane_fills_generic_hits(self):
-        """A lane-resolved entry must be a generic-path cache hit — for
-        every EDNS payload edge (none, below floor, typical, above
-        clamp), so a drifting floor/clamp copy splits the cache and
-        fails here."""
-        for payload in (None, 100, 511, 512, 1232, 4096, 4097):
-            _, cache = make_fixture()
-            srv = new_server(cache, lane=True)
-            wire = make_query("web.foo.com", Type.A, qid=9,
-                              edns_payload=payload).encode()
-            first = ask_raw(srv, wire)
-            # disable the lane; the generic path must hit the same entry
-            srv.engine.raw_lane = None
-            hits_before = srv.answer_cache.hits
-            second = ask_raw(srv, wire)
-            assert srv.answer_cache.hits == hits_before + 1, payload
-            assert first == second, payload
-
-    def test_cache_key_parity_generic_fills_lane_hits(self):
-        _, cache = make_fixture()
-        srv = new_server(cache, lane=True)
-        srv.engine.raw_lane = None
-        wire = make_query("web.foo.com", Type.A, qid=9,
-                          edns_payload=1232).encode()
-        first = ask_raw(srv, wire)
-        srv.engine.raw_lane = srv._raw_lane
-        hits_before = srv.answer_cache.hits
-        second = ask_raw(srv, wire)
-        assert srv.answer_cache.hits == hits_before + 1
-        assert first == second
+    @pytest.mark.parametrize("fixture,wire_of", SHAPE_CASES)
+    def test_both_sights_are_the_reference_render(self, fixture, wire_of):
+        """The first sight of a shape (a resolve) and the second (an
+        answer-cache hit, unless the answer is an error of the server's
+        or a set that rotates) are byte for byte what the reference
+        server renders, the id apart."""
+        # fresh servers per shape: no cross-shape cache pollution
+        _, cache_a = fixture()
+        _, cache_b = fixture()
+        srv = new_server(cache_a)
+        ref = reference_server(cache_b)
+        for sight, qid in enumerate((77, 78), start=1):
+            got = [r[2:] for r in responses(srv, wire_of(qid))]
+            # the reference is asked as often, so that a rotating
+            # answer's shuffle has advanced alike on both
+            want = [r[2:] for r in responses(ref, wire_of(qid + 1000))]
+            assert len(want) <= 1
+            assert got == want, (
+                f"sight {sight}: "
+                f"got={[g.hex() for g in got]} "
+                f"reference={[w.hex() for w in want]}")
+        # SERVFAIL and FORMERR are never cached; a set of several
+        # records is withheld until eight shuffles of it are stored
+        rcode = want[0][1] & 0x0F if want else Rcode.FORMERR
+        ancount = int.from_bytes(want[0][4:6], "big") if want else 0
+        cached = (rcode not in (Rcode.SERVFAIL, Rcode.FORMERR)
+                  and ancount <= 1)
+        assert srv.answer_cache.hits == (1 if cached else 0)
+        assert ref.answer_cache.hits == 0
 
     def test_fastpath_key_parity(self):
-        """The lane's inline C-cache key must equal _fastpath_key's."""
+        """The native answer-cache key built from its components must
+        equal the one _fastpath_key builds from a decoded request."""
         _, cache = make_fixture()
-        srv = new_server(cache, lane=True)
+        srv = new_server(cache)
         for name, qtype, rd, payload in QUERY_SHAPES:
             if qtype != Type.A:
                 continue
@@ -186,25 +219,25 @@ class TestDifferential:
             q = QueryCtx(req, ("192.0.2.9", 1), "udp", lambda b: None,
                          raw=wire)
             expect = srv._fastpath_key(q)
-            # the lane builds through the same shared builder; prove the
+            # both build through the one shared builder; prove the
             # component path equals the Message path
             from binder_tpu.server import _fastpath_key_parts
             off = 12
             while wire[off]:
                 off += 1 + wire[off]
             off += 1
-            lane_key = _fastpath_key_parts(
+            parts_key = _fastpath_key_parts(
                 req.rd, req.edns is not None, req.max_udp_payload(),
                 1, 1, wire[12:off].lower())
-            assert lane_key == expect, name
+            assert parts_key == expect, name
 
 
-class TestLaneBehavior:
+class TestCacheBehavior:
     def test_case_preserving_question_echo(self):
-        """dns0x20: the lane echoes the question with the request's
-        original case (an improvement over the generic lowercase echo)."""
+        """dns0x20: the question is echoed with the request's original
+        case."""
         _, cache = make_fixture()
-        srv = new_server(cache, lane=True)
+        srv = new_server(cache)
         q = make_query("WeB.FoO.cOm", Type.A, qid=2).encode()
         # make_query normalizes, so craft mixed case directly in the wire
         q = q.replace(b"web", b"WeB").replace(b"foo", b"FoO")
@@ -219,7 +252,7 @@ class TestLaneBehavior:
         responses (cache stores the question lowercased; hits splice the
         requester's own bytes back in)."""
         _, cache = make_fixture()
-        srv = new_server(cache, lane=True)
+        srv = new_server(cache)
         mixed = make_query("web.foo.com", Type.A, qid=2).encode() \
             .replace(b"web", b"WeB").replace(b"foo", b"FoO")
         lower = make_query("web.foo.com", Type.A, qid=3).encode()
@@ -234,39 +267,11 @@ class TestLaneBehavior:
             m = Message.decode(r)
             assert str(m.answers[0].address) == "192.168.0.1"
 
-    def test_lane_declines_to_generic_on_edns_options(self):
-        """An OPT with options (a DNS cookie) must take the generic
-        path and still be answered."""
-        _, cache = make_fixture()
-        srv = new_server(cache, lane=True)
-        wire = make_query("web.foo.com", Type.A, qid=4,
-                          edns_payload=1232).encode()
-        # splice a COOKIE option into the OPT RDATA
-        cookie = b"\x00\x0a\x00\x08" + b"\x01" * 8
-        assert wire.endswith(b"\x00\x00")   # RDLEN 0
-        wire = wire[:-2] + len(cookie).to_bytes(2, "big") + cookie
-        resp = Message.decode(ask_raw(srv, wire))
-        assert resp.rcode == Rcode.NOERROR
-        assert str(resp.answers[0].address) == "192.168.0.1"
-
-    def test_lane_declines_compressed_qname(self):
-        _, cache = make_fixture()
-        srv = new_server(cache, lane=True)
-        # header + qname containing a (self-referential, invalid)
-        # compression pointer: both paths must refuse gracefully —
-        # generic drops it as malformed (FORMERR)
-        wire = (b"\x00\x07\x00\x00\x00\x01\x00\x00\x00\x00\x00\x00"
-                + b"\xc0\x0c\x00\x01\x00\x01")
-        out = []
-        srv.engine._handle_raw(wire, ("192.0.2.9", 1), "udp", out.append)
-        if out:   # FORMERR response is acceptable; silence is too
-            assert Message.decode(out[0]).rcode == Rcode.FORMERR
-
-    def test_mutation_invalidates_lane_cache(self):
-        """Generation bump: a store mutation must stop the lane serving
-        the stale cached answer."""
+    def test_mutation_invalidates_cached_answer(self):
+        """A store mutation must stop the answer cache serving the
+        stale cached answer."""
         store, cache = make_fixture()
-        srv = new_server(cache, lane=True)
+        srv = new_server(cache)
         wire = make_query("web.foo.com", Type.A, qid=11).encode()
         first = Message.decode(ask_raw(srv, wire))
         assert str(first.answers[0].address) == "192.168.0.1"
@@ -275,40 +280,40 @@ class TestLaneBehavior:
         second = Message.decode(ask_raw(srv, wire))
         assert str(second.answers[0].address) == "192.168.0.2"
 
-    def test_lane_serves_rotating_service_hits(self):
-        """Once the generic path completes a rotatable service-A entry,
-        lane hits must rotate through the variants like respond_raw."""
+    def test_rotating_service_hits(self):
+        """Once eight resolves complete a rotatable service-A entry,
+        cache hits must rotate through the variants like respond_raw."""
         _, cache = make_fixture()
-        srv = new_server(cache, lane=True)
+        srv = new_server(cache)
         wire = make_query("svc.foo.com", Type.A, qid=1).encode()
         seen = set()
-        # 8 variants must be collected by the generic path first, then
-        # hits rotate; drive enough queries to see rotation
+        # 8 variants must be collected by resolves first, then hits
+        # rotate; drive enough queries to see rotation
         for _ in range(24):
             msg = Message.decode(ask_raw(srv, wire))
             assert msg.rcode == Rcode.NOERROR
             seen.add(tuple(str(a.address) for a in msg.answers))
         assert len(seen) > 1, "no rotation observed"
 
-    def test_metrics_recorded_for_lane_queries(self):
+    def test_metrics_recorded_with_the_log_off(self):
         _, cache = make_fixture()
-        srv = new_server(cache, lane=True)
+        srv = new_server(cache)
         wire = make_query("web.foo.com", Type.A, qid=6).encode()
         ask_raw(srv, wire)
-        ask_raw(srv, wire)   # second one is a lane cache hit
+        ask_raw(srv, wire)   # second one is an answer-cache hit
         text = srv.collector.expose()
         assert 'binder_requests_completed{type="A"} 2' in text
         assert 'binder_answer_cache_hits{tier="python"} 1' in text
 
-    def test_balancer_protocol_lane(self):
-        """Lane handles balancer-framed queries; TCP client transport
+    def test_balancer_protocol_keys_by_client_transport(self):
+        """Balancer-framed queries are answered; TCP client transport
         keys separately from UDP (truncation semantics) in the PYTHON
         answer cache.  The native wire-serve entry would intercept the
-        repeat before it reaches the lane (correct — fitting responses
+        repeat before it reaches Python (correct — fitting responses
         are transport-identical; tests/test_zone.py covers that lane),
         so it is detached here to exercise the Python keying."""
         _, cache = make_fixture()
-        srv = new_server(cache, lane=True)
+        srv = new_server(cache)
         srv.engine.fastpath = None
         wire = make_query("web.foo.com", Type.A, qid=8).encode()
         u = ask_raw(srv, wire, protocol="balancer", client_transport="udp")

@@ -448,8 +448,8 @@ class TestServerCaseEcho:
     def test_generic_path_echoes_requester_case(self):
         """dns0x20 server side: mixed-case questions come back with the
         exact case mask on every path, including the generic resolver
-        (QueryCtx._echo_question_case) — an SRV query cannot take the
-        raw lane, so this pins the generic path."""
+        (QueryCtx._echo_question_case) — pinned here with an SRV query
+        through the resolver."""
         async def run():
             server, _ = await start_local({})
             store = server.zk_cache.store

@@ -284,7 +284,7 @@ class TestMetrics:
             store, cache = fixture_store()
             # zone-precompiled answers never surface to Python (no
             # latency stamp to promote); the warn path under test is the
-            # raw-lane/generic one
+            # Python lanes' (_on_after)
             server = await start_server(cache, query_log=False,
                                         zone_precompile=False)
             monkeypatch.setattr(srv_mod, "SLOW_QUERY_MS", -1.0)

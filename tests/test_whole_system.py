@@ -54,7 +54,7 @@ async def udp_ask(port, name, qtype, qid):
 
 # all three serving postures: python-path (query_log=True, plain
 # logger) keeps every query in Python; native-path (query_log=False)
-# engages the full native stack — raw lane, fastpath cache, zone
+# engages the full native stack — fastpath cache, zone
 # precompilation, serve_wire on the balancer lane; native-logged
 # (query_log=True + JSON logger) engages the native stack WITH the
 # query-log ring — the reference-parity posture — and the test asserts

@@ -12,8 +12,9 @@ Layers here:
   aside — the zone can never answer differently, only faster;
 - coherence: store mutations re-point zone answers through the same
   tag-invalidation path as the caches; deletions fall back to Python;
-- policy: shapes the raw lane declines (service records, doubled
-  dnsDomain suffixes, non-IN classes) are never zone-served.
+- policy: shapes the zone table must leave to the Python lanes
+  (service records, doubled dnsDomain suffixes, non-IN classes) are
+  never zone-served.
 """
 import asyncio
 
@@ -441,9 +442,9 @@ class TestZoneCoherence:
 
     def test_mutation_burst_bounded_drain_stays_fresh(self):
         """A burst of mutations larger than the zone drain batch (r5
-        churn coalescing): answers must be FRESH immediately (raw-lane /
-        generic fallback while the name's re-push is still queued in the
-        dirty set) and zone-served again once the bounded drain catches
+        churn coalescing): answers must be FRESH immediately (the
+        Python lanes' fallback while the name's re-push is still queued in
+        the dirty set) and zone-served again once the bounded drain catches
         up — never stale in between."""
         async def run():
             store, cache = fixture_store()

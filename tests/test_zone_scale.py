@@ -4,7 +4,7 @@ backpressure, the late-drop counter, and the binder_mirror_* metric
 family pins.
 
 The heavyweight end-to-end figures (RSS/name, 1M-name serving) live in
-the bench's zone_scale axis and `make zone-smoke`; these tests pin the
+`make zone-smoke` and `tools/zone_probe.py`; these tests pin the
 MECHANISMS at sizes tier-1 can afford.
 """
 import asyncio

@@ -1,10 +1,10 @@
 #!/usr/bin/env python3
 """Adversarial multi-flow DNS load harness (the ZDNS-style client).
 
-The bench's dnsblast is a *friendly* client: one source address, well-
-formed queries, qids it waits on.  That is exactly the flood shape the
-per-client admission limiter sheds, which is why the recursion-heavy
-bench axes had to lift the limit in config (PR 8) — and why "binder
+dnsblast is a *friendly* client: one source address, well-formed
+queries, qids it waits on.  That is exactly the flood shape the
+per-client admission limiter sheds, which is why recursion-heavy load
+tests had to lift the limit in config (PR 8) — and why "binder
 survives the open internet" was an unmeasured claim.  This harness is
 the unfriendly one:
 
@@ -34,7 +34,7 @@ harness fires.
 
 Synchronous by design (selectors, not asyncio): the harness is the
 measurement instrument, and per-packet event-loop overhead would cap
-the flood it can represent.  `hostile_smoke.py` and the bench drive it
+the flood it can represent.  `hostile_smoke.py` drives it
 from a thread next to a legit-traffic measurement loop.
 """
 from __future__ import annotations
@@ -449,7 +449,7 @@ def legit_probe(host: str, port: int, *, duration: float = 5.0,
                 qps: int = 0) -> Dict[str, float]:
     """Closed-loop legit client from 127.0.0.1 (NOT a hostile prefix):
     one query at a time, waits for each answer — the goodput
-    measurement the hostile bench axis compares against its no-flood
+    measurement the hostile smoke compares against its no-flood
     control.  ``qps`` paces the offered load (0 = as fast as answers
     come back); pace it *below* the server's RRL per-prefix limit, or
     the probe measures its own rate limiting instead of the flood's

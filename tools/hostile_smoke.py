@@ -14,9 +14,8 @@ realistic queries — while the same paced legit client measures goodput
   ``binder_shed_total{reason="response-ratelimit"}`` moved.
 - **Legit goodput survives**: the paced 127.0.0.1 client (its own
   /24, under the per-prefix limit) keeps a goodput ratio vs the
-  no-flood control above the smoke floor.  The bench's ``hostile``
-  axis records the real number; this gate only refuses regressions
-  to "flood starves everyone".
+  no-flood control above the smoke floor: this gate only refuses
+  regressions to "flood starves everyone".
 - **Fuzz-clean**: malformed frames produce FORMERR-or-drop (never a
   served answer), and the server process stays up throughout.
 - **Bounded state**: server RSS growth over the soak stays bounded
@@ -65,8 +64,8 @@ FLOOD_FLOWS = 64
 #: RSS growth bound over the soak; the bucket LRU (512 entries) and
 #: prefix cache are the only per-flood state, orders of magnitude less
 MAX_RSS_GROWTH_KB = 64 * 1024
-#: smoke floor for goodput-under-flood vs control (the bench axis
-#: records the real ratio; ISSUE 12's target there is >= 0.8)
+#: smoke floor for goodput-under-flood vs control (ISSUE 12's target
+#: for the real ratio is >= 0.8)
 GOODPUT_FLOOR = 0.5
 
 

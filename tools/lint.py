@@ -1285,8 +1285,7 @@ def collect(paths):
 
 def main(argv):
     paths = argv or ["binder_tpu", "tests", "bin", "tools",
-                     "bench.py", "bench_impl.py", "chip_smoke.py",
-                     "__graft_entry__.py"]
+                     "chip_smoke.py", "__graft_entry__.py"]
     files = collect(paths)
     if not files:
         print("lint: no files found", file=sys.stderr)

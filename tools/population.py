@@ -37,8 +37,8 @@ shape, and the RRL false-positive question is invisible without it:
 
 Synchronous selectors loop (the hostile.py discipline): the model is
 the measurement instrument.  Exported JSON carries the population
-shape (identities, prefixes, zipf_s, nat_fan_in) so a bench axis or a
-smoke can assert against a *described* population, not a folklore one.
+shape (identities, prefixes, zipf_s, nat_fan_in) so a smoke or a
+benchmark cell can assert against a *described* population, not a folklore one.
 """
 from __future__ import annotations
 

@@ -70,8 +70,8 @@ SHARDS = 2
 #: low enough that the farm /24s trip RRL fast, high enough that one
 #: adaptation step visibly relieves them
 RRL_RPS, RRL_BURST = 60, 120
-#: smoke floors/ceilings (the bench's population axis records the real
-#: numbers; the gate only refuses regressions to "RRL starves farms")
+#: smoke floors/ceilings (the gate only refuses regressions to "RRL
+#: starves farms")
 GOODPUT_FLOOR = 0.5
 FP_CEILING = 0.10
 #: freak-packet tolerance for first-try probe timeouts across 4 rolls

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Verify-plane probe: mutation→glass latency + checker cost at N names.
 
-The measurement half of ISSUE 16's ``verify`` bench axis, run as one
+The measurement half of ISSUE 16's ``verify`` axis, run as one
 subprocess per zone size (like tools/zone_probe.py, whose answer-path
 harness it reuses) so the sizes never pollute each other's RSS.
 

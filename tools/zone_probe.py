@@ -1,10 +1,10 @@
 #!/usr/bin/env python3
 """Zone-scale probe: mirror RSS/build/mutation-latency at N names.
 
-The measurement half of ISSUE 7's ``zone_scale`` axis, shared by the
-bench (``bench_impl._bench_zone_scale`` runs one probe subprocess per
-zone size so measurements never pollute each other's RSS), by ``make
-zone-smoke`` (tools/zone_smoke.py), and by tests/test_zone_scale.py.
+The measurement half of ISSUE 7's ``zone_scale`` axis, shared by ``make
+zone-smoke`` (tools/zone_smoke.py) and by tests/test_zone_scale.py; run
+as a script, one process a zone size keeps measurements out of each
+other's RSS.
 
 Builds a synthetic zone (``store.fake.populate_synthetic``) in a fake
 store, mirrors it, wires the answer-cache + mutation-time precompiler
