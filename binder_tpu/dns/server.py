@@ -820,7 +820,11 @@ class DnsServer:
         served and framed back as one block.  Returns
         ``(resp_block, consumed, misses)`` or None when the native path
         is unavailable/declined.  The one call site is the stream
-        lane's feed loop (dns/stream.py)."""
+        lane's feed loop (dns/stream.py), so this is the one entry that
+        knows its frames came over a stream: C passes over a cached
+        TC=1 wire and serves the zone table's whole set up to its 4096
+        bytes, whatever UDP payload the frame's key holds.  Timed in C
+        under the leaf stage ``native-serve``."""
         return self._fp_call(_fp_serve_frames, buf, src, "tcp")
 
     def _handle_raw(self, data: bytes, src: Tuple[str, int],
@@ -857,14 +861,17 @@ class DnsServer:
             # surface here.
             rrl.note_tcp(src[0])
         # Native answer-cache/zone serve for the lanes that have no C
-        # drain of their own — TCP and the balancer socket.  Direct-UDP
-        # packets reaching here already missed inside fastpath_drain,
-        # and TCP payloads surfaced by the bulk frame serve arrive with
+        # drain of their own — the balancer socket, and the TCP frames
+        # the bulk frame serve never saw.  Direct-UDP packets reaching
+        # here already missed inside fastpath_drain, and TCP payloads
+        # surfaced by the bulk frame serve arrive with
         # fastpath_checked=True — a second lookup would be pure waste.
-        # Correct for every lane: entries hold only untruncated
-        # responses and decline when the assembled wire would exceed
-        # the query's advertised ceiling, so a TCP serve can never
-        # differ from the Python path's.
+        # Correct for either transport, which this entry is not told:
+        # C declines a truncated cached wire and a zone set above the
+        # query's advertised ceiling, so a serve can never differ from
+        # the Python path's (over TCP that leaves sets above the UDP
+        # payload to Python; the stream's own ceiling is the bulk frame
+        # serve's alone).
         if protocol != "udp" and not fastpath_checked:
             resp = self._fp_call(_fp_serve_wire, data, src, protocol)
             if resp is not None:

@@ -42,7 +42,8 @@ Every transition feeds :class:`TcpStats`, folded into the
 crossings are leaf spans of the time ledger (``introspect/ledger.py``):
 ``tcp-recv`` a ``recv`` call, ``tcp-send`` a ``send``/``sendmsg`` call,
 ``tcp-close`` a connection closed (``tcp-accept`` is the accept path's,
-``dns/server.py``); the serve between them is not theirs.
+``dns/server.py``); the serve between them is not theirs: the bulk frame
+serve is ``native-serve``'s, a declined frame's the per-query stages'.
 """
 from __future__ import annotations
 
@@ -62,9 +63,10 @@ class TcpStats:
     scrape when ``BinderServer._fold_engine_counters`` folds the deltas
     into the Prometheus collectors."""
 
-    FIELDS = ("accepts", "fast_serves", "promotions", "oneshot_closes",
-              "idle_timeouts", "slow_reader_drops", "coalesced_writes",
-              "coalesced_frames", "half_closes", "rst_drops")
+    FIELDS = ("accepts", "fast_serves", "native_serves", "promotions",
+              "oneshot_closes", "idle_timeouts", "slow_reader_drops",
+              "coalesced_writes", "coalesced_frames", "half_closes",
+              "rst_drops")
     __slots__ = FIELDS
 
     def __init__(self) -> None:
@@ -211,8 +213,10 @@ class TcpConn:
                         nblock += 1
                     dispatched += nblock
                     if resp:
+                        answered = nblock - len(fmisses)
                         self.out.append(resp)
-                        self.out_nframes += nblock - len(fmisses)
+                        self.out_nframes += answered
+                        srv.tcp_stats.native_serves += answered
                     for payload in fmisses:
                         self.q_out += 1
                         try:

@@ -12,7 +12,9 @@ stage           observed per       where the clock is read
 ==============  =================  ====================================
 ``loop-idle``   ``select`` call    :class:`TimingSelector`, inside select
 ``udp-recv``    ``recvmmsg`` call  C (``native/fastio``), EAGAIN included
-``native-serve``  batch            C, after recvmmsg to before sendmmsg
+``native-serve``  batch            C, after recvmmsg to before sendmmsg;
+                                   a ``fastpath_serve_frames`` call (the
+                                   stream lane's bulk frame serve)
 ``udp-send``    ``sendmmsg`` call  C
 ``log-write``   log write          ``BinderServer._write_log``
 ``log-line``    Python-lane line   ``BinderServer._on_after``
@@ -28,10 +30,11 @@ stage           observed per       where the clock is read
 ==============  =================  ====================================
 
 The four ``tcp-*`` spans are the stream lane's kernel crossings
-(``dns/stream.py``); the frames' serve between them is the per-query
-stages' and ``native-serve`` has no part in it (the bulk frame serve is
-not timed).  A connection's reader *registration* after its first serve
-is the one crossing of a one-shot leg that no span names.
+(``dns/stream.py``); the frames' serve between them is
+``native-serve``'s (the bulk frame serve, one observation a call) and,
+for the frames it declines, the per-query stages'.  A connection's
+reader *registration* after its first serve is the one crossing of a
+one-shot leg that no span names.
 
 Always on, like the stage histogram: no switch, option or environment
 variable.  Every span reads ``CLOCK_MONOTONIC``.  The Python spans

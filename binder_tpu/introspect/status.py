@@ -246,6 +246,7 @@ class Introspector:
         return {"udp_truncated": 0, "open_conns": 0, "max_conns": 0,
                 "idle_timeout_seconds": 0.0, "max_write_buffer": 0,
                 "cap_refusals": 0, "accepts": 0, "fast_serves": 0,
+                "native_serves": 0,
                 "promotions": 0, "oneshot_closes": 0,
                 "idle_timeouts": 0, "slow_reader_drops": 0,
                 "coalesced_writes": 0, "coalesced_frames": 0,

@@ -516,6 +516,9 @@ class BinderServer:
             ("accepts", "TCP connections accepted"),
             ("fast_serves", "frames served via the accept fast path "
              "(connections not yet promoted to the pipelined protocol)"),
+            ("native_serves", "TCP frames the native bulk serve answered "
+             "(answer cache or zone table; the rest went to the Python "
+             "lanes)"),
             ("promotions", "TCP connections promoted to the full "
              "pipelined protocol (kept sending after the first served "
              "burst)"),

@@ -415,13 +415,19 @@ fastpath_serve_wire(PyObject *self, PyObject *args)
     /* logged posture: the caller must supply the client context or the
      * serve declines inside the core (parity: Python then logs) */
     fp_logsrc_t src = { client, port, proto };
-    /* decline_tc: TC responses cached off the UDP path are correct for
-     * UDP requesters but must never replay over TCP (Python answers
+    /* FP_VIA_UNKNOWN: TC responses cached off the UDP path are correct
+     * for UDP requesters but must never replay over TCP (Python answers
      * those in full — its cache keys carry transport semantics; this
      * entry point cannot know the transport, so the core declines every
-     * truncated wire before any hit accounting) */
+     * truncated wire before any hit accounting, and holds a zone serve
+     * to the key's payload).  Its callers are the balancer socket's
+     * Python lane and the stream frames the bulk serve never saw; the
+     * stream's own ceiling is fastpath_serve_frames' alone, and comes
+     * here with the cell that reaches this entry (balancer_fronted,
+     * PERF.md section 7). */
     size_t wlen = fp_serve_one_lx(c, pkt.buf, (size_t)pkt.len,
-                                  (uint64_t)gen, t0, out, &qtype, 1,
+                                  (uint64_t)gen, t0, out, &qtype,
+                                  FP_VIA_UNKNOWN,
                                   client != NULL ? &src : NULL);
     PyBuffer_Release(&pkt);
     if (wlen == 0)
@@ -470,6 +476,9 @@ fastpath_serve_frames(PyObject *self, PyObject *args)
     static uint8_t out[262144];
     size_t out_used = 0;
     size_t consumed = 0;
+    /* native-serve: the whole call, misses' surfacing included, as a
+     * drained batch's span holds its misses' */
+    double t_call = fp_now();
     const uint8_t *p = (const uint8_t *)data.buf;
     size_t n = (size_t)data.len;
     PyObject *misses = PyList_New(0);
@@ -489,10 +498,12 @@ fastpath_serve_frames(PyObject *self, PyObject *args)
         const uint8_t *pkt = p + consumed + 2;
         uint16_t qtype = 0;
         double t0 = fp_now();
-        /* decline_tc=1: cached TC wires must never replay over TCP */
+        /* FP_VIA_STREAM: a cached TC wire never replays over TCP, and
+         * the zone table serves the whole set up to the arena slot
+         * reserved above, whatever UDP payload the frame's key holds */
         size_t wlen = fp_serve_one_lx(c, pkt, flen, (uint64_t)gen, t0,
-                                      out + out_used + 2, &qtype, 1,
-                                      srcp);
+                                      out + out_used + 2, &qtype,
+                                      FP_VIA_STREAM, srcp);
         if (wlen == 0) {
             PyObject *payload = PyBytes_FromStringAndSize(
                 (const char *)pkt, (Py_ssize_t)flen);
@@ -529,6 +540,8 @@ fastpath_serve_frames(PyObject *self, PyObject *args)
         Py_DECREF(misses);
         return NULL;
     }
+    if (consumed > 0)
+        fastio_span_note(FASTIO_SPAN_SERVE, fp_now() - t_call);
     return Py_BuildValue("(NnN)", resp, (Py_ssize_t)consumed, misses);
 }
 
@@ -641,10 +654,11 @@ fastpath_serve_balancer(PyObject *self, PyObject *args)
                     && inet_ntop(family == 4 ? AF_INET : AF_INET6, addr,
                                  client, sizeof(client)) != NULL)
                 src.client = client;
-            /* decline_tc=0: the transport is known UDP, so truncated
+            /* the transport is known UDP (TCP-transport frames never
+             * get here: they surface to Python above), so truncated
              * wires replay exactly as on the direct UDP drain */
             wlen = fp_serve_one_lx(c, pkt, plen, (uint64_t)gen, t0,
-                                   outs[n_hits], &qtype, 0,
+                                   outs[n_hits], &qtype, FP_VIA_DATAGRAM,
                                    src.client != NULL ? &src : NULL);
         }
         if (wlen == 0) {
@@ -850,7 +864,7 @@ fastpath_drain(PyObject *self, PyObject *args)
             }
         }
         size_t wlen = fp_serve_one_lx(c, pkt, plen, (uint64_t)gen, t0,
-                                      out, &entry_qtype, 0,
+                                      out, &entry_qtype, FP_VIA_DATAGRAM,
                                       src.client != NULL ? &src : NULL);
         if (wlen == 0) {
             /* miss: surface to Python exactly like recv_batch */

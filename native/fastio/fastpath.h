@@ -35,7 +35,8 @@ PyObject *fastio_addr_to_tuple(const struct sockaddr_storage *ss);
 enum {
     FASTIO_SPAN_RECV = 0,   /* udp-recv: one recvmmsg, EAGAIN included */
     FASTIO_SPAN_SERVE,      /* native-serve: after recvmmsg to before
-                             * sendmmsg, per batch with a datagram */
+                             * sendmmsg, per batch with a datagram; a
+                             * fastpath_serve_frames call with a frame */
     FASTIO_SPAN_SEND,       /* udp-send: one sendmmsg */
     FASTIO_N_SPANS
 };

@@ -883,17 +883,32 @@ fp_invalidate_tag(fp_cache_t *c, const uint8_t *tag, size_t taglen)
     return fp_invalidate_tags(c, &tag, &taglen, 1);
 }
 
+/* The transport a query arrived on, as far as the serving entry knows
+ * it.  A dnskey carries none: a query without an OPT record has the
+ * payload 512 in its key whatever socket it came from, so the ceiling
+ * of a native serve is the caller's to say. */
+#define FP_VIA_DATAGRAM 0   /* UDP: the key's payload is the ceiling and
+                             * a cached TC=1 wire is the right answer */
+#define FP_VIA_UNKNOWN 1    /* either (fastpath_serve_wire): the key's
+                             * payload is the ceiling AND a cached TC=1
+                             * wire declines, so the serve is right on
+                             * both transports */
+#define FP_VIA_STREAM 2     /* TCP (fastpath_serve_frames): a frame has
+                             * no UDP ceiling and is never truncated */
+
 /*
  * Serve one packet from the zone table: assemble header + question echo
  * (original case) + precompiled body + optional OPT echo.  `key` is the
  * full dnskey (RD/EDNS/payload in its lead bytes), `out` must hold
  * FP_MAX_WIRE.  Returns response length, or 0 to decline to Python
- * (miss, stale generation, or would-truncate).
+ * (miss, stale generation, or would-truncate: over the key's payload,
+ * except for a stream frame, whose only ceiling is FP_MAX_WIRE).
  */
 static inline size_t
 fp_zone_serve(fp_cache_t *c, const uint8_t *pkt, const uint8_t *key,
               size_t keylen, size_t qn_len, uint64_t gen, uint8_t *out,
-              uint16_t *qtype_out, double now, const fp_logsrc_t *src)
+              uint16_t *qtype_out, double now, int via,
+              const fp_logsrc_t *src)
 {
     /* table routing mirrors fp_zone_put exactly: (A|PTR, IN) keys can
      * only live in zmain, everything else only in zalien — probing the
@@ -911,9 +926,17 @@ fp_zone_serve(fp_cache_t *c, const uint8_t *pkt, const uint8_t *key,
     }
     int rd = key[0] & 1;
     int edns = key[0] & 2;
-    unsigned payload = ((unsigned)key[1] << 8) | key[2];
+    size_t ceiling = ((size_t)key[1] << 8) | key[2];
+    if (via == FP_VIA_STREAM || ceiling > FP_MAX_WIRE)
+        ceiling = FP_MAX_WIRE;
 
     uint8_t v = e->next_variant;
+    size_t blen = e->body_lens[v];
+    size_t total = 12 + qn_len + 4 + blen + (edns ? sizeof(fp_opt_echo) : 0);
+    if (total > ceiling)
+        /* truncation semantics: Python (BEFORE rotation: the entry's
+         * next stream serve takes the variant this one left) */
+        return 0;
     if (c->lr.enabled) {
         /* logged posture: a serve whose log line cannot be produced
          * declines (BEFORE rotation/accounting) — Python logs it */
@@ -924,10 +947,6 @@ fp_zone_serve(fp_cache_t *c, const uint8_t *pkt, const uint8_t *key,
         }
     }
     e->next_variant = (uint8_t)((v + 1) % e->n_variants);
-    size_t blen = e->body_lens[v];
-    size_t total = 12 + qn_len + 4 + blen + (edns ? sizeof(fp_opt_echo) : 0);
-    if (total > payload || total > FP_MAX_WIRE)
-        return 0;                       /* truncation semantics: Python */
 
     out[0] = pkt[0];                    /* request id */
     out[1] = pkt[1];
@@ -964,16 +983,20 @@ fp_zone_serve(fp_cache_t *c, const uint8_t *pkt, const uint8_t *key,
  * must hold FP_MAX_WIRE bytes.  Returns the response length on hit, 0 on
  * miss (the caller surfaces the packet to the slow path).
  *
- * `decline_tc`: refuse to serve truncated cached wires — set by the
- * socket-free entry (fastpath_serve_wire) whose callers may be TCP;
- * the decline happens BEFORE hit accounting and rotation so refused
- * serves neither inflate the folded cache-hit counter nor burn a
- * rotation step.  The UDP drain passes 0 (TC wires are correct there).
+ * `via` (FP_VIA_*) is the transport the packet arrived on.  A cached
+ * wire whose next variant carries TC=1 was promoted off the UDP path
+ * and is right for a datagram only.  The socket-free entry that cannot
+ * know its caller's transport (fastpath_serve_wire) declines it; a
+ * stream frame (fastpath_serve_frames) passes it over and asks the zone
+ * table, which holds the whole set and serves it up to FP_MAX_WIRE.
+ * Either happens BEFORE hit accounting and rotation, so a passed-over
+ * entry neither inflates the folded cache-hit counter nor burns a
+ * rotation step.
  */
 static inline size_t
 fp_serve_one_lx(fp_cache_t *c, const uint8_t *pkt, size_t plen,
                 uint64_t gen, double now, uint8_t *out,
-                uint16_t *qtype_out, int decline_tc,
+                uint16_t *qtype_out, int via,
                 const fp_logsrc_t *src)
 {
     uint8_t key[FP_MAX_KEY];
@@ -985,19 +1008,24 @@ fp_serve_one_lx(fp_cache_t *c, const uint8_t *pkt, size_t plen,
     if (keylen == 0)
         return 0;
     fp_entry_t *e = fp_find(c, key, keylen, gen, now);
+    if (e != NULL && via != FP_VIA_DATAGRAM
+            && e->wire_lens[e->next_variant] >= 3
+            && (e->wires[e->next_variant][2] & 0x02)) {
+        if (via != FP_VIA_STREAM)
+            return 0;
+        e = NULL;
+    }
     if (e == NULL)
-        /* not in the answer cache: a precompiled zone answer still
-         * serves it natively (first query for a name included; zone
-         * entries are never truncated, so decline_tc is moot there) */
+        /* not in the answer cache (or held there truncated, for a
+         * stream): a precompiled zone answer still serves it natively,
+         * the first query for a name included */
         return fp_zone_serve(c, pkt, key, keylen, qn_len, gen, out,
-                             qtype_out, now, src);
+                             qtype_out, now, via, src);
 
     /* hit: copy the variant, patch id + the client's question bytes
      * (same length by construction — key match implies identical
      * lowercased label structure) */
     uint8_t v = e->next_variant;
-    if (decline_tc && e->wire_lens[v] >= 3 && (e->wires[v][2] & 0x02))
-        return 0;
     if (c->lr.enabled) {
         /* logged posture: decline (before rotation/accounting) when the
          * line can't be produced — Python serves AND logs instead */
@@ -1028,21 +1056,14 @@ fp_serve_one_lx(fp_cache_t *c, const uint8_t *pkt, size_t plen,
     return wlen;
 }
 
-static inline size_t
-fp_serve_one_ex(fp_cache_t *c, const uint8_t *pkt, size_t plen,
-                uint64_t gen, double now, uint8_t *out,
-                uint16_t *qtype_out, int decline_tc)
-{
-    return fp_serve_one_lx(c, pkt, plen, gen, now, out, qtype_out,
-                           decline_tc, NULL);
-}
-
-/* drain-path spelling: TC wires serve (UDP requesters asked for them) */
+/* drain-path spelling, log off: TC wires serve (a UDP requester asked
+ * for them) */
 static inline size_t
 fp_serve_one(fp_cache_t *c, const uint8_t *pkt, size_t plen, uint64_t gen,
              double now, uint8_t *out, uint16_t *qtype_out)
 {
-    return fp_serve_one_ex(c, pkt, plen, gen, now, out, qtype_out, 0);
+    return fp_serve_one_lx(c, pkt, plen, gen, now, out, qtype_out,
+                           FP_VIA_DATAGRAM, NULL);
 }
 
 #endif /* BINDER_FPCORE_H */

@@ -173,14 +173,32 @@ void fuzz_one(const uint8_t *data, size_t len) {
             int had_room = !ring
                 || fp_log_room(fz_c, sizeof(zfrag) - 1);
             size_t wlen = fp_serve_one_lx(fz_c, q, qlen, fz_gen,
-                                          fz_clock, out, &got_qtype, 0,
+                                          fz_clock, out, &got_qtype,
+                                          FP_VIA_DATAGRAM,
                                           ring ? &zsrc : nullptr);
             if (ring && wlen > 0)
                 assert(fz_c->lr.lines == lines_before + 1);
             size_t want = 12 + qn_len + 4 + blens[0];
             if (want > DNSKEY_CLASSIC_PAYLOAD) {
-                /* would truncate: must decline to the slow path */
+                /* would truncate: must decline to the slow path, and
+                 * leave the rotation where it was; the same bytes as a
+                 * stream frame have no UDP ceiling, and FP_MAX_WIRE
+                 * and the ring's room alone decide */
                 assert(wlen == 0);
+                assert(fz_c->lr.lines == lines_before);
+                size_t slen = fp_serve_one_lx(fz_c, q, qlen, fz_gen,
+                                              fz_clock, out, &got_qtype,
+                                              FP_VIA_STREAM,
+                                              ring ? &zsrc : nullptr);
+                if (want > FP_MAX_WIRE || !had_room) {
+                    assert(slen == 0);
+                } else {
+                    assert(slen == want);
+                    assert(memcmp(out + 12 + qn_len + 4, bodies[0],
+                                  blens[0]) == 0);
+                    if (ring)
+                        assert(fz_c->lr.lines == lines_before + 1);
+                }
             } else if (!had_room) {
                 /* ring backpressure: must decline, never serve-and-
                  * drop the log line */
@@ -291,7 +309,8 @@ void fuzz_one(const uint8_t *data, size_t len) {
             int had_room = !ring
                 || fp_log_room(fz_c, sizeof(cfrag) - 1);
             size_t wlen = fp_serve_one_lx(fz_c, q, qlen, fz_gen,
-                                          fz_clock, out, &got_qtype, 0,
+                                          fz_clock, out, &got_qtype,
+                                          FP_VIA_DATAGRAM,
                                           ring ? &csrc : nullptr);
             if (ring && !had_room) {
                 assert(wlen == 0);      /* backpressure decline */

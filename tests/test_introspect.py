@@ -653,7 +653,8 @@ class TestSnapshotValidator:
             "tcp": {"open_conns": 0, "max_conns": 1024,
                     "idle_timeout_seconds": 30.0,
                     "max_write_buffer": 262144, "cap_refusals": 0,
-                    "accepts": 0, "fast_serves": 0, "promotions": 0,
+                    "accepts": 0, "fast_serves": 0, "native_serves": 0,
+                    "promotions": 0,
                     "oneshot_closes": 0, "idle_timeouts": 0,
                     "slow_reader_drops": 0, "coalesced_writes": 0,
                     "coalesced_frames": 0, "half_closes": 0,

@@ -474,7 +474,8 @@ _SNAPSHOT_SCHEMA = {
         "idle_timeout_seconds": (_NUM, False),
         "max_write_buffer": (int, False),
         "cap_refusals": (int, False), "accepts": (int, False),
-        "fast_serves": (int, False), "promotions": (int, False),
+        "fast_serves": (int, False), "native_serves": (int, False),
+        "promotions": (int, False),
         "oneshot_closes": (int, False), "idle_timeouts": (int, False),
         "slow_reader_drops": (int, False),
         "coalesced_writes": (int, False),
@@ -793,6 +794,7 @@ def validate_degradation_metrics(text):
 _TCP_FAMILIES = {
     "binder_tcp_accepts": "counter",
     "binder_tcp_fast_serves": "counter",
+    "binder_tcp_native_serves": "counter",
     "binder_tcp_promotions": "counter",
     "binder_tcp_oneshot_closes": "counter",
     "binder_tcp_idle_timeouts": "counter",
