@@ -3,13 +3,15 @@
 Nothing here comes from ``binder_tpu``: what the benchmark sends and how it
 reads what came back must not move when the program's codec does.  RFC 1035
 messages, RFC 6891 OPT in queries; the record types the zone serves (A, PTR,
-SRV, SOA) are decoded, anything else is kept as raw rdata.
+SRV, SOA) are decoded, anything else is kept as raw rdata.  AAAA is a type
+the benchmark asks and the zone declines (NOTIMP): a libc stub sends one
+beside every A.
 """
 import socket
 import struct
 
-A, PTR, SOA, SRV, OPT = 1, 12, 6, 33, 41
-QTYPES = {"A": A, "PTR": PTR, "SRV": SRV}
+A, PTR, SOA, AAAA, SRV, OPT = 1, 12, 6, 28, 33, 41
+QTYPES = {"A": A, "PTR": PTR, "SRV": SRV, "AAAA": AAAA}
 NOERROR, SERVFAIL, NXDOMAIN, NOTIMP, REFUSED = 0, 2, 3, 4, 5
 
 
@@ -57,7 +59,8 @@ def _name(wire: bytes, off: int):
 class Answer:
     """A decoded response: header fields and the three sections as lists
     of ``(name, type, ttl, rdata)``; rdata is an address, a name, a
-    ``(priority, weight, port, target)`` tuple, or bytes."""
+    ``(priority, weight, port, target)`` tuple, an SOA's ``(mname, rname,
+    serial, refresh, retry, expire, minimum)``, or bytes."""
 
     __slots__ = ("qid", "tc", "rcode", "question", "answers", "authorities",
                  "additionals")
@@ -95,6 +98,11 @@ class Answer:
                     prio, weight, port = struct.unpack(">HHH", rdata[:6])
                     rdata = (prio, weight, port,
                              _name(wire, off + 6)[0].lower())
+                elif rtype == SOA:
+                    mname, at = _name(wire, off)
+                    rname, at = _name(wire, at)
+                    rdata = (mname.lower(), rname.lower()) \
+                        + struct.unpack(">IIIII", wire[at:at + 20])
                 off += rdlen
                 if rtype != OPT:
                     recs.append((name.lower(), rtype, ttl, rdata))

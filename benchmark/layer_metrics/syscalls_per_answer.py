@@ -1,6 +1,8 @@
 """Kernel crossings the workers made per answer: ``select``, ``recvmmsg``
-(empty-handed ones too), ``sendmmsg``, writes of the native log ring and of
-Python-lane log lines, by the counts of their spans."""
+(empty-handed ones too), ``sendmmsg``, writes of the query log (the native
+ring's block with the Python lanes' lines behind it) and the stream lane's
+``accept``, ``recv``, ``send`` and ``close``, by the counts of their spans
+(``spans.SYSCALL_STAGES``)."""
 import spans
 
 LAYER = "kernel socket path"

@@ -30,11 +30,17 @@
  *     own getrusage(RUSAGE_THREAD) over the window, so that a saturated
  *     or starved generator shows;
  *   - the full latency histogram goes out (log-linear, 512 buckets to
- *     the octave, nanoseconds); percentiles are the harness's arithmetic.
+ *     the octave, nanoseconds); percentiles are the harness's arithmetic;
+ *   - every template carries the index of the traffic mix's entry it was
+ *     drawn from; where the mix has more than one entry the latency
+ *     histogram is kept once more for each (one more array index an
+ *     answer; a mix of one entry does per answer what it always did, and
+ *     its one histogram is the whole), so that a reader can give the
+ *     median of one kind of question and its share of the answers.
  *
  * Files:
  *   -t templates: repeated [u16 BE wire length][u8 expected rcode]
- *                 [u16 BE expected answer count][query wire]
+ *                 [u16 BE expected answer count][u8 mix entry][query wire]
  *   -q sequence:  u32 LE template indexes, bit 31 = keep the answer
  *   -a arrivals:  u64 LE nanoseconds after the start (warm-up included),
  *                 non-decreasing; must outlast warm-up + window
@@ -102,6 +108,7 @@ struct Template {
     std::string wire;
     uint8_t rcode = 0;
     uint16_t ancount = 0;
+    uint8_t entry = 0;              /* index into the traffic mix */
 };
 
 std::string read_file(const char *path) {
@@ -120,17 +127,18 @@ std::vector<Template> load_templates(const char *path) {
     std::vector<Template> out;
     size_t off = 0;
     while (off < raw.size()) {
-        if (raw.size() - off < 5) bail("truncated template file");
+        if (raw.size() - off < 6) bail("truncated template file");
         const unsigned char *p = (const unsigned char *)raw.data() + off;
         size_t len = ((size_t)p[0] << 8) | p[1];
         Template t;
         t.rcode = p[2];
         t.ancount = (uint16_t)(((unsigned)p[3] << 8) | p[4]);
-        if (len < 12 || raw.size() - off - 5 < len)
+        t.entry = p[5];
+        if (len < 12 || raw.size() - off - 6 < len)
             bail("bad template length");
-        t.wire.assign(raw.data() + off + 5, len);
+        t.wire.assign(raw.data() + off + 6, len);
         out.push_back(std::move(t));
-        off += 5 + len;
+        off += 6 + len;
     }
     if (out.empty()) bail("no templates");
     return out;
@@ -157,6 +165,7 @@ struct Config {
     const std::vector<Template> *templates = nullptr;
     const std::vector<uint32_t> *sequence = nullptr;
     const std::vector<uint64_t> *arrivals = nullptr;
+    size_t entries = 1;             /* mix entries the templates name */
     int64_t t0 = 0;                 /* start of the warm-up */
 };
 
@@ -195,9 +204,14 @@ struct Stats {
     uint64_t sent = 0, ok = 0, ok_in_window = 0, tc_retries = 0;
     uint64_t fails[F_KINDS] = {0};
     std::vector<uint32_t> lat, late;
+    /* per mix entry; empty where the mix has one entry (it is `lat`) */
+    std::vector<std::vector<uint32_t>> lat_entry;
     std::vector<uint32_t> inflight_samples;
     double cpu_user = 0, cpu_sys = 0;
-    Stats() : lat(kHistSize, 0), late(kHistSize, 0) {}
+    explicit Stats(size_t entries)
+        : lat(kHistSize, 0), late(kHistSize, 0),
+          lat_entry(entries > 1 ? entries : 0,
+                    std::vector<uint32_t>(kHistSize, 0)) {}
 };
 
 std::atomic<uint64_t> g_next_pos{0};
@@ -209,7 +223,8 @@ double tv_s(const struct timeval &tv) {
 
 class Worker {
   public:
-    Worker(const Config &cfg, int tid) : cfg_(cfg), tid_(tid) {
+    Worker(const Config &cfg, int tid)
+        : stats(cfg.entries), cfg_(cfg), tid_(tid) {
         ep_ = epoll_create1(0);
         if (ep_ < 0) die("epoll_create1");
         int cap = cfg.open_loop ? kOpenSlots : kClosedSlots;
@@ -399,7 +414,11 @@ class Worker {
             if (fail < 0) {
                 stats.ok++;
                 if (now <= t_end) stats.ok_in_window++;
-                stats.lat[hist_bucket(now - sl.ref_ns)]++;
+                size_t bucket = hist_bucket(now - sl.ref_ns);
+                stats.lat[bucket]++;
+                if (!stats.lat_entry.empty())
+                    stats.lat_entry[(*cfg_.templates)[
+                        sl.entry & ~kCaptureFlag].entry][bucket]++;
             } else {
                 stats.fails[fail]++;
             }
@@ -663,6 +682,8 @@ int main(int argc, char **argv) {
         bail("-j in [1, sources]");
 
     std::vector<Template> templates = load_templates(tmpl_path);
+    for (const Template &t : templates)
+        if ((size_t)t.entry + 1 > cfg.entries) cfg.entries = t.entry + 1u;
     std::vector<uint32_t> sequence = load_array<uint32_t>(seq_path);
     for (uint32_t e : sequence)
         if ((e & ~kCaptureFlag) >= templates.size())
@@ -694,8 +715,10 @@ int main(int argc, char **argv) {
     for (Worker *w : workers) threads.emplace_back([w] { w->run(); });
     for (auto &t : threads) t.join();
 
-    Stats total;
+    Stats total(0);
     std::vector<uint64_t> lat(kHistSize, 0), late(kHistSize, 0);
+    std::vector<std::vector<uint64_t>> lat_entry(
+        cfg.entries, std::vector<uint64_t>(kHistSize, 0));
     size_t samples = 0;
     for (Worker *w : workers) {
         total.sent += w->stats.sent;
@@ -708,6 +731,9 @@ int main(int argc, char **argv) {
             lat[i] += w->stats.lat[i];
             late[i] += w->stats.late[i];
         }
+        for (size_t e = 0; e < w->stats.lat_entry.size(); e++)
+            for (size_t i = 0; i < kHistSize; i++)
+                lat_entry[e][i] += w->stats.lat_entry[e][i];
         if (samples == 0 || w->stats.inflight_samples.size() < samples)
             samples = w->stats.inflight_samples.size();
     }
@@ -760,7 +786,14 @@ int main(int argc, char **argv) {
     print_hist(out, "latency_ns", lat);
     fprintf(out, ", ");
     print_hist(out, "late_ns", late);
-    fprintf(out, "}\n");
+    /* the latency histogram of each mix entry */
+    fprintf(out, ", \"latency_ns_by_entry\": [");
+    for (size_t e = 0; e < cfg.entries; e++) {
+        fprintf(out, "%s{", e ? ", " : "");
+        print_hist(out, "latency_ns", cfg.entries > 1 ? lat_entry[e] : lat);
+        fprintf(out, "}");
+    }
+    fprintf(out, "]}\n");
     if (out != stdout && fclose(out) != 0) die("write result");
     for (Worker *w : workers) delete w;
     return 0;

@@ -12,14 +12,20 @@ documentation restates them: A of a host-like record is its one address; A
 of a service is its members' addresses; SRV ``_srvce._proto.<service>`` is
 one record per member (priority 0, weight 10, the service's port) with the
 member's A as glue; PTR is the owner of the address; SRV on a name that is
-not a service is NODATA; a name the zone lacks is REFUSED.  TTL 30.  Sets
-compare without order: rotation is the server's to choose.
+not a service is NODATA (no answer, one SOA owned by that name in the
+authority section, its TTL and minimum the record's 30 s:
+``lib/server.js:276-292``); a name the zone lacks is REFUSED
+(``lib/server.js:227-246``); a type other than A, SRV and PTR is NOTIMP,
+decided by the type alone before any look at the name
+(``lib/server.js:491-506``).  An answer without records has every section
+empty, but for NODATA's SOA.  TTL 30.  Sets compare without order: rotation
+is the server's to choose.
 """
 import re
 
 import numpy as np
 
-from dnswire import A, NOERROR, PTR, REFUSED, SOA, SRV
+from dnswire import A, NOERROR, NOTIMP, NXDOMAIN, PTR, REFUSED, SOA, SRV
 
 TTL = 30
 #: registrar record types that count as service members (binder
@@ -70,6 +76,10 @@ class Zone:
         self.written = {f"chaos{k}.{domain}": f"10.254.{k}.{k + 1}"
                         for k in range(int(config["chaos"]["writes"]))}
         self.writes_done = False
+        #: types the reference takes for answered without records where
+        #: the deployment declines them: empty but under the control
+        #: ``--break reference-declined``
+        self.answered_empty = frozenset()
         self._host_rx = re.compile(
             r"^h(\d{6})\.r(\d{4})\.%s\.%s$" % (re.escape(self.subtree),
                                                 re.escape(domain)))
@@ -157,15 +167,23 @@ class Zone:
     def expected(self, qname: str, qtype: int) -> dict:
         """``{"rcode", "answers", "glue", "nodata"}`` for one question;
         answers and glue are sorted lists of ``(type, rdata)`` and
-        ``(name, address)``."""
+        ``(name, address)``; ``nodata`` is the owner of the SOA that a
+        NODATA answer carries, else ``None``."""
         qname = qname.lower().rstrip(".")
-        refused = {"rcode": REFUSED, "answers": [], "glue": [],
-                   "nodata": False}
 
-        def ok(answers, glue=(), nodata=False):
+        def empty(rcode):
+            return {"rcode": rcode, "answers": [], "glue": [],
+                    "nodata": None}
+
+        def ok(answers, glue=(), nodata=None):
             return {"rcode": NOERROR, "answers": sorted(answers),
                     "glue": sorted(glue), "nodata": nodata}
 
+        if qtype not in (A, SRV, PTR):
+            # routed by type first: the name is never looked at
+            return ok([]) if qtype in self.answered_empty \
+                else empty(NOTIMP)
+        refused = empty(REFUSED)
         if qtype == PTR:
             m = re.match(r"^(\d+)\.(\d+)\.(\d+)\.(\d+)\.in-addr\.arpa$",
                          qname)
@@ -189,14 +207,12 @@ class Zone:
             if not m:
                 return refused
             want_srv, name = (m.group(1), m.group(2)), m.group(3)
-        elif qtype != A:
-            raise ValueError(f"the zone is not asked for type {qtype}")
         if not name.endswith("." + self.domain):
             return refused
         address = self._address_of(name)
         if address is not None:         # host-like
             if want_srv:
-                return ok([], nodata=True)
+                return ok([], nodata=name)
             return ok([(A, address)])
         service = self.by_label.get(name[:-len(self.domain) - 1])
         if service is None:
@@ -204,7 +220,7 @@ class Zone:
         if not want_srv:
             return ok([(A, addr) for _, addr in service.members])
         if want_srv != (self.srvce, self.proto):
-            return {"rcode": 3, "answers": [], "glue": [], "nodata": False}
+            return empty(NXDOMAIN)
         targets = [(f"{label}.{name}", addr)
                    for label, addr in service.members]
         return ok([(SRV, (0, 10, self.port, t)) for t, _ in targets],
@@ -257,7 +273,19 @@ def compare(answer, qname: str, qtype: int, want: dict,
     ttls = {ttl for _, _, ttl, _ in answer.answers + answer.additionals}
     if ttls - {TTL}:
         wrong.append(f"TTLs {sorted(ttls)}, reference {TTL}")
-    if want["nodata"] and not any(rtype == SOA for _, rtype, _, _
-                                  in answer.authorities):
-        wrong.append("NODATA without an SOA")
+    if want["nodata"]:
+        soa = [(name, ttl, rdata[-1]) for name, rtype, ttl, rdata
+               in answer.authorities if rtype == SOA]
+        if soa != [(want["nodata"], TTL, TTL)]:
+            wrong.append(f"NODATA with the SOAs (owner, TTL, minimum) "
+                         f"{soa}, reference one of {want['nodata']}, "
+                         f"{TTL}, {TTL}")
+    if not want["answers"]:
+        # an answer without records is its header and question, and for
+        # NODATA the one SOA
+        extra = len(answer.authorities) - (1 if want["nodata"] else 0) \
+            + len(answer.additionals)
+        if extra:
+            wrong.append(f"{extra} records in the authority and additional "
+                         "sections of an answer without records")
     return wrong

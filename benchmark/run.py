@@ -12,7 +12,8 @@ store fixture, the query templates, their sequence and the arrival schedule
 are made from ``--seed``; ``python -m binder_tpu.main --shards N`` is
 spawned in ``etc/config.json``'s production posture and waited for until it
 is *settled*; a seeded sample of the cell's own questions is asked from
-fresh sockets and compared with ``reference.py``, the chaos plan's write is
+fresh sockets (one of every entry of its mix and the zone's largest set
+among them) and compared with ``reference.py``, the chaos plan's write is
 read back from every worker; the generator warms up and then drives the
 window; the answers it kept are compared with the reference, the sample and
 the written names are asked once more; SIGTERM must end the group with exit
@@ -55,7 +56,8 @@ SETTLE_TIMEOUT_S = 240.0
 ASK_SOURCE = "127.0.1.1"
 ASKS = 96                   # seeded sample asked before and after the window
 GENERATOR = os.path.join(HERE, "loadgen", "build", "dnsblast")
-BREAKS = ("reference-address", "fixture-address", "skew-replica")
+BREAKS = ("reference-address", "reference-declined", "fixture-address",
+          "skew-replica")
 
 
 def fail(phase: str, why: str) -> None:
@@ -321,12 +323,14 @@ class Verdict:
     def correct(self) -> bool:
         return all(value <= limit for _, value, limit in self.rows)
 
+    def lines(self) -> list:
+        return [f"compared {name} = {value} (limit {limit})"
+                + ("" if value <= limit else "  <-- outside")
+                for name, value, limit in self.rows]
+
     def show(self) -> None:
-        for name, value, limit in self.rows:
-            say(f"compared {name} = {value} (limit {limit})"
-                + ("" if value <= limit else "  <-- outside"))
-        for line in self.examples:
-            say(f"  e.g. {line}")
+        for line in self.lines() + [f"  e.g. {e}" for e in self.examples]:
+            say(line)
 
 
 def requests_completed(workers: list) -> list:
@@ -378,10 +382,12 @@ def read_back(udp: int, zone, workers: list, verdict: Verdict) -> tuple:
 
 def check_captures(path: str, traffic, zone, verdict: Verdict) -> int:
     """The answers the generator kept from the window, each against the
-    reference.  Returns how many were compared."""
+    reference; every entry of the cell's mix has to be among them.
+    Returns how many were compared."""
     with open(path, "rb") as f:
         raw = f.read()
     off = compared = bad = longest = 0
+    by_entry = [0] * len(traffic.workload["mix"])
     while off < len(raw):
         _pos, tmpl, _tcp, length = struct.unpack_from("<IIBH", raw, off)
         off += 11
@@ -397,9 +403,12 @@ def check_captures(path: str, traffic, zone, verdict: Verdict) -> int:
             problems = [f"undecodable answer: {e}"]
         bad += verdict.wrong(f"window {qname}/{qtype}", problems)
         compared += 1
+        by_entry[traffic.templates[tmpl][3]] += 1
     verdict.hold("window_answers_mismatching", bad, 0)
-    say(f"window answers compared: {compared}, the longest with "
-        f"{longest} records")
+    verdict.hold("mix_entries_with_no_window_answer_compared",
+                 by_entry.count(0), 0)
+    say(f"window answers compared: {compared} ({by_entry} by mix entry), "
+        f"the longest with {longest} records")
     return compared
 
 
@@ -560,6 +569,13 @@ def run(args) -> int:
         # the traffic is made while the server starts
         traffic = Traffic(workload, zone, args.seed, args.seconds)
         files = traffic.write(out_dir)
+        if args.break_ == "reference-declined":
+            # the control of a declined type: the reference is told that
+            # AAAA is answered, NOERROR without records (what a later PR
+            # might serve for it), once the generator's files are written,
+            # so that the answers are kept and it is the comparison that
+            # fails
+            zone.answered_empty = frozenset({dnswire.AAAA})
         say(f"traffic: {len(traffic.templates)} templates, "
             f"{len(traffic.sequence)} sequence entries"
             + (f", {len(traffic.arrivals)} arrivals"
@@ -599,14 +615,13 @@ def run(args) -> int:
                         "chaos watch-storm")
         zone.writes_done = True
 
-        # a seeded sample of the cell's own questions, before the window
+        # a seeded sample of the cell's own questions, before the window,
+        # with one of every mix entry and the zone's largest set in it
         picks = rng_for(args.seed, 6).choice(len(traffic.sequence),
                                              size=ASKS, replace=False)
-        sample = []
-        for pos in picks:
-            tmpl = int(traffic.sequence[pos]) & 0x7FFFFFFF
-            sample.append(traffic.questions[tmpl]
-                          + (traffic.templates[tmpl][0],))
+        sample = [traffic.questions[tmpl] + (traffic.templates[tmpl][0],)
+                  for tmpl in [int(traffic.sequence[pos]) & 0x7FFFFFFF
+                               for pos in picks] + traffic.always_asked]
 
         def asks_and_read_back() -> tuple:
             start = requests_completed(workers)
@@ -616,8 +631,8 @@ def run(args) -> int:
             return (bad, unseen) + read_back(udp, zone, workers, verdict)
 
         bad, unseen, silent, stale, asked = asks_and_read_back()
-        say(f"before the window: {ASKS} sampled asks, the write read back "
-            f"in {asked} asks")
+        say(f"before the window: {len(sample)} sampled asks, the write "
+            f"read back in {asked} asks")
 
         device = child.device()
         if not traced:
@@ -708,7 +723,7 @@ def run(args) -> int:
     if traced:
         metrics = layer_values(manifest, args.workload, {
             "before": scrape_before, "after": scrape_after, "generator": g,
-            "workload": workload,
+            "workload": workload, "mix_rcodes": traffic.rcodes_by_entry(),
             "harness": {"ready_s": ready_s, "seed_s": seed_s,
                         "setup_s": setup_s}})
     else:
@@ -721,6 +736,12 @@ def run(args) -> int:
         result["breakdown"] = {
             "device_ops": device.pop("device_ops", []),
             "idle_gaps": stage_seconds(scrape_before, scrape_after)}
+    # every number compared beside its limit, as the benchmark's contract
+    # asks: the line's last key, and the run's last lines on standard error
+    result["compared"] = {name: {"value": value, "limit": limit}
+                          for name, value, limit in verdict.rows}
+    print("\n".join(f"benchmark: {line}" for line in verdict.lines()),
+          file=sys.stderr, flush=True)
     if args.cpu:
         # a rehearsal: no number of a CPU run goes out under a device
         # metric's name, and no result line

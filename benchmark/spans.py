@@ -16,37 +16,38 @@ import stats
 
 STAGE = "binder_query_stage_seconds"
 
+#: the stream lane's four leaf spans, in the order a one-shot leg passes
+#: them: one kernel crossing of a TCP connection each (``accept``,
+#: ``recv``, ``send``, ``close``)
+TCP_STAGES = ("tcp-accept", "tcp-recv", "tcp-send", "tcp-close")
 #: spans that neither overlap each other nor the per-query stages: one
 #: ``select``, ``recvmmsg`` or ``sendmmsg`` call each, the native serve
 #: loop between them, a write of the native log ring, a Python-lane
-#: log line
+#: log line, the stream lane's crossings
 LEDGER_STAGES = ("loop-idle", "udp-recv", "native-serve", "udp-send",
-                 "log-write", "log-line")
+                 "log-write", "log-line") + TCP_STAGES
 #: the per-query cursor stages of the Python lanes that run on the loop
 #: (``QueryCtx.stamp``).  ``await`` and ``upstream`` span a wait of the
 #: loop and ``upstream-rtt`` / ``loop-wait`` overlay it: none of the
 #: four is summed anywhere here.
 QUERY_STAGES = ("cache-hit", "precompile-hit", "store-lookup", "pre-resp",
-                "log-after", "dispatch", "splice", "rebuild",
+                "lazy-render", "log-after", "dispatch", "splice", "rebuild",
                 "foreign-stale", "foreign-withheld")
-#: what one Python-lane query of the cell's kind passes through
+#: what one Python-lane query of the cells' kinds passes through
+#: (``lazy-render`` stands in place of ``store-lookup`` and ``pre-resp``
+#: where a set is rendered at query time)
 PYTHON_LANE_STAGES = ("cache-hit", "precompile-hit", "store-lookup",
-                      "pre-resp", "log-after", "log-line")
-#: one kernel crossing per observation
-SYSCALL_STAGES = ("loop-idle", "udp-recv", "udp-send", "log-write",
-                  "log-line")
+                      "pre-resp", "lazy-render", "log-after", "log-line")
+#: one kernel crossing per observation.  ``log-line`` is none: a
+#: Python-lane line is rendered to bytes and leaves with the next
+#: ``log-write``
+SYSCALL_STAGES = ("loop-idle", "udp-recv", "udp-send", "log-write") \
+    + TCP_STAGES
+#: the calls that move a query in or an answer out
+SOCKET_STAGES = ("udp-recv", "udp-send") + TCP_STAGES
 
 FREEZE_SHARE = 0.75     # of the workers, stalled ...
 FREEZE_WITHIN_S = 0.15  # ... within this of each other: the sandbox
-#: No property of freezes, and to go with its cause: the accepted
-#: rehearsal test (``tests/test_benchmark.py``
-#: ``test_rehearsal_sound_run_is_correct``, not the ledger PR's to edit)
-#: wants every non-% metric of its traced 4 s CPU window above 0, and 0
-#: is the two stall metrics' normal value.  So a window shorter than
-#: this reports neither; every longer one (the cell's 51 s, a 15 s
-#: sweep step) reports both, 0 included.  The ``benchmark`` PR that
-#: exempts the two there drops this (PERF.md section 7).
-REHEARSAL_WINDOW_S = 10.0
 
 
 def reader(fn):
@@ -149,15 +150,11 @@ def stall_split(ctx):
     freeze of the sandbox and counts once, with the worst worker's lag;
     every other instant is that worker's own stall and counts with its
     lag.  With a single worker nothing is shared and every instant is a
-    stall.  None where no worker has a ring, and in a window as short
-    as a rehearsal's (``REHEARSAL_WINDOW_S``, for the rehearsal test's
-    sake alone)."""
+    stall.  None where no worker has a ring; 0 is a value."""
     ps = pairs(ctx)
     if ps is None:
         return None
     lo, hi = ctx["before"]["at"], ctx["after"]["at"]
-    if hi - lo < REHEARSAL_WINDOW_S:
-        return None
     instants, rings = [], 0
     for worker, (_, after) in enumerate(ps):
         ring = (after["status"].get("loop") or {}).get("stalls")

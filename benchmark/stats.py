@@ -93,6 +93,26 @@ def spread(values: list) -> float:
 
 # -- helpers for the per-layer readers (layer_metrics/*.py) --
 
+def qtype_percentile(ctx: dict, qtype: str, q: float):
+    """The q-th percentile, in microseconds, of the latency of the mix
+    entries that ask *qtype*, from the generator's per-entry histograms;
+    None where the mix asks no such type or none was answered."""
+    g = ctx.get("generator") or {}
+    by_entry = g.get("latency_ns_by_entry")
+    mix = (ctx.get("workload") or {}).get("mix")
+    if not by_entry or not mix:
+        return None
+    merged = {}
+    for entry, hist in zip(mix, by_entry):
+        if entry["qtype"] != qtype:
+            continue
+        for bucket, count in hist["latency_ns"]:
+            merged[bucket] = merged.get(bucket, 0) + count
+    if not merged:
+        return None
+    return hist_percentile(list(merged.items()), g["hist_bits"], q) / 1e3
+
+
 def window_delta(ctx: dict, name: str) -> list:
     """Per worker, how much a counter of its own ``/metrics`` grew
     between the two scrapes of a traced run."""

@@ -11,8 +11,6 @@ or counter (a program older than these), which the readers pass on.
 """
 import spans
 
-#: in the order a one-shot leg passes them
-TCP_STAGES = ("tcp-accept", "tcp-recv", "tcp-send", "tcp-close")
 LAZY_STAGE = "lazy-render"
 
 
@@ -46,7 +44,7 @@ def tcp(ctx, part="sum"):
     """Seconds inside the four spans (``part="sum"``) or their
     observations (``"count"``); None unless the program has all four
     (a part of them would read as a cheaper leg)."""
-    parts = [spans.stage(ctx, name, part) for name in TCP_STAGES]
+    parts = [spans.stage(ctx, name, part) for name in spans.TCP_STAGES]
     return None if None in parts else sum(parts)
 
 

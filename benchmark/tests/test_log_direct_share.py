@@ -60,9 +60,12 @@ def test_nothing_to_read_is_none(empty):
 
 def test_the_manifest_states_what_the_reader_states():
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
-        entry = json.load(f)["per_layer"][-1]
+        entry = next(m for m in json.load(f)["per_layer"]
+                     if m["name"] == "log_direct_share")
     module = reader()
     assert entry == {"name": "log_direct_share", "unit": module.UNIT,
                      "better": "higher", "source": "program_counter",
                      "layer": module.LAYER, "moves": module.MOVES,
-                     "workloads": ["hosts_zipf_open60"]}
+                     "workloads": ["hosts_zipf_open60",
+                                   "services_srv_open60",
+                                   "hosts_a_aaaa_open60"]}

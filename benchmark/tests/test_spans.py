@@ -88,8 +88,9 @@ def known_ctx():
     ("loop_busy_share", 100.0 * (1 - 24.0 / 40.0)),
     # named: 2 x (12 + 1 + .9 + 2 + .5 + .3 + .2 + .1 + .15 + .05 + .4)
     ("busy_unnamed_share", 100.0 * (40.0 - 2 * 17.6) / (40.0 - 24.0)),
-    # 2 x (600 + 700 + 550 + 450 + 100) crossings for 2,000 answers
-    ("syscalls_per_answer", 2 * 2400 / 2000.0),
+    # 2 x (600 + 700 + 550 + 450) crossings for 2,000 answers: the 100
+    # Python-lane lines are rendered, not written
+    ("syscalls_per_answer", 2 * 2300 / 2000.0),
     ("recv_batch_mean", 2.0),
     ("socket_us_per_answer", 1e6 * 2 * 3.0 / 2000),
     ("native_us_per_answer", 1e6 * 2 * 0.9 / 1800),
@@ -152,17 +153,11 @@ def test_a_single_worker_shares_nothing():
     assert spans.stall_split(one) == pytest.approx((0.0, 100.0))
 
 
-def test_a_rehearsal_window_is_left_out_for_the_rehearsal_tests_sake():
-    """``test_benchmark.py``'s traced rehearsal (a 4 s window on the
-    CPU) asserts every non-% metric above 0, and a quiet window's stall
-    metrics read 0.  That test is not the ledger PR's to edit, so a
-    window that short reports neither metric; one as long as a real
-    run's reports both, and 0 is a value there."""
-    short = stall_ctx({0: [(101.0, 0.1)]},
-                      hi=100.0 + spans.REHEARSAL_WINDOW_S - 0.1)
-    assert readers()["sandbox_freeze_ms"].read(short) is None
-    assert readers()["worker_stall_ms"].read(short) is None
-    quiet = stall_ctx({}, hi=100.0 + spans.REHEARSAL_WINDOW_S)
+def test_a_window_as_short_as_a_rehearsals_reports_both_and_0_is_a_value():
+    short = stall_ctx({0: [(101.0, 0.1)]}, hi=104.0)
+    assert readers()["sandbox_freeze_ms"].read(short) == 0.0
+    assert readers()["worker_stall_ms"].read(short) == pytest.approx(100.0)
+    quiet = stall_ctx({}, hi=104.0)
     assert readers()["sandbox_freeze_ms"].read(quiet) == 0.0
     assert readers()["worker_stall_ms"].read(quiet) == 0.0
 
@@ -196,11 +191,13 @@ def test_reader_gives_none_where_there_is_nothing_to_read(name, ctx):
     assert readers()[name].read(ctx) is None
 
 
-def test_the_new_readers_are_the_manifests_last_eleven():
+def test_the_manifest_lists_the_eleven_readers_by_name():
     import json
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
-        per_layer = json.load(f)["per_layer"]
-    assert tuple(m["name"] for m in per_layer[-11:]) == NEW
-    for metric in per_layer[-11:]:
-        assert metric["source"] == "program_counter"
-        assert metric["workloads"] == ["hosts_zipf_open60"]
+        by_name = {m["name"]: m for m in json.load(f)["per_layer"]}
+    for name in NEW:
+        assert by_name[name]["source"] == "program_counter"
+        # every cell asks one of the zones over UDP: all report all eleven
+        assert sorted(by_name[name]["workloads"]) == [
+            "hosts_a_aaaa_open60", "hosts_zipf_open60",
+            "services_srv_open60"]
