@@ -2,8 +2,9 @@
 
 Nothing here comes from ``binder_tpu``: what the benchmark sends and how it
 reads what came back must not move when the program's codec does.  RFC 1035
-messages, RFC 6891 OPT in queries; the record types the zone serves (A, PTR,
-SRV, SOA) are decoded, anything else is kept as raw rdata.  AAAA is a type
+messages, RFC 6891 OPT in queries and, kept apart from the records, in
+answers; the record types the zone serves (A, PTR, SRV, SOA) are decoded,
+anything else is kept as raw rdata.  AAAA is a type
 the benchmark asks and the zone declines (NOTIMP): a libc stub sends one
 beside every A.
 """
@@ -37,6 +38,20 @@ def make_query(name: str, qtype: int, qid: int = 0, rd: bool = False,
     return wire
 
 
+def query_payload(wire: bytes):
+    """The UDP payload size a query's OPT record advertises, or 0 for a
+    query without one (one question, the OPT its only additional)."""
+    if not int.from_bytes(wire[10:12], "big"):
+        return 0
+    _, off = _name(wire, 12)
+    off += 4
+    _, off = _name(wire, off)
+    rtype, payload = struct.unpack(">HH", wire[off:off + 4])
+    if rtype != OPT:
+        raise ValueError("a query's additional record is not an OPT")
+    return payload
+
+
 def _name(wire: bytes, off: int):
     """(name, offset after it), following compression pointers."""
     labels, end, hops = [], None, 0
@@ -60,10 +75,13 @@ class Answer:
     """A decoded response: header fields and the three sections as lists
     of ``(name, type, ttl, rdata)``; rdata is an address, a name, a
     ``(priority, weight, port, target)`` tuple, an SOA's ``(mname, rname,
-    serial, refresh, retry, expire, minimum)``, or bytes."""
+    serial, refresh, retry, expire, minimum)``, or bytes.  OPT records are
+    no records of the zone: they are kept apart in ``opts``, as ``(section
+    0-2, owner, payload size, extended rcode, version)``; ``size`` is the
+    length of the wire."""
 
     __slots__ = ("qid", "tc", "rcode", "question", "answers", "authorities",
-                 "additionals")
+                 "additionals", "opts", "size")
 
     def __init__(self, wire: bytes) -> None:
         (self.qid, flags, qd, an, ns, ar) = struct.unpack(">HHHHHH",
@@ -72,6 +90,8 @@ class Answer:
             raise ValueError("not a response")
         self.tc = bool(flags & 0x0200)
         self.rcode = flags & 0x0F
+        self.size = len(wire)
+        self.opts = []
         off = 12
         self.question = None
         for _ in range(qd):
@@ -80,11 +100,11 @@ class Answer:
             off += 4
             self.question = (qname.lower(), qtype)
         sections = []
-        for count in (an, ns, ar):
+        for section, count in enumerate((an, ns, ar)):
             recs = []
             for _ in range(count):
                 name, off = _name(wire, off)
-                rtype, _rclass, ttl, rdlen = struct.unpack(
+                rtype, rclass, ttl, rdlen = struct.unpack(
                     ">HHIH", wire[off:off + 10])
                 off += 10
                 rdata = wire[off:off + rdlen]
@@ -104,7 +124,10 @@ class Answer:
                     rdata = (mname.lower(), rname.lower()) \
                         + struct.unpack(">IIIII", wire[at:at + 20])
                 off += rdlen
-                if rtype != OPT:
+                if rtype == OPT:
+                    self.opts.append((section, name, rclass, ttl >> 24,
+                                      (ttl >> 16) & 255))
+                else:
                     recs.append((name.lower(), rtype, ttl, rdata))
             sections.append(recs)
         self.answers, self.authorities, self.additionals = sections

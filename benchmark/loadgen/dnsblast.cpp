@@ -36,7 +36,15 @@
  *     histogram is kept once more for each (one more array index an
  *     answer; a mix of one entry does per answer what it always did, and
  *     its one histogram is the whole), so that a reader can give the
- *     median of one kind of question and its share of the answers.
+ *     median of one kind of question and its share of the answers;
+ *   - where the window is cut into segments (-g, seconds from the window's
+ *     first due time) the latency histogram is kept once more for each, by
+ *     the query's *due* time (its send time in a closed loop), with the
+ *     count of the queries of that segment that failed: the same mechanism,
+ *     and a window without segments does per answer what it did plus one
+ *     branch, its one histogram the whole; -s names a file that gets the
+ *     window's first due time (CLOCK_MONOTONIC, nanoseconds) as soon as it
+ *     is fixed, so that the harness can place an event inside the window.
  *
  * Files:
  *   -t templates: repeated [u16 BE wire length][u8 expected rcode]
@@ -44,6 +52,10 @@
  *   -q sequence:  u32 LE template indexes, bit 31 = keep the answer
  *   -a arrivals:  u64 LE nanoseconds after the start (warm-up included),
  *                 non-decreasing; must outlast warm-up + window
+ *   -g cuts:      seconds into the window, ascending, comma-separated: the
+ *                 boundaries of its segments (n cuts, n + 1 segments)
+ *   -s start:     out; the window's first due time, decimal nanoseconds on
+ *                 CLOCK_MONOTONIC, written once the start is fixed
  *   -c captures:  out; repeated [u32 LE sequence position][u32 LE
  *                 template][u8 came over TCP][u16 LE length][answer wire]
  * Output (-o file, else stdout): one JSON object.
@@ -166,6 +178,7 @@ struct Config {
     const std::vector<uint32_t> *sequence = nullptr;
     const std::vector<uint64_t> *arrivals = nullptr;
     size_t entries = 1;             /* mix entries the templates name */
+    std::vector<int64_t> cuts;      /* segment boundaries, ns into the window */
     int64_t t0 = 0;                 /* start of the warm-up */
 };
 
@@ -206,12 +219,18 @@ struct Stats {
     std::vector<uint32_t> lat, late;
     /* per mix entry; empty where the mix has one entry (it is `lat`) */
     std::vector<std::vector<uint32_t>> lat_entry;
+    /* per segment of the window; empty where it is not cut (it is `lat`) */
+    std::vector<std::vector<uint32_t>> lat_segment;
+    std::vector<uint64_t> failed_segment;
     std::vector<uint32_t> inflight_samples;
     double cpu_user = 0, cpu_sys = 0;
-    explicit Stats(size_t entries)
+    Stats(size_t entries, size_t cuts)
         : lat(kHistSize, 0), late(kHistSize, 0),
           lat_entry(entries > 1 ? entries : 0,
-                    std::vector<uint32_t>(kHistSize, 0)) {}
+                    std::vector<uint32_t>(kHistSize, 0)),
+          lat_segment(cuts ? cuts + 1 : 0,
+                      std::vector<uint32_t>(kHistSize, 0)),
+          failed_segment(cuts ? cuts + 1 : 0, 0) {}
 };
 
 std::atomic<uint64_t> g_next_pos{0};
@@ -224,7 +243,7 @@ double tv_s(const struct timeval &tv) {
 class Worker {
   public:
     Worker(const Config &cfg, int tid)
-        : stats(cfg.entries), cfg_(cfg), tid_(tid) {
+        : stats(cfg.entries, cfg.cuts.size()), cfg_(cfg), tid_(tid) {
         ep_ = epoll_create1(0);
         if (ep_ < 0) die("epoll_create1");
         int cap = cfg.open_loop ? kOpenSlots : kClosedSlots;
@@ -373,7 +392,11 @@ class Worker {
         size_t s = rr_++ % socks_.size();
         for (size_t tries = 1; socks_[s].free_slots.empty(); tries++) {
             if (tries == socks_.size()) {
-                if (measured) stats.fails[F_OVERFLOW]++;
+                if (measured) {
+                    stats.fails[F_OVERFLOW]++;
+                    if (!stats.failed_segment.empty())
+                        stats.failed_segment[segment_of(ref - t_meas)]++;
+                }
                 return;
             }
             s = rr_++ % socks_.size();
@@ -419,8 +442,12 @@ class Worker {
                 if (!stats.lat_entry.empty())
                     stats.lat_entry[(*cfg_.templates)[
                         sl.entry & ~kCaptureFlag].entry][bucket]++;
+                if (!stats.lat_segment.empty())
+                    stats.lat_segment[segment_of(sl.ref_ns - t_meas)][bucket]++;
             } else {
                 stats.fails[fail]++;
+                if (!stats.failed_segment.empty())
+                    stats.failed_segment[segment_of(sl.ref_ns - t_meas)]++;
             }
         }
         sl.in_flight = false;
@@ -428,6 +455,13 @@ class Worker {
         sk.free_slots.push_back(si);
         in_flight_--;
         if (next) send_next(now, now, t_meas, t_end);
+    }
+
+    /* which segment a query that was due *into* ns into the window is in */
+    size_t segment_of(int64_t into) const {
+        size_t k = 0;
+        while (k < cfg_.cuts.size() && into >= cfg_.cuts[k]) k++;
+        return k;
     }
 
     void keep(const Slot &sl, const unsigned char *wire, size_t len,
@@ -640,13 +674,13 @@ int main(int argc, char **argv) {
     const char *host = "127.0.0.1";
     const char *tmpl_path = nullptr, *seq_path = nullptr;
     const char *arr_path = nullptr, *cap_path = nullptr;
-    const char *out_path = nullptr;
+    const char *out_path = nullptr, *start_path = nullptr;
     int port = 0;
     double seconds = 10.0, warm = 0.0, timeout = 1.0;
     Config cfg;
 
     int c;
-    while ((c = getopt(argc, argv, "H:p:t:q:a:c:o:d:W:T:C:S:j:R")) != -1) {
+    while ((c = getopt(argc, argv, "H:p:t:q:a:c:o:d:W:T:C:S:j:Rg:s:")) != -1) {
         switch (c) {
         case 'H': host = optarg; break;
         case 'p': port = atoi(optarg); break;
@@ -662,12 +696,26 @@ int main(int argc, char **argv) {
         case 'S': cfg.sources = atoi(optarg); break;
         case 'j': cfg.threads = atoi(optarg); break;
         case 'R': cfg.tc_retry = true; break;
+        case 's': start_path = optarg; break;
+        case 'g':
+            for (const char *p = optarg; *p != '\0';) {
+                char *end;
+                double at = strtod(p, &end);
+                if (end == p || at <= 0
+                        || (!cfg.cuts.empty()
+                            && (int64_t)(at * 1e9) <= cfg.cuts.back()))
+                    bail("-g: seconds into the window, ascending");
+                cfg.cuts.push_back((int64_t)(at * 1e9));
+                p = *end == ',' ? end + 1 : end;
+            }
+            break;
         default:
             fprintf(stderr,
                     "usage: dnsblast -p port -t templates -q sequence "
                     "-d seconds [-W warm] [-T timeout] [-S sources] "
                     "[-C callers] [-j threads] [-a arrivals] "
-                    "[-R] [-c captures] [-o out.json] [-H host]\n");
+                    "[-R] [-g cut,cut,...] [-s start file] [-c captures] "
+                    "[-o out.json] [-H host]\n");
             return 2;
         }
     }
@@ -711,14 +759,28 @@ int main(int argc, char **argv) {
     for (int t = 0; t < cfg.threads; t++)
         workers.push_back(new Worker(cfg, t));
     cfg.t0 = now_ns() + 20000000LL;
+    if (start_path != nullptr) {
+        /* written under another name and renamed: a reader that finds the
+         * file finds the whole number */
+        std::string tmp = std::string(start_path) + ".tmp";
+        FILE *f = fopen(tmp.c_str(), "w");
+        if (f == nullptr) die(start_path);
+        fprintf(f, "%" PRId64 "\n", cfg.t0 + cfg.warm_ns);
+        if (fclose(f) != 0 || rename(tmp.c_str(), start_path) != 0)
+            die(start_path);
+    }
     std::vector<std::thread> threads;
     for (Worker *w : workers) threads.emplace_back([w] { w->run(); });
     for (auto &t : threads) t.join();
 
-    Stats total(0);
+    Stats total(0, 0);
     std::vector<uint64_t> lat(kHistSize, 0), late(kHistSize, 0);
     std::vector<std::vector<uint64_t>> lat_entry(
         cfg.entries, std::vector<uint64_t>(kHistSize, 0));
+    const size_t segments = cfg.cuts.size() + 1;
+    std::vector<std::vector<uint64_t>> lat_segment(
+        segments, std::vector<uint64_t>(kHistSize, 0));
+    std::vector<uint64_t> failed_segment(segments, 0);
     size_t samples = 0;
     for (Worker *w : workers) {
         total.sent += w->stats.sent;
@@ -734,6 +796,11 @@ int main(int argc, char **argv) {
         for (size_t e = 0; e < w->stats.lat_entry.size(); e++)
             for (size_t i = 0; i < kHistSize; i++)
                 lat_entry[e][i] += w->stats.lat_entry[e][i];
+        for (size_t g = 0; g < w->stats.lat_segment.size(); g++) {
+            for (size_t i = 0; i < kHistSize; i++)
+                lat_segment[g][i] += w->stats.lat_segment[g][i];
+            failed_segment[g] += w->stats.failed_segment[g];
+        }
         if (samples == 0 || w->stats.inflight_samples.size() < samples)
             samples = w->stats.inflight_samples.size();
     }
@@ -791,6 +858,18 @@ int main(int argc, char **argv) {
     for (size_t e = 0; e < cfg.entries; e++) {
         fprintf(out, "%s{", e ? ", " : "");
         print_hist(out, "latency_ns", cfg.entries > 1 ? lat_entry[e] : lat);
+        fprintf(out, "}");
+    }
+    /* and of each segment of the window, by the queries' due times, with
+     * the bounds in seconds and how many of its queries failed */
+    fprintf(out, "], \"latency_ns_by_segment\": [");
+    for (size_t g = 0; g < segments; g++) {
+        fprintf(out, "%s{\"from_s\": %.6f, \"to_s\": %.6f, \"failed\": %"
+                PRIu64 ", ", g ? ", " : "",
+                g ? (double)cfg.cuts[g - 1] / 1e9 : 0.0,
+                g + 1 < segments ? (double)cfg.cuts[g] / 1e9 : seconds,
+                segments > 1 ? failed_segment[g] : failed);
+        print_hist(out, "latency_ns", segments > 1 ? lat_segment[g] : lat);
         fprintf(out, "}");
     }
     fprintf(out, "]}\n");

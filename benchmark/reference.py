@@ -20,12 +20,23 @@ decided by the type alone before any look at the name
 (``lib/server.js:491-506``).  An answer without records has every section
 empty, but for NODATA's SOA.  TTL 30.  Sets compare without order: rotation
 is the server's to choose.
+
+EDNS (RFC 6891): an answer to a question that carried an OPT record carries
+exactly one, version 0, in its additional section (6.1.1: "if a query
+message with an OPT record is received, the response MUST include an OPT");
+an answer to a question without one carries none.  A UDP answer may say
+TC=1 only where the whole answer passes the payload the question
+advertised (512 without an OPT), and goes whole only where it fits.  The
+whole answer's size is the reference's own plain wire: every owner name
+compressed against the question's name (RFC 1035 4.1.4), an SRV target
+spelled out (RFC 2782: no compression in that field), the OPT's 11 bytes.
 """
 import re
 
 import numpy as np
 
-from dnswire import A, NOERROR, NOTIMP, NXDOMAIN, PTR, REFUSED, SOA, SRV
+from dnswire import (A, NOERROR, NOTIMP, NXDOMAIN, PTR, REFUSED, SOA, SRV,
+                     encode_name)
 
 TTL = 30
 #: registrar record types that count as service members (binder
@@ -80,6 +91,9 @@ class Zone:
         #: the deployment declines them: empty but under the control
         #: ``--break reference-declined``
         self.answered_empty = frozenset()
+        #: whether an answer to a question with an OPT record carries one:
+        #: true but under the control ``--break reference-opt``
+        self.opt_echoed = True
         self._host_rx = re.compile(
             r"^h(\d{6})\.r(\d{4})\.%s\.%s$" % (re.escape(self.subtree),
                                                 re.escape(domain)))
@@ -164,11 +178,23 @@ class Zone:
 
     # -- the resolver --
 
-    def expected(self, qname: str, qtype: int) -> dict:
-        """``{"rcode", "answers", "glue", "nodata"}`` for one question;
-        answers and glue are sorted lists of ``(type, rdata)`` and
-        ``(name, address)``; ``nodata`` is the owner of the SOA that a
-        NODATA answer carries, else ``None``."""
+    def expected(self, qname: str, qtype: int, payload=None) -> dict:
+        """``{"rcode", "answers", "glue", "nodata", "opt", "payload"}``
+        for one question; answers and glue are sorted lists of ``(type,
+        rdata)`` and ``(name, address)``; ``nodata`` is the owner of the
+        SOA that a NODATA answer carries, else ``None``.  *payload* is
+        what the question's OPT record advertised, 0 where it had none:
+        ``opt`` is how many OPT records the answer carries, ``payload``
+        the size a UDP answer may have.  A caller that does not say
+        (None) gets None for both, and ``compare`` then holds the answer
+        to neither."""
+        if payload is None:
+            return dict(self._records(qname, qtype), opt=None, payload=None)
+        return dict(self._records(qname, qtype),
+                    opt=1 if payload and self.opt_echoed else 0,
+                    payload=int(payload or 512))
+
+    def _records(self, qname: str, qtype: int) -> dict:
         qname = qname.lower().rstrip(".")
 
         def empty(rcode):
@@ -243,17 +269,68 @@ class Zone:
         return None
 
 
+def whole_size(qname: str, want: dict) -> int:
+    """Bytes of the reference's own wire of the whole answer (the module's
+    docstring says how it is laid out)."""
+    question = qname.lower().rstrip(".").split(".")
+
+    def owner(name: str) -> int:
+        labels = name.lower().rstrip(".").split(".")
+        shared = 0
+        while (shared < min(len(labels), len(question))
+               and labels[-1 - shared] == question[-1 - shared]):
+            shared += 1
+        spelled = sum(1 + len(lab) for lab in labels[:len(labels) - shared])
+        return spelled + (2 if shared else 1)
+
+    def rdata(rtype: int, value) -> int:
+        if rtype == A:
+            return 4
+        if rtype == PTR:
+            return len(encode_name(value))
+        return 6 + len(encode_name(value[3]))           # SRV
+
+    return (12 + len(encode_name(qname)) + 4 + (11 if want["opt"] else 0)
+            + sum(2 + 10 + rdata(rtype, value)
+                  for rtype, value in want["answers"])
+            + sum(owner(name) + 10 + 4 for name, _ in want["glue"]))
+
+
 def compare(answer, qname: str, qtype: int, want: dict,
-            whole: bool = True) -> list:
+            whole: bool = True, truncated=None) -> list:
     """What is wrong with a decoded answer, as a list of strings (empty:
     it is what the reference gives).  *whole* is false for a UDP answer
-    with TC=1, where only the header can be held to anything."""
+    with TC=1, where only the header and the OPT record can be held to
+    anything.  *truncated* says whether the UDP answer to this question
+    said TC=1 (None: not known, as for an answer asked over TCP alone):
+    it may only where the whole answer passes the advertised payload.
+    The OPT and payload rows are held only where *want* says what the
+    question carried (``Zone.expected`` with its *payload* given)."""
     wrong = []
     if answer.question != (qname.lower(), qtype):
         wrong.append(f"question echoed as {answer.question}")
     if answer.rcode != want["rcode"]:
         wrong.append(f"rcode {answer.rcode}, reference {want['rcode']}")
         return wrong
+    if want.get("opt") is None:
+        pass                # the caller did not say what the question had
+    elif len(answer.opts) != want["opt"]:
+        wrong.append(f"{len(answer.opts)} OPT records, reference "
+                     f"{want['opt']}")
+    elif any(opt[0] != 2 or opt[1] != "" or opt[3] or opt[4]
+             for opt in answer.opts):
+        wrong.append(f"OPT (section, owner, payload, extended rcode, "
+                     f"version) {answer.opts}, reference in the additional "
+                     "section, owned by the root, 0, 0")
+    limit = want.get("payload")
+    if truncated and limit is not None:
+        size = whole_size(qname, want)
+        if size <= limit:
+            wrong.append(f"TC=1 over UDP where the reference's whole answer "
+                         f"of {size} bytes fits the {limit} advertised")
+    if truncated is False and limit is not None and answer.size > limit:
+        wrong.append(f"{answer.size} bytes whole over UDP past the {limit} "
+                     "advertised")
     if not whole:
         return wrong
     if answer.tc:

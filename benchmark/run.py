@@ -15,9 +15,13 @@ is *settled*; a seeded sample of the cell's own questions is asked from
 fresh sockets (one of every entry of its mix and the zone's largest set
 among them) and compared with ``reference.py``, the chaos plan's write is
 read back from every worker; the generator warms up and then drives the
-window; the answers it kept are compared with the reference, the sample and
-the written names are asked once more; SIGTERM must end the group with exit
-0 and no orphan.  The last line of stdout is the result object.
+window, and where the workload names ``events`` each is delivered at its
+offset inside it (a SIGHUP to the supervisor rolls every shard: the run then
+waits for the roll's end and takes the group's new workers for what
+follows); the answers it kept are compared with the reference, the sample
+and the written names are asked once more; SIGTERM must end the group with
+exit 0 and no orphan of any generation.  The last line of stdout is the
+result object.
 
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file found by name: ``configs/<name>.json``,
@@ -51,13 +55,14 @@ from traffic import Traffic  # noqa: E402
 
 READY_TIMEOUT_S = 240.0
 SETTLE_TIMEOUT_S = 240.0
+ROLL_TIMEOUT_S = 120.0      # after the window, for a roll's last line
 #: the correctness asks' source: outside the RRL allowlist, so an ordinary
 #: client's path (RRL judging it) is what gets checked
 ASK_SOURCE = "127.0.1.1"
 ASKS = 96                   # seeded sample asked before and after the window
 GENERATOR = os.path.join(HERE, "loadgen", "build", "dnsblast")
-BREAKS = ("reference-address", "reference-declined", "fixture-address",
-          "skew-replica")
+BREAKS = ("reference-address", "reference-declined", "reference-opt",
+          "fixture-address", "skew-replica")
 
 
 def fail(phase: str, why: str) -> None:
@@ -191,22 +196,36 @@ class Server:
                     rec = json.loads(line)
                 except ValueError:
                     continue            # a traceback line: in the file
+                rec["arrived"] = time.monotonic()
                 with self._lock:
                     self.control.append(rec)
 
-    def wait_msg(self, pattern: str, timeout: float, what: str):
+    def find_msg(self, pattern: str):
+        """(match, when the record arrived here) of the first record
+        whose message matches, or None."""
         rx = re.compile(pattern)
+        with self._lock:
+            for rec in self.control:
+                m = rx.search(str(rec.get("msg", "")))
+                if m:
+                    return m, rec["arrived"]
+        return None
+
+    def wait_msg(self, pattern: str, timeout: float, what: str,
+                 fatal: bool = True):
+        """The match of the first record whose message matches; without
+        one in time the run fails, or (not *fatal*) None comes back."""
         deadline = time.monotonic() + timeout
         while True:
-            with self._lock:
-                for rec in self.control:
-                    m = rx.search(str(rec.get("msg", "")))
-                    if m:
-                        return m
+            found = self.find_msg(pattern)
+            if found:
+                return found[0]
             if self.proc.poll() is not None:
                 fail("serve", f"server exited {self.proc.returncode} "
                      f"while waiting for {what} (see {self.log_path})")
             if time.monotonic() > deadline:
+                if not fatal:
+                    return None
                 fail("serve", f"no {what} within {timeout:.0f}s "
                      f"(see {self.log_path})")
             time.sleep(0.02)
@@ -233,11 +252,26 @@ def scrape(port: int) -> dict:
             "status": json.loads(http_get(port, "/status"))}
 
 
-def cpu_seconds(pid: int) -> float:
-    """User plus system time of a process so far (/proc/<pid>/stat)."""
-    with open(f"/proc/{pid}/stat") as f:
-        fields = f.read().rsplit(")", 1)[1].split()
+def cpu_seconds(pid: int):
+    """User plus system time of a process so far (/proc/<pid>/stat); None
+    for a process that is gone (a worker rolled away)."""
+    try:
+        with open(f"/proc/{pid}/stat") as f:
+            fields = f.read().rsplit(")", 1)[1].split()
+    except (FileNotFoundError, ProcessLookupError):
+        return None
     return (int(fields[11]) + int(fields[12])) / os.sysconf("SC_CLK_TCK")
+
+
+def group_workers(mport: int, shards: int) -> list:
+    """The group as the supervisor's ``/status`` has it now: a pid and a
+    metrics port a shard.  Read again after a roll: a replaced worker is
+    a new pid, a new port and counters from zero."""
+    workers = json.loads(http_get(mport, "/status"))["shards"]["workers"]
+    if len({w["pid"] for w in workers}) != shards:
+        fail("serve", f"{len({w['pid'] for w in workers})} worker pids for "
+             f"{shards} shards")
+    return workers
 
 
 def scrape_all(mport: int, workers: list) -> dict:
@@ -349,8 +383,9 @@ def ask_sample(udp: int, tcp: int, zone, questions: list,
     for n, (qname, qtype, wire) in enumerate(questions):
         wire = bytes([n >> 8, n & 255]) + wire[2:]
         answer = dnswire.Answer(ask_udp(udp, wire))
-        want = zone.expected(qname, qtype)
-        problems = compare(answer, qname, qtype, want, whole=not answer.tc)
+        want = zone.expected(qname, qtype, dnswire.query_payload(wire))
+        problems = compare(answer, qname, qtype, want, whole=not answer.tc,
+                           truncated=answer.tc)
         if answer.tc and not problems:
             problems = compare(dnswire.Answer(ask_tcp(tcp, wire)), qname,
                                qtype, want)
@@ -380,35 +415,59 @@ def read_back(udp: int, zone, workers: list, verdict: Verdict) -> tuple:
     return sum(1 for n in served if n < 1), bad, asked
 
 
-def check_captures(path: str, traffic, zone, verdict: Verdict) -> int:
+def check_captures(path: str, traffic, zone, seconds: float,
+                   verdict: Verdict) -> int:
     """The answers the generator kept from the window, each against the
-    reference; every entry of the cell's mix has to be among them.
+    reference; every entry of the cell's mix has to be among them, and
+    where the window is cut into segments enough of each segment's (an
+    answer that came over TCP stands for a UDP answer that said TC=1).
     Returns how many were compared."""
     with open(path, "rb") as f:
         raw = f.read()
+    workload = traffic.workload
     off = compared = bad = longest = 0
-    by_entry = [0] * len(traffic.workload["mix"])
+    by_entry = [0] * len(workload["mix"])
+    # (a closed loop's sequence positions are no due times: not cut)
+    cuts = [float(c) for c in workload.get("segments_at_s") or ()] \
+        if traffic.arrivals is not None else []
+    by_segment = [0] * (len(cuts) + 1)
     while off < len(raw):
-        _pos, tmpl, _tcp, length = struct.unpack_from("<IIBH", raw, off)
+        pos, tmpl, tcp, length = struct.unpack_from("<IIBH", raw, off)
         off += 11
         wire = raw[off:off + length]
         off += length
         qname, qtype = traffic.questions[tmpl]
         try:
             answer = dnswire.Answer(wire)
-            problems = compare(answer, qname, qtype,
-                               zone.expected(qname, qtype))
+            problems = compare(
+                answer, qname, qtype, zone.expected(
+                    qname, qtype,
+                    dnswire.query_payload(traffic.templates[tmpl][0])),
+                truncated=bool(tcp) or answer.tc)
             longest = max(longest, len(answer.answers))
         except (ValueError, IndexError, struct.error) as e:
             problems = [f"undecodable answer: {e}"]
         bad += verdict.wrong(f"window {qname}/{qtype}", problems)
         compared += 1
         by_entry[traffic.templates[tmpl][3]] += 1
+        if cuts:
+            due_s = int(traffic.arrivals[pos]) / 1e9 - float(
+                workload["warm_s"])
+            by_segment[sum(1 for c in cuts if due_s >= c)] += 1
     verdict.hold("window_answers_mismatching", bad, 0)
     verdict.hold("mix_entries_with_no_window_answer_compared",
                  by_entry.count(0), 0)
     say(f"window answers compared: {compared} ({by_entry} by mix entry), "
         f"the longest with {longest} records")
+    if cuts:
+        # a segment has to hold its share of the answers kept, 200 at the
+        # most: the comparison covers before, during and after an event
+        edges = [0.0] + [min(c, seconds) for c in cuts] + [seconds]
+        thin = sum(1 for n, lo, hi in zip(by_segment, edges, edges[1:])
+                   if n < min(200, int(workload["capture_answers"])
+                              * (hi - lo) / seconds / 2))
+        verdict.hold("segments_with_too_few_answers_compared", thin, 0)
+        say(f"by segment of the window: {by_segment}")
     return compared
 
 
@@ -451,7 +510,7 @@ def stage_seconds(before: dict, after: dict) -> list:
     the breakdown's ``idle_gaps`` (what the host did while the device
     idled), at most ten."""
     sums = {}
-    for b, a in zip(before["workers"], after["workers"]):
+    for b, a in stats.worker_pairs(before, after):
         was = {lab.get("stage"): v for lab, v in stats.samples(
             b["metrics"], "binder_query_stage_seconds_sum")}
         for lab, v in stats.samples(a["metrics"],
@@ -471,6 +530,9 @@ def generator_argv(workload: dict, files: dict, udp: int, seconds: float,
             "-j", str(workload["threads"]), "-c", captures, "-o", gen_out]
     if workload.get("tc_retry"):
         argv.append("-R")
+    if workload.get("segments_at_s"):
+        argv += ["-g", ",".join(str(float(c))
+                                for c in workload["segments_at_s"])]
     for flag, path in files.items():
         argv += [flag, path]
     return argv
@@ -501,6 +563,64 @@ def describe_window(g: dict) -> None:
             f"{sum(g['inflight'][:third]) / third:.1f}, of its last third "
             f"{sum(g['inflight'][-third:]) / third:.1f} (a backlog that "
             "grows shows here)")
+
+
+# -- events inside the window --
+
+class Events:
+    """A workload's ``events``: a list, in time order, of objects with
+    ``at_s`` (seconds from the first due time of the measured window) and
+    one verb.  One verb is built, because one cell uses it: ``"signal":
+    "SIGHUP", "to": "supervisor"`` (the zero-downtime roll of every
+    shard).  Each is delivered from this object's own thread; ``left``
+    says at which offset each really went."""
+
+    def __init__(self, events: list, server: Server, out_dir: str) -> None:
+        for n, event in enumerate(events):
+            if (event.get("signal") != "SIGHUP"
+                    or event.get("to") != "supervisor"
+                    or set(event) != {"at_s", "signal", "to"}):
+                fail("start", f"event {event}: the one event built is "
+                     '{"at_s": s, "signal": "SIGHUP", "to": "supervisor"}')
+            if n and float(event["at_s"]) < float(events[n - 1]["at_s"]):
+                fail("start", "events are not in time order")
+        self.events = events
+        self.server = server
+        #: the generator writes the window's first due time here
+        self.start_file = os.path.join(out_dir, "window_start")
+        if os.path.exists(self.start_file):
+            os.remove(self.start_file)
+        self.left = []
+        self._thread = threading.Thread(target=self._deliver, daemon=True)
+
+    def start(self) -> None:
+        self._thread.start()
+
+    def _deliver(self) -> None:
+        deadline = time.monotonic() + 60
+        while not os.path.exists(self.start_file):
+            if time.monotonic() > deadline:
+                return                  # join() then finds events undelivered
+            time.sleep(0.002)
+        with open(self.start_file) as f:
+            window_start = int(f.read()) / 1e9      # CLOCK_MONOTONIC
+        for event in self.events:
+            wait = window_start + float(event["at_s"]) - time.monotonic()
+            if wait > 0:
+                time.sleep(wait)
+            self.server.proc.send_signal(getattr(signal, event["signal"]))
+            went = time.monotonic()
+            self.left.append(dict(event, left_at_s=went - window_start,
+                                  left_mono=went))
+
+    def join(self) -> int:
+        """Wait for the thread; how many events did not leave."""
+        self._thread.join(10)
+        return len(self.events) - len(self.left)
+
+    def report(self) -> list:
+        return [{k: v for k, v in e.items() if k != "left_mono"}
+                for e in self.left]
 
 
 # -- one run --
@@ -576,6 +696,10 @@ def run(args) -> int:
             # so that the answers are kept and it is the comparison that
             # fails
             zone.answered_empty = frozenset({dnswire.AAAA})
+        if args.break_ == "reference-opt":
+            # the control of an OPT record on every question: the
+            # reference is told that no OPT record comes back
+            zone.opt_echoed = False
         say(f"traffic: {len(traffic.templates)} templates, "
             f"{len(traffic.sequence)} sequence entries"
             + (f", {len(traffic.arrivals)} arrivals"
@@ -601,10 +725,8 @@ def run(args) -> int:
                     dnswire.Answer(ask_udp(udp, dnswire.make_query(
                         qname, dnswire.A, qid=1))), qname, dnswire.A,
                     zone.expected(qname, dnswire.A)))
-        workers = json.loads(http_get(mport, "/status"))["shards"]["workers"]
-        pids = sorted(w["pid"] for w in workers)
-        if len(set(pids)) != shards:
-            fail("serve", f"{len(set(pids))} worker pids for {shards} shards")
+        workers = group_workers(mport, shards)
+        pids = {w["pid"] for w in workers}
 
         wait_settled(workers, zone.hosts)
         seed_s = time.monotonic() - server.spawned - ready_s
@@ -642,6 +764,12 @@ def run(args) -> int:
         captures = os.path.join(out_dir, "captures.bin")
         argv = generator_argv(workload, files, udp, args.seconds,
                               captures, gen_out)
+        events = None
+        if workload.get("events"):
+            events = Events(workload["events"], server, out_dir)
+            argv += ["-s", events.start_file]
+            aborts = stats.total(http_get(mport, "/metrics").decode(),
+                                 "binder_shard_roll_aborts_total")
         setup_s = time.monotonic() - T_START + float(workload["warm_s"])
         say(f"set-up {setup_s:.1f}s with the warm-up; the device child "
             f"named {device['kind']}")
@@ -651,6 +779,8 @@ def run(args) -> int:
             # for tens of milliseconds): the deltas cover warm-up and window
             scrape_before = scrape_all(mport, workers)
         gen = subprocess.Popen(argv, cwd=out_dir)
+        if events:
+            events.start()
         try:
             rc = gen.wait(timeout=args.seconds + 60)
         finally:
@@ -661,6 +791,32 @@ def run(args) -> int:
             fail("window", f"the generator exited {rc}")
         if traced:
             child.tell("stop")
+        roll_s = None
+        if events:
+            # the event was a SIGHUP to the supervisor: wait (outside
+            # every end-to-end metric) for its roll to end, then take the
+            # group as it is now for the asks, the read-back and the scrape
+            verdict.hold("events_not_delivered", events.join(), 0)
+            ended = r"^rolling upgrade (complete|stopped)"
+            server.wait_msg(ended, ROLL_TIMEOUT_S, "the roll's end",
+                            fatal=False)
+            done = server.find_msg(ended)       # (match, arrival) or None
+            complete = bool(done) and done[0].group(1) == "complete"
+            verdict.hold("roll_not_complete", 0 if complete else 1, 0)
+            if complete and events.left:
+                roll_s = done[1] - events.left[0]["left_mono"]
+            was = {w["shard"]: w["pid"] for w in workers}
+            workers = group_workers(mport, shards)
+            pids |= {w["pid"] for w in workers}
+            verdict.hold("shards_not_rolled", sum(
+                1 for w in workers if was.get(w["shard"]) == w["pid"]), 0)
+            verdict.hold("roll_aborts", int(stats.total(
+                http_get(mport, "/metrics").decode(),
+                "binder_shard_roll_aborts_total") - aborts), 0)
+            say("events: " + json.dumps(events.report()) + "; the roll "
+                + (f"took {roll_s:.1f}s" if roll_s is not None
+                   else "did not complete"))
+        if traced:
             time.sleep(1.5)     # the workers report to the supervisor at 1 Hz
             scrape_after = scrape_all(mport, workers)
             device.update(child.traced_window())
@@ -669,15 +825,17 @@ def run(args) -> int:
         if traced:
             between = scrape_after["at"] - scrape_before["at"]
             say("worker CPU between the scrapes, % of a core: " + ", ".join(
-                f"{100 * (a['cpu_s'] - b['cpu_s']) / between:.0f}"
-                for b, a in zip(scrape_before["workers"],
-                                scrape_after["workers"]))
+                "replaced" if b is stats.FRESH or a["cpu_s"] is None
+                else f"{100 * (a['cpu_s'] - b['cpu_s']) / between:.0f}"
+                for b, a in stats.worker_pairs(scrape_before, scrape_after))
                 + "; generator threads: " + ", ".join(
                     f"{100 * (user + system) / g['window_s']:.0f}"
                     for user, system in g["thread_cpu_s"]))
 
         # after the window: the kept answers, the sample and the write again
-        compared = check_captures(captures, traffic, zone, verdict)
+        # (after a roll: from the group's new workers)
+        compared = check_captures(captures, traffic, zone, args.seconds,
+                                  verdict)
         bad2, unseen2, silent2, stale2, _ = asks_and_read_back()
         verdict.hold("asks_mismatching_before_window", bad, 0)
         verdict.hold("asks_mismatching_after_window", bad2, 0)
@@ -701,7 +859,9 @@ def run(args) -> int:
         except subprocess.TimeoutExpired:
             fail("serve", "supervisor ignored SIGTERM for 60s")
         time.sleep(0.2)
-        orphans = [p for p in pids if os.path.exists(f"/proc/{p}")]
+        orphans = [p for p in sorted(pids) if os.path.exists(f"/proc/{p}")]
+        say(f"orphan check over {len(pids)} worker pids"
+            + (" (both generations)" if events else ""))
         verdict.hold("supervisor_exit_code", rc, 0)
         verdict.hold("orphan_processes", len(orphans), 0)
     finally:
@@ -724,8 +884,9 @@ def run(args) -> int:
         metrics = layer_values(manifest, args.workload, {
             "before": scrape_before, "after": scrape_after, "generator": g,
             "workload": workload, "mix_rcodes": traffic.rcodes_by_entry(),
+            "events": events.report() if events else [],
             "harness": {"ready_s": ready_s, "seed_s": seed_s,
-                        "setup_s": setup_s}})
+                        "setup_s": setup_s, "roll_s": roll_s}})
     else:
         metrics = {name: {"value": values[name][0], "unit": values[name][1]}
                    for name in workload["end_to_end"]}
@@ -736,6 +897,11 @@ def run(args) -> int:
         result["breakdown"] = {
             "device_ops": device.pop("device_ops", []),
             "idle_gaps": stage_seconds(scrape_before, scrape_after)}
+        if events:
+            result["breakdown"]["replaced_workers"] = stats.replaced_workers(
+                scrape_before, scrape_after)
+    if events:
+        result["events"] = events.report()
     # every number compared beside its limit, as the benchmark's contract
     # asks: the line's last key, and the run's last lines on standard error
     result["compared"] = {name: {"value": value, "limit": limit}

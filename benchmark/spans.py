@@ -67,12 +67,13 @@ def reader(fn):
 
 
 def pairs(ctx):
-    """``[(before, after), ...]`` per worker, or None."""
+    """``[(before, after), ...]`` per worker, by shard, or None.  A worker
+    whose pid changed between the scrapes (a roll) is taken as starting
+    from zero (``stats.worker_pairs``)."""
     before, after = ctx.get("before"), ctx.get("after")
     if not before or not after:
         return None
-    out = list(zip(before["workers"], after["workers"]))
-    return out or None
+    return stats.worker_pairs(before, after) or None
 
 
 def wall_s(ctx):

@@ -113,12 +113,51 @@ def qtype_percentile(ctx: dict, qtype: str, q: float):
     return hist_percentile(list(merged.items()), g["hist_bits"], q) / 1e3
 
 
+def segment_percentile(ctx: dict, i: int, q: float):
+    """The q-th percentile, in microseconds, of the latency of the
+    queries that were *due* in the i-th segment of the window (the
+    workload's ``segments_at_s`` cut it; the generator keeps its
+    histogram once more for each); None where the window is not cut, has
+    no such segment, or none of its queries was answered."""
+    g = ctx.get("generator") or {}
+    segments = g.get("latency_ns_by_segment") or []
+    if len(segments) < 2 or not 0 <= i < len(segments):
+        return None
+    hist = segments[i]["latency_ns"]
+    if not hist:
+        return None
+    return hist_percentile(hist, g["hist_bits"], q) / 1e3
+
+
+#: the scrape that stands before a worker that was not there yet: every
+#: counter of a process that started inside the window starts from zero
+FRESH = {"metrics": "", "status": {}}
+
+
+def worker_pairs(before: dict, after: dict) -> list:
+    """``[(before, after), ...]`` per worker of the closing scrape, paired
+    by shard.  A shard whose pid changed between the scrapes was replaced
+    (a roll, a respawn): the new process counts from zero, so ``FRESH``
+    stands before it; what the old one did between the first scrape and
+    its exit is in no scrape.  Scrapes that name no shard (hand-made
+    ones) pair in order."""
+    if not all("shard" in w and "pid" in w
+               for w in before["workers"] + after["workers"]):
+        return list(zip(before["workers"], after["workers"]))
+    was = {w["shard"]: w for w in before["workers"]}
+    return [(b if b is not None and b["pid"] == a["pid"] else FRESH, a)
+            for a in after["workers"] for b in [was.get(a["shard"])]]
+
+
+def replaced_workers(before: dict, after: dict) -> int:
+    return sum(1 for b, _ in worker_pairs(before, after) if b is FRESH)
+
+
 def window_delta(ctx: dict, name: str) -> list:
     """Per worker, how much a counter of its own ``/metrics`` grew
     between the two scrapes of a traced run."""
     return [total(a["metrics"], name) - total(b["metrics"], name)
-            for b, a in zip(ctx["before"]["workers"],
-                            ctx["after"]["workers"])]
+            for b, a in worker_pairs(ctx["before"], ctx["after"])]
 
 
 def native_serve_percent(ctx: dict):
@@ -133,9 +172,8 @@ def native_serve_percent(ctx: dict):
     native = sum(window_delta(ctx, "binder_zone_serves")) \
         + sum(window_delta(ctx, "binder_answer_cache_hits")) \
         - sum(a["status"]["answer_cache"]["hits"]
-              - b["status"]["answer_cache"]["hits"]
-              for b, a in zip(ctx["before"]["workers"],
-                              ctx["after"]["workers"]))
+              - (b["status"].get("answer_cache") or {"hits": 0})["hits"]
+              for b, a in worker_pairs(ctx["before"], ctx["after"]))
     return 100.0 * native / served
 
 
@@ -157,7 +195,7 @@ def loop_lag_p99_ms(ctx: dict):
     """Event-loop lag between the scrapes, 99th percentile on the worst
     worker, from ``binder_loop_lag_seconds`` (a bucket's upper edge)."""
     worst = None
-    for b, a in zip(ctx["before"]["workers"], ctx["after"]["workers"]):
+    for b, a in worker_pairs(ctx["before"], ctx["after"]):
         buckets = histogram_delta(b["metrics"], a["metrics"],
                                   "binder_loop_lag_seconds")
         p99 = bucketed_percentile(buckets, 99)

@@ -308,13 +308,15 @@ def test_layer_readers_state_what_the_manifest_states():
 
 # -- run.py end to end, on the CPU, at a size a test can hold --
 
-def rehearse(workload: str, seed: int, trace: int, broken=None) -> dict:
+def rehearse(workload: str, seed: int, trace: int, broken=None,
+             cell: str = "tiny", seconds: int = 2) -> dict:
     argv = [sys.executable, os.path.join(BENCH, "run.py"), "--cpu",
-            "--dir", "benchmark/tests/tiny", "--workload", workload,
-            "--seed", str(seed), "--seconds", "2", "--trace", str(trace)]
+            "--dir", "benchmark/tests/" + cell, "--workload", workload,
+            "--seed", str(seed), "--seconds", str(seconds),
+            "--trace", str(trace)]
     if broken:
         argv += ["--break", broken]
-    proc = subprocess.run(argv, cwd=ROOT, text=True, timeout=240,
+    proc = subprocess.run(argv, cwd=ROOT, text=True, timeout=300,
                           stdout=subprocess.PIPE, stderr=subprocess.DEVNULL)
     lines = [ln for ln in proc.stdout.splitlines() if "REHEARSAL" in ln]
     assert lines, proc.stdout[-3000:]

@@ -68,4 +68,5 @@ def test_the_manifest_states_what_the_reader_states():
                      "layer": module.LAYER, "moves": module.MOVES,
                      "workloads": ["hosts_zipf_open60",
                                    "services_srv_open60",
-                                   "hosts_a_aaaa_open60"]}
+                                   "hosts_a_aaaa_open60",
+                                   "services_srv_edns"]}

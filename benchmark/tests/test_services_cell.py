@@ -84,7 +84,9 @@ def test_the_new_metrics_list_the_cell_alone_and_the_old_ones_gain_it():
     mods = readers()
     for name in NEW:
         entry, module = by_name[name], mods[name]
-        assert entry["workloads"] == [CELL] and entry["moves"] == "p50_us"
+        # (and, since PR 31, the same traffic with an OPT record)
+        assert entry["workloads"] == [CELL, "services_srv_edns"] \
+            and entry["moves"] == "p50_us"
         assert (entry["layer"], entry["unit"], entry["moves"]) \
             == (module.LAYER, module.UNIT, module.MOVES)
     # the four whose stage tuples knew neither the stream stages nor the

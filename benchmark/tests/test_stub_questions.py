@@ -64,7 +64,7 @@ GLUE = record("x.foo.com", dnswire.A, 30, bytes([10, 0, 0, 9]))
 def test_reference_answers_without_records(qname, qtype, rcode, nodata):
     want = hand_zone().expected(qname, qtype)
     assert want == {"rcode": rcode, "answers": [], "glue": [],
-                    "nodata": nodata}
+                    "nodata": nodata, "opt": None, "payload": None}
 
 
 @pytest.mark.parametrize("qname,qtype,good,bad", [

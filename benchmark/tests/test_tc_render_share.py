@@ -66,4 +66,5 @@ def test_the_manifest_states_what_the_reader_states():
     assert entries == [{"name": NAME, "unit": module.UNIT,
                         "better": "lower", "source": "program_counter",
                         "layer": module.LAYER, "moves": module.MOVES,
-                        "workloads": ["services_srv_open60"]}]
+                        "workloads": ["services_srv_open60",
+                                      "services_srv_edns"]}]
