@@ -67,6 +67,14 @@ HOST_LIKE_TYPES = frozenset({
 
 DEFAULT_TTL = 30  # reference lib/server.js:270 (the ZK session timeout)
 
+#: The one statement of which questions the engine resolves at all
+#: (lib/server.js:491-506): ``(served types, rcode of every other
+#: type)``.  ``Resolver.handle`` decides by it before any lookup, and
+#: ``BinderServer`` installs it as the native zone table's type row, so
+#: a declined type's answer (a header and the question) is the same
+#: decision in both lanes.
+TYPE_RULE = (frozenset({Type.A, Type.SRV, Type.PTR}), Rcode.NOTIMP)
+
 
 def _is_suffix(suffix: str, s: str) -> bool:
     return s.endswith(suffix)
@@ -178,14 +186,15 @@ class Resolver:
 
     def handle(self, query: QueryCtx):
         qt = query.qtype()
-        if qt in (Type.A, Type.SRV):
-            return self.resolve(query)
+        served, declined = TYPE_RULE
+        if qt not in served:
+            # anything unsupported we tell the client the truth
+            query.set_error(declined)
+            query.respond()
+            return None
         if qt == Type.PTR:
             return self.resolve_ptr(query)
-        # anything unsupported we tell the client the truth
-        query.set_error(Rcode.NOTIMP)
-        query.respond()
-        return None
+        return self.resolve(query)
 
     # -- forward resolution (lib/server.js:136-429) --
     #

@@ -59,6 +59,7 @@ from binder_tpu.resolver.engine import (
     DEFAULT_TTL,
     Resolver,
     SERVICE_CHILD_TYPES as _SERVICE_CHILD_TYPES,
+    TYPE_RULE,
     _record_ttl as _engine_record_ttl,
 )
 from binder_tpu.utils.jsonlog import JsonFormatter, log_event
@@ -647,6 +648,23 @@ class BinderServer:
         self._zone_enabled = (
             zone_precompile and self._fastpath is not None
             and hasattr(_fastio, "fastpath_zone_put"))
+        # The zone table's type row: the engine's own rule (TYPE_RULE)
+        # of which types it resolves and what rcode the rest get by the
+        # type alone.  Such an answer is a header and the question, so
+        # C gives it with no name in its key and no first sight in
+        # Python per name.  In the logged posture the row carries the
+        # one fragment a Python-lane first sight of a declined question
+        # logs: no `cached`, no `precompiled` — the native answer IS
+        # the engine's decision, not a replay of it.  `_type_row` is
+        # the served types while the row is installed, else None.
+        self._type_row: Optional[frozenset] = None
+        if self._zone_enabled and hasattr(_fastio, "fastpath_type_row"):
+            served, declined = TYPE_RULE
+            frag = (self._log_frag({}, declined, [], [])
+                    if self._log_ring else None)
+            if _fastio.fastpath_type_row(self._fastpath, sorted(served),
+                                         declined, frag):
+                self._type_row = served
         # churn-path coalescing: batched C invalidation + deferred zone
         # refills (see _on_store_invalidate)
         self._fp_inval_many = getattr(_fastio, "fastpath_invalidate_many",
@@ -658,6 +676,10 @@ class BinderServer:
             "binder_zone_serves",
             "queries answered from precompiled zone entries")
         self._zone_serve_child = self.zone_serve_counter.labelled({})
+        self._zone_type_serve_child = self.collector.counter(
+            "binder_zone_type_serves",
+            "answers the zone table gave by the question's type alone"
+        ).labelled({})
         if self._fastpath is not None:
             # Residency gauges: operators watching a mirror fill (or an
             # epoch rebuild) can see the native tables converge.  All
@@ -767,8 +789,12 @@ class BinderServer:
                 # promote-on-first-hit: a repeat proves the name is hot,
                 # so hand the entry to the C fast path NOW (resolve-time
                 # pushes made one-shot cold names pay the native-push
-                # cost for entries never served again)
+                # cost for entries never served again).  Not a key whose
+                # type the type row answers: C serves the row ahead of
+                # its cache probe, so such an entry could never be hit
                 if (query.udp_semantics and self._fastpath is not None
+                        and (self._type_row is None
+                             or q0.qtype in self._type_row)
                         and self._fastpath_active()):
                     self._fastpath_push(key, self.zk_cache.epoch, query)
                 return None
@@ -1652,6 +1678,15 @@ class BinderServer:
                                    req.max_udp_payload(), q0.qtype,
                                    q0.qclass, raw[12:off].lower())
 
+    def type_row_serves(self) -> int:
+        """Answers the zone table's type row has given since start (0
+        without the row): ``/status`` ``answer_cache.type_row_serves``,
+        read from C as ``binder_zone_type_serves`` is at a scrape."""
+        if self._type_row is None:
+            return 0
+        return int(_fastio.fastpath_stats(
+            self._fastpath)["zone_type_hits"])
+
     def _fold_engine_counters(self) -> None:
         # scrapes run on ThreadingHTTPServer threads: fold under the
         # shared lock or two concurrent scrapes double-count the delta
@@ -1688,14 +1723,15 @@ class BinderServer:
             stats = _fastio.fastpath_stats(self._fastpath)
             self._fp_last_stats = stats   # shared with residency gauges
             last = self._fp_folded
-            hits_delta = stats["hits"] - last.get("hits", 0)
-            if hits_delta > 0:
-                self._cache_hit_native_child.inc(hits_delta)
-            last["hits"] = stats["hits"]
-            zone_delta = stats.get("zone_hits", 0) - last.get("zone_hits", 0)
-            if zone_delta > 0:
-                self._zone_serve_child.inc(zone_delta)
-            last["zone_hits"] = stats.get("zone_hits", 0)
+            # an extension built before a counter has none of it
+            for key, child in (("hits", self._cache_hit_native_child),
+                               ("zone_hits", self._zone_serve_child),
+                               ("zone_type_hits",
+                                self._zone_type_serve_child)):
+                now = stats.get(key, 0)
+                if now > last.get(key, 0):
+                    child.inc(now - last.get(key, 0))
+                last[key] = now
             self._fp_inval_total = stats.get("invalidations", 0)
             for qtype, s in stats["per_qtype"].items():
                 children = self._children_for(qtype)

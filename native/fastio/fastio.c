@@ -429,6 +429,10 @@ static PyMethodDef fastio_methods[] = {
     {"fastpath_zone_put", fastpath_zone_put, METH_VARARGS,
      "fastpath_zone_put(cache, zkey, gen, ancount, bodies, tag"
      "[, arcount]) -> bool"},
+    {"fastpath_type_row", fastpath_type_row, METH_VARARGS,
+     "fastpath_type_row(cache, served_qtypes, rcode[, log_frag]) -> bool "
+     "(the zone table's answer to every question of a type the engine "
+     "declines by the type alone: rcode, no records, no name in its key)"},
     {"fastpath_serve_wire", fastpath_serve_wire, METH_VARARGS,
      "fastpath_serve_wire(cache, packet, gen) -> bytes | None"},
     {"fastpath_serve_frames", fastpath_serve_frames, METH_VARARGS,

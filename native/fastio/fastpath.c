@@ -391,6 +391,58 @@ fastpath_zone_put(PyObject *self, PyObject *args)
 }
 
 PyObject *
+fastpath_type_row(PyObject *self, PyObject *args)
+{
+    (void)self;
+    PyObject *capsule, *served;
+    int rcode;
+    Py_buffer fragbuf;
+
+    fragbuf.buf = NULL;
+    fragbuf.len = 0;
+    fragbuf.obj = NULL;
+    if (!PyArg_ParseTuple(args, "OOi|z*", &capsule, &served, &rcode,
+                          &fragbuf))
+        return NULL;
+    fp_cache_t *c = fp_from_capsule(capsule);
+    PyObject *fast = c != NULL
+        ? PySequence_Fast(served, "served types must be a sequence") : NULL;
+    if (fast == NULL) {
+        PyBuffer_Release(&fragbuf);     /* a no-op without a fragment */
+        return NULL;
+    }
+    Py_ssize_t n = PySequence_Fast_GET_SIZE(fast);
+    uint16_t types[FP_ROW_MAX_TYPES];
+    int rc = 0;
+    if (n >= 1 && n <= FP_ROW_MAX_TYPES) {
+        for (Py_ssize_t i = 0; i < n; i++) {
+            long t = PyLong_AsLong(PySequence_Fast_GET_ITEM(fast, i));
+            if (t == -1 && PyErr_Occurred()) {
+                Py_DECREF(fast);
+                PyBuffer_Release(&fragbuf);
+                return NULL;
+            }
+            if (t < 0 || t > 0xFFFF) {
+                n = 0;                  /* no such type: skip the put */
+                break;
+            }
+            types[i] = (uint16_t)t;
+        }
+        if (n > 0)
+            rc = fp_type_row_put(c, types, (int)n, rcode,
+                                 (const uint8_t *)fragbuf.buf,
+                                 (size_t)fragbuf.len);
+    }
+    Py_DECREF(fast);
+    PyBuffer_Release(&fragbuf);
+    if (rc < 0)
+        return PyErr_NoMemory();
+    if (rc == 0)
+        Py_RETURN_FALSE;
+    Py_RETURN_TRUE;
+}
+
+PyObject *
 fastpath_serve_wire(PyObject *self, PyObject *args)
 {
     (void)self;
@@ -1105,13 +1157,14 @@ fastpath_stats(PyObject *self, PyObject *args)
         }
     }
     return Py_BuildValue(
-        "{s:K,s:K,s:I,s:K,s:K,s:K,s:I,s:K,s:K,s:K,s:K,s:N}",
+        "{s:K,s:K,s:I,s:K,s:K,s:K,s:K,s:I,s:K,s:K,s:K,s:K,s:N}",
         "hits", (unsigned long long)c->hits,
         "lookups", (unsigned long long)c->lookups,
         "entries", (unsigned)c->n_entries,
         "bytes", (unsigned long long)c->total_bytes,
         "invalidations", (unsigned long long)c->invalidations,
         "zone_hits", (unsigned long long)c->zone_hits,
+        "zone_type_hits", (unsigned long long)c->zone_type_hits,
         "zone_entries", (unsigned)(c->zmain.n + c->zalien.n),
         "zone_bytes", (unsigned long long)c->ztotal_bytes,
         "log_lines", (unsigned long long)c->lr.lines,

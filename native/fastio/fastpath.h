@@ -104,6 +104,7 @@ extern unsigned char fastio_shared_bufs[FASTIO_BATCH][FASTIO_DGRAM_MAX];
 PyObject *fastpath_new(PyObject *self, PyObject *args);
 PyObject *fastpath_put(PyObject *self, PyObject *args);
 PyObject *fastpath_zone_put(PyObject *self, PyObject *args);
+PyObject *fastpath_type_row(PyObject *self, PyObject *args);
 PyObject *fastpath_serve_wire(PyObject *self, PyObject *args);
 PyObject *fastpath_serve_frames(PyObject *self, PyObject *args);
 PyObject *fastpath_serve_balancer(PyObject *self, PyObject *args);

@@ -165,6 +165,27 @@ typedef struct {
     uint32_t n;
 } fp_ztab_t;
 
+/*
+ * Type row: the zone's answer to any question whose TYPE the engine
+ * declines before any lookup (lib/server.js:491-506).  Such an answer
+ * is its header and the question echoed: it depends on no name and no
+ * store generation, so the row has neither in its key, and one row
+ * answers every name.  Which types are resolved and which rcode the
+ * rest get is the engine's statement (resolver/engine.py TYPE_RULE),
+ * handed down by Python when it arms the zone table; C holds no list
+ * of its own.
+ */
+#define FP_ROW_MAX_TYPES 16
+
+typedef struct {
+    uint16_t served[FP_ROW_MAX_TYPES];  /* types the engine resolves */
+    uint8_t n_served;                   /* 0: no row installed */
+    uint8_t rcode;                      /* what every other type gets */
+    uint8_t *frag;            /* the one pre-rendered log fragment (NULL
+                               * when installed in the log-off posture) */
+    uint16_t frag_len;
+} fp_typerow_t;
+
 typedef struct {
     fp_entry_t *slots;
     uint32_t mask;            /* slot count - 1 (power of two) */
@@ -184,6 +205,8 @@ typedef struct {
     fp_ztab_t zalien;         /* tag != qname: scan invalidation */
     uint64_t ztotal_bytes;
     uint64_t zone_hits;
+    fp_typerow_t trow;
+    uint64_t zone_type_hits;  /* the subset of zone_hits the row gave */
     fp_logring_t lr;
 } fp_cache_t;
 
@@ -303,6 +326,8 @@ fp_core_free(fp_cache_t *c)
     c->zmain.slots = NULL;
     free(c->zalien.slots);
     c->zalien.slots = NULL;
+    free(c->trow.frag);
+    memset(&c->trow, 0, sizeof(c->trow));
     free(c->lr.buf);
     c->lr.buf = NULL;
     c->lr.enabled = 0;
@@ -978,10 +1003,105 @@ fp_zone_serve(fp_cache_t *c, const uint8_t *pkt, const uint8_t *key,
 }
 
 /*
+ * Install (or replace) the type row.  `frag` (may be NULL: log-off
+ * posture) is the middle of the log line a Python-lane first sight of
+ * a declined question renders.  The row is no table entry: it is not
+ * counted in the zone's entries or bytes, no generation or tag drops
+ * it, and fp_core_clear leaves it (it holds nothing of the store).
+ * Returns 1 stored, 0 skipped (bounds), -1 OOM (row unchanged).
+ */
+static inline int
+fp_type_row_put(fp_cache_t *c, const uint16_t *served, int n_served,
+                int rcode, const uint8_t *frag, size_t fraglen)
+{
+    if (n_served < 1 || n_served > FP_ROW_MAX_TYPES
+            || rcode < 0 || rcode > 15)
+        return 0;
+    uint8_t *fc = NULL;
+    if (frag != NULL) {
+        if (fraglen == 0 || fraglen > FP_MAX_FRAG)
+            return 0;                   /* unloggable: stays in Python */
+        fc = (uint8_t *)malloc(fraglen);
+        if (fc == NULL)
+            return -1;
+        memcpy(fc, frag, fraglen);
+    }
+    free(c->trow.frag);
+    memset(&c->trow, 0, sizeof(c->trow));
+    memcpy(c->trow.served, served, (size_t)n_served * sizeof(*served));
+    c->trow.n_served = (uint8_t)n_served;
+    c->trow.rcode = (uint8_t)rcode;
+    c->trow.frag = fc;
+    c->trow.frag_len = (uint16_t)(fc != NULL ? fraglen : 0);
+    return 1;
+}
+
+/* does the row decline this type?  (0 without a row) */
+static inline int
+fp_type_row_covers(const fp_typerow_t *r, uint16_t qtype)
+{
+    if (r->n_served == 0)
+        return 0;
+    for (int i = 0; i < r->n_served; i++) {
+        if (r->served[i] == qtype)
+            return 0;
+    }
+    return 1;
+}
+
+/*
+ * Serve a question of a declined type from the row: header (the row's
+ * rcode, no records) + question echo (original case) + OPT echo when
+ * the key says EDNS, byte for byte QueryCtx.respond's encoding of the
+ * engine's decision.  At most 12 + 255 + 4 + 11 bytes: under every
+ * payload ceiling, so right on every transport.  Returns the length,
+ * or 0 to decline to Python (logged posture and the line cannot be
+ * produced).
+ */
+static inline size_t
+fp_type_serve(fp_cache_t *c, const uint8_t *pkt, const uint8_t *key,
+              size_t qn_len, uint8_t *out, double now,
+              const fp_logsrc_t *src)
+{
+    const fp_typerow_t *r = &c->trow;
+    if (c->lr.enabled) {
+        if (src == NULL || r->frag == NULL
+                || !fp_log_room(c, r->frag_len)) {
+            c->lr.declines++;
+            return 0;
+        }
+    }
+    int edns = key[0] & 2;
+    out[0] = pkt[0];                    /* request id */
+    out[1] = pkt[1];
+    out[2] = (uint8_t)(0x84 | (key[0] & 1));      /* QR|AA, RD echo */
+    out[3] = r->rcode;                  /* RA=0 */
+    out[4] = 0; out[5] = 1;             /* QD=1 */
+    out[6] = 0; out[7] = 0;             /* AN=0 */
+    out[8] = 0; out[9] = 0;             /* NS=0 */
+    out[10] = 0; out[11] = (uint8_t)(edns ? 1 : 0);
+    memcpy(out + 12, pkt + 12, qn_len + 4);       /* 0x20 case echo */
+    size_t total = 12 + qn_len + 4;
+    if (edns) {
+        memcpy(out + total, fp_opt_echo, sizeof(fp_opt_echo));
+        total += sizeof(fp_opt_echo);
+    }
+    c->zone_hits++;
+    c->zone_type_hits++;
+    if (c->lr.enabled)
+        fp_log_append(c, pkt, edns, r->frag, r->frag_len, src,
+                      (fp_now() - now) * 1e3);
+    return total;
+}
+
+/*
  * Serve one packet from the cache: key build, lookup (with lazy gen/TTL
  * invalidation), variant rotation, id + 0x20 question patching.  `out`
  * must hold FP_MAX_WIRE bytes.  Returns the response length on hit, 0 on
  * miss (the caller surfaces the packet to the slow path).
+ *
+ * A question whose type the installed type row declines is answered
+ * from the row ahead of both probes, for every caller alike.
  *
  * `via` (FP_VIA_*) is the transport the packet arrived on.  A cached
  * wire whose next variant carries TC=1 was promoted off the UDP path
@@ -1007,6 +1127,12 @@ fp_serve_one_lx(fp_cache_t *c, const uint8_t *pkt, size_t plen,
     size_t keylen = dnskey_build(pkt, plen, key, &qn_len, &qtype);
     if (keylen == 0)
         return 0;
+    if (fp_type_row_covers(&c->trow, qtype)) {
+        /* a declined type is its header: no name to probe for */
+        if (qtype_out != NULL)
+            *qtype_out = qtype;
+        return fp_type_serve(c, pkt, key, qn_len, out, now, src);
+    }
     fp_entry_t *e = fp_find(c, key, keylen, gen, now);
     if (e != NULL && via != FP_VIA_DATAGRAM
             && e->wire_lens[e->next_variant] >= 3

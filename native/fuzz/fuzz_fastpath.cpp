@@ -13,6 +13,13 @@
  *    with fp_put_raw and immediately served back — round-trip asserts
  *    check id/0x20 patching and variant rotation.
  *
+ * The zone table's type row is installed for the whole run with the
+ * engine's rule (A, PTR and SRV resolved, NOTIMP for the rest).  One
+ * synthesized query in four asks a declined type: it must be answered
+ * from the row whatever the tables hold, and the answer is checked
+ * against the packet it answers (length, id, question echo, rcode), as
+ * a zone serve's is; a raw packet the row serves is checked likewise.
+ *
  * Cross-iteration state persists (one cache for the whole run) with a
  * deliberately small table, so probe-window eviction, replace-in-place,
  * expiry, generation bumps, and clear all fire; accounting invariants
@@ -33,6 +40,12 @@ double fz_clock = 1000.0;
 /* tag used by zone-mode iterations to exercise the scan path; shared so
  * other modes can clear those entries before asserting a miss */
 const uint8_t fz_alien_tag[5] = {3, 'z', 'z', 'z', 0};
+
+/* the type row's rule (resolver/engine.py TYPE_RULE) and its fragment */
+const uint16_t fz_served[3] = {1, 12, 33};
+const uint8_t fz_declined_rcode = 4;    /* NOTIMP */
+const uint8_t fz_row_frag[] =
+    "\"rcode\": \"NOTIMP\", \"answers\": [], \"additional\": []";
 
 /* build a well-formed query: header + one question, hostname-charset
  * name derived from the input bytes */
@@ -56,11 +69,72 @@ size_t build_query(const uint8_t *data, size_t len, uint8_t *q /*512*/) {
         }
     }
     q[pos++] = 0x00;                              /* root */
-    uint16_t qtype = (uint16_t)(1 + (len > 4 ? data[4] % 34 : 0));
+    /* three in four ask a type the row's rule resolves, so that the
+     * tables behind the row keep filling; the fourth any of 34 types */
+    uint8_t sel = len > 4 ? data[4] : 0;
+    uint16_t qtype = (sel & 3) ? fz_served[(sel >> 2) % 3]
+                               : (uint16_t)(1 + (sel >> 2) % 34);
     q[pos++] = (uint8_t)(qtype >> 8);
     q[pos++] = (uint8_t)(qtype & 0xff);
     q[pos++] = 0x00; q[pos++] = 0x01;             /* IN */
     return pos;
+}
+
+/* A response the row gave, against the packet it answers: the header
+ * (id, QR|AA with the RD echo, the rule's rcode, one question, no
+ * record but the OPT echo), the question as asked, and nothing after. */
+void check_declined(const uint8_t *pkt, size_t plen, const uint8_t *out,
+                    size_t wlen) {
+    uint8_t key[FP_MAX_KEY];
+    size_t qn_len = 0;
+    uint16_t qtype = 0;
+    size_t klen = dnskey_build(pkt, plen, key, &qn_len, &qtype);
+    assert(klen > 0);
+    int edns = key[0] & 2;
+    assert(wlen == 12 + qn_len + 4 + (edns ? sizeof(fp_opt_echo) : 0));
+    assert(out[0] == pkt[0] && out[1] == pkt[1]);
+    assert(out[2] == (0x84 | (key[0] & 1)));
+    assert(out[3] == fz_declined_rcode);
+    assert(dnskey_rd16(out + 4) == 1 && dnskey_rd16(out + 6) == 0);
+    assert(dnskey_rd16(out + 8) == 0);
+    assert(dnskey_rd16(out + 10) == (edns ? 1 : 0));
+    assert(memcmp(out + 12, pkt + 12, qn_len + 4) == 0);
+    if (edns)
+        assert(memcmp(out + 12 + qn_len + 4, fp_opt_echo,
+                      sizeof(fp_opt_echo)) == 0);
+}
+
+/* A synthesized question of a declined type through the serve path:
+ * the row answers it under every transport, counts it, and logs it; a
+ * serve that cannot log (ring on, and no room or no source) declines
+ * before any accounting. */
+void serve_declined(const uint8_t *q, size_t qlen, uint16_t qtype,
+                    uint8_t steer) {
+    uint8_t out[FP_MAX_WIRE];
+    int ring = fz_c->lr.enabled;
+    int with_src = steer % 5 != 0;
+    int had_room = fp_log_room(fz_c, sizeof(fz_row_frag) - 1);
+    fp_logsrc_t src = { "192.0.2.7", 5353, "udp" };
+    uint64_t lines = fz_c->lr.lines, declines = fz_c->lr.declines;
+    uint64_t type_hits = fz_c->zone_type_hits, zone_hits = fz_c->zone_hits;
+    uint64_t hits = fz_c->hits;
+    uint16_t got_qtype = 0;
+    size_t wlen = fp_serve_one_lx(fz_c, q, qlen, fz_gen, fz_clock, out,
+                                  &got_qtype, steer % 3,
+                                  with_src ? &src : nullptr);
+    assert(got_qtype == qtype && fz_c->hits == hits);
+    if (ring && (!with_src || !had_room)) {
+        assert(wlen == 0);
+        assert(fz_c->lr.declines == declines + 1);
+        assert(fz_c->lr.lines == lines);
+        assert(fz_c->zone_type_hits == type_hits);
+        assert(fz_c->zone_hits == zone_hits);
+        return;
+    }
+    check_declined(q, qlen, out, wlen);
+    assert(fz_c->zone_type_hits == type_hits + 1);
+    assert(fz_c->zone_hits == zone_hits + 1);
+    assert(fz_c->lr.lines == lines + (ring ? 1 : 0));
 }
 
 }  // namespace
@@ -72,9 +146,13 @@ void fuzz_setup() {
      * evict-oldest path runs constantly */
     int rc = fp_core_init(fz_c, 64, 60000);
     assert(rc == 0);
+    rc = fp_type_row_put(fz_c, fz_served, 3, fz_declined_rcode,
+                         fz_row_frag, sizeof(fz_row_frag) - 1);
+    assert(rc == 1);
 }
 
-void fuzz_one(const uint8_t *data, size_t len) {
+/* one input through one of the three modes */
+static void fuzz_step(const uint8_t *data, size_t len) {
     fz_iter++;
     fz_clock += 0.001;
     if (fz_iter % 97 == 0)
@@ -108,8 +186,14 @@ void fuzz_one(const uint8_t *data, size_t len) {
     if (fz_iter % 3 == 0) {
         /* raw client bytes straight into the serve path (cache AND
          * zone lookup paths, via fp_serve_one's miss fall-through) */
-        (void)fp_serve_one(fz_c, data, len, fz_gen, fz_clock, out,
-                           nullptr);
+        uint64_t type_hits = fz_c->zone_type_hits;
+        size_t wlen = fp_serve_one(fz_c, data, len, fz_gen, fz_clock, out,
+                                   nullptr);
+        if (fz_c->zone_type_hits != type_hits) {
+            /* the row's (no source, so only with the ring off) */
+            assert(!fz_c->lr.enabled);
+            check_declined(data, len, out, wlen);
+        }
     } else if (fz_iter % 3 == 2) {
         /* zone put + serve round trip: synthesized query, precompiled
          * body, assert the assembled response */
@@ -120,6 +204,10 @@ void fuzz_one(const uint8_t *data, size_t len) {
         uint16_t qtype = 0;
         size_t klen = dnskey_build(q, qlen, key, &qn_len, &qtype);
         assert(klen > 0 && klen <= FP_MAX_KEY);
+        if (fp_type_row_covers(&fz_c->trow, qtype)) {
+            serve_declined(q, qlen, qtype, d0);
+            return;
+        }
 
         const uint8_t *tag = key + 7;     /* qname wire */
         size_t taglen = klen - 7;
@@ -239,6 +327,17 @@ void fuzz_one(const uint8_t *data, size_t len) {
         uint16_t qtype = 0;
         size_t klen = dnskey_build(q, qlen, key, &qn_len, &qtype);
         assert(klen > 0 && klen <= FP_MAX_KEY);   /* we built it valid */
+        if (fp_type_row_covers(&fz_c->trow, qtype)) {
+            /* a cache entry of that key first: the row must answer
+             * ahead of the probe that would find it */
+            const uint8_t *w = q;
+            uint16_t wl = (uint16_t)qlen;
+            (void)fp_put_raw(fz_c, key, klen, qtype, fz_gen, &w, &wl, 1,
+                             fz_clock, fz_c->expiry_s, key + 7, klen - 7,
+                             nullptr, nullptr);
+            serve_declined(q, qlen, qtype, d0);
+            return;
+        }
 
         /* synthesize 1..FP_MAX_VARIANTS response wires; variant 0 always
          * embeds the question (the normal shape), later variants may be
@@ -332,6 +431,10 @@ void fuzz_one(const uint8_t *data, size_t len) {
                 assert(w2 >= 12 + qn_len + 4);
         }
     }
+}
+
+void fuzz_one(const uint8_t *data, size_t len) {
+    fuzz_step(data, len);
 
     if (fz_iter % 211 == 0)
         fp_core_clear(fz_c);

@@ -160,8 +160,10 @@ class TestZoneDifferential:
         asyncio.run(run())
 
     def test_shapes_the_zone_declines_are_not_zone_served(self):
-        """Negative SRV shapes, missing names, and non-precompiled
-        qtypes go through Python; the zone table must not touch them."""
+        """Negative SRV shapes and missing names go through Python; the
+        zone table must not touch them.  A type the engine declines by
+        the type alone has no entry either: it is the type row's
+        (tests/test_type_row.py), counted apart from the entries'."""
         async def run():
             _, cache = fixture_store()
             server = await start_server(cache)
@@ -173,7 +175,6 @@ class TestZoneDifferential:
                     # SRV on a non-service name we own: NODATA + SOA
                     make_query("_pg._tcp.web.foo.com", Type.SRV, qid=22),
                     make_query("absent.foo.com", Type.A, qid=23),
-                    make_query("web.foo.com", Type.AAAA, qid=24),
                 )
                 for q in probes:
                     before = zone_stats(server)["zone_hits"]
@@ -182,6 +183,16 @@ class TestZoneDifferential:
                     assert zone_stats(server)["zone_hits"] == before, \
                         q.questions[0]
                     assert resp.id == q.id
+                before = zone_stats(server)
+                q = make_query("web.foo.com", Type.AAAA, qid=24)
+                resp = Message.decode(
+                    await udp_ask_raw(server.udp_port, q.encode()))
+                assert (resp.id, resp.rcode) == (q.id, Rcode.NOTIMP)
+                after = zone_stats(server)
+                assert after["zone_type_hits"] == \
+                    before["zone_type_hits"] + 1
+                assert after["zone_hits"] == before["zone_hits"] + 1
+                assert after["zone_entries"] == before["zone_entries"]
             finally:
                 await server.stop()
 

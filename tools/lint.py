@@ -465,6 +465,7 @@ _SNAPSHOT_SCHEMA = {
         "compiled_entries": (int, False),
         "compiled_serves": (int, False),
         "compiled_installs": (int, False),
+        "type_row_serves": (int, False),
     },
     "inflight": {
         "count": (int, False), "queries": (list, False),
@@ -1202,6 +1203,10 @@ _LEDGER_FAMILIES = {
     "binder_udp_datagrams": "counter",
     "binder_udp_batch_size": "histogram",
     "binder_answer_cache_hits": "counter",
+    # the native serves that are neither: the zone table's, and of
+    # those the type row's (benchmark: type_declined_native_share)
+    "binder_zone_serves": "counter",
+    "binder_zone_type_serves": "counter",
     "binder_query_log_bytes": "counter",
     "binder_query_log_lines": "counter",
     "binder_truncated_renders": "counter",
