@@ -221,6 +221,12 @@ class AnswerCache:
         self._by_tag.setdefault(tag, set()).add((_COMPILED,) + ckey)
         self.compiled_installs += 1
 
+    def compiled_full(self) -> bool:
+        """True when the next new shape's ``put_compiled`` would evict
+        (or, with a table of none, be dropped): the startup seed stops
+        rendering here (``Precompiler.seed_mirror``)."""
+        return len(self._compiled) >= self.compiled_size
+
     def get_compiled(self, qtype: int, qname: str, epoch: int):
         """Probe the compiled table: ``(variant, rotatable, tag,
         negative)`` with the rotation cursor advanced, or None.  No time

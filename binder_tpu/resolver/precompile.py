@@ -19,10 +19,13 @@ record:
   sections, negative answers (NXDOMAIN / NODATA+SOA), in both EDNS
   postures.  Mutations of names nobody queries cost nothing beyond the
   synchronous drop;
-- at startup the whole mirror is seeded (``seed_mirror`` — the
-  ``_zone_fill`` analog), including into the native answer cache under
-  the canonical client postures, so a cold zone serves precompiled from
-  query one;
+- at startup the mirror is seeded (``seed_mirror`` — the
+  ``_zone_fill`` analog) up to the compiled table's capacity, including
+  into the native answer cache under the canonical client postures: a
+  zone whose shapes fit the table serves precompiled from query one; of
+  a larger zone the table holds what it can keep and the rest is the
+  native zone table's (``_zone_fill``, which no capacity evicts) and
+  the lazy resolve's;
 - the finished wires are installed into the ``AnswerCache``'s compiled
   table under the same dependency tags, so the post-churn query is a
   dict probe plus an ID/flags patch (``dns/wire.patch_answer_wire``)
@@ -133,6 +136,10 @@ class Precompiler:
         # chunked startup seed (large zones only)
         self._seed_task = None
         self._seed_remaining = 0
+        # what the startup seed did: shapes it rendered and installed,
+        # and shapes it passed over because the compiled table was full
+        self.seeded = 0
+        self.seed_skipped = 0
         # monotonic counters (also folded into the metrics below)
         self.compiled = 0
         self.declined = 0
@@ -312,10 +319,25 @@ class Precompiler:
             self._schedule()
 
     def seed_mirror(self) -> None:
-        """Compile every currently mirrored name — run once at server
-        start, for mirrors built before this server subscribed to
-        invalidation events (the same reason ``_zone_fill`` exists).
-        Later arrivals ride the mutation path.
+        """Compile the currently mirrored names, as many shapes as the
+        compiled table can keep — run once at server start, for mirrors
+        built before this server subscribed to invalidation events (the
+        same reason ``_zone_fill`` exists).  Later arrivals ride the
+        mutation path.
+
+        The walk renders until the table is full
+        (``AnswerCache.compiled_full``) and only counts the shapes of
+        the names after that (``seed_skipped``): ``put_compiled`` evicts
+        its oldest install, so every render past the capacity would
+        push out one this same walk had just paid for.  A mirror whose
+        shapes fit the table is seeded whole; of a larger one the HEAD
+        of the walk is kept (the mirror's dict order, no more related to
+        popularity than the tail the evictions used to leave), because
+        only stopping saves the renders.  The names left out are served
+        by the native zone table and the lazy resolve, and enter the
+        answer caches by query evidence; an operator who wants the whole
+        zone compiled raises ``precompileSize`` to hold it and pays the
+        full walk at every start.
 
         Small zones seed inline (the historical semantics: precompiled
         from query one).  Past ``SEED_INLINE_MAX`` the walk moves to a
@@ -333,25 +355,36 @@ class Precompiler:
             return
         self._seed_task = loop.create_task(self._seed_chunked())
 
-    def _seed_one(self, domain: str) -> None:
+    def seed_shapes(self, domain: str) -> list:
+        """The question identities the seed walk holds for one mirrored
+        name: what ``items_for_tag`` gives for it (its A, a service's
+        SRV) and the PTR of its v4 address."""
         node = self.zk_cache.nodes.get(domain)
         if node is None:
-            return                      # left the mirror mid-walk
-        for item in self.items_for_tag(domain):
-            try:
-                self._compile_one(item, native=True)
-            except Exception:
-                self.log.exception("precompile seed failed for %s", item)
+            return []                   # left the mirror mid-walk
+        shapes = list(self.items_for_tag(domain))
         ip = getattr(node, "ip", None)
         if ip and type(ip) is str:
             parts = ip.split(".")
             if len(parts) == 4 and all(p.isdigit() for p in parts):
-                rev = ".".join(reversed(parts)) + ".in-addr.arpa"
-                try:
-                    self._compile_one((Type.PTR, rev), native=True)
-                except Exception:
-                    self.log.exception(
-                        "precompile seed failed for %s", rev)
+                shapes.append(
+                    (Type.PTR, ".".join(reversed(parts)) + ".in-addr.arpa"))
+        return shapes
+
+    def _seed_one(self, domain: str) -> None:
+        """Render one mirrored name's shapes (a name is not split), or
+        count them once the table is full."""
+        shapes = self.seed_shapes(domain)
+        if self.answer_cache.compiled_full():
+            self.seed_skipped += len(shapes)
+            return
+        before = self.compiled
+        for item in shapes:
+            try:
+                self._compile_one(item, native=True)
+            except Exception:
+                self.log.exception("precompile seed failed for %s", item)
+        self.seeded += self.compiled - before
 
     async def _seed_chunked(self) -> None:
         domains = list(self.zk_cache.nodes)
@@ -367,8 +400,10 @@ class Precompiler:
                 i += 1
             self._seed_remaining = len(domains) - i
             await asyncio.sleep(0)
-        self.log.info("precompile seed done: %d names in %.1fs",
-                      len(domains), time.perf_counter() - started)
+        self.log.info("precompile seed done: %d names in %.1fs "
+                      "(%d shapes seeded, %d left to the lazy path)",
+                      len(domains), time.perf_counter() - started,
+                      self.seeded, self.seed_skipped)
 
     # -- one item: plan → render variants → install --
 
@@ -423,11 +458,12 @@ class Precompiler:
                      evidence_at: Optional[float] = None,
                      trace=None) -> None:
         """``native=True`` only on the startup seed: the C answer cache
-        is COLD there, so installing the whole mirror is pure win.  The
-        mutation path must NOT native-install — its sustained insert
-        stream would evict the resident hot set (the C cache evicts
-        oldest-inserted within a probe window), which measured as a
-        ~45%% churn-throughput collapse.  Post-churn names serve from
+        is COLD there, so the shapes the seed renders (at most the
+        compiled table's capacity, ``seed_mirror``) evict nothing that
+        was asked for.  The mutation path must NOT native-install — its
+        sustained insert stream would evict the resident hot set (the C
+        cache evicts oldest-inserted within a probe window), which
+        measured as a ~45%% churn-throughput collapse.  Post-churn names serve from
         the Python compiled table immediately and re-enter the native
         tier through the ordinary promote-on-first-hit path once they
         prove hot.  ``evidence_at`` propagates the shape's query
@@ -499,4 +535,6 @@ class Precompiler:
             "declined": self.declined,
             "shed": self.shed,
             "seed_remaining": self._seed_remaining,
+            "seeded": self.seeded,
+            "seed_skipped": self.seed_skipped,
         }

@@ -2104,9 +2104,10 @@ class BinderServer:
 
     async def start(self) -> None:
         if self._precompiler is not None:
-            # compile the already-mirrored names (mirrors built before
-            # this server subscribed to invalidation events); mutation
-            # events keep the table fresh from here on
+            # compile the already-mirrored names, as many shapes as the
+            # compiled table keeps (mirrors built before this server
+            # subscribed to invalidation events); mutation events keep
+            # the table fresh from here on
             self._precompiler.seed_mirror()
         self._zone_fill()
         if self.balancer_socket:

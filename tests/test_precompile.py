@@ -21,15 +21,22 @@ Pins the tentpole properties of the precompiled answer layer:
   exposition text.
 """
 import asyncio
+import importlib.machinery
+import importlib.util
+import os
+
+import pytest
 
 from binder_tpu.dns import Message, Rcode, Type, make_query
 from binder_tpu.dns.query import QueryCtx
-from binder_tpu.introspect import FlightRecorder
+from binder_tpu.introspect import FlightRecorder, Introspector
 from binder_tpu.metrics.collector import MetricsCollector
+from binder_tpu.resolver.precompile import Precompiler
 from binder_tpu.server import BinderServer
 from binder_tpu.store import FakeStore, MirrorCache
 
-from tools.lint import validate_precompile_metrics
+from tools.lint import (validate_precompile_metrics,
+                        validate_status_snapshot)
 
 DOMAIN = "foo.com"
 SVC = "/com/foo/svc"
@@ -181,6 +188,142 @@ class TestMutationInstalls:
         # cross-DC forward): only the lazy path may decide
         assert server.answer_cache.get_compiled(
             Type.A, "web.foo.com", cache.epoch) is None
+
+
+class _Unshuffled:
+    """``resolver.rng`` that leaves a set in plan order: the rotation
+    the precompiler renders as variant 0."""
+
+    def shuffle(self, lst):
+        pass
+
+
+def load_host_zone(store):
+    for i in range(12):
+        put_host(store, f"/com/foo/h{i:02d}", f"10.2.0.{i + 1}")
+
+
+def load_service_zone(store):
+    for s in range(4):
+        base = f"/com/foo/svc{s}"
+        store.put_json(base, {"type": "service",
+                              "service": {"srvce": "_pg", "proto": "_tcp",
+                                          "port": 5432}})
+        for m in range(3):
+            store.put_json(f"{base}/lb{m}", {
+                "type": "load_balancer",
+                "load_balancer": {"address": f"10.3.{s}.{m + 1}"}})
+
+
+#: zone -> (its load, the shapes its mirror answers: an A and a PTR a
+#: host or member, an A and an SRV a service; the most one name holds)
+SEED_ZONES = {"hosts": (load_host_zone, 12 * 2, 2),
+              "services": (load_service_zone, 4 * 2 + 12 * 2, 2)}
+
+
+class TestBoundedSeed:
+    """The startup seed renders what its table keeps (ISSUE 36): a
+    mirror whose shapes fit the compiled table is seeded whole, as it
+    always was; of a larger one the seed fills the table and counts the
+    rest, instead of rendering every shape to keep the last
+    ``compiled_size``."""
+
+    def seeded_pair(self, zone, **kw):
+        load, shapes, widest = SEED_ZONES[zone]
+        _s1, _c1, server = build(**kw)
+        _s2, _c2, engine = build(precompile=False)
+        load(server.zk_cache.store)
+        load(engine.zk_cache.store)
+        server.resolver.rng = engine.resolver.rng = _Unshuffled()
+        return server, engine, shapes, widest
+
+    def assert_seeded_wires_are_the_engines(self, server, engine):
+        """Every shape in the table serves, with the engine forbidden,
+        the bytes the generic path gives; every shape of the mirror
+        left out of it is still answered, by a resolve, with the same
+        bytes."""
+        table = set(server.answer_cache._compiled)
+        resolve = server.resolver.handle
+        left_out = 0
+        for domain in list(server.zk_cache.nodes):
+            for qtype, qname in server._precompiler.seed_shapes(domain):
+                kept = (qtype, qname) in table
+                left_out += not kept
+                if kept:
+                    forbid_engine(server)
+                else:
+                    server.resolver.handle = resolve
+                _, wire, q = ask(server, qname, qtype, qid=11)
+                _, want, _q = ask(engine, qname, qtype, qid=11)
+                assert wire == want, (qtype, qname)
+                assert bool(q.log_ctx.get("precompiled")) is kept
+        server.resolver.handle = resolve
+        return left_out
+
+    @pytest.mark.parametrize("walk", ["inline", "chunked"])
+    @pytest.mark.parametrize("zone", sorted(SEED_ZONES))
+    def test_a_table_smaller_than_the_mirror_is_filled_and_no_more(
+            self, zone, walk, monkeypatch):
+        """Inline, and from the background task a mirror above
+        ``SEED_INLINE_MAX`` seeds from under a loop: the task ends and
+        ``seed_remaining`` reaches 0 (what ``wait_settled`` and
+        ``chip_smoke.py`` wait for) with the table full and the rest
+        counted."""
+        size = 10
+        server, engine, shapes, widest = self.seeded_pair(
+            zone, precompile_size=size)
+        pc = server._precompiler
+        if walk == "inline":
+            pc.seed_mirror()
+        else:
+            monkeypatch.setattr(Precompiler, "SEED_INLINE_MAX", 3)
+
+            async def run():
+                pc.seed_mirror()
+                assert pc._seed_task is not None, "the walk ran inline"
+                await asyncio.wait_for(pc._seed_task, timeout=30)
+
+            asyncio.run(run())
+        intro = pc.introspect()
+        assert server.answer_cache.stats()["compiled_entries"] == size
+        assert server.answer_cache.compiled_full()
+        # the bound, give or take the shapes of the one name in hand
+        assert size <= pc.compiled < size + widest
+        assert f"binder_precompile_compiled {pc.compiled}\n" \
+            in server.collector.expose()
+        assert intro["seed_remaining"] == 0
+        assert intro["seeded"] == pc.compiled
+        assert intro["seeded"] + intro["seed_skipped"] == shapes
+        assert intro["declined"] == 0
+        left_out = self.assert_seeded_wires_are_the_engines(server,
+                                                            engine)
+        assert left_out == shapes - size
+
+    @pytest.mark.parametrize("zone", sorted(SEED_ZONES))
+    def test_a_table_that_holds_the_mirror_is_seeded_whole(self, zone):
+        server, engine, shapes, _widest = self.seeded_pair(zone)
+        pc = server._precompiler
+        pc.seed_mirror()
+        intro = pc.introspect()
+        assert intro["seeded"] == intro["compiled"] == shapes
+        assert intro["seed_skipped"] == 0
+        assert intro["seed_remaining"] == 0
+        assert server.answer_cache.stats()["compiled_entries"] == shapes
+        assert not server.answer_cache.compiled_full()
+        assert self.assert_seeded_wires_are_the_engines(server,
+                                                        engine) == 0
+
+    @pytest.mark.parametrize("zone", sorted(SEED_ZONES))
+    def test_a_table_of_none_seeds_nothing(self, zone):
+        server, engine, shapes, _widest = self.seeded_pair(
+            zone, precompile_size=0)
+        pc = server._precompiler
+        pc.seed_mirror()
+        intro = pc.introspect()
+        assert (intro["seeded"], intro["compiled"]) == (0, 0)
+        assert intro["seed_skipped"] == shapes
+        assert self.assert_seeded_wires_are_the_engines(
+            server, engine) == shapes
 
 
 class TestChurn:
@@ -495,6 +638,30 @@ class TestMetrics:
             if "binder_precompile_shed" not in ln) + "\n"
         assert any("binder_precompile_shed" in e
                    for e in validate_precompile_metrics(broken))
+
+    def test_status_schema_and_bstat_hold_the_seed_fields(self):
+        store, cache, server = build(precompile_size=2)
+        put_host(store, "/com/foo/web", "10.1.2.3")
+        put_host(store, "/com/foo/db", "10.1.2.4")
+        server._precompiler.seed_mirror()
+        snap = Introspector(server=server).snapshot()
+        assert validate_status_snapshot(snap) == []
+        pc = snap["precompile"]
+        assert (pc["seeded"], pc["seed_skipped"]) == (2, 2)
+        for key in ("seeded", "seed_skipped"):
+            cut = dict(snap, precompile={k: v for k, v in pc.items()
+                                         if k != key})
+            assert validate_status_snapshot(cut) == [
+                f"precompile: missing {key!r}"]
+        loader = importlib.machinery.SourceFileLoader(
+            "bstat", os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), "bin", "bstat"))
+        bstat = importlib.util.module_from_spec(
+            importlib.util.spec_from_loader("bstat", loader))
+        loader.exec_module(bstat)
+        line = next(ln for ln in bstat.render(snap).splitlines()
+                    if ln.startswith("precompile:"))
+        assert "seed 2 shape(s), 2 past the table's capacity" in line
 
     def test_introspect_section(self):
         store, cache, server = build()

@@ -606,7 +606,8 @@ def validate_status_snapshot(snap):
     pc = snap.get("precompile")
     if isinstance(pc, dict):
         for key in ("queue_depth", "max_pending", "batch", "compiled",
-                    "declined", "shed", "seed_remaining"):
+                    "declined", "shed", "seed_remaining", "seeded",
+                    "seed_skipped"):
             if key not in pc:
                 errs.append(f"precompile: missing {key!r}")
     vf = snap.get("verify")
