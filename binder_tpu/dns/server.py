@@ -76,7 +76,13 @@ CTL_DIRECT = 2
 
 
 def _no_span(seconds: float) -> None:
-    """Where no ledger is attached, a stream-lane span goes nowhere."""
+    """Where no ledger is attached, a span goes nowhere."""
+
+
+def _no_event(fn, *args):
+    """Where no ledger is attached, a callback registered through an
+    event span (introspect/ledger.py ``event``) is just called."""
+    return fn(*args)
 
 
 def pack_balancer_frame(family: int, addr: str, port: int,
@@ -197,7 +203,8 @@ class BalancerLink:
             engine._balancer_writers[self] = True
         if engine.balancer_direct_return:
             self.send_frame(pack_direct_frame())
-        self.loop.add_reader(self.fd, self._on_readable)
+        self.loop.add_reader(self.fd, engine.event_balancer,
+                             self._on_readable)
 
     # -- reads --
 
@@ -381,7 +388,8 @@ class BalancerLink:
             return
         # late (async-completed) response: coalesce per event-loop pass
         if not self._direct_late:
-            self.loop.call_soon(self._flush_direct_late)
+            self.loop.call_soon(self.engine.event_deferred,
+                                self._flush_direct_late)
         self._direct_late.append((wire, addr))
 
     def _flush_direct_late(self) -> None:
@@ -462,7 +470,8 @@ class BalancerLink:
             del self._wbuf[:n]
         if self._wbuf and not self._writing:
             self._writing = True
-            self.loop.add_writer(self.fd, self._flush)
+            self.loop.add_writer(self.fd, self.engine.event_balancer,
+                                 self._flush)
         elif not self._wbuf and self._writing:
             self._writing = False
             self.loop.remove_writer(self.fd)
@@ -560,7 +569,15 @@ class DnsServer:
         # lane took.  BinderServer hands in its stage children's
         # `observe`; an engine on its own times and drops them.
         self.span_accept = self.span_recv = self.span_send = \
-            self.span_close = _no_span
+            self.span_close = self.span_register = _no_span
+        # the leaf `query-ingress`: a packet's way through _handle_raw
+        # up to its QueryCtx's start, or to the return that ends it
+        self.span_ingress = _no_span
+        # the ledger's event spans, one a lane (introspect/ledger.py
+        # `event`): every readiness callback of the served path is
+        # registered behind its lane's, which times the whole callback
+        self.event_udp = self.event_tcp = self.event_balancer = \
+            self.event_deferred = _no_event
         # cap-refusal accounting: a connect flood at the cap must not
         # become a log flood, so refusals log at most once per interval
         # (with the count of everything refused since the last line)
@@ -661,13 +678,8 @@ class DnsServer:
     # an awaitable for work that needs real I/O (the recursion path),
     # which is then driven by a task.
 
-    def _dispatch(self, request: Message, src: Tuple[str, int],
-                  protocol: str, send: Callable[[bytes], None],
-                  client_transport: Optional[str] = None,
-                  raw: Optional[bytes] = None,
+    def _dispatch(self, query: QueryCtx,
                   ctx_box: Optional[list] = None) -> None:
-        query = QueryCtx(request, src, protocol, send,
-                         client_transport=client_transport, raw=raw)
         if ctx_box is not None:
             # transports that need per-response state (the balancer's
             # do-not-store marker) observe the context through this box
@@ -832,6 +844,29 @@ class DnsServer:
                     client_transport: Optional[str] = None,
                     ctx_box: Optional[list] = None,
                     fastpath_checked: bool = False) -> None:
+        # query-ingress: one span a packet, from here to the return of
+        # a packet that ends in _ingress, or to where the per-query
+        # stages begin: the clock read the QueryCtx makes for itself
+        # (observed once the query is served, so that no stage of the
+        # query holds the observation's own cost)
+        t_in = time.monotonic()
+        request = self._ingress(data, src, protocol, send,
+                                fastpath_checked)
+        if request is None:
+            self.span_ingress(time.monotonic() - t_in)
+            return
+        query = QueryCtx(request, src, protocol, send,
+                         client_transport=client_transport, raw=data)
+        self._dispatch(query, ctx_box)
+        self.span_ingress(query.start - t_in)
+
+    def _ingress(self, data: bytes, src: Tuple[str, int], protocol: str,
+                 send: Callable[[bytes], None],
+                 fastpath_checked: bool) -> Optional[Message]:
+        """What a packet passes before it is a query: the rate limiter,
+        the native serve of the lanes without a drain of their own, the
+        decode.  The decoded request, or None for a packet that ended
+        here (dropped, slipped, served, malformed, not a query)."""
         # Response rate limiting at the UDP ingress, before decode and
         # before any lane can spend work on the packet: a flooded
         # prefix gets a TC slip or silence at raw-bytes cost.  While
@@ -851,7 +886,7 @@ class DnsServer:
                             send(resp)
                         except OSError:
                             pass
-                return
+                return None
         elif rrl is not None and protocol == "tcp":
             # adaptive-bucket liveness evidence: a TCP query reaching
             # the serve path at all proves a completed handshake — the
@@ -879,7 +914,7 @@ class DnsServer:
                     send(resp)
                 except OSError:
                     pass
-                return
+                return None
         try:
             request = self._decode_query(data)
         except WireError as e:
@@ -891,11 +926,10 @@ class DnsServer:
                     send(resp.encode())
                 except OSError:
                     pass
-            return
+            return None
         if request.qr:
-            return  # not a query
-        self._dispatch(request, src, protocol, send, client_transport,
-                       raw=data, ctx_box=ctx_box)
+            return None  # not a query
+        return request
 
     # -- UDP --
 
@@ -978,7 +1012,7 @@ class DnsServer:
                 finally:
                     self._flush_log()
 
-        loop.add_reader(sock.fileno(), on_readable)
+        loop.add_reader(sock.fileno(), self.event_udp, on_readable)
         self._udp_socks.append((loop, sock))
         actual = sock.getsockname()[1]
         if announce:
@@ -1078,7 +1112,8 @@ class DnsServer:
         def send_late(wire: bytes, addr) -> None:
             if not late_out:
                 try:
-                    asyncio.get_running_loop().call_soon(flush_late)
+                    asyncio.get_running_loop().call_soon(
+                        self.event_deferred, flush_late)
                 except RuntimeError:
                     try:
                         sendto(wire, addr)
@@ -1217,8 +1252,8 @@ class DnsServer:
             # socket behind
             lsock.close()
             raise
-        loop.add_reader(lsock.fileno(), self._on_accept_ready, lsock,
-                        loop)
+        loop.add_reader(lsock.fileno(), self.event_tcp,
+                        self._on_accept_ready, lsock, loop)
         self._tcp_listeners.append((loop, lsock))
         if self._tcp_sweep_handle is None and self.tcp_idle_timeout:
             # ONE idle sweep for the whole connection table (vs a timer
@@ -1226,7 +1261,8 @@ class DnsServer:
             # overstay at ~T/4 past the deadline
             interval = max(0.05, min(self.tcp_idle_timeout / 4.0, 5.0))
             self._tcp_sweep_handle = loop.call_later(
-                interval, self._sweep_idle_tcp, loop, interval)
+                interval, self.event_deferred, self._sweep_idle_tcp,
+                loop, interval)
         actual = lsock.getsockname()[1]
         if announce:
             self.announce_tcp(address, actual)
@@ -1295,7 +1331,8 @@ class DnsServer:
                 conn.close()
         if self._tcp_listeners or self._tcp_conns:
             self._tcp_sweep_handle = loop.call_later(
-                interval, self._sweep_idle_tcp, loop, interval)
+                interval, self.event_deferred, self._sweep_idle_tcp,
+                loop, interval)
 
     def tcp_introspect(self) -> dict:
         """The ``/status`` ``tcp`` section: live connection-table state
@@ -1325,8 +1362,8 @@ class DnsServer:
         except OSError:
             lsock.close()
             raise
-        loop.add_reader(lsock.fileno(), self._on_balancer_accept, lsock,
-                        loop)
+        loop.add_reader(lsock.fileno(), self.event_balancer,
+                        self._on_balancer_accept, lsock, loop)
         self._unix_servers.append((loop, lsock, path))
         self.log.info("balancer service started on %s", path)
 

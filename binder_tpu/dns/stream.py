@@ -42,8 +42,12 @@ Every transition feeds :class:`TcpStats`, folded into the
 crossings are leaf spans of the time ledger (``introspect/ledger.py``):
 ``tcp-recv`` a ``recv`` call, ``tcp-send`` a ``send``/``sendmsg`` call,
 ``tcp-close`` a connection closed (``tcp-accept`` is the accept path's,
-``dns/server.py``); the serve between them is not theirs: the bulk frame
-serve is ``native-serve``'s, a declined frame's the per-query stages'.
+``dns/server.py``), ``tcp-register`` a selector change of an open
+connection; the serve between them is not theirs: the bulk frame serve
+is ``native-serve``'s, a declined frame's ``query-ingress``'s and the
+per-query stages'.  Every callback the loop calls here is registered
+behind the lane's event span (``srv.event_tcp``; a ``call_soon`` or
+timer behind ``srv.event_deferred``), which times the whole callback.
 """
 from __future__ import annotations
 
@@ -130,8 +134,14 @@ class TcpConn:
         # iteration (accept → read → serve → vectored write)
         self._on_readable()
         if not self.closed and not self.eof and not self.reader_on:
-            self.loop.add_reader(self.fd, self._on_readable)
+            # tcp-register: one span a selector change of an open leg;
+            # the reader's own events are timed from here on, the read
+            # above is the accept event's
+            t0 = monotonic()
+            self.loop.add_reader(self.fd, srv.event_tcp,
+                                 self._on_readable)
             self.reader_on = True
+            srv.span_register(monotonic() - t0)
 
     # -- read side --
 
@@ -296,7 +306,8 @@ class TcpConn:
         # cannot wedge the slot
         srv.tcp_stats.half_closes += 1
         grace = min(srv.tcp_idle_timeout or 5.0, 5.0)
-        self.grace = self.loop.call_later(grace, self.close)
+        self.grace = self.loop.call_later(grace, srv.event_deferred,
+                                          self.close)
 
     # -- write side --
 
@@ -319,7 +330,7 @@ class TcpConn:
             # write — upstream answers arrive in batches, so their
             # completions cluster in one pass
             self.flush_scheduled = True
-            self.loop.call_soon(self._flush_cb)
+            self.loop.call_soon(self.srv.event_deferred, self._flush_cb)
 
     def _flush_cb(self) -> None:
         self.flush_scheduled = False
@@ -397,8 +408,11 @@ class TcpConn:
         out.clear()
         self.wbuf = tail
         if not self.writer_on:
-            self.loop.add_writer(self.fd, self._on_writable)
+            t0 = monotonic()
+            self.loop.add_writer(self.fd, self.srv.event_tcp,
+                                 self._on_writable)
             self.writer_on = True
+            self.srv.span_register(monotonic() - t0)
         self._enforce_write_cap()
 
     def _on_writable(self) -> None:
@@ -420,11 +434,13 @@ class TcpConn:
         if not wbuf:
             self.wbuf = None
             if self.writer_on:
+                t0 = monotonic()
                 try:
                     self.loop.remove_writer(self.fd)
                 except (OSError, ValueError):
                     pass
                 self.writer_on = False
+                self.srv.span_register(monotonic() - t0)
             if self.out:
                 self._flush()
             else:

@@ -27,14 +27,46 @@ stage           observed per       where the clock is read
                 ``sendmsg`` call   ``_on_writable``
 ``tcp-close``   connection closed  ``TcpConn.close`` (and ``abort``): the
                                    selector unregistrations and ``close``
+``tcp-register``  selector change  ``TcpConn.start``, ``_flush``,
+                of an open leg     ``_on_writable``: an ``add_reader``,
+                                   ``add_writer`` or ``remove_writer``
+                                   outside ``tcp-close`` (an
+                                   ``epoll_ctl`` each)
+``query-ingress``  packet in       ``DnsServer._handle_raw``: its entry
+                ``_handle_raw``    to the ``QueryCtx``'s own ``start``,
+                                   or to the return of a packet that
+                                   ends there (an RRL drop or slip, a
+                                   native serve, a malformed packet)
 ==============  =================  ====================================
 
-The four ``tcp-*`` spans are the stream lane's kernel crossings
+The ``tcp-*`` spans are the stream lane's kernel crossings
 (``dns/stream.py``); the frames' serve between them is
 ``native-serve``'s (the bulk frame serve, one observation a call) and,
-for the frames it declines, the per-query stages'.  A connection's
-reader *registration* after its first serve is the one crossing of a
-one-shot leg that no span names.
+for the frames it declines, ``query-ingress`` and the per-query
+stages'.  A connection's reader *registration* after its first serve,
+the one crossing of a one-shot leg that no other span names, is
+``tcp-register``'s.
+
+**The event span** is the parent whose children the leaf stages are:
+every readiness callback the loop calls on the served path is
+registered through :func:`event`, which times the whole callback into
+``binder_loop_event_seconds{lane}``, a family of its own.  It is no
+stage of ``binder_query_stage_seconds``: it overlays the leaves inside
+it, and a reader that sums that histogram's stages would count them
+twice.  With it a worker's busy time (wall less ``loop-idle``) splits
+three ways: *the loop's own turn* (busy less the events: asyncio's
+``_run_once``, the selector's Python, the timers and tasks that hold no
+leaf), *the callbacks' glue* (the events less the leaves and per-query
+stages inside them: their self time) and what a stage names.  An event
+never nests in another: the wrap is put on at the registration
+(``add_reader``, ``add_writer``, ``call_soon``, ``call_later``), not in
+the method, so an accept event holds the first read of the legs it
+accepted and that read is observed once.
+
+``binder_process_cpu_seconds_total{mode}`` is the process's user and
+system CPU time by ``os.times()``, read at a scrape only
+(:func:`install_process_cpu`): what ``/proc/<pid>/stat`` says of the
+same process, beside the ledger's own busy time.
 
 Always on, like the stage histogram: no switch, option or environment
 variable.  Every span reads ``CLOCK_MONOTONIC``.  The Python spans
@@ -46,6 +78,7 @@ are folded in by deltas at scrape, as the native per-qtype latency is
 from __future__ import annotations
 
 import asyncio
+import os
 import selectors
 import threading
 import time
@@ -56,6 +89,8 @@ from binder_tpu.metrics.collector import (DEFAULT_STAGE_BUCKETS,
 
 METRIC_STAGE_HISTOGRAM = "binder_query_stage_seconds"
 STAGE_HISTOGRAM_HELP = "per-stage decomposition of request processing time"
+METRIC_EVENT_HISTOGRAM = "binder_loop_event_seconds"
+METRIC_PROCESS_CPU = "binder_process_cpu_seconds_total"
 
 #: the stream lane's four (dns/stream.py), in the order a one-shot leg
 #: passes them
@@ -63,7 +98,13 @@ TCP_STAGES = ("tcp-accept", "tcp-recv", "tcp-send", "tcp-close")
 #: the ledger's leaf stages (docs/observability.md); the per-query
 #: stages beside them are whatever ``QueryCtx.stamp`` was given
 LEAF_STAGES = ("loop-idle", "udp-recv", "native-serve", "udp-send",
-               "log-write", "log-line") + TCP_STAGES
+               "log-write", "log-line") + TCP_STAGES \
+    + ("tcp-register", "query-ingress")
+#: the event span's lanes: the UDP readers, the stream lane's accept,
+#: reader and writer callbacks, the balancer lane's sockets, and the
+#: ``call_soon`` and timer callbacks that hold a leaf outside a
+#: socket's callback (a deferred log write, a late flush)
+EVENT_LANES = ("udp", "tcp", "balancer", "deferred")
 
 
 def stage_child(collector, stage: str) -> HistogramChild:
@@ -71,6 +112,48 @@ def stage_child(collector, stage: str) -> HistogramChild:
     return collector.histogram(
         METRIC_STAGE_HISTOGRAM, STAGE_HISTOGRAM_HELP,
         buckets=DEFAULT_STAGE_BUCKETS).labelled({"stage": stage})
+
+
+def event(collector, lane: str):
+    """The event span of one lane: ``run(fn, *args)`` reads the clock,
+    calls ``fn(*args)`` and observes how long it held the loop into
+    ``binder_loop_event_seconds{lane}``.  Made once a lane and handed
+    to the loop in front of the callback (``loop.add_reader(fd, run,
+    callback)``), so a call allocates nothing."""
+    observe = collector.histogram(
+        METRIC_EVENT_HISTOGRAM,
+        "time one readiness callback of the served path held the loop",
+        buckets=DEFAULT_STAGE_BUCKETS).labelled({"lane": lane}).observe
+    monotonic = time.monotonic
+
+    def run(fn, *args):
+        t0 = monotonic()
+        try:
+            return fn(*args)
+        finally:
+            observe(monotonic() - t0)
+    return run
+
+
+def install_process_cpu(collector) -> None:
+    """Export the process's CPU seconds, user and system, as
+    ``binder_process_cpu_seconds_total{mode}``: ``os.times()`` read in
+    a pre-scrape hook and nowhere else."""
+    counter = collector.counter(
+        METRIC_PROCESS_CPU,
+        "CPU time of this process by os.times(), read at the scrape")
+    children = (counter.labelled({"mode": "user"}),
+                counter.labelled({"mode": "system"}))
+    last = [0.0, 0.0]
+    lock = threading.Lock()     # scrapes run on their own threads
+
+    def fold() -> None:
+        with lock:
+            now = os.times()[:2]
+            for i, child in enumerate(children):
+                child.inc(max(0.0, now[i] - last[i]))
+                last[i] = now[i]
+    collector.on_expose(fold)
 
 
 class SpanFold:

@@ -196,6 +196,7 @@ async def run_supervisor(options: Dict[str, object]):
     watchdog = LoopLagWatchdog(collector=collector, recorder=recorder)
     watchdog.start()
     ledger.install_loop_idle(collector)    # the owner's loop has a ledger too
+    ledger.install_process_cpu(collector)
     recorder.install_sigusr2(loop, path=options.get("flightRecorderDump"))
     supervisor.watchdog = watchdog
     supervisor.metrics = metrics
@@ -467,6 +468,7 @@ async def run(options: Dict[str, object]) -> BinderServer:
     watchdog = LoopLagWatchdog(collector=collector, recorder=recorder)
     watchdog.start()
     ledger.install_loop_idle(collector)    # the time ledger's idle wait
+    ledger.install_process_cpu(collector)  # and the kernel's account of it
     introspector = Introspector(server=server, recorder=recorder,
                                 watchdog=watchdog, collector=collector,
                                 name=NAME)

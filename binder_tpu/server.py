@@ -42,10 +42,12 @@ from binder_tpu.dns.wire import (
     reverse_name_for_ip,
 )
 from binder_tpu.introspect.ledger import (
+    EVENT_LANES,
     METRIC_STAGE_HISTOGRAM,
     STAGE_HISTOGRAM_HELP,
     TCP_STAGES,
     SpanFold,
+    event,
 )
 from binder_tpu.metrics.collector import (
     DEFAULT_SIZE_BUCKETS,
@@ -489,10 +491,18 @@ class BinderServer:
         self.engine.rrl = self._rrl
         # the ledger's stream-lane spans: timed in dns/stream.py and the
         # accept path, observed straight into their stage's child
-        for stage, slot in zip(TCP_STAGES, ("span_accept", "span_recv",
-                                            "span_send", "span_close")):
+        for stage, slot in zip(
+                TCP_STAGES + ("tcp-register", "query-ingress"),
+                ("span_accept", "span_recv", "span_send", "span_close",
+                 "span_register", "span_ingress")):
             setattr(self.engine, slot, self.stage_histogram.labelled(
                 {"stage": stage}).observe)
+        # the ledger's event spans: every readiness callback of the
+        # served path is registered behind its lane's, so the whole
+        # callback is timed into binder_loop_event_seconds{lane}
+        for lane in EVENT_LANES:
+            setattr(self.engine, "event_" + lane,
+                    event(self.collector, lane))
         # the engine's cap-refusal log line is rate-limited, so the
         # counter is the only complete record — surface it in the scrape
         self._cap_refusal_child = self.collector.counter(
@@ -1937,7 +1947,7 @@ class BinderServer:
             self._write_log()
             return
         self._log_soon = True
-        loop.call_soon(self._write_log_soon)
+        loop.call_soon(self.engine.event_deferred, self._write_log_soon)
 
     def _write_log_soon(self) -> None:
         self._log_soon = False
@@ -2001,7 +2011,7 @@ class BinderServer:
         try:
             while True:
                 await asyncio.sleep(0.1)
-                self._write_log()
+                self.engine.event_deferred(self._write_log)
         except asyncio.CancelledError:
             self._write_log()
             raise

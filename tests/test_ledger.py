@@ -34,6 +34,8 @@ import time
 import pytest
 
 from binder_tpu.dns import Type, make_query
+from binder_tpu.dns.server import DnsServer
+from binder_tpu.dns.stream import TcpConn
 from binder_tpu.introspect import Introspector, LoopLagWatchdog
 from binder_tpu.introspect import ledger
 from binder_tpu.introspect.watchdog import STALL_RING_SIZE
@@ -783,3 +785,395 @@ def test_scrape_thread_and_loop_fold_without_double_counting():
     for t in threads:
         t.join()
     assert sum(fold.child._cells) == 1000
+
+
+# -- ISSUE 37: the event span, query-ingress, tcp-register, CPU seconds --
+
+OVERLAY = ("await", "upstream", "upstream-rtt", "loop-wait")
+
+
+def event_sums(collector, part="sum"):
+    """{lane: seconds or observations} of ``binder_loop_event_seconds``."""
+    hist = collector.get(ledger.METRIC_EVENT_HISTOGRAM)
+    out = {}
+    for key, cells in hist._counts.items():
+        lane = dict(key)["lane"]
+        out[lane] = (hist._sums.get(key, 0.0) if part == "sum"
+                     else sum(cells))
+    return out
+
+
+def grew(now, was):
+    return {k: v - was.get(k, 0) for k, v in now.items()}
+
+
+def reading(collector):
+    return (stage_sums(collector), stage_sums(collector, "count"),
+            event_sums(collector), event_sums(collector, "count"))
+
+
+async def settle(server, stage, want, base):
+    """Wait until *stage* has grown by *want* observations over *base*
+    (a leg's EOF lands a turn after its answer)."""
+    for _ in range(400):
+        if stage_sums(server.collector, "count").get(stage, 0) \
+                - base.get(stage, 0) >= want:
+            return
+        await asyncio.sleep(0.005)
+    raise AssertionError(f"{stage} never grew by {want}")
+
+
+def test_event_series_and_new_leaves_exist_from_the_first_scrape():
+    async def run():
+        server = await start_logged_server(io.StringIO())
+        try:
+            return server.collector.expose()
+        finally:
+            await server.stop()
+
+    text = ledger.run(run())
+    for lane in ledger.EVENT_LANES:
+        assert (f'binder_loop_event_seconds_count{{lane="{lane}"}}'
+                in text), lane
+    for stage in ("tcp-register", "query-ingress"):
+        assert (f'binder_query_stage_seconds_count{{stage="{stage}"}}'
+                in text), stage
+    # the event span is a family of its own: an overlay among the
+    # stages would be summed twice by every reader of that histogram
+    assert 'stage="event' not in text and 'stage="udp"' not in text
+
+
+@needs_native
+@pytest.mark.parametrize("name,served_by", [("h3", "native-serve"),
+                                            ("nx3", "store-lookup")])
+def test_an_accept_event_holds_the_legs_first_read_once(name, served_by):
+    """A one-shot leg is two ``tcp`` events, the accept's and the EOF
+    read's: the first frame is read, served and answered inside the
+    accept event (``TcpConn.start``) and observed there alone."""
+    async def run():
+        server = await start_logged_server(io.StringIO())
+        ledger.install_loop_idle(server.collector)
+        loop = asyncio.get_running_loop()
+        try:
+            await asyncio.sleep(0.05)
+            sums, counts, ev, evn = reading(server.collector)
+            assert await loop.run_in_executor(
+                None, tcp_oneshot, server.tcp_port, make_query(
+                    f"{name}.{DOMAIN}", Type.A, qid=77).encode())
+            await settle(server, "tcp-close", 1, counts)
+            now = reading(server.collector)
+            return [grew(n, w) for n, w in zip(now, (sums, counts, ev, evn))]
+        finally:
+            await server.stop()
+
+    sums, counts, ev, evn = ledger.run(run())
+    assert evn["tcp"] == 2 and evn["udp"] == 0, evn
+    assert counts["tcp-recv"] == 2 and counts["tcp-send"] == 1
+    assert counts[served_by] == 1
+    inside = sum(sums.get(s, 0.0) for s in
+                 ledger.TCP_STAGES + ("tcp-register", served_by))
+    assert ev["tcp"] >= inside > 0.0, (ev, sums)
+
+
+@needs_native
+def test_events_hold_their_leaves_and_busy_holds_the_events():
+    """Over a UDP burst and then TCP legs on a real server: each lane's
+    events hold the leaves observed inside them, the events together
+    hold every leaf and per-query stage but ``loop-idle``, and the
+    loop's busy time (wall less ``loop-idle``) holds the events.  A
+    nested event or a leaf outside every event breaks one of these."""
+    async def run():
+        server = await start_logged_server(io.StringIO())
+        ledger.install_loop_idle(server.collector)
+        loop = asyncio.get_running_loop()
+        try:
+            await asyncio.sleep(0.05)
+            base, t0 = reading(server.collector), time.monotonic()
+            assert await loop.run_in_executor(
+                None, drive_burst, server.udp_port, 300, 0.0005) == 300
+            await asyncio.sleep(0.15)   # a turn of the log's flusher
+            mid = reading(server.collector)
+            counts = mid[1]
+            for i in range(LEGS):
+                name = f"nx{i}" if i % 2 else f"h{i}"
+                assert await loop.run_in_executor(
+                    None, tcp_oneshot, server.tcp_port, make_query(
+                        f"{name}.{DOMAIN}", Type.A, qid=300 + i).encode())
+            await settle(server, "tcp-close", LEGS, counts)
+            end, wall = reading(server.collector), time.monotonic() - t0
+            return ([grew(n, w) for n, w in zip(mid, base)],
+                    [grew(n, w) for n, w in zip(end, mid)],
+                    [grew(n, w) for n, w in zip(end, base)], wall)
+        finally:
+            await server.stop()
+
+    udp, tcp, whole, wall = ledger.run(run())
+    # the UDP phase: no TCP event, and the lane holds its leaves
+    sums, counts, ev, evn = udp
+    assert evn["tcp"] == 0 and evn["udp"] >= 1
+    inside = sum(v for k, v in sums.items()
+                 if k != "loop-idle" and k not in OVERLAY)
+    assert ev["udp"] + ev["deferred"] >= inside > 0.0, (ev, sums)
+    assert ev["udp"] >= sums["udp-recv"] + sums["udp-send"]
+    # the TCP phase: no UDP event, two events a one-shot leg
+    sums, counts, ev, evn = tcp
+    assert evn["udp"] == 0 and evn["tcp"] == 2 * LEGS
+    assert ev["tcp"] >= sum(sums[s] for s in ledger.TCP_STAGES) \
+        + sums["tcp-register"]
+    # the whole: busy >= events >= named
+    sums, counts, ev, evn = whole
+    events = sum(ev.values())
+    named = sum(v for k, v in sums.items()
+                if k != "loop-idle" and k not in OVERLAY)
+    busy = wall - sums["loop-idle"]
+    assert busy >= events >= named > 0.0, (busy, events, named)
+
+
+class DropAll:
+    """An RRL that drops (or slips) every UDP packet."""
+    SEND, SLIP, DROP = 0, 1, 2
+
+    def __init__(self, verdict):
+        self.verdict = verdict
+
+    def decide(self, addr):
+        return self.verdict
+
+    def slip_reply(self, data):
+        return data[:2] + b"\x82\x00" + data[4:12]
+
+    def note_tcp(self, addr):
+        pass
+
+
+def ingress_cases():
+    query = make_query(f"nx1.{DOMAIN}", Type.A, qid=5).encode()
+    host = make_query(f"h1.{DOMAIN}", Type.A, qid=6).encode()
+    answer = bytearray(query)
+    answer[2] |= 0x80       # QR=1: not a query
+    return [
+        # id, wire, protocol, fastpath_checked, rrl; what it becomes:
+        # Python-lane queries, answers metered (C's too), wires sent
+        ("python-lane-udp", query, "udp", False, None, 1, 1, 1),
+        ("tcp-frame-the-bulk-serve-missed", query, "tcp", True, None,
+         1, 1, 1),
+        ("tcp-frame-served-by-serve-wire", host, "tcp", False, None,
+         0, 1, 1),
+        ("rrl-drop", query, "udp", False, DropAll(DropAll.DROP), 0, 0, 0),
+        ("rrl-slip", query, "udp", False, DropAll(DropAll.SLIP), 0, 0, 1),
+        ("malformed", b"\x00\x07garbage", "udp", False, None, 0, 0, 1),
+        ("not-a-query", bytes(answer), "udp", False, None, 0, 0, 0),
+    ]
+
+
+@needs_native
+@pytest.mark.parametrize(
+    "wire,protocol,checked,rrl,queries,answers,sends",
+    [c[1:] for c in ingress_cases()], ids=[c[0] for c in ingress_cases()])
+def test_query_ingress_is_observed_once_a_packet_in_handle_raw(
+        wire, protocol, checked, rrl, queries, answers, sends):
+    """Every packet that reaches ``_handle_raw`` observes the leaf once,
+    whether it becomes a query (then the per-query stages follow it) or
+    ends there."""
+    async def run():
+        server = await start_logged_server(io.StringIO())
+        try:
+            if rrl is not None:
+                server.engine.rrl = rrl
+            sums, counts = reading(server.collector)[:2]
+            latency = server.collector.get("binder_request_latency_seconds")
+            was = latency.count({"type": "A"})
+            out = []
+            t0 = time.monotonic()
+            server.engine._handle_raw(wire, ("192.0.2.9", 4000), protocol,
+                                      out.append, fastpath_checked=checked)
+            took = time.monotonic() - t0
+            now = reading(server.collector)[:2]
+            return (grew(now[0], sums), grew(now[1], counts), out, took,
+                    latency.count({"type": "A"}) - was)
+        finally:
+            await server.stop()
+
+    sums, counts, out, took, answered = ledger.run(run())
+    assert counts["query-ingress"] == 1
+    assert len(out) == sends
+    # a query is stamped as before: its stages once each, the request
+    # latency once, and ingress and stages together inside the call
+    assert counts.get("log-after", 0) == queries
+    assert answered == answers
+    assert counts.get("store-lookup", 0) == queries
+    named = sum(v for k, v in sums.items() if k != "loop-idle")
+    assert 0.0 < sums["query-ingress"] <= named <= took
+
+
+@needs_native
+def test_a_datagram_the_drain_answered_observes_no_query_ingress():
+    async def run():
+        server = await start_logged_server(io.StringIO())
+        try:
+            await udp_ask(server.udp_port, f"h2.{DOMAIN}", Type.A, qid=1)
+            counts = stage_sums(server.collector, "count")
+            for i in range(6):
+                r = await udp_ask(server.udp_port, f"h2.{DOMAIN}", Type.A,
+                                  qid=10 + i)
+                assert r.answers
+            r = await udp_ask(server.udp_port, f"nx9.{DOMAIN}", Type.A, qid=9)
+            assert not r.answers
+            return grew(stage_sums(server.collector, "count"), counts)
+        finally:
+            await server.stop()
+
+    counts = ledger.run(run())
+    assert counts["native-serve"] >= 6
+    assert counts["query-ingress"] == 1     # the refused name alone
+
+
+def raw_tcp(port, payload, read=True):
+    """A connection that sends *payload* as it is and stays until the
+    server answers or closes."""
+    with socket.create_connection(("127.0.0.1", port), timeout=2.0) as s:
+        s.sendall(payload)
+        return s.recv(65536) if read else b""
+
+
+def framed(name, qid):
+    wire = make_query(f"{name}.{DOMAIN}", Type.A, qid=qid).encode()
+    return struct.pack(">H", len(wire)) + wire
+
+
+@needs_native
+@pytest.mark.parametrize("payload,registers,closes_itself", [
+    (framed("h4", 41), 1, False),         # a one-shot leg: its reader
+    (framed("h4", 42) + framed("nx4", 43), 1, False),   # two frames, one read
+    (b"\x00\x00", 0, True),               # closed inside its accept
+], ids=["one-shot", "pipelined-burst", "zero-length-frame"])
+def test_tcp_register_counts_the_selector_changes_of_an_open_leg(
+        payload, registers, closes_itself):
+    """``tcp-register`` is the ``add_reader`` of a leg that is still
+    open after its first serve (and a writer's registration after a
+    short write); a connection closed inside its accept event registers
+    nothing, and the unregistrations at the close are ``tcp-close``'s."""
+    async def run():
+        server = await start_logged_server(io.StringIO())
+        loop = asyncio.get_running_loop()
+        try:
+            counts = stage_sums(server.collector, "count")
+            await loop.run_in_executor(None, raw_tcp, server.tcp_port,
+                                       payload, not closes_itself)
+            await settle(server, "tcp-close", 1, counts)
+            return grew(stage_sums(server.collector, "count"), counts)
+        finally:
+            await server.stop()
+
+    counts = ledger.run(run())
+    assert counts["tcp-register"] == registers
+    assert counts["tcp-close"] == 1
+
+
+def test_a_short_write_registers_and_unregisters_its_writer():
+    """A write that goes short arms the writer (one ``tcp-register``)
+    and draining it disarms it (another), both outside ``tcp-close``."""
+    def read_all(sock, want):
+        while want > 0:
+            want -= len(sock.recv(1 << 20))
+
+    async def run():
+        engine = DnsServer(max_tcp_write_buffer=1 << 20)
+        registered = []
+        engine.span_register = registered.append
+        a, b = socket.socketpair()
+        try:
+            a.setblocking(False)
+            a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+            loop = asyncio.get_running_loop()
+            conn = TcpConn(engine, a, ("127.0.0.1", 1), loop)
+            conn.out.append(b"x" * (1 << 19))       # cannot go out whole
+            conn.out_nframes = 1
+            conn._flush()
+            assert conn.writer_on and len(registered) == 1
+            await loop.run_in_executor(None, read_all, b, 1 << 19)
+            for _ in range(400):
+                if not conn.writer_on:
+                    break
+                await asyncio.sleep(0.005)
+            assert not conn.writer_on and len(registered) == 2
+            conn.close()
+            assert len(registered) == 2     # the close's are tcp-close's
+        finally:
+            a.close()
+            b.close()
+
+    asyncio.run(run())
+
+
+def test_process_cpu_seconds_are_read_at_a_scrape_and_nowhere_else(
+        monkeypatch):
+    reads = []
+    real = ledger.os.times
+
+    def times():
+        reads.append(1)
+        return real()
+
+    monkeypatch.setattr(ledger.os, "times", times)
+    collector = MetricsCollector()
+    ledger.install_process_cpu(collector)
+    assert reads == []                      # installing reads nothing
+    counter = collector.get(ledger.METRIC_PROCESS_CPU)
+    first = collector.expose()
+    assert len(reads) == 1
+    user1 = counter.value({"mode": "user"})
+    system1 = counter.value({"mode": "system"})
+    assert validate_exposition(first) == []
+    for mode in ("user", "system"):
+        assert f'binder_process_cpu_seconds_total{{mode="{mode}"}}' in first
+    t0 = time.process_time()
+    while time.process_time() - t0 < 0.05:
+        sum(range(1000))
+    assert len(reads) == 1                  # burning CPU reads nothing
+    collector.expose()
+    assert len(reads) == 2
+    assert counter.value({"mode": "user"}) >= user1 + 0.03
+    assert counter.value({"mode": "system"}) >= system1
+    assert user1 == pytest.approx(real()[0], abs=0.5)
+
+
+@needs_native
+def test_serving_reads_no_process_cpu(monkeypatch):
+    """The CPU counter costs the served path nothing: a burst of
+    queries makes no ``os.times()`` call; the scrape after it makes
+    one."""
+    reads = []
+    real = ledger.os.times
+    monkeypatch.setattr(ledger.os, "times",
+                        lambda: reads.append(1) or real())
+
+    async def run():
+        server = await start_logged_server(io.StringIO())
+        ledger.install_process_cpu(server.collector)
+        try:
+            got = await asyncio.get_running_loop().run_in_executor(
+                None, drive_burst, server.udp_port, 60, 0.0, server.tcp_port)
+            served = len(reads)
+            server.collector.expose()
+            return got, served, len(reads)
+        finally:
+            await server.stop()
+
+    got, served, scraped = ledger.run(run())
+    assert got == 60 and served == 0 and scraped == 1
+
+
+def test_an_event_times_the_whole_callback_and_passes_its_result():
+    collector = MetricsCollector()
+    run = ledger.event(collector, "udp")
+
+    def callback(a, b):
+        time.sleep(0.01)
+        return a + b
+
+    assert run(callback, 2, 3) == 5
+    with pytest.raises(ZeroDivisionError):
+        run(lambda: 1 / 0)                  # observed all the same
+    assert event_sums(collector, "count") == {"udp": 2}
+    assert event_sums(collector)["udp"] >= 0.009

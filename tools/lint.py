@@ -1211,11 +1211,16 @@ _LEDGER_FAMILIES = {
     "binder_query_log_bytes": "counter",
     "binder_query_log_lines": "counter",
     "binder_truncated_renders": "counter",
+    # the event span: the parent of the leaves, a family of its own
+    "binder_loop_event_seconds": "histogram",
 }
 _LEDGER_STAGES = ("loop-idle", "udp-recv", "native-serve", "udp-send",
                   "log-write", "log-line",
-                  "tcp-accept", "tcp-recv", "tcp-send", "tcp-close")
+                  "tcp-accept", "tcp-recv", "tcp-send", "tcp-close",
+                  "tcp-register", "query-ingress")
 _LEDGER_LABELS = {
+    "binder_loop_event_seconds_count": (
+        "lane", ("udp", "tcp", "balancer", "deferred")),
     "binder_udp_datagrams": ("dir", ("in", "out")),
     "binder_answer_cache_hits": ("tier", ("native", "python")),
     "binder_query_log_lines": ("path", ("direct", "logging")),
