@@ -228,11 +228,14 @@ class Introspector:
                     "hit_ratio": 0.0, "invalidations": 0,
                     "expiry_ms": 0.0, "neg_hits": 0,
                     "compiled_entries": 0, "compiled_serves": 0,
-                    "compiled_installs": 0, "type_row_serves": 0}
+                    "compiled_installs": 0, "type_row_serves": 0,
+                    "zone_put_skips": {"size": 0, "bytes": 0}}
         # beside the cache, what never reaches it: the questions the
-        # zone table's type row answered by their type alone
+        # zone table's type row answered by their type alone, and the
+        # entries the zone table refused to hold
         return dict(self.server.answer_cache.stats(),
-                    type_row_serves=self.server.type_row_serves())
+                    type_row_serves=self.server.type_row_serves(),
+                    zone_put_skips=self.server.zone_put_skips())
 
     def _tcp_section(self) -> dict:
         """Stream-lane state (dns/stream.py): live connection table

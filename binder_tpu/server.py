@@ -690,6 +690,19 @@ class BinderServer:
             "binder_zone_type_serves",
             "answers the zone table gave by the question's type alone"
         ).labelled({})
+        # fp_zone_put's refusals of a well-formed entry: the names they
+        # leave to the Python lanes are slower, never wrong, and this
+        # is the one place that says so (runbook "Large zones")
+        zone_put_skips = self.collector.counter(
+            "binder_zone_put_skips",
+            "zone entries the native table refused, by reason: size (a "
+            "body or log fragment above what a message over TCP "
+            "carries), bytes (the table's byte cap)")
+        self._zone_put_skip_children = {
+            reason: zone_put_skips.labelled({"reason": reason})
+            for reason in ("size", "bytes")}
+        for child in self._zone_put_skip_children.values():
+            child.inc(0)                    # both series from the start
         if self._fastpath is not None:
             # Residency gauges: operators watching a mirror fill (or an
             # epoch rebuild) can see the native tables converge.  All
@@ -1395,7 +1408,14 @@ class BinderServer:
         the C side's alien table and are invalidated by its bounded
         scan.  Negative SRV shapes (wrong srvce/proto → NXDOMAIN, SRV on
         a non-service → NODATA+SOA, malformed qnames → REFUSED) are
-        never pushed and keep resolving through Python."""
+        never pushed and keep resolving through Python.
+
+        A set is held whole up to what a message over TCP carries; C
+        holds a datagram to its key's payload at serve time, and counts
+        the set that passes the stream's bound or the table's byte cap
+        (``binder_zone_put_skips``).  Each member's pieces, wire and
+        log summary alike, are rendered once: a rotation variant is a
+        join of them."""
         if not self._zone_suffix_ok(name):
             return
         head = self._zone_service_ttl(node.data)
@@ -1419,8 +1439,7 @@ class BinderServer:
         raw_members = self._zone_service_members(node, ttl)
         if not raw_members:
             return                      # empty set: NOERROR via Python
-        if len(raw_members) > Precompiler.MAX_SET_RECORDS:
-            return      # oversize rotation set: lazy (see precompile.py)
+        dumps = _json.dumps
         members = []
         for knode, ksub, packed, rttl in raw_members:
             # compact members (ksub None) carry no ports key by
@@ -1447,14 +1466,14 @@ class BinderServer:
                                       6 + len(tw))
                         + struct.pack(">HHH", 0, 10, p) + tw)
                 if self._log_ring:
-                    srv_sums.append(self._summarize(SRVRecord(
+                    srv_sums.append(dumps(self._summarize(SRVRecord(
                         name=name, ttl=ttl, priority=0, weight=10,
-                        port=p, target=target)))
+                        port=p, target=target))))
             # summaries rendered only in the logged posture — churn-path
             # zone refreshes in the log-off posture must not pay for them
-            add_sum = (self._summarize(ARecord(
+            add_sum = (dumps(self._summarize(ARecord(
                 name=target, ttl=rttl,
-                address=_socket.inet_ntoa(packed)))
+                address=_socket.inet_ntoa(packed))))
                 if self._log_ring else None)
             add = (tw + b"\x00\x01\x00\x01"
                    + struct.pack(">IH", rttl & 0xFFFFFFFF, 4) + packed)
@@ -1467,25 +1486,24 @@ class BinderServer:
         arcount = len(members)
         if ancount > 0xFFFF:
             return
-        nv = min(len(members), _FP_MAX_VARIANTS)
-        bodies = []
-        for i in range(nv):
-            rot = members[i:] + members[:i]
-            bodies.append(b"".join(m[0] for m in rot)
-                          + b"".join(m[1] for m in rot))
+        rotations = [members[i:] + members[:i]
+                     for i in range(min(len(members), _FP_MAX_VARIANTS))]
+        bodies = [b"".join(m[0] for m in rot) + b"".join(m[1] for m in rot)
+                  for rot in rotations]
         frags = None
         if self._log_ring:
-            ctx = {"query": {"srv": f"{srvce}.{proto}", "name": name,
-                             "type": "SRV"}}
+            # _log_frag's bytes for each variant, from the members'
+            # summaries as rendered above (C holds a fragment to the
+            # stream's bound, as it does the body)
+            head = dumps({"query": {"srv": f"{srvce}.{proto}",
+                                    "name": name, "type": "SRV"},
+                          "rcode": Rcode.name(Rcode.NOERROR)})[1:-1]
             frags = []
-            for i in range(nv):
-                rot = members[i:] + members[:i]
-                frags.append(self._log_frag(
-                    ctx, Rcode.NOERROR,
-                    [s for m in rot for s in m[3]],
-                    [m[4] for m in rot]))
-            if any(f is None for f in frags):
-                return
+            for rot in rotations:
+                ans = ", ".join(s for m in rot for s in m[3])
+                add = ", ".join(m[4] for m in rot)
+                frags.append(f'{head}, "answers": [{ans}], '
+                             f'"additional": [{add}]'.encode())
         try:
             self._zone_put(b"\x00\x21\x00\x01" + qn, ancount, bodies,
                            tag, arcount, frags)
@@ -1697,6 +1715,15 @@ class BinderServer:
         return int(_fastio.fastpath_stats(
             self._fastpath)["zone_type_hits"])
 
+    def zone_put_skips(self) -> dict:
+        """Zone entries the native table has refused since start, by
+        reason: ``/status`` ``answer_cache.zone_put_skips``, read from
+        C as ``binder_zone_put_skips`` is at a scrape."""
+        stats = (_fastio.fastpath_stats(self._fastpath)
+                 if self._zone_enabled else {})
+        return {reason: int(stats.get(f"zone_put_skips_{reason}", 0))
+                for reason in self._zone_put_skip_children}
+
     def _fold_engine_counters(self) -> None:
         # scrapes run on ThreadingHTTPServer threads: fold under the
         # shared lock or two concurrent scrapes double-count the delta
@@ -1734,10 +1761,12 @@ class BinderServer:
             self._fp_last_stats = stats   # shared with residency gauges
             last = self._fp_folded
             # an extension built before a counter has none of it
-            for key, child in (("hits", self._cache_hit_native_child),
-                               ("zone_hits", self._zone_serve_child),
-                               ("zone_type_hits",
-                                self._zone_type_serve_child)):
+            for key, child in (
+                    ("hits", self._cache_hit_native_child),
+                    ("zone_hits", self._zone_serve_child),
+                    ("zone_type_hits", self._zone_type_serve_child),
+                    *((f"zone_put_skips_{reason}", child) for reason, child
+                      in self._zone_put_skip_children.items())):
                 now = stats.get(key, 0)
                 if now > last.get(key, 0):
                     child.inc(now - last.get(key, 0))

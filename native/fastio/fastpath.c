@@ -181,12 +181,14 @@ fastpath_new(PyObject *self, PyObject *args)
 
 /* Borrow (ptr, len) arrays for a per-variant fragment sequence.  On
  * success *fast_out holds the sequence keeping the pointers alive and
- * frag_ptrs/frag_lens are filled for exactly `expect` items.  Returns
- * 1 usable, 0 skip-the-put (wrong count / oversize / empty), -1 with a
- * Python exception set. */
+ * frag_ptrs/frag_lens are filled for exactly `expect` items, none
+ * longer than `max_len` (the bound of the table the entry is for).
+ * Returns 1 usable, 0 skip-the-put (wrong count / empty), -2 the same
+ * for a fragment above `max_len`, -1 with a Python exception set. */
 static int
-fp_load_frags(PyObject *frags, Py_ssize_t expect, PyObject **fast_out,
-              const uint8_t **frag_ptrs, uint16_t *frag_lens)
+fp_load_frags(PyObject *frags, Py_ssize_t expect, Py_ssize_t max_len,
+              PyObject **fast_out, const uint8_t **frag_ptrs,
+              uint16_t *frag_lens)
 {
     *fast_out = NULL;
     if (frags == NULL || frags == Py_None)
@@ -206,9 +208,9 @@ fp_load_frags(PyObject *frags, Py_ssize_t expect, PyObject **fast_out,
             Py_DECREF(fast);
             return -1;
         }
-        if (dlen < 1 || dlen > FP_MAX_FRAG) {
+        if (dlen < 1 || dlen > max_len) {
             Py_DECREF(fast);
-            return 0;               /* unloggable: stays in Python */
+            return dlen < 1 ? 0 : -2;   /* unloggable: stays in Python */
         }
         frag_ptrs[i] = (const uint8_t *)data;
         frag_lens[i] = (uint16_t)dlen;
@@ -277,9 +279,10 @@ fastpath_put(PyObject *self, PyObject *args)
             wire_lens[i] = (uint16_t)dlen;
         }
         int frc = sizes_ok
-            ? fp_load_frags(frags, nw, &frag_fast, frag_ptrs, frag_lens)
+            ? fp_load_frags(frags, nw, FP_MAX_FRAG, &frag_fast, frag_ptrs,
+                            frag_lens)
             : 1;
-        if (frc < 0) {
+        if (frc == -1) {
             Py_DECREF(fast);
             PyBuffer_Release(&keybuf);
             if (tagbuf.obj != NULL)
@@ -353,17 +356,24 @@ fastpath_zone_put(PyObject *self, PyObject *args)
                 PyBuffer_Release(&tagbuf);
                 return NULL;
             }
-            if (dlen < 1 || dlen > FP_MAX_WIRE) {
+            /* what a uint16_t cannot hold is above every bound of
+             * fp_zone_put's, which judges the rest */
+            if (dlen < 1 || dlen > FP_MAX_STREAM_WIRE) {
                 sizes_ok = 0;
+                if (dlen >= 1)
+                    c->zput_skips[FP_ZSKIP_SIZE]++;
                 break;
             }
             body_ptrs[i] = (const uint8_t *)data;
             body_lens[i] = (uint16_t)dlen;
         }
         int frc = sizes_ok
-            ? fp_load_frags(frags, nv, &frag_fast, frag_ptrs, frag_lens)
+            ? fp_load_frags(frags, nv, FP_MAX_STREAM_WIRE, &frag_fast,
+                            frag_ptrs, frag_lens)
             : 1;
-        if (frc < 0) {
+        if (frc == -2)
+            c->zput_skips[FP_ZSKIP_SIZE]++;
+        if (frc == -1) {
             Py_DECREF(fast);
             PyBuffer_Release(&zkeybuf);
             PyBuffer_Release(&tagbuf);
@@ -461,6 +471,8 @@ fastpath_serve_wire(PyObject *self, PyObject *args)
         PyBuffer_Release(&pkt);
         return NULL;
     }
+    /* FP_MAX_WIRE holds whatever this entry serves: a zone serve above
+     * it is FP_VIA_STREAM's alone */
     static uint8_t out[FP_MAX_WIRE];
     uint16_t qtype = 0;
     double t0 = fp_now();
@@ -478,8 +490,8 @@ fastpath_serve_wire(PyObject *self, PyObject *args)
      * here with the cell that reaches this entry (balancer_fronted,
      * PERF.md section 7). */
     size_t wlen = fp_serve_one_lx(c, pkt.buf, (size_t)pkt.len,
-                                  (uint64_t)gen, t0, out, &qtype,
-                                  FP_VIA_UNKNOWN,
+                                  (uint64_t)gen, t0, out, sizeof(out),
+                                  &qtype, FP_VIA_UNKNOWN,
                                   client != NULL ? &src : NULL);
     PyBuffer_Release(&pkt);
     if (wlen == 0)
@@ -551,10 +563,14 @@ fastpath_serve_frames(PyObject *self, PyObject *args)
         uint16_t qtype = 0;
         double t0 = fp_now();
         /* FP_VIA_STREAM: a cached TC wire never replays over TCP, and
-         * the zone table serves the whole set up to the arena slot
-         * reserved above, whatever UDP payload the frame's key holds */
+         * the zone table serves the whole set up to the stream's
+         * ceiling, whatever UDP payload the frame's key holds.  The
+         * slot is what is left of the arena: an empty arena holds three
+         * answers of the stream's bound, and a set longer than what is
+         * left behind the answers before it is a miss (Python's) */
         size_t wlen = fp_serve_one_lx(c, pkt, flen, (uint64_t)gen, t0,
-                                      out + out_used + 2, &qtype,
+                                      out + out_used + 2,
+                                      sizeof(out) - out_used - 2, &qtype,
                                       FP_VIA_STREAM, srcp);
         if (wlen == 0) {
             PyObject *payload = PyBytes_FromStringAndSize(
@@ -710,7 +726,8 @@ fastpath_serve_balancer(PyObject *self, PyObject *args)
              * get here: they surface to Python above), so truncated
              * wires replay exactly as on the direct UDP drain */
             wlen = fp_serve_one_lx(c, pkt, plen, (uint64_t)gen, t0,
-                                   outs[n_hits], &qtype, FP_VIA_DATAGRAM,
+                                   outs[n_hits], sizeof(outs[n_hits]),
+                                   &qtype, FP_VIA_DATAGRAM,
                                    src.client != NULL ? &src : NULL);
         }
         if (wlen == 0) {
@@ -916,7 +933,8 @@ fastpath_drain(PyObject *self, PyObject *args)
             }
         }
         size_t wlen = fp_serve_one_lx(c, pkt, plen, (uint64_t)gen, t0,
-                                      out, &entry_qtype, FP_VIA_DATAGRAM,
+                                      out, sizeof(outs[0]), &entry_qtype,
+                                      FP_VIA_DATAGRAM,
                                       src.client != NULL ? &src : NULL);
         if (wlen == 0) {
             /* miss: surface to Python exactly like recv_batch */
@@ -1157,7 +1175,7 @@ fastpath_stats(PyObject *self, PyObject *args)
         }
     }
     return Py_BuildValue(
-        "{s:K,s:K,s:I,s:K,s:K,s:K,s:K,s:I,s:K,s:K,s:K,s:K,s:N}",
+        "{s:K,s:K,s:I,s:K,s:K,s:K,s:K,s:I,s:K,s:K,s:K,s:K,s:K,s:K,s:N}",
         "hits", (unsigned long long)c->hits,
         "lookups", (unsigned long long)c->lookups,
         "entries", (unsigned)c->n_entries,
@@ -1167,6 +1185,10 @@ fastpath_stats(PyObject *self, PyObject *args)
         "zone_type_hits", (unsigned long long)c->zone_type_hits,
         "zone_entries", (unsigned)(c->zmain.n + c->zalien.n),
         "zone_bytes", (unsigned long long)c->ztotal_bytes,
+        "zone_put_skips_size",
+        (unsigned long long)c->zput_skips[FP_ZSKIP_SIZE],
+        "zone_put_skips_bytes",
+        (unsigned long long)c->zput_skips[FP_ZSKIP_BYTES],
         "log_lines", (unsigned long long)c->lr.lines,
         "log_declines", (unsigned long long)c->lr.declines,
         "log_pending", (unsigned long long)c->lr.len,

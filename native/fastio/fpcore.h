@@ -22,9 +22,22 @@
 
 #include "../common/dnskey.h"
 
+#ifdef __cplusplus
+#define FP_STATIC_ASSERT(cond, why) static_assert(cond, why)
+#else
+#define FP_STATIC_ASSERT(cond, why) _Static_assert(cond, why)
+#endif
+
 #define FP_MAX_VARIANTS 8
 #define FP_PROBE 8
-#define FP_MAX_WIRE 4096          /* larger responses stay in Python */
+#define FP_MAX_WIRE 4096          /* a datagram's ceiling, and an answer-
+                                   * cache wire's: larger stay in Python */
+/* What only a stream can carry: a DNS message over TCP has a 16-bit
+ * length in front of it (RFC 1035 4.2.2) and no other ceiling.  The
+ * zone table holds every set such a message can carry; a serve above
+ * FP_MAX_WIRE is reachable through FP_VIA_STREAM alone, so no datagram
+ * buffer is larger than FP_MAX_WIRE. */
+#define FP_MAX_STREAM_WIRE 65535
 #define FP_MAX_KEY DNSKEY_MAX
 #define FP_MAX_QTYPES 16
 #define FP_MAX_BUCKETS 24
@@ -52,7 +65,10 @@
  * never serve-and-drop the line.  Logged-posture serving degrades to
  * the slow path under pressure; it never loses log records.
  */
-#define FP_MAX_FRAG 4096          /* per-variant pre-rendered fragment */
+#define FP_MAX_FRAG 4096          /* per-variant pre-rendered fragment of
+                                   * an answer-cache entry or the type
+                                   * row; a zone entry's is held to
+                                   * FP_MAX_STREAM_WIRE like its body */
 #define FP_LOG_PREFIX_MAX 512    /* constant line head from Python */
 #define FP_LOG_OVERHEAD 256      /* time+id+client+port+latency+glue */
 
@@ -204,6 +220,7 @@ typedef struct {
     fp_ztab_t zmain;          /* tag == qname: O(1) invalidation */
     fp_ztab_t zalien;         /* tag != qname: scan invalidation */
     uint64_t ztotal_bytes;
+    uint64_t zput_skips[2];   /* fp_zone_put's refusals, by FP_ZSKIP_* */
     uint64_t zone_hits;
     fp_typerow_t trow;
     uint64_t zone_type_hits;  /* the subset of zone_hits the row gave */
@@ -579,7 +596,17 @@ fp_put_raw(fp_cache_t *c, const uint8_t *key, size_t keylen,
 
 #define FP_ZONE_MIN_SLOTS 1024
 #define FP_ZONE_MAX_SLOTS (1u << 24)
-#define FP_ZONE_MAX_BYTES (256u << 20)
+#define FP_ZONE_MAX_BYTES (512u << 20)
+
+/* Why fp_zone_put refused an entry that was well formed: the names it
+ * leaves to the Python lanes are slower, never wrong, and nothing else
+ * says that they are. */
+#define FP_ZSKIP_SIZE 0     /* a body or fragment above its bound */
+#define FP_ZSKIP_BYTES 1    /* the table's byte cap */
+
+FP_STATIC_ASSERT(FP_MAX_WIRE <= FP_MAX_STREAM_WIRE
+                 && FP_MAX_STREAM_WIRE <= UINT16_MAX,
+                 "a zone entry's lengths are uint16_t");
 
 /* Grow (or create) a zone slot table so a put can always find a free
  * probe slot at <=50% load.  Every live entry MUST stay findable
@@ -685,7 +712,13 @@ fp_ztab_find(fp_ztab_t *t, const uint8_t *zkey, size_t zklen)
  * target A records) are included at the tail of each body.  Routes to
  * zmain when the tag is the entry's own qname with a directly-probed
  * qtype/class (O(1) invalidation), zalien otherwise (scan).
- * Returns 1 stored, 0 skipped, -1 OOM.
+ * A body is admitted up to what a stream can carry (FP_MAX_STREAM_WIRE
+ * less the header, the question and the OPT echo, so that a stored
+ * entry serves every posture of a frame); its fragment's uint16_t
+ * length is the same bound.  What a datagram may carry of it is
+ * fp_zone_serve's to say.
+ * Returns 1 stored, 0 skipped (counted in zput_skips where the entry
+ * was well formed and a bound refused it), -1 OOM.
  */
 static inline int
 fp_zone_put(fp_cache_t *c, const uint8_t *zkey, size_t zklen,
@@ -700,20 +733,29 @@ fp_zone_put(fp_cache_t *c, const uint8_t *zkey, size_t zklen,
         return 0;                   /* uninvalidatable: never stale-safe */
     if (nv < 1 || nv > FP_MAX_VARIANTS || ancount == 0)
         return 0;
+    /* header + question (the key less type and class is the qname)
+     * + type and class + OPT echo */
+    size_t max_body = FP_MAX_STREAM_WIRE
+        - (12 + (zklen - 4) + 4 + sizeof(fp_opt_echo));
     uint64_t add = 0;
     for (int i = 0; i < nv; i++) {
-        if (body_lens[i] == 0 || body_lens[i] > FP_MAX_WIRE)
+        if (body_lens[i] == 0)
             return 0;
+        if (body_lens[i] > max_body) {
+            c->zput_skips[FP_ZSKIP_SIZE]++;
+            return 0;
+        }
         if (frags != NULL) {
-            if (frags[i] == NULL || frag_lens[i] == 0
-                    || frag_lens[i] > FP_MAX_FRAG)
+            if (frags[i] == NULL || frag_lens[i] == 0)
                 return 0;           /* unloggable: stays in Python */
             add += frag_lens[i];
         }
         add += body_lens[i];
     }
-    if (c->ztotal_bytes + add > FP_ZONE_MAX_BYTES)
+    if (c->ztotal_bytes + add > FP_ZONE_MAX_BYTES) {
+        c->zput_skips[FP_ZSKIP_BYTES]++;
         return 0;
+    }
 
     /* Table routing must be a function of the KEY alone (the serve
      * path has only the key): (A|PTR, IN) keys live in zmain — where
@@ -919,20 +961,24 @@ fp_invalidate_tag(fp_cache_t *c, const uint8_t *tag, size_t taglen)
                              * wire declines, so the serve is right on
                              * both transports */
 #define FP_VIA_STREAM 2     /* TCP (fastpath_serve_frames): a frame has
-                             * no UDP ceiling and is never truncated */
+                             * the stream's ceiling, FP_MAX_STREAM_WIRE,
+                             * and is never truncated */
 
 /*
  * Serve one packet from the zone table: assemble header + question echo
  * (original case) + precompiled body + optional OPT echo.  `key` is the
- * full dnskey (RD/EDNS/payload in its lead bytes), `out` must hold
- * FP_MAX_WIRE.  Returns response length, or 0 to decline to Python
- * (miss, stale generation, or would-truncate: over the key's payload,
- * except for a stream frame, whose only ceiling is FP_MAX_WIRE).
+ * full dnskey (RD/EDNS/payload in its lead bytes), `out` holds `room`
+ * bytes.  The ceiling is the transport's: the key's payload and at most
+ * FP_MAX_WIRE for a datagram and for a caller that does not know, the
+ * stream's own for a frame; and never more than `room`.  Returns
+ * response length, or 0 to decline to Python (miss, stale generation,
+ * or over the ceiling: would-truncate, or an entry only a stream
+ * carries).
  */
 static inline size_t
 fp_zone_serve(fp_cache_t *c, const uint8_t *pkt, const uint8_t *key,
               size_t keylen, size_t qn_len, uint64_t gen, uint8_t *out,
-              uint16_t *qtype_out, double now, int via,
+              size_t room, uint16_t *qtype_out, double now, int via,
               const fp_logsrc_t *src)
 {
     /* table routing mirrors fp_zone_put exactly: (A|PTR, IN) keys can
@@ -951,16 +997,22 @@ fp_zone_serve(fp_cache_t *c, const uint8_t *pkt, const uint8_t *key,
     }
     int rd = key[0] & 1;
     int edns = key[0] & 2;
-    size_t ceiling = ((size_t)key[1] << 8) | key[2];
-    if (via == FP_VIA_STREAM || ceiling > FP_MAX_WIRE)
-        ceiling = FP_MAX_WIRE;
+    size_t ceiling = FP_MAX_STREAM_WIRE;
+    if (via != FP_VIA_STREAM) {
+        ceiling = ((size_t)key[1] << 8) | key[2];
+        if (ceiling > FP_MAX_WIRE)
+            ceiling = FP_MAX_WIRE;
+    }
+    if (ceiling > room)
+        ceiling = room;
 
     uint8_t v = e->next_variant;
     size_t blen = e->body_lens[v];
     size_t total = 12 + qn_len + 4 + blen + (edns ? sizeof(fp_opt_echo) : 0);
     if (total > ceiling)
-        /* truncation semantics: Python (BEFORE rotation: the entry's
-         * next stream serve takes the variant this one left) */
+        /* truncation semantics, or a set no datagram carries: Python
+         * (BEFORE rotation and log accounting: the entry's next stream
+         * serve takes the variant this one left) */
         return 0;
     if (c->lr.enabled) {
         /* logged posture: a serve whose log line cannot be produced
@@ -1097,8 +1149,10 @@ fp_type_serve(fp_cache_t *c, const uint8_t *pkt, const uint8_t *key,
 /*
  * Serve one packet from the cache: key build, lookup (with lazy gen/TTL
  * invalidation), variant rotation, id + 0x20 question patching.  `out`
- * must hold FP_MAX_WIRE bytes.  Returns the response length on hit, 0 on
- * miss (the caller surfaces the packet to the slow path).
+ * holds `room` bytes, FP_MAX_WIRE at least (what an answer-cache wire
+ * and the type row's answer may take; only a zone serve over a stream
+ * uses more).  Returns the response length on hit, 0 on miss (the
+ * caller surfaces the packet to the slow path).
  *
  * A question whose type the installed type row declines is answered
  * from the row ahead of both probes, for every caller alike.
@@ -1108,14 +1162,15 @@ fp_type_serve(fp_cache_t *c, const uint8_t *pkt, const uint8_t *key,
  * and is right for a datagram only.  The socket-free entry that cannot
  * know its caller's transport (fastpath_serve_wire) declines it; a
  * stream frame (fastpath_serve_frames) passes it over and asks the zone
- * table, which holds the whole set and serves it up to FP_MAX_WIRE.
+ * table, which holds the whole set and serves it up to the stream's
+ * ceiling.
  * Either happens BEFORE hit accounting and rotation, so a passed-over
  * entry neither inflates the folded cache-hit counter nor burns a
  * rotation step.
  */
 static inline size_t
 fp_serve_one_lx(fp_cache_t *c, const uint8_t *pkt, size_t plen,
-                uint64_t gen, double now, uint8_t *out,
+                uint64_t gen, double now, uint8_t *out, size_t room,
                 uint16_t *qtype_out, int via,
                 const fp_logsrc_t *src)
 {
@@ -1146,7 +1201,7 @@ fp_serve_one_lx(fp_cache_t *c, const uint8_t *pkt, size_t plen,
          * stream): a precompiled zone answer still serves it natively,
          * the first query for a name included */
         return fp_zone_serve(c, pkt, key, keylen, qn_len, gen, out,
-                             qtype_out, now, via, src);
+                             room, qtype_out, now, via, src);
 
     /* hit: copy the variant, patch id + the client's question bytes
      * (same length by construction — key match implies identical
@@ -1183,13 +1238,13 @@ fp_serve_one_lx(fp_cache_t *c, const uint8_t *pkt, size_t plen,
 }
 
 /* drain-path spelling, log off: TC wires serve (a UDP requester asked
- * for them) */
+ * for them); `out` holds FP_MAX_WIRE */
 static inline size_t
 fp_serve_one(fp_cache_t *c, const uint8_t *pkt, size_t plen, uint64_t gen,
              double now, uint8_t *out, uint16_t *qtype_out)
 {
-    return fp_serve_one_lx(c, pkt, plen, gen, now, out, qtype_out,
-                           FP_VIA_DATAGRAM, NULL);
+    return fp_serve_one_lx(c, pkt, plen, gen, now, out, FP_MAX_WIRE,
+                           qtype_out, FP_VIA_DATAGRAM, NULL);
 }
 
 #endif /* BINDER_FPCORE_H */

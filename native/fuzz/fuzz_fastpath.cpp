@@ -120,7 +120,7 @@ void serve_declined(const uint8_t *q, size_t qlen, uint16_t qtype,
     uint64_t hits = fz_c->hits;
     uint16_t got_qtype = 0;
     size_t wlen = fp_serve_one_lx(fz_c, q, qlen, fz_gen, fz_clock, out,
-                                  &got_qtype, steer % 3,
+                                  sizeof(out), &got_qtype, steer % 3,
                                   with_src ? &src : nullptr);
     assert(got_qtype == qtype && fz_c->hits == hits);
     if (ring && (!with_src || !had_room)) {
@@ -218,13 +218,20 @@ static void fuzz_step(const uint8_t *data, size_t len) {
 
         int nv = 1 + (int)(len > 5 ? data[5] % FP_MAX_VARIANTS : 0);
         uint16_t ancount = (uint16_t)(1 + (len > 6 ? data[6] % 3 : 0));
-        static uint8_t body_store[FP_MAX_VARIANTS][FP_MAX_WIRE];
+        static uint8_t body_store[FP_MAX_VARIANTS][FP_MAX_STREAM_WIRE];
         const uint8_t *bodies[FP_MAX_VARIANTS];
         uint16_t blens[FP_MAX_VARIANTS];
+        /* one input in four holds what only a stream carries: bodies
+         * up to and above the stream's bound (fp_zone_put's to refuse) */
+        unsigned scale = len > 2 && data[2] % 4 == 0 ? 29u : 1u;
+        size_t max_body = FP_MAX_STREAM_WIRE
+            - (12 + qn_len + 4 + sizeof(fp_opt_echo));
+        int above = 0;
         for (int i = 0; i < nv; i++) {
             size_t bl = 1 + (len > (size_t)(7 + i)
-                             ? data[7 + i] * 9u : 16u);
-            if (bl > FP_MAX_WIRE) bl = FP_MAX_WIRE;
+                             ? data[7 + i] * 9u : 16u) * scale;
+            if (bl > FP_MAX_STREAM_WIRE) bl = FP_MAX_STREAM_WIRE;
+            above |= bl > max_body;
             for (size_t b = 0; b < bl; b++)
                 body_store[i][b] = (uint8_t)(b * 17 + d0 + i);
             bodies[i] = body_store[i];
@@ -246,6 +253,7 @@ static void fuzz_step(const uint8_t *data, size_t len) {
             zflens[i] = (uint16_t)(sizeof(zfrag) - 1);
         }
         int ring = fz_c->lr.enabled;
+        uint64_t size_skips = fz_c->zput_skips[FP_ZSKIP_SIZE];
         int rc = fp_zone_put(fz_c, key + 3, klen - 3, fz_gen, ancount,
                              arcount, bodies, blens, nv,
                              alien ? fz_alien_tag : tag,
@@ -253,6 +261,10 @@ static void fuzz_step(const uint8_t *data, size_t len) {
                              ring ? zfrags : nullptr,
                              ring ? zflens : nullptr);
         assert(rc >= 0);
+        /* a body above the stream's bound is in no table, and counted */
+        assert(fz_c->zput_skips[FP_ZSKIP_SIZE] == size_skips + (above != 0));
+        if (above)
+            assert(rc == 0);
 
         if (rc == 1) {
             uint16_t got_qtype = 0;
@@ -261,28 +273,34 @@ static void fuzz_step(const uint8_t *data, size_t len) {
             int had_room = !ring
                 || fp_log_room(fz_c, sizeof(zfrag) - 1);
             size_t wlen = fp_serve_one_lx(fz_c, q, qlen, fz_gen,
-                                          fz_clock, out, &got_qtype,
-                                          FP_VIA_DATAGRAM,
+                                          fz_clock, out, sizeof(out),
+                                          &got_qtype, FP_VIA_DATAGRAM,
                                           ring ? &zsrc : nullptr);
+            /* a datagram never yields more than its buffer */
+            assert(wlen <= FP_MAX_WIRE);
             if (ring && wlen > 0)
                 assert(fz_c->lr.lines == lines_before + 1);
             size_t want = 12 + qn_len + 4 + blens[0];
             if (want > DNSKEY_CLASSIC_PAYLOAD) {
                 /* would truncate: must decline to the slow path, and
                  * leave the rotation where it was; the same bytes as a
-                 * stream frame have no UDP ceiling, and FP_MAX_WIRE
-                 * and the ring's room alone decide */
+                 * stream frame have the stream's ceiling (which every
+                 * stored entry is under), and the ring's room alone
+                 * decides */
                 assert(wlen == 0);
                 assert(fz_c->lr.lines == lines_before);
+                static uint8_t sout[FP_MAX_STREAM_WIRE];
                 size_t slen = fp_serve_one_lx(fz_c, q, qlen, fz_gen,
-                                              fz_clock, out, &got_qtype,
+                                              fz_clock, sout,
+                                              sizeof(sout), &got_qtype,
                                               FP_VIA_STREAM,
                                               ring ? &zsrc : nullptr);
-                if (want > FP_MAX_WIRE || !had_room) {
+                assert(want <= FP_MAX_STREAM_WIRE);
+                if (!had_room) {
                     assert(slen == 0);
                 } else {
                     assert(slen == want);
-                    assert(memcmp(out + 12 + qn_len + 4, bodies[0],
+                    assert(memcmp(sout + 12 + qn_len + 4, bodies[0],
                                   blens[0]) == 0);
                     if (ring)
                         assert(fz_c->lr.lines == lines_before + 1);
@@ -408,8 +426,8 @@ static void fuzz_step(const uint8_t *data, size_t len) {
             int had_room = !ring
                 || fp_log_room(fz_c, sizeof(cfrag) - 1);
             size_t wlen = fp_serve_one_lx(fz_c, q, qlen, fz_gen,
-                                          fz_clock, out, &got_qtype,
-                                          FP_VIA_DATAGRAM,
+                                          fz_clock, out, sizeof(out),
+                                          &got_qtype, FP_VIA_DATAGRAM,
                                           ring ? &csrc : nullptr);
             if (ring && !had_room) {
                 assert(wlen == 0);      /* backpressure decline */

@@ -649,7 +649,8 @@ class TestSnapshotValidator:
                              "neg_hits": 0, "compiled_entries": 0,
                              "compiled_serves": 0,
                              "compiled_installs": 0,
-                             "type_row_serves": 0},
+                             "type_row_serves": 0,
+                             "zone_put_skips": {"size": 0, "bytes": 0}},
             "inflight": {"count": 0, "queries": []},
             "tcp": {"open_conns": 0, "max_conns": 1024,
                     "idle_timeout_seconds": 30.0,

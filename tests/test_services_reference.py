@@ -25,8 +25,9 @@ section) on an answer to a question that had one, none otherwise.
 The sizes: 6/7 is where 512 bytes run out, 8/9 the edge between the
 deployment's ``small`` and ``medium`` classes, 16/17 where 1232 bytes run
 out, 32/33 the precompiler's 64 *records* for an SRV set with glue
-(``Precompiler.MAX_SET_RECORDS``), 64/65 the zone table's 64 *members*,
-250 the deployment's largest set.
+(``Precompiler.MAX_SET_RECORDS``), 64/65 what was the zone table's
+64-*member* rule until ISSUE 39 (its SRV entries now hold every set a
+TCP message carries), 250 the deployment's largest set.
 """
 import asyncio
 import os

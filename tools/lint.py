@@ -466,6 +466,7 @@ _SNAPSHOT_SCHEMA = {
         "compiled_serves": (int, False),
         "compiled_installs": (int, False),
         "type_row_serves": (int, False),
+        "zone_put_skips": (dict, False),
     },
     "inflight": {
         "count": (int, False), "queries": (list, False),
@@ -1208,6 +1209,8 @@ _LEDGER_FAMILIES = {
     # those the type row's (benchmark: type_declined_native_share)
     "binder_zone_serves": "counter",
     "binder_zone_type_serves": "counter",
+    # what the zone table refused to hold (runbook "Large zones")
+    "binder_zone_put_skips": "counter",
     "binder_query_log_bytes": "counter",
     "binder_query_log_lines": "counter",
     "binder_truncated_renders": "counter",
@@ -1224,6 +1227,7 @@ _LEDGER_LABELS = {
     "binder_udp_datagrams": ("dir", ("in", "out")),
     "binder_answer_cache_hits": ("tier", ("native", "python")),
     "binder_query_log_lines": ("path", ("direct", "logging")),
+    "binder_zone_put_skips": ("reason", ("size", "bytes")),
 }
 
 
