@@ -593,6 +593,15 @@ class DnsServer:
         self.late_drop_counter = None   # metrics child or None
         self._late_drop_event_last = 0.0
         self.LATE_DROP_EVENT_WINDOW_S = 1.0
+        # the batched UDP reader's own accounts, folded at the scrape:
+        # drains made with no select before them (_UDP_CHAIN_MIN;
+        # binder_udp_chained_drains_total), and the Python lanes'
+        # answers that a send buffer still full at the retry cost
+        # (binder_udp_send_drops_total{lane="python"}; the C lanes
+        # keep their own count, io_stats "send_drops")
+        self.udp_chained_drains = 0
+        self.udp_send_drops = 0
+        self._send_drop_event_last = 0.0
         self.on_query: Optional[Callable] = None   # async (QueryCtx) -> None
         self.on_after: Optional[Callable] = None   # sync  (QueryCtx) -> None
         self._udp_socks: List[tuple] = []   # (loop, socket)
@@ -617,8 +626,9 @@ class DnsServer:
         # The query log's writer (installed by BinderServer where lines
         # are rendered ahead of their write: the native ring's and the
         # Python lanes' direct ones); a lane calls it once a readiness
-        # event, after the batch's responses are sent, so one stream
-        # write carries a whole batch of lines (_flush_log).
+        # event (the UDP lane once a drain, of which an event holds one
+        # or a chain), after the batch's responses are sent, so one
+        # stream write carries a whole batch of lines (_flush_log).
         self.log_flush: Optional[Callable[[], None]] = None
         # True while a lane callback runs that ends in _flush_log: a
         # line rendered meanwhile is left to it
@@ -815,9 +825,9 @@ class DnsServer:
             return None
 
     def _flush_log(self) -> None:
-        """The end of a lane's readiness callback: write the query-log
-        lines it produced, the native ring's and the Python lanes'
-        alike, in one write."""
+        """The end of a lane's readiness callback, and of every drain
+        of a UDP one: write the query-log lines it produced, the native
+        ring's and the Python lanes' alike, in one write."""
         self.log_flush_owed = False
         flush = self.log_flush
         if flush is not None:
@@ -936,6 +946,17 @@ class DnsServer:
     # Packets drained per readiness callback: bounds event-loop
     # starvation of timers/TCP under sustained UDP flood.
     _UDP_BURST = 128
+    # A drain (the socket read until a recvmmsg brings fewer than 64,
+    # the answers sent, their log lines written) that brought this many
+    # datagrams or more is followed by the next in the same callback,
+    # with no select between them: a socket that filled while one batch
+    # was served has most likely filled again, and the queries in it
+    # would otherwise wait out a trip through the loop.  One datagram is
+    # what a woken, otherwise idle loop finds, and the recvmmsg that
+    # would find nothing behind it costs a crossing.  _UDP_BURST bounds
+    # the whole chain: a callback starts no recvmmsg once it has taken
+    # that many datagrams.
+    _UDP_CHAIN_MIN = 2
 
     async def listen_udp(self, address: str, port: int,
                          announce: bool = True,
@@ -966,11 +987,14 @@ class DnsServer:
         # hijack concern above does not apply).
         if reuse_port:
             sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEPORT, 1)
-        # absorb bursts while the event loop is busy with other work
-        try:
-            sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 1 << 20)
-        except OSError:
-            pass
+        # absorb bursts while the event loop is busy with other work,
+        # and hold a whole callback's answers on the way out: up to
+        # _UDP_BURST datagrams of up to 1,232 bytes leave back to back
+        for opt in (socket.SO_RCVBUF, socket.SO_SNDBUF):
+            try:
+                sock.setsockopt(socket.SOL_SOCKET, opt, 1 << 20)
+            except OSError:
+                pass
         sock.setblocking(False)
         sock.bind((address, port))
 
@@ -1065,6 +1089,28 @@ class DnsServer:
                 self.recorder.record("udp-late-drop", dropped=n,
                                      total=self.udp_late_drops)
 
+    def note_send_drops(self, lane: str, n: int) -> None:
+        """Account answers of a UDP drain dropped at a send buffer that
+        was still full at the one retry
+        (binder_udp_send_drops_total{lane}): the Python lanes' are
+        tallied here and folded at the scrape, the C lanes' are counted
+        where they are dropped (``io_stats`` "send_drops"); either way a
+        debug line and a rate-limited flight event, like
+        ``note_late_drops``."""
+        if n <= 0:
+            return
+        if lane == "python":
+            self.udp_send_drops += n
+        self.log.debug("dropped %d UDP responses of the %s lane "
+                       "(send buffer full)", n, lane)
+        if self.recorder is not None:
+            now = time.monotonic()
+            if (now - self._send_drop_event_last
+                    >= self.LATE_DROP_EVENT_WINDOW_S):
+                self._send_drop_event_last = now
+                self.recorder.record("udp-send-drop", lane=lane,
+                                     dropped=n)
+
     def _batched_udp_reader(self, sock: socket.socket) -> Callable[[], None]:
         """recvmmsg/sendmmsg datapath (native/fastio/fastio.c).
 
@@ -1074,7 +1120,14 @@ class DnsServer:
         overhead is the throughput floor, and batching roughly halves it.
         Responses produced synchronously during the drain are flushed as
         one sendmmsg; responses that arrive later (the recursion path) fall
-        back to plain sendto."""
+        back to plain sendto.
+
+        A readiness callback holds one *drain* or a chain of them
+        (``_UDP_CHAIN_MIN``).  Every drain keeps the order of a lone
+        one: its answers leave before its log lines are written, and
+        its lines are written before the next ``recvmmsg``.  The gate,
+        the generation and the limiter's sampling tick are a drain's,
+        not a callback's."""
         handle_raw = self._handle_raw
         recv_batch = _fastio.recv_batch
         send_batch = _fastio.send_batch
@@ -1083,12 +1136,13 @@ class DnsServer:
         fd = sock.fileno()
         log = self.log
         burst = self._UDP_BURST
+        chain_min = self._UDP_CHAIN_MIN
         batch_out: List[Optional[list]] = [None]  # non-None while draining
         # RRL duty-cycle sampling tick (see ResponseRateLimiter): a
         # cache-hit flood served entirely inside fastpath_drain would
         # never reach rrl.decide() to trip hot(), so while the gate is
-        # open every Nth readiness event drains through Python with
-        # decide() charging N tokens per sampled packet
+        # open every Nth drain goes through Python with decide()
+        # charging N tokens per sampled packet
         rrl_tick = [0]
         # Late (async-completed) responses — the recursion path — are
         # coalesced per event-loop pass into one sendmmsg instead of a
@@ -1122,42 +1176,57 @@ class DnsServer:
                     return
             late_out.append((wire, addr))
 
-        def on_readable() -> None:
+        def drain(drained: int) -> int:
+            """One drain, whole: the gate and the limiter's tick, the
+            socket read until a ``recvmmsg`` brings fewer than 64 (or
+            the callback, which had ``drained`` before, has its
+            ``_UDP_BURST``), the misses through Python and their
+            answers in one ``sendmmsg``, then the drain's log lines.
+            Returns the datagrams it brought, or -1 where the callback
+            has to end here: the socket failed, or a send was short."""
             out: list = []
             batch_out[0] = out
             self.log_flush_owed = True
-            # fast path on/off is decided once per readiness event — the
-            # gate (query-log / probe state) can flip at runtime
+            # fast path on/off is decided once per drain — the gate
+            # (query-log / probe state) can flip at runtime, and a
+            # sampled drain can trip the limiter's hot()
             fp = self.fastpath
             use_fp = (fp is not None and fp_drain is not None
                       and (self.fastpath_gate is None
                            or self.fastpath_gate()))
             fp_gen = self.fastpath_gen
             rrl = self.rrl
+            tick = -1
             if rrl is not None:
                 rrl.sample_cost = 1.0
                 if use_fp:
-                    rrl_tick[0] += 1
-                    if rrl_tick[0] >= rrl.FASTPATH_SAMPLE_EVERY:
-                        rrl_tick[0] = 0
+                    tick = rrl_tick[0] + 1
+                    if tick >= rrl.FASTPATH_SAMPLE_EVERY:
+                        tick = 0
                         use_fp = False
                         rrl.sample_cost = float(rrl.FASTPATH_SAMPLE_EVERY)
+            got = 0
+            short = False
             try:
-                drained = 0
-                while drained < burst:
+                while drained + got < burst:
                     served = 0
                     try:
                         if use_fp:
-                            msgs, served = fp_drain(
+                            msgs, served, retried, dropped = fp_drain(
                                 fp, fd, fp_gen() if fp_gen else 0, 64)
+                            if retried:
+                                short = True
+                                self.note_send_drops("native", dropped)
                         else:
                             msgs = recv_batch(fd, 64)
                     except OSError as e:
                         log.error("UDP socket error: %s", e)
+                        short = True
                         break
-                    if not msgs and not served:
+                    brought = len(msgs) + served
+                    if not brought:
                         break
-                    drained += len(msgs) + served
+                    got += brought
                     for data, addr in msgs:
                         def send(wire: bytes, _addr=addr) -> None:
                             cur = batch_out[0]
@@ -1174,12 +1243,20 @@ class DnsServer:
                             # flush of other clients' responses
                             log.exception("unhandled error processing "
                                           "packet from %s", addr)
-                    if len(msgs) + served < 64:
+                    if brought < 64 or short:
                         break
             finally:
                 # flush in finally so responses already produced are
                 # never lost to an unexpected escape above
                 batch_out[0] = None
+                if tick >= 0:
+                    if got:
+                        rrl_tick[0] = tick
+                    else:
+                        # the limiter's eighth is of the drains that
+                        # brought datagrams: an empty one leaves the
+                        # tick and the cost as it found them
+                        rrl.sample_cost = 1.0
                 if out:
                     try:
                         sent = send_batch(fd, out)
@@ -1187,17 +1264,27 @@ class DnsServer:
                             # socket buffer full: one retry, then drop
                             # (UDP clients retransmit; blocking here
                             # would stall the event loop for every other
-                            # client)
+                            # client) and count
+                            short = True
                             sent += send_batch(fd, out[sent:])
-                            if sent < len(out):
-                                log.debug("dropped %d UDP responses "
-                                          "(send buffer full)",
-                                          len(out) - sent)
+                            self.note_send_drops("python", len(out) - sent)
                     except OSError as e:
                         log.error("batched UDP send failed: %s", e)
+                        short = True
                 # after the batch's responses: no answer waits behind
                 # a log write, and the batch's lines share one
                 self._flush_log()
+            return -1 if short else got
+
+        def on_readable() -> None:
+            # the drains of one socket are chained for as long as the
+            # drains themselves say that the socket is being fed, and
+            # the send buffer takes what they answer
+            drained = got = drain(0)
+            while got >= chain_min and drained < burst:
+                self.udp_chained_drains += 1
+                got = drain(drained)
+                drained += got
 
         return on_readable
 

@@ -40,7 +40,8 @@ Mechanics, mirroring `AdmissionControl`'s house style:
   in C would never reach `decide()` to trip ``hot()`` in the first
   place.  The batched UDP reader therefore **duty-cycle samples**
   while the gate is open: every ``FASTPATH_SAMPLE_EVERY``-th
-  readiness event drains through Python with ``sample_cost`` set to
+  drain that brought datagrams (a readiness event holds one drain or a
+  chain of them) goes through Python with ``sample_cost`` set to
   the sampling factor, so each sampled packet charges its prefix what
   the unsampled stream would have.  A flooded prefix overdraws within
   a bucket-burst of sampled traffic → ``hot()`` → gate shut → full
@@ -108,8 +109,8 @@ class ResponseRateLimiter:
     #: long enough to hold the fastpath gate shut across flood bursts,
     #: short enough that the gate reopens promptly once the flood ends
     HOT_HOLD_S = 2.0
-    #: while the fastpath gate is open, 1 in this many UDP readiness
-    #: events surfaces to Python so the limiter samples the C-served
+    #: while the fastpath gate is open, 1 in this many UDP drains
+    #: surfaces to Python so the limiter samples the C-served
     #: stream (each sampled packet charged this many tokens)
     FASTPATH_SAMPLE_EVERY = 8
     #: adapted-prefix records tracked at once — entries exist only for
@@ -171,7 +172,7 @@ class ResponseRateLimiter:
         self._hot_until = 0.0
         self._flood_event_last = 0.0
         #: tokens one decide() charges; the batched UDP reader raises
-        #: it to FASTPATH_SAMPLE_EVERY during sampled drain events so
+        #: it to FASTPATH_SAMPLE_EVERY during sampled drains so
         #: the sampled stream approximates the true per-prefix rate
         self.sample_cost = 1.0
         # fold-ready plain-int counters (scrape-time fold pattern)

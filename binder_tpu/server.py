@@ -518,6 +518,32 @@ class BinderServer:
             "socket send buffer stayed full through the retry").labelled()
         late_drops.inc(0)                # series exists from scrape 1
         self.engine.late_drop_counter = late_drops
+        # answers of a UDP drain dropped the same way, by the lane that
+        # sent them: the C lanes count their own (io_stats
+        # "send_drops"), the engine the Python lanes'; folded at the
+        # scrape like every such counter
+        send_drops = self.collector.counter(
+            "binder_udp_send_drops_total",
+            "UDP answers dropped because the socket send buffer was "
+            "still full at the one retry, by the lane that sent them")
+        self._send_drop_children = {
+            lane: send_drops.labelled({"lane": lane})
+            for lane in ("native", "python", "balancer")}
+        for child in self._send_drop_children.values():
+            child.inc(0)                 # series exist from scrape 1
+        # the C lanes' counts are the process's: what they held before
+        # this server was made is not its own
+        self._send_drops_folded = dict(self.udp_send_drops(), python=0)
+        # drains the batched UDP reader chained behind another in one
+        # readiness callback, with no select between them
+        # (DnsServer._UDP_CHAIN_MIN)
+        self._chained_child = self.collector.counter(
+            "binder_udp_chained_drains_total",
+            "UDP drains (the socket read empty, the answers sent, their "
+            "log lines written) made in the callback of the drain "
+            "before them, with no select between").labelled()
+        self._chained_child.inc(0)       # series exists from scrape 1
+        self._chained_folded = 0
         # stream-lane counters (dns/stream.py TcpStats), folded at
         # scrape time like the cap refusals; every series exists from
         # scrape 1 so absence is always an exporter bug
@@ -610,7 +636,7 @@ class BinderServer:
         # prefix rendered once: by C into a byte ring, one complete
         # bunyan-style line per native serve, and by _on_after into
         # _log_pending for a Python-lane query.  Ring and pending lines
-        # go out in ONE stream write a readiness event, after the
+        # go out in ONE stream write a drain, after the
         # batch's responses, onto the same stream the JSON logger
         # writes to.  Before the ring the fast path stood down
         # completely under logging, forfeiting ~9x throughput.  A
@@ -1732,6 +1758,18 @@ class BinderServer:
             if delta > 0:
                 self._cap_refusal_child.inc(delta)
                 self._cap_folded += delta
+            delta = self.engine.udp_chained_drains - self._chained_folded
+            if delta > 0:
+                self._chained_child.inc(delta)
+                self._chained_folded += delta
+            # a C count that stepped back (a test's io_stats(True))
+            # restarts its baseline, like the ledger's
+            drops = self.udp_send_drops()
+            for lane, child in self._send_drop_children.items():
+                delta = drops[lane] - self._send_drops_folded[lane]
+                if delta > 0:
+                    child.inc(delta)
+            self._send_drops_folded = drops
             snap = self.engine.tcp_stats.snapshot()
             folded = self._tcp_stats_folded
             for field, child in self._tcp_stat_children.items():
@@ -1747,6 +1785,16 @@ class BinderServer:
                     if d > 0:
                         child.inc(d)
                         rfolded[field] = val
+
+    def udp_send_drops(self) -> dict:
+        """UDP answers dropped at a send buffer still full at the retry,
+        by lane: the C lanes' from ``io_stats`` (process-wide, like the
+        rest of it), the Python lanes' from the engine."""
+        drops = {"native": 0, "python": self.engine.udp_send_drops,
+                 "balancer": 0}
+        if self._io_folds:
+            drops.update(_fastio.io_stats().get("send_drops", {}))
+        return drops
 
     def _fold_fastpath_metrics(self) -> None:
         """Fold the C fast path's monotonic counters into the Prometheus
@@ -1833,8 +1881,10 @@ class BinderServer:
                    {"path": "direct"})),
                "log_bytes": int(self._log_bytes.value()),
                "recv_calls": 0, "recv_empty": 0, "recv_datagrams": 0,
+               "recv_chained": self.engine.udp_chained_drains,
                "recv_batch_cells": [0] * (len(UDP_BATCH_BUCKETS) + 1),
-               "send_calls": 0, "send_datagrams": 0}
+               "send_calls": 0, "send_datagrams": 0,
+               "send_drops": self.udp_send_drops()}
         if self._io_folds:
             io = _fastio.io_stats()
             out.update(
@@ -1993,7 +2043,8 @@ class BinderServer:
     def _write_log(self) -> None:
         """The query log's one writer: the native ring's complete lines
         and the pending Python-lane lines, in one write per handler.
-        Called once a readiness event by the lane that served it, in
+        Called once a readiness event (the UDP lane: once a drain, of
+        which an event holds one or a chain) by the lane that served it, in
         its ``finally`` after the responses are sent
         (``DnsServer._flush_log``), by ``_log_owed``'s ``call_soon``,
         by a record on its way through ``logging``, and by the

@@ -14,8 +14,9 @@
  *                             empty list when the socket would block
  *   send_batch(fd, msgs)   -> int processed count; msgs is a sequence of
  *                             (bytes payload, addr) where addr is
- *                             (host, port) or, for IPv6, optionally
- *                             (host, port, flowinfo, scope_id).
+ *                             (host, port), for IPv6 optionally
+ *                             (host, port, flowinfo, scope_id), or
+ *                             None on a connected socket.
  *                             Per-destination errors (EHOSTUNREACH,
  *                             EPERM, ...) skip that one datagram and
  *                             continue — one unreachable client must not
@@ -96,6 +97,10 @@ tuple_to_addr(PyObject *addr, struct sockaddr_storage *ss, socklen_t *len)
     unsigned port;
     unsigned flowinfo = 0, scope_id = 0;
 
+    if (addr == Py_None) {      /* a connected socket's own peer */
+        *len = 0;
+        return 0;
+    }
     if (!PyTuple_Check(addr)) {
         PyErr_SetString(PyExc_TypeError,
                         "address must be (host, port[, flowinfo, scope_id])");
@@ -243,7 +248,7 @@ fastio_send_batch(PyObject *self, PyObject *args)
             iovs[n].iov_len = (size_t)dlen;
             msgs[n].msg_hdr.msg_iov = &iovs[n];
             msgs[n].msg_hdr.msg_iovlen = 1;
-            msgs[n].msg_hdr.msg_name = &addrs[n];
+            msgs[n].msg_hdr.msg_name = alen ? &addrs[n] : NULL;
             msgs[n].msg_hdr.msg_namelen = alen;
         }
 
@@ -351,11 +356,14 @@ fastio_io_stats(PyObject *self, PyObject *args)
         return NULL;
     }
     PyObject *d = Py_BuildValue(
-        "{s:K,s:K,s:K,s:K,s:N,s:N}",
+        "{s:K,s:K,s:K,s:K,s:{s:K,s:K},s:N,s:N}",
         "recv_calls", fastio_io.recv_calls,
         "recv_msgs", fastio_io.recv_msgs,
         "send_calls", fastio_io.send_calls,
         "send_msgs", fastio_io.send_msgs,
+        "send_drops",
+        "native", fastio_io.send_drops[FASTIO_LANE_NATIVE],
+        "balancer", fastio_io.send_drops[FASTIO_LANE_BALANCER],
         "recv_cells", cells,
         "spans", spans);
     if (d == NULL)
@@ -416,8 +424,9 @@ static PyMethodDef fastio_methods[] = {
      "send_batch(fd, msgs) -> int sent"},
     {"io_stats", fastio_io_stats, METH_VARARGS,
      "io_stats(reset=False) -> dict of process-wide batched-I/O "
-     "counters (recvmmsg/sendmmsg calls, messages, the recvmmsg "
-     "batch-size log2 histogram) and the time ledger's spans "
+     "counters (recvmmsg/sendmmsg calls, messages, the answers the C "
+     "lanes dropped at a full send buffer, the recvmmsg batch-size "
+     "log2 histogram) and the time ledger's spans "
      "(udp-recv, native-serve, udp-send: sum, count, cells)"},
     {"io_span_grid", fastio_io_span_grid, METH_VARARGS,
      "io_span_grid(buckets) -> None; the stage histogram's upper "
@@ -445,7 +454,9 @@ static PyMethodDef fastio_methods[] = {
      "(balancer-owned) fd via sendmmsg with explicit msg_name, and "
      "surface everything else as raw frames for the Python lane"},
     {"fastpath_drain", fastpath_drain, METH_VARARGS,
-     "fastpath_drain(cache, fd, gen, max_n=64) -> (misses, served)"},
+     "fastpath_drain(cache, fd, gen, max_n=64) -> (misses, served, "
+     "retried, dropped): the answers a full send buffer made wait for "
+     "the one retry, and those it still refused"},
     {"fastpath_stats", fastpath_stats, METH_VARARGS,
      "fastpath_stats(cache) -> dict"},
     {"fastpath_clear", fastpath_clear, METH_VARARGS,

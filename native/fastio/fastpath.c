@@ -641,8 +641,12 @@ bal_flush(int fd, struct mmsghdr *omsgs, int n_hits)
         }
         if (errno == EINTR)
             continue;
-        if (errno == EAGAIN || errno == EWOULDBLOCK)
-            return 0;            /* buffer full: drop rest (UDP) */
+        if (errno == EAGAIN || errno == EWOULDBLOCK) {
+            /* buffer full: drop the rest (UDP), and say how many */
+            fastio_io.send_drops[FASTIO_LANE_BALANCER] +=
+                (unsigned long long)(n_hits - off);
+            return 0;
+        }
         if (errno == EBADF || errno == ENOTSOCK || errno == EFAULT ||
             errno == ENOMEM)
             return errno;        /* fatal: caller drops direct mode */
@@ -884,8 +888,7 @@ fastpath_drain(PyObject *self, PyObject *args)
             PyObject *empty = PyList_New(0);
             if (empty == NULL)
                 return NULL;
-            PyObject *r = Py_BuildValue("(Ni)", empty, 0);
-            return r;
+            return Py_BuildValue("(Niii)", empty, 0, 0, 0);
         }
         return PyErr_SetFromErrno(PyExc_OSError);
     }
@@ -972,8 +975,12 @@ fastpath_drain(PyObject *self, PyObject *args)
 
     /* flush hits; per-destination errors skip one datagram and continue
      * (same policy as send_batch — one unreachable client must not drop
-     * other clients' responses) */
-    int off = 0;
+     * other clients' responses).  A full send buffer gets the rest one
+     * more try, as the Python lanes give theirs; what is still left is
+     * dropped (UDP clients retransmit, and blocking here would stall
+     * every other client), counted, and reported with the retry: the
+     * caller sends no more on this socket before the loop has turned */
+    int off = 0, retried = 0, dropped = 0;
     while (off < n_hits) {
         double t_send = t_sent;     /* where the last span ended */
         int sent = sendmmsg(fd, omsgs + off, (unsigned)(n_hits - off),
@@ -989,8 +996,16 @@ fastpath_drain(PyObject *self, PyObject *args)
         }
         if (errno == EINTR)
             continue;
-        if (errno == EAGAIN || errno == EWOULDBLOCK)
-            break;                      /* buffer full: drop rest (UDP) */
+        if (errno == EAGAIN || errno == EWOULDBLOCK) {
+            if (retried == 0) {
+                retried = n_hits - off;
+                continue;
+            }
+            dropped = n_hits - off;
+            fastio_io.send_drops[FASTIO_LANE_NATIVE] +=
+                (unsigned long long)dropped;
+            break;
+        }
         if (errno == EBADF || errno == ENOTSOCK || errno == EFAULT ||
             errno == ENOMEM) {
             Py_DECREF(misses);
@@ -1015,7 +1030,7 @@ fastpath_drain(PyObject *self, PyObject *args)
         }
     }
 
-    return Py_BuildValue("(Ni)", misses, n_hits);
+    return Py_BuildValue("(Niii)", misses, n_hits, retried, dropped);
 }
 
 PyObject *

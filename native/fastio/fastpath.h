@@ -46,12 +46,23 @@ typedef struct {
     unsigned long long cells[FASTIO_SPAN_MAX_BUCKETS + 1];
 } fastio_span_t;
 
+/* The C lanes that send UDP answers themselves and so can lose one to
+ * a full send buffer (binder_udp_send_drops_total{lane}; the Python
+ * lanes' send_batch reports its count and its caller keeps the tally) */
+enum {
+    FASTIO_LANE_NATIVE = 0,     /* fastpath_drain */
+    FASTIO_LANE_BALANCER,       /* fastpath_serve_balancer */
+    FASTIO_N_LANES
+};
+
 typedef struct {
     unsigned long long recv_calls;   /* recvmmsg calls that returned >0 */
     unsigned long long recv_msgs;
     unsigned long long recv_cells[FASTIO_IO_CELLS];
     unsigned long long send_calls;   /* sendmmsg calls that sent >0 */
     unsigned long long send_msgs;
+    /* answers a lane gave up on: the send buffer was still full */
+    unsigned long long send_drops[FASTIO_N_LANES];
     fastio_span_t spans[FASTIO_N_SPANS];
 } fastio_io_t;
 extern fastio_io_t fastio_io;

@@ -214,7 +214,8 @@ def test_udp_recv_counts_an_eagain_call_of_the_drain():
     sock = bound_udp()
     try:
         recv, serve = span("udp-recv"), span("native-serve")
-        assert fastio.fastpath_drain(cache, sock.fileno(), 1, 64) == ([], 0)
+        assert fastio.fastpath_drain(cache, sock.fileno(), 1, 64) == (
+            [], 0, 0, 0)
         assert span("udp-recv")["count"] == recv["count"] + 1
         # no datagram, no batch: the serve loop did not run
         assert span("native-serve")["count"] == serve["count"]

@@ -144,7 +144,7 @@ def drain_one(cache, pkt):
     try:
         cli.sendto(pkt, ("127.0.0.1", port))
         for _ in range(200):
-            misses, hits = fastio.fastpath_drain(cache, srv.fileno(), GEN)
+            misses, hits = fastio.fastpath_drain(cache, srv.fileno(), GEN)[:2]
             if hits or misses:
                 break
         return (cli.recvfrom(65535)[0] if hits else None), misses

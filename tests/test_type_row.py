@@ -117,7 +117,7 @@ def serve(cache, lane, pkt, logged=False):
     try:
         cli.sendto(pkt, ("127.0.0.1", port))
         for _ in range(200):
-            misses, hits = fastio.fastpath_drain(cache, srv.fileno(), GEN)
+            misses, hits = fastio.fastpath_drain(cache, srv.fileno(), GEN)[:2]
             if hits or misses:
                 break
         assert [m[0] for m in misses] == ([] if hits else [pkt])

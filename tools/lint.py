@@ -488,11 +488,16 @@ _SNAPSHOT_SCHEMA = {
 # the counts behind the time ledger's socket and log stages
 _IO_SCHEMA = {
     "recv_calls": (int, False), "recv_empty": (int, False),
+    "recv_chained": (int, False),
     "recv_datagrams": (int, False), "recv_batch_cells": (list, False),
     "send_calls": (int, False), "send_datagrams": (int, False),
+    "send_drops": (dict, False),
     "log_writes": (int, False), "log_lines": (int, False),
     "log_lines_direct": (int, False), "log_bytes": (int, False),
 }
+#: who can lose a UDP answer to a full send buffer
+#: (binder_udp_send_drops_total{lane}, /status io.send_drops)
+_SEND_DROP_LANES = ("native", "python", "balancer")
 _SESSION_STATES = ("never-connected", "connected", "degraded", "expired",
                    "closed")
 _INFLIGHT_KEYS = ("trace", "name", "type", "client", "protocol",
@@ -588,6 +593,12 @@ def validate_status_snapshot(snap):
     io = snap.get("io")
     if isinstance(io, dict):
         _check_keys(io, _IO_SCHEMA, "io", errs)
+        drops = io.get("send_drops")
+        if isinstance(drops, dict):
+            for lane in _SEND_DROP_LANES:
+                if not isinstance(drops.get(lane), int):
+                    errs.append(f"io.send_drops: lane {lane!r} missing "
+                                f"or not an int")
     fr = snap.get("flight_recorder")
     if isinstance(fr, dict):
         for key in ("capacity", "recorded", "dropped", "by_type",
@@ -1204,6 +1215,12 @@ _LEDGER_FAMILIES = {
     "binder_query_stage_seconds": "histogram",
     "binder_udp_datagrams": "counter",
     "binder_udp_batch_size": "histogram",
+    # drains made with no select before them (benchmark:
+    # udp_chained_share), and the answers a full send buffer cost, by
+    # the lane that sent them (benchmark: udp_send_drops; runbook
+    # "Performance notes")
+    "binder_udp_chained_drains_total": "counter",
+    "binder_udp_send_drops_total": "counter",
     "binder_answer_cache_hits": "counter",
     # the native serves that are neither: the zone table's, and of
     # those the type row's (benchmark: type_declined_native_share)
@@ -1225,6 +1242,7 @@ _LEDGER_LABELS = {
     "binder_loop_event_seconds_count": (
         "lane", ("udp", "tcp", "balancer", "deferred")),
     "binder_udp_datagrams": ("dir", ("in", "out")),
+    "binder_udp_send_drops_total": ("lane", _SEND_DROP_LANES),
     "binder_answer_cache_hits": ("tier", ("native", "python")),
     "binder_query_log_lines": ("path", ("direct", "logging")),
     "binder_zone_put_skips": ("reason", ("size", "bytes")),
@@ -1234,8 +1252,8 @@ _LEDGER_LABELS = {
 def validate_ledger_metrics(text):
     """Validate that a Prometheus exposition carries the time ledger:
     the families with their TYPEs, every leaf stage as a series of the
-    stage histogram, and the pinned ``dir`` / ``tier`` / ``path`` label
-    values.
+    stage histogram, and the pinned ``dir`` / ``tier`` / ``path`` /
+    ``lane`` label values.
     Returns error strings; empty == valid."""
     errs = list(validate_exposition(text))
     types = {}
