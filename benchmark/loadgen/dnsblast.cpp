@@ -44,7 +44,18 @@
  *     and a window without segments does per answer what it did plus one
  *     branch, its one histogram the whole; -s names a file that gets the
  *     window's first due time (CLOCK_MONOTONIC, nanoseconds) as soon as it
- *     is fixed, so that the harness can place an event inside the window.
+ *     is fixed, so that the harness can place an event inside the window;
+ *   - every sender thread compares each of its loop's own clock reads (the
+ *     top of a pass, after each open-loop send, after a pass's events) with
+ *     the one before and keeps the intervals of 50 ms or more (`gaps_ns` in
+ *     the JSON, a list a thread, offsets from the window's first due time;
+ *     warm-up and the wait after the window included): a sender never
+ *     sleeps that long, so all threads at once stood still only if the
+ *     machine did (stats.py machine_stops); -f gets every measured query
+ *     that failed or was unanswered at the end, with its due time and kind,
+ *     and -n the due time of every measured send, written only where some
+ *     thread saw a gap (8 bytes a send), so that the harness can leave the
+ *     queries a stop of the machine covers out of its counts.
  *
  * Files:
  *   -t templates: repeated [u16 BE wire length][u8 expected rcode]
@@ -56,6 +67,11 @@
  *                 boundaries of its segments (n cuts, n + 1 segments)
  *   -s start:     out; the window's first due time, decimal nanoseconds on
  *                 CLOCK_MONOTONIC, written once the start is fixed
+ *   -f failures:  out; repeated [i64 LE due time, ns from the window's first
+ *                 due time][i64 LE kind: index into "fail_kinds"]
+ *   -n sends:     out, only where "gaps_ns" names a gap (the caller removes
+ *                 an earlier run's); i64 LE due times as above, a thread's
+ *                 after another's
  *   -c captures:  out; repeated [u32 LE sequence position][u32 LE
  *                 template][u8 came over TCP][u16 LE length][answer wire]
  * Output (-o file, else stdout): one JSON object.
@@ -79,6 +95,7 @@
 #include <atomic>
 #include <string>
 #include <thread>
+#include <utility>
 #include <vector>
 
 namespace {
@@ -89,6 +106,7 @@ constexpr uint32_t kCaptureFlag = 0x80000000u;
 constexpr int kClosedSlots = 4;       /* in flight per socket, closed loop */
 constexpr int kOpenSlots = 64;        /* in flight per socket, open loop */
 constexpr size_t kCaptureCap = 8192;  /* answers kept, over all threads */
+constexpr int64_t kGapNs = 50000000;  /* between two clock reads: a gap */
 
 int64_t now_ns() {
     struct timespec ts;
@@ -186,6 +204,8 @@ enum Fail { F_TIMEOUT, F_RCODE, F_ANCOUNT, F_TCP, F_SEND, F_OVERFLOW,
             F_KINDS };
 const char *kFailNames[F_KINDS] = {"timeout", "rcode", "ancount", "tcp",
                                    "send", "overflow"};
+/* in the failures file, a query still out when its thread ended */
+constexpr int64_t kUnanswered = F_KINDS;
 
 struct Slot {
     bool in_flight = false;
@@ -223,6 +243,12 @@ struct Stats {
     std::vector<std::vector<uint32_t>> lat_segment;
     std::vector<uint64_t> failed_segment;
     std::vector<uint32_t> inflight_samples;
+    /* [start, end) between two clock reads kGapNs apart or more */
+    std::vector<std::pair<int64_t, int64_t>> gaps;
+    /* (due time, kind) of every measured query that failed, and the due
+     * time of every measured send; from the window's first due time */
+    std::vector<std::pair<int64_t, int64_t>> failures;
+    std::vector<int64_t> sends;
     double cpu_user = 0, cpu_sys = 0;
     Stats(size_t entries, size_t cuts)
         : lat(kHistSize, 0), late(kHistSize, 0),
@@ -278,6 +304,11 @@ class Worker {
         const int64_t t_meas = cfg_.t0 + cfg_.warm_ns;
         const int64_t t_end = t_meas + cfg_.window_ns;
         while (now_ns() < cfg_.t0) usleep(200);
+        /* room for every send now: no reallocation inside the window */
+        stats.sends.reserve(cfg_.open_loop
+                            ? cfg_.arrivals->size() / (size_t)cfg_.threads + 1
+                            : (size_t)1 << 20);
+        last_tick_ = now_ns();
         struct rusage ru0, ru1;
         bool ru_started = false, ru_done = false;
         int64_t last_sweep = cfg_.t0, last_sample = t_meas;
@@ -287,7 +318,7 @@ class Worker {
                 send_next(now_ns(), now_ns(), t_meas, t_end);
         struct epoll_event evs[256];
         for (;;) {
-            int64_t now = now_ns();
+            int64_t now = tick(now_ns());
             if (!ru_started && now >= t_meas) {
                 getrusage(RUSAGE_THREAD, &ru0);
                 ru_started = true;
@@ -317,7 +348,7 @@ class Worker {
                  * does not wait for the rest of them */
                 if (cfg_.open_loop) send_due(k, t_meas, t_end);
             }
-            now = now_ns();
+            now = tick(now_ns());
             if (now - last_sweep >= 50000000LL) {
                 last_sweep = now;
                 sweep(now, t_meas, t_end);
@@ -331,6 +362,11 @@ class Worker {
                                  || now >= t_end + cfg_.timeout_ns + 100000000LL))
                 break;
         }
+        for (const Sock &sk : socks_)
+            for (const Slot &sl : sk.slots)
+                if (sl.in_flight && sl.measured)
+                    stats.failures.emplace_back(sl.ref_ns - t_meas,
+                                                kUnanswered);
         if (!ru_done) getrusage(RUSAGE_THREAD, &ru1);
         stats.cpu_user = tv_s(ru1.ru_utime) - tv_s(ru0.ru_utime);
         stats.cpu_sys = tv_s(ru1.ru_stime) - tv_s(ru0.ru_stime);
@@ -351,6 +387,23 @@ class Worker {
         std::vector<int> free_slots;
     };
 
+    /* one compare a clock read of the loop: a sender that did not get
+     * from one read to the next in kGapNs stood still meanwhile */
+    int64_t tick(int64_t now) {
+        if (now - last_tick_ >= kGapNs)
+            stats.gaps.emplace_back(last_tick_, now);
+        last_tick_ = now;
+        return now;
+    }
+
+    /* a measured query failed: by kind, by segment, and for the harness */
+    void count_failed(int fail, int64_t into) {
+        stats.fails[fail]++;
+        if (!stats.failed_segment.empty())
+            stats.failed_segment[segment_of(into)]++;
+        stats.failures.emplace_back(into, (int64_t)fail);
+    }
+
     static struct sockaddr_in source_addr(const char *addr) {
         struct sockaddr_in src;
         memset(&src, 0, sizeof(src));
@@ -363,13 +416,13 @@ class Worker {
     /* open loop: every query whose time has come, from arrival k on */
     void send_due(uint64_t &k, int64_t t_meas, int64_t t_end) {
         const std::vector<uint64_t> &arr = *cfg_.arrivals;
-        int64_t now = now_ns();
+        int64_t now = tick(now_ns());
         while (k < arr.size() && cfg_.t0 + (int64_t)arr[k] <= now) {
             int64_t due = cfg_.t0 + (int64_t)arr[k];
             if (due >= t_end) return;
             send_query((uint32_t)k, due, now, t_meas);
             k += (uint64_t)cfg_.threads;
-            now = now_ns();
+            now = tick(now_ns());
         }
         if (k >= arr.size()) bail("arrivals ran out in the window");
     }
@@ -387,16 +440,15 @@ class Worker {
         const std::vector<uint32_t> &seq = *cfg_.sequence;
         uint32_t entry = seq[pos % seq.size()];
         bool measured = ref >= t_meas;
-        if (measured) stats.sent++;
+        if (measured) {
+            stats.sent++;
+            stats.sends.push_back(ref - t_meas);
+        }
         /* the next source socket in turn that has a slot free */
         size_t s = rr_++ % socks_.size();
         for (size_t tries = 1; socks_[s].free_slots.empty(); tries++) {
             if (tries == socks_.size()) {
-                if (measured) {
-                    stats.fails[F_OVERFLOW]++;
-                    if (!stats.failed_segment.empty())
-                        stats.failed_segment[segment_of(ref - t_meas)]++;
-                }
+                if (measured) count_failed(F_OVERFLOW, ref - t_meas);
                 return;
             }
             s = rr_++ % socks_.size();
@@ -445,9 +497,7 @@ class Worker {
                 if (!stats.lat_segment.empty())
                     stats.lat_segment[segment_of(sl.ref_ns - t_meas)][bucket]++;
             } else {
-                stats.fails[fail]++;
-                if (!stats.failed_segment.empty())
-                    stats.failed_segment[segment_of(sl.ref_ns - t_meas)]++;
+                count_failed(fail, sl.ref_ns - t_meas);
             }
         }
         sl.in_flight = false;
@@ -654,6 +704,7 @@ class Worker {
     std::string sendbuf_;
     size_t rr_ = 0;
     long in_flight_ = 0;
+    int64_t last_tick_ = 0;
 };
 
 void print_hist(FILE *f, const char *name,
@@ -675,12 +726,13 @@ int main(int argc, char **argv) {
     const char *tmpl_path = nullptr, *seq_path = nullptr;
     const char *arr_path = nullptr, *cap_path = nullptr;
     const char *out_path = nullptr, *start_path = nullptr;
+    const char *fail_path = nullptr, *sends_path = nullptr;
     int port = 0;
     double seconds = 10.0, warm = 0.0, timeout = 1.0;
     Config cfg;
 
     int c;
-    while ((c = getopt(argc, argv, "H:p:t:q:a:c:o:d:W:T:C:S:j:Rg:s:")) != -1) {
+    while ((c = getopt(argc, argv, "H:p:t:q:a:c:o:d:W:T:C:S:j:Rg:s:f:n:")) != -1) {
         switch (c) {
         case 'H': host = optarg; break;
         case 'p': port = atoi(optarg); break;
@@ -697,6 +749,8 @@ int main(int argc, char **argv) {
         case 'j': cfg.threads = atoi(optarg); break;
         case 'R': cfg.tc_retry = true; break;
         case 's': start_path = optarg; break;
+        case 'f': fail_path = optarg; break;
+        case 'n': sends_path = optarg; break;
         case 'g':
             for (const char *p = optarg; *p != '\0';) {
                 char *end;
@@ -715,7 +769,7 @@ int main(int argc, char **argv) {
                     "-d seconds [-W warm] [-T timeout] [-S sources] "
                     "[-C callers] [-j threads] [-a arrivals] "
                     "[-R] [-g cut,cut,...] [-s start file] [-c captures] "
-                    "[-o out.json] [-H host]\n");
+                    "[-f failures] [-n sends] [-o out.json] [-H host]\n");
             return 2;
         }
     }
@@ -820,6 +874,25 @@ int main(int argc, char **argv) {
         if (fclose(f) != 0) die("write captures");
     }
 
+    if (fail_path != nullptr) {
+        FILE *f = fopen(fail_path, "wb");
+        if (f == nullptr) die(fail_path);
+        for (Worker *w : workers)
+            fwrite(w->stats.failures.data(), sizeof(w->stats.failures[0]),
+                   w->stats.failures.size(), f);
+        if (fclose(f) != 0) die("write failures");
+    }
+    bool any_gap = false;
+    for (Worker *w : workers) any_gap = any_gap || !w->stats.gaps.empty();
+    if (sends_path != nullptr && any_gap) {
+        FILE *f = fopen(sends_path, "wb");
+        if (f == nullptr) die(sends_path);
+        for (Worker *w : workers)
+            fwrite(w->stats.sends.data(), sizeof(int64_t),
+                   w->stats.sends.size(), f);
+        if (fclose(f) != 0) die("write sends");
+    }
+
     FILE *out = out_path != nullptr ? fopen(out_path, "w") : stdout;
     if (out == nullptr) die(out_path);
     uint64_t failed = 0;
@@ -838,7 +911,22 @@ int main(int argc, char **argv) {
     for (int k = 0; k < F_KINDS; k++)
         fprintf(out, "%s\"%s\": %" PRIu64, k ? ", " : "", kFailNames[k],
                 total.fails[k]);
-    fprintf(out, "}, \"thread_cpu_s\": [");
+    /* the kinds' names in the failures file's numbering, and each thread's
+     * gaps as [start, length], ns from the window's first due time */
+    fprintf(out, "}, \"fail_kinds\": [");
+    for (int k = 0; k < F_KINDS; k++) fprintf(out, "\"%s\", ", kFailNames[k]);
+    fprintf(out, "\"unanswered_at_end\"], \"gap_least_ns\": %" PRId64
+            ", \"gaps_ns\": [", kGapNs);
+    for (size_t t = 0; t < workers.size(); t++) {
+        fprintf(out, "%s[", t ? ", " : "");
+        const auto &gaps = workers[t]->stats.gaps;
+        for (size_t i = 0; i < gaps.size(); i++)
+            fprintf(out, "%s[%" PRId64 ", %" PRId64 "]", i ? ", " : "",
+                    gaps[i].first - cfg.t0 - cfg.warm_ns,
+                    gaps[i].second - gaps[i].first);
+        fprintf(out, "]");
+    }
+    fprintf(out, "], \"thread_cpu_s\": [");
     for (size_t t = 0; t < workers.size(); t++)
         fprintf(out, "%s[%.6f, %.6f]", t ? ", " : "",
                 workers[t]->stats.cpu_user, workers[t]->stats.cpu_sys);

@@ -19,9 +19,11 @@ window, and where the workload names ``events`` each is delivered at its
 offset inside it (a SIGHUP to the supervisor rolls every shard: the run then
 waits for the roll's end and takes the group's new workers for what
 follows); the answers it kept are compared with the reference, the sample
-and the written names are asked once more; SIGTERM must end the group with
-exit 0 and no orphan of any generation.  The last line of stdout is the
-result object.
+and the written names are asked once more; the queries that a stop of the
+machine covers (every sender thread of the generator stood still at once,
+for a quarter second or more) leave ``attempted`` and ``failed``; SIGTERM
+must end the group with exit 0 and no orphan of any generation.  The last
+line of stdout is the result object.
 
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file found by name: ``configs/<name>.json``,
@@ -43,6 +45,8 @@ import threading
 import time
 import urllib.request
 
+import numpy as np
+
 T_START = time.monotonic()
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -61,8 +65,16 @@ ROLL_TIMEOUT_S = 120.0      # after the window, for a roll's last line
 ASK_SOURCE = "127.0.1.1"
 ASKS = 96                   # seeded sample asked before and after the window
 GENERATOR = os.path.join(HERE, "loadgen", "build", "dnsblast")
+#: what the generator writes, under the run's ``out`` directory, by flag
+GENERATOR_OUT = {"-o": "generator.json", "-c": "captures.bin",
+                 "-f": "failures.bin", "-n": "sends.bin",
+                 "-s": "window_start"}
 BREAKS = ("reference-address", "reference-declined", "reference-opt",
           "fixture-address", "skew-replica")
+#: the two controls of the stop rule (``Stop``): no wrong run, ``correct``
+#: is untouched; what they move is ``stops``, ``voided`` and ``failed``
+STOP_BREAKS = ("machine-stop", "server-stop")
+STOP_BREAK_S = 1.5
 
 
 def fail(phase: str, why: str) -> None:
@@ -522,12 +534,14 @@ def stage_seconds(before: dict, after: dict) -> list:
 
 
 def generator_argv(workload: dict, files: dict, udp: int, seconds: float,
-                   captures: str, gen_out: str) -> list:
+                   gen_files: dict) -> list:
     argv = [GENERATOR, "-p", str(udp), "-d", str(seconds),
             "-W", str(workload["warm_s"]), "-T", str(workload["timeout_s"]),
             "-S", str(workload["sources"]),
             "-C", str(workload.get("callers", workload["threads"])),
-            "-j", str(workload["threads"]), "-c", captures, "-o", gen_out]
+            "-j", str(workload["threads"])]
+    for flag, path in gen_files.items():
+        argv += [flag, path]
     if workload.get("tc_retry"):
         argv.append("-R")
     if workload.get("segments_at_s"):
@@ -538,6 +552,45 @@ def generator_argv(workload: dict, files: dict, udp: int, seconds: float,
     return argv
 
 
+def account_for_stops(g: dict, workload: dict, gen_files: dict) -> None:
+    """Name the stops of the machine the generator lived through and leave
+    the queries they cover out of the counts (``stats.py``, README.md "A
+    stop of the machine").  Into the generator's object go ``stops``
+    (``[start, length]`` in seconds from the window's first due time, every
+    one of the generator's ``gap_least_ns`` or more), ``voided``
+    (``queries``, ``of_them_failed``), ``attempted``, ``failed_by_kind``
+    (what is left after voiding; their sum is the run's ``failed``), and
+    each segment's ``failed`` becomes what is left of it.  The histograms,
+    ``sent``, ``failed`` and ``fails`` stay the generator's own."""
+    stops = stats.machine_stops(g["gaps_ns"], g["gap_least_ns"])
+    spans = stats.covered_spans(stops, int(float(workload["timeout_s"])
+                                           * 1e9), g["gap_least_ns"])
+    failures = np.fromfile(gen_files["-f"], dtype="<i8").reshape(-1, 2)
+    if len(failures) != g["failed"] + g["unanswered_at_end"]:
+        fail("window", f"{len(failures)} failures on file for the "
+             f"generator's {g['failed']} + {g['unanswered_at_end']}")
+    sends = []
+    if spans:
+        # (the generator writes its sends' due times where a thread saw a
+        # gap, and only a gap of every thread makes a span)
+        sends = np.fromfile(gen_files["-n"], dtype="<i8")
+        if len(sends) != g["sent"]:
+            fail("window", f"{len(sends)} sends on file for the "
+                 f"generator's {g['sent']}")
+    # (the generator's own arithmetic for its -g cuts)
+    cuts = [int(float(c) * 1e9) for c in workload.get("segments_at_s") or ()]
+    account = stats.void_account(sends, failures, g["fail_kinds"], spans,
+                                 cuts)
+    g["stops"] = [[start / 1e9, length / 1e9] for start, length in stops]
+    g["voided"] = {"queries": account["queries"],
+                   "of_them_failed": account["of_them_failed"]}
+    g["attempted"] = g["sent"] - account["queries"]
+    g["failed_by_kind"] = account["failed_by_kind"]
+    for segment, left in zip(g["latency_ns_by_segment"],
+                             account["failed_by_segment"]):
+        segment["failed"] = left
+
+
 def describe_window(g: dict) -> None:
     """Say what the generator counted, with the sample count behind the
     percentiles."""
@@ -546,6 +599,10 @@ def describe_window(g: dict) -> None:
         f"ok {g['ok']}, failed {g['failed']} {g['fails']}, TC retries "
         f"{g['tc_retries']}, unanswered at the end "
         f"{g['unanswered_at_end']}")
+    say(f"stops of the machine ({g['gap_least_ns'] / 1e6:.0f} ms or more, "
+        f"[start, length] s): {json.dumps(g['stops'])}; voided "
+        f"{g['voided']['queries']} queries, {g['voided']['of_them_failed']} "
+        f"of them failed; failed after that {g['failed_by_kind']}")
     if not lat:
         fail("window", "no query was answered in the window")
     p50_us, p99_us = (stats.hist_percentile(lat, bits, q) / 1e3
@@ -567,6 +624,67 @@ def describe_window(g: dict) -> None:
 
 # -- events inside the window --
 
+def window_start(out_dir: str):
+    """The window's first due time, seconds on CLOCK_MONOTONIC, as soon as
+    the generator has written it (``-s``); None if it does not within a
+    minute."""
+    path = os.path.join(out_dir, GENERATOR_OUT["-s"])
+    deadline = time.monotonic() + 60
+    while not os.path.exists(path):
+        if time.monotonic() > deadline:
+            return None
+        time.sleep(0.002)
+    with open(path) as f:
+        return int(f.read()) / 1e9
+
+
+class Stop:
+    """The two controls of the stop rule (``--break``, README.md "A stop of
+    the machine"), from a thread of their own: in mid-window SIGSTOP for
+    ``STOP_BREAK_S`` seconds, then SIGCONT.  ``machine-stop`` stops the
+    generator and the server's whole process group, as the sandbox stops:
+    the run has to name that stop and void what it cost.  ``server-stop``
+    stops the server's group alone: the generator's threads never paused,
+    so no stop is named and every query lost stays failed; a stall of the
+    program is never forgiven, whatever its length."""
+
+    def __init__(self, which: str, server: Server, gen, out_dir: str,
+                 seconds: float) -> None:
+        self.pids = [gen.pid] if which == "machine-stop" else []
+        self.group = server.proc.pid    # its own session: pgid == pid
+        self.out_dir = out_dir
+        self.at_s = seconds / 2 - STOP_BREAK_S / 2
+        self.stopped_s = None
+        self._thread = threading.Thread(target=self._run, daemon=True)
+        self._thread.start()
+
+    def _run(self) -> None:
+        start = window_start(self.out_dir)
+        if start is None:
+            return
+        time.sleep(max(0.0, start + self.at_s - time.monotonic()))
+        began = time.monotonic()
+        for pid in self.pids:
+            os.kill(pid, signal.SIGSTOP)
+        os.killpg(self.group, signal.SIGSTOP)
+        try:
+            time.sleep(STOP_BREAK_S)
+        finally:
+            # the server first: it is up when the generator's backlog comes
+            os.killpg(self.group, signal.SIGCONT)
+            for pid in self.pids:
+                os.kill(pid, signal.SIGCONT)
+        self.stopped_s = time.monotonic() - began
+
+    def join(self) -> None:
+        self._thread.join(90)
+        if self.stopped_s is None:
+            fail("window", "the --break stop was not delivered")
+        say(f"--break: stopped {'generator and ' if self.pids else ''}"
+            f"server group for {self.stopped_s:.3f}s from {self.at_s:.2f}s "
+            "into the window")
+
+
 class Events:
     """A workload's ``events``: a list, in time order, of objects with
     ``at_s`` (seconds from the first due time of the measured window) and
@@ -586,10 +704,7 @@ class Events:
                 fail("start", "events are not in time order")
         self.events = events
         self.server = server
-        #: the generator writes the window's first due time here
-        self.start_file = os.path.join(out_dir, "window_start")
-        if os.path.exists(self.start_file):
-            os.remove(self.start_file)
+        self.out_dir = out_dir
         self.left = []
         self._thread = threading.Thread(target=self._deliver, daemon=True)
 
@@ -597,20 +712,16 @@ class Events:
         self._thread.start()
 
     def _deliver(self) -> None:
-        deadline = time.monotonic() + 60
-        while not os.path.exists(self.start_file):
-            if time.monotonic() > deadline:
-                return                  # join() then finds events undelivered
-            time.sleep(0.002)
-        with open(self.start_file) as f:
-            window_start = int(f.read()) / 1e9      # CLOCK_MONOTONIC
+        start = window_start(self.out_dir)
+        if start is None:
+            return                      # join() then finds events undelivered
         for event in self.events:
-            wait = window_start + float(event["at_s"]) - time.monotonic()
+            wait = start + float(event["at_s"]) - time.monotonic()
             if wait > 0:
                 time.sleep(wait)
             self.server.proc.send_signal(getattr(signal, event["signal"]))
             went = time.monotonic()
-            self.left.append(dict(event, left_at_s=went - window_start,
+            self.left.append(dict(event, left_at_s=went - start,
                                   left_mono=went))
 
     def join(self) -> int:
@@ -760,14 +871,15 @@ def run(args) -> int:
         if not traced:
             child.finish()
         scrape_before = scrape_after = None
-        gen_out = os.path.join(out_dir, "generator.json")
-        captures = os.path.join(out_dir, "captures.bin")
-        argv = generator_argv(workload, files, udp, args.seconds,
-                              captures, gen_out)
+        gen_files = {flag: os.path.join(out_dir, name)
+                     for flag, name in GENERATOR_OUT.items()}
+        for path in gen_files.values():
+            if os.path.exists(path):
+                os.remove(path)         # an earlier run's is not this run's
+        argv = generator_argv(workload, files, udp, args.seconds, gen_files)
         events = None
         if workload.get("events"):
             events = Events(workload["events"], server, out_dir)
-            argv += ["-s", events.start_file]
             aborts = stats.total(http_get(mport, "/metrics").decode(),
                                  "binder_shard_roll_aborts_total")
         setup_s = time.monotonic() - T_START + float(workload["warm_s"])
@@ -781,6 +893,8 @@ def run(args) -> int:
         gen = subprocess.Popen(argv, cwd=out_dir)
         if events:
             events.start()
+        stop = Stop(args.break_, server, gen, out_dir, args.seconds) \
+            if args.break_ in STOP_BREAKS else None
         try:
             rc = gen.wait(timeout=args.seconds + 60)
         finally:
@@ -789,6 +903,8 @@ def run(args) -> int:
                 gen.wait()
         if rc != 0:
             fail("window", f"the generator exited {rc}")
+        if stop:
+            stop.join()
         if traced:
             child.tell("stop")
         roll_s = None
@@ -821,7 +937,8 @@ def run(args) -> int:
             scrape_after = scrape_all(mport, workers)
             device.update(child.traced_window())
             child.finish()
-        g = load_json(gen_out)
+        g = load_json(gen_files["-o"])
+        account_for_stops(g, workload, gen_files)
         if traced:
             between = scrape_after["at"] - scrape_before["at"]
             say("worker CPU between the scrapes, % of a core: " + ", ".join(
@@ -834,8 +951,8 @@ def run(args) -> int:
 
         # after the window: the kept answers, the sample and the write again
         # (after a roll: from the group's new workers)
-        compared = check_captures(captures, traffic, zone, args.seconds,
-                                  verdict)
+        compared = check_captures(gen_files["-c"], traffic, zone,
+                                  args.seconds, verdict)
         bad2, unseen2, silent2, stale2, _ = asks_and_read_back()
         verdict.hold("asks_mismatching_before_window", bad, 0)
         verdict.hold("asks_mismatching_after_window", bad2, 0)
@@ -890,8 +1007,13 @@ def run(args) -> int:
     else:
         metrics = {name: {"value": values[name][0], "unit": values[name][1]}
                    for name in workload["end_to_end"]}
-    result = {"correct": verdict.correct, "attempted": g["sent"],
-              "failed": g["failed"] + g["unanswered_at_end"],
+    # what no stop of the machine covers; none of the three that follow
+    # enters ``correct``
+    result = {"correct": verdict.correct, "attempted": g["attempted"],
+              "failed": sum(g["failed_by_kind"].values()),
+              "voided": g["voided"],
+              "stops": sorted(sorted(g["stops"], key=lambda s: -s[1])[:20]),
+              "failed_by_kind": g["failed_by_kind"],
               "metrics": metrics, "device": device}
     if traced:
         result["breakdown"] = {
@@ -906,8 +1028,10 @@ def run(args) -> int:
     # asks: the line's last key, and the run's last lines on standard error
     result["compared"] = {name: {"value": value, "limit": limit}
                           for name, value, limit in verdict.rows}
-    print("\n".join(f"benchmark: {line}" for line in verdict.lines()),
-          file=sys.stderr, flush=True)
+    print("\n".join(f"benchmark: {line}" for line in [
+        f"{key} {json.dumps(result[key])}"
+        for key in ("voided", "stops", "failed_by_kind")] + verdict.lines()),
+        file=sys.stderr, flush=True)
     if args.cpu:
         # a rehearsal: no number of a CPU run goes out under a device
         # metric's name, and no result line
@@ -930,9 +1054,11 @@ def main() -> None:
     ap.add_argument("--dir", default="benchmark",
                     help="where configs/ and workloads/ are looked up "
                     "(the tests keep a tiny cell of their own)")
-    ap.add_argument("--break", dest="break_", choices=BREAKS,
+    ap.add_argument("--break", dest="break_", choices=BREAKS + STOP_BREAKS,
                     help="a deliberately wrong run, for the tests and the "
-                    "control: correct must come out false")
+                    "control: correct must come out false; or (machine-stop, "
+                    "server-stop) a stop in mid-window: a control of the "
+                    "stop rule, which leaves correct alone")
     args = ap.parse_args()
     for needed in ("BENCHMARK.json", "binder_tpu/main.py", "native/Makefile",
                    "etc/config.json", "__graft_entry__.py"):

@@ -1,7 +1,10 @@
 """The benchmark's arithmetic: percentiles from the generator's histograms,
-sums and histogram deltas from Prometheus text, spreads of runs."""
+sums and histogram deltas from Prometheus text, spreads of runs, and which
+queries a stop of the machine covers."""
 import re
 import statistics
+
+import numpy as np
 
 
 def bucket_bounds(idx: int, bits: int):
@@ -89,6 +92,103 @@ def spread(values: list) -> float:
     """Distance between the first and third quartile over the median."""
     q1, _, q3 = statistics.quantiles(values, n=4)
     return (q3 - q1) / statistics.median(values)
+
+
+# -- a stop of the machine (README.md, "A stop of the machine") --
+# Every time is a whole number of nanoseconds from the window's first due
+# time, as the generator writes them: 249,999,999 is under the rule's
+# quarter second and 250,000,000 is not, whatever a float would say.
+
+#: a stop this long or longer covers queries; a shorter one is only named
+STOP_COVERS_NS = 250_000_000
+#: how many lengths after its end a stop still covers, as a fraction: two
+#: and a quarter.  Behind a stop the generator sends the whole backlog at
+#: once, the workers' socket buffers stay full and two queries in three
+#: time out until the backlog is served: for 1.98 s behind a stop of 1.50 s
+#: and 4.68 s behind one of 2.71 s in ``hosts_zipf_open60`` at 0.6 of its
+#: knee (PERF.md section 6, PR 44), a line of slope 2.24
+STOP_TAIL = (9, 4)
+#: the kinds of failure a stop can cost; a wrong answer (``rcode``,
+#: ``ancount``) is the program's whenever it was due
+VOIDABLE = frozenset(("timeout", "tcp", "send", "overflow",
+                      "unanswered_at_end"))
+
+
+def machine_stops(gaps_by_thread: list, least_ns: int) -> list:
+    """``[(start, length), ...]``: every interval of *least_ns* or more
+    that lies inside a gap of every sender thread (each thread's gaps as
+    ``[start, length]``).  A gap that one thread alone saw is the
+    scheduler's or the generator's and names nothing."""
+    if not gaps_by_thread:
+        return []
+    common = [(s, s + n) for s, n in gaps_by_thread[0]]
+    for gaps in gaps_by_thread[1:]:
+        common = [(max(a, s), min(b, s + n)) for a, b in common
+                  for s, n in gaps if min(b, s + n) > max(a, s)]
+    return [(a, b - a) for a, b in sorted(common) if b - a >= least_ns]
+
+
+def covered_spans(stops: list, timeout_ns: int, join_ns: int) -> list:
+    """``[(first, last), ...]``, both ends included and no two touching:
+    the due times that stops cover.  Stops less than *join_ns* apart are
+    one stop from the first's start to the last's end (the machine ran for
+    an instant between them: a stop of 2.18 s, one of 0.12 s and one of
+    0.41 s, each beginning where the last ended, left the backlog of one
+    stop of 2.71 s); a stop of ``STOP_COVERS_NS`` or more covers from the
+    timeout before it began (a query due then could still be out when the
+    machine stood) to its end plus ``STOP_TAIL`` lengths."""
+    joined = []
+    for start, length in sorted(stops):
+        if joined and start - joined[-1][1] < join_ns:
+            joined[-1][1] = max(joined[-1][1], start + length)
+        else:
+            joined.append([start, start + length])
+    spans = []
+    for start, end in joined:
+        if end - start < STOP_COVERS_NS:
+            continue
+        first = start - timeout_ns
+        last = end + STOP_TAIL[0] * (end - start) // STOP_TAIL[1]
+        if spans and first <= spans[-1][1]:
+            spans[-1] = (spans[-1][0], max(last, spans[-1][1]))
+        else:
+            spans.append((first, last))
+    return spans
+
+
+def void_account(sends, failures, kinds: list, spans: list,
+                 cuts_ns: list) -> dict:
+    """What the spans leave of a window's counts.  *sends*: the due time
+    of every measured send; *failures*: rows of (due time, index into
+    *kinds*) of every one that failed or was unanswered at the end.  A
+    covered query leaves both counts, answered or not, but one that got a
+    wrong answer: that one stays in both.  ``failed_by_segment`` counts,
+    as the generator does, the failures it saw itself (not
+    ``unanswered_at_end``) by the segment their due time lies in."""
+    sends = np.asarray(sends, dtype=np.int64)
+    failures = np.asarray(failures, dtype=np.int64).reshape(-1, 2)
+    due, kind = failures[:, 0], failures[:, 1]
+
+    def inside(times):
+        hit = np.zeros(len(times), dtype=bool)
+        for first, last in spans:
+            hit |= (times >= first) & (times <= last)
+        return hit
+
+    voidable = np.isin(kind, [i for i, k in enumerate(kinds)
+                              if k in VOIDABLE])
+    voided = inside(due) & voidable
+    wrong_in_spans = int((inside(due) & ~voidable).sum())
+    left = ~voided
+    seen = left & (kind != kinds.index("unanswered_at_end"))
+    segment = np.searchsorted(np.asarray(cuts_ns, dtype=np.int64),
+                              due[seen], side="right")
+    return {"queries": int(inside(sends).sum()) - wrong_in_spans,
+            "of_them_failed": int(voided.sum()),
+            "failed_by_kind": {k: int((kind[left] == i).sum())
+                               for i, k in enumerate(kinds)},
+            "failed_by_segment": np.bincount(
+                segment, minlength=len(cuts_ns) + 1).tolist()}
 
 
 # -- helpers for the per-layer readers (layer_metrics/*.py) --

@@ -338,10 +338,23 @@ def test_rehearsal_sound_run_is_correct(workload, seed, trace, expects):
     assert result["correct"], result["stdout"][-3000:]
     assert result["failed"] == 0 and result["attempted"] > 1000
     assert expects <= set(result["metrics"])
-    # (0 is the two stall metrics' value in a quiet window)
+    # (0 is the value, in a quiet window, of the two stall metrics, of the
+    # answers dropped at a full send buffer and of the generator's stops)
     assert all(m["value"] > 0 for name, m in result["metrics"].items()
-               if m["unit"] != "%" and name not in ("sandbox_freeze_ms",
-                                                    "worker_stall_ms"))
+               if m["unit"] != "%" and name not in (
+                   "sandbox_freeze_ms", "worker_stall_ms", "udp_send_drops",
+                   "gen_stop_ms"))
+    # a window without a stop of the machine of 250 ms counts what the
+    # generator counted: nothing is voided
+    with open(os.path.join(BENCH, "out", workload, "generator.json")) as f:
+        g = json.load(f)
+    if not any(length >= 0.25 for _, length in result["stops"]):
+        assert result["voided"] == {"queries": 0, "of_them_failed": 0}
+        assert result["attempted"] == g["sent"]
+        assert result["failed"] == g["failed"] + g["unanswered_at_end"]
+    assert sum(result["failed_by_kind"].values()) == result["failed"]
+    assert result["attempted"] + result["voided"]["queries"] == g["sent"]
+    assert [key for key in result if key != "stdout"][-1] == "compared"
     assert "compared window_answers_mismatching = 0 (limit 0)" \
         in result["stdout"]
     if trace:

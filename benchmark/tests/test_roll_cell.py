@@ -151,9 +151,13 @@ def test_events_and_segments_change_no_question_and_no_due_time(tmp_path):
         == files_digest(plain, tmp_path / "b")
     sys.path.insert(0, BENCH)
     import run
-    argv = run.generator_argv(plain, {}, 53, 2.0, "c", "o")
-    assert "-g" not in argv and "-s" not in argv
-    argv = run.generator_argv(roll, {}, 53, 2.0, "c", "o")
+    # (the window's first due time is written in every run since PR 44:
+    # the stop controls place their stop by it, as the events do)
+    outs = {flag: "out/" + name for flag, name in run.GENERATOR_OUT.items()}
+    argv = run.generator_argv(plain, {}, 53, 2.0, outs)
+    assert "-g" not in argv
+    assert argv[argv.index("-s") + 1] == "out/window_start"
+    argv = run.generator_argv(roll, {}, 53, 2.0, outs)
     assert argv[argv.index("-g") + 1] == "1.0,3.0"
 
 

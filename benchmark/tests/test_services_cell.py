@@ -94,9 +94,11 @@ def test_the_new_metrics_list_the_cell_alone_and_the_old_ones_gain_it():
     for name in ("busy_unnamed_share", "syscalls_per_answer",
                  "socket_us_per_answer", "python_us_per_query"):
         assert CELL in by_name[name]["workloads"]
-    # PR 26's eight, PR 27's and PR 29's one each, and the 21 it shares
+    # as the manifest stands: PR 26's eight, PR 27's and PR 29's one each
+    # (the services cells alone); shared with the hosts cells: the 21 of
+    # PR 23 to 25, PR 37's nine, PR 43's three and PR 44's two
     assert sum(CELL in p["workloads"] for p in m["per_layer"]) \
-        == len(NEW) + 2 + 21
+        == len(NEW) + 2 + 21 + 9 + 3 + 2
 
 
 @pytest.mark.parametrize("seed", [1, 2**31 + 7])
@@ -254,8 +256,11 @@ def test_rehearsal_of_the_cell_is_correct_and_retries_over_tcp():
     assert 0 < metrics["stream_busy_share"] < 100
     assert 0 < metrics["lazy_render_share"] < 100
     assert 100 < metrics["srv_answer_bytes_mean"] < 20000
+    # (the lazy render is held by ``lazy_render_us`` above: since PR 39 the
+    # zone table gives every leg, and on this cut the stage is the tenth
+    # largest in some runs and the eleventh in others)
     stages = dict(result["breakdown"]["idle_gaps"])
-    assert "lazy-render" in stages
+    assert {"tcp-accept", "tcp-close"} <= set(stages)
     # the four readers that know the stream stages and the lazy render
     # since PR 30: the crossings of the legs are among the calls counted
     assert {"busy_unnamed_share", "syscalls_per_answer",
