@@ -53,16 +53,23 @@ Actions
 - ``shard-kill [shard=I]`` — SIGKILL one shard worker mid-load via the
   driver's ``shard_target`` (the supervisor's ``kill_shard``;
   ``shard`` omitted or -1 picks a live worker at random).  The
-  acceptance invariant is the supervisor's: the kernel re-hashes the
-  dead socket's share to the survivors at once, and the respawned
-  worker catches up from snapshot (binder_tpu/shard).
+  acceptance invariant is the supervisor's: the shard's sockets are
+  its own and stay open, what the kernel queues for the dead worker's
+  share waits for the respawn, and the respawned worker catches up
+  from snapshot and reads the same sockets (binder_tpu/shard).
 - ``worker-roll [shard=I]`` — request a zero-downtime drain-and-
   replace cycle via the driver's ``roll_target`` (the supervisor's
   ``request_roll``; ``shard`` omitted or -1 rolls every shard in
   sequence).  Unlike ``shard-kill`` this is the *cooperative* path:
-  the acceptance invariant is zero query loss — replacement converges
-  from snapshot and joins the reuseport group BEFORE the incumbent is
-  drained, one shard at a time.  Rolling mid-incident (after a
+  the acceptance invariant is zero query loss, measured at the clients
+  (the benchmark's cell ``hosts_zipf_rolling``: ``failed`` 0 outside
+  what a stop of the machine covers) and accounted for by the
+  supervisor (``binder_shard_roll_unserved_total`` 0).  The supervisor
+  binds a shard's sockets once and every incarnation inherits them; a
+  replacement converges from snapshot, fills its tables, and only then
+  starts to read the sockets; the incumbent is drained after that (it
+  stops reading, closes nothing, serves out what it holds), one shard
+  at a time.  Rolling mid-incident (after a
   ``lose-session`` or during an ``rrl-flood``) is exactly the
   operator reality the chaos smoke pins.
 - ``rrl-flood [n=N] [qname=...]`` — synchronous burst of N (default

@@ -533,6 +533,85 @@ async def bind_port_pair(port: int, bind_udp, bind_tcp, release_udp):
                 raise
 
 
+def bind_udp_socket(address: str, port: int,
+                    reuse_port: bool = False) -> socket.socket:
+    """A bound, non-blocking UDP socket as a DNS listener wants it.  No
+    SO_REUSEADDR: UDP has no TIME_WAIT to work around, and on Linux the
+    option would let another local process bind a more-specific address
+    on the same port and divert queries (the reason asyncio removed it
+    for datagram endpoints).  SO_REUSEPORT is the deliberate exception:
+    shard mode binds N sockets on ONE port so the kernel's 4-tuple hash
+    balances queries across them (same-UID only, so the hijack concern
+    does not apply)."""
+    fam = socket.AF_INET6 if ":" in address else socket.AF_INET
+    sock = socket.socket(fam, socket.SOCK_DGRAM)
+    try:
+        if reuse_port:
+            sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEPORT, 1)
+        # absorb bursts while the event loop is busy with other work,
+        # and hold a whole callback's answers on the way out: up to
+        # _UDP_BURST datagrams of up to 1,232 bytes leave back to back
+        for opt in (socket.SO_RCVBUF, socket.SO_SNDBUF):
+            try:
+                sock.setsockopt(socket.SOL_SOCKET, opt, 1 << 20)
+            except OSError:
+                pass
+        sock.setblocking(False)
+        sock.bind((address, port))
+    except OSError:
+        sock.close()
+        raise
+    return sock
+
+
+def bind_tcp_listener(address: str, port: int,
+                      reuse_port: bool = False) -> socket.socket:
+    """A bound, listening, non-blocking TCP socket; a bind or listen
+    failure (the pair-bind redraw path) leaves no socket behind."""
+    fam = socket.AF_INET6 if ":" in address else socket.AF_INET
+    lsock = socket.socket(fam, socket.SOCK_STREAM)
+    try:
+        lsock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
+        if reuse_port:
+            # shard mode: the kernel spreads incoming connections
+            # across every listener on this port
+            lsock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEPORT, 1)
+        lsock.setblocking(False)
+        lsock.bind((address, port))
+        lsock.listen(1024)
+        # accept fast path: wake only when the first frame's bytes
+        # are already in the socket buffer (guarded: not every
+        # platform has the option, and serving must not depend on it)
+        try:
+            lsock.setsockopt(socket.IPPROTO_TCP, socket.TCP_DEFER_ACCEPT,
+                             DnsServer.TCP_DEFER_ACCEPT_S)
+        except (AttributeError, OSError):
+            pass
+    except OSError:
+        lsock.close()
+        raise
+    return lsock
+
+
+def bind_socket_pair(address: str, port: int,
+                     reuse_port: bool = False) -> tuple:
+    """``(udp_socket, tcp_listener)`` on one port number, bound here and
+    handed to whoever serves them (the shard supervisor binds a pair a
+    shard once, and every incarnation of the shard inherits it).  The
+    redraw rule is ``bind_port_pair``'s: a kernel-chosen UDP port that
+    is taken on TCP is released and drawn again."""
+    for attempt in range(PAIR_BIND_ATTEMPTS):
+        udp = bind_udp_socket(address, port, reuse_port)
+        try:
+            return udp, bind_tcp_listener(
+                address, port or udp.getsockname()[1], reuse_port)
+        except OSError as e:
+            udp.close()
+            if not (port == 0 and e.errno in (errno.EADDRINUSE, None)
+                    and attempt < PAIR_BIND_ATTEMPTS - 1):
+                raise
+
+
 class DnsServer:
     #: Bounds for the TCP front (the reference's mname engine had none;
     #: a DNS front end that one slow peer can fd-starve is not done).
@@ -606,6 +685,9 @@ class DnsServer:
         self.on_after: Optional[Callable] = None   # sync  (QueryCtx) -> None
         self._udp_socks: List[tuple] = []   # (loop, socket)
         self._tcp_listeners: List[tuple] = []   # (loop, socket)
+        # listeners whose readers wait for ``start_reading`` (None: a
+        # listener is read from the moment it is set up)
+        self._held_reads: Optional[list] = None
         self._tcp_sweep_handle = None       # idle-sweep TimerHandle
         self._unix_servers: List[tuple] = []   # (loop, socket, path)
         self._tasks: set = set()
@@ -960,7 +1042,7 @@ class DnsServer:
 
     async def listen_udp(self, address: str, port: int,
                          announce: bool = True,
-                         reuse_port: bool = False) -> int:
+                         sock: Optional[socket.socket] = None) -> int:
         """Direct add_reader recv/send loop.
 
         asyncio's DatagramTransport costs ~15µs/packet in protocol
@@ -973,30 +1055,17 @@ class DnsServer:
         ``announce=False`` defers the "service started" log line — the
         ephemeral pair bind (BinderServer.start) must not advertise a
         port it may yet release and redraw: harnesses watch that line,
-        and one observed CI failure latched a redrawn (dead) port."""
+        and one observed CI failure latched a redrawn (dead) port.
+
+        ``sock`` is a socket somebody else bound and keeps open (a shard
+        worker inherits its shard's from the supervisor): it is read
+        here, never bound, and closing it here ends this process's
+        reading, not the socket."""
         loop = asyncio.get_running_loop()
-        fam = socket.AF_INET6 if ":" in address else socket.AF_INET
-        sock = socket.socket(fam, socket.SOCK_DGRAM)
-        # no SO_REUSEADDR: UDP has no TIME_WAIT to work around, and on
-        # Linux the option would let another local process bind a
-        # more-specific address on the same port and divert queries
-        # (the reason asyncio removed it for datagram endpoints).
-        # SO_REUSEPORT is the deliberate exception — shard mode binds N
-        # worker sockets on ONE port so the kernel's 4-tuple hash
-        # balances queries across processes (same-UID only, so the
-        # hijack concern above does not apply).
-        if reuse_port:
-            sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEPORT, 1)
-        # absorb bursts while the event loop is busy with other work,
-        # and hold a whole callback's answers on the way out: up to
-        # _UDP_BURST datagrams of up to 1,232 bytes leave back to back
-        for opt in (socket.SO_RCVBUF, socket.SO_SNDBUF):
-            try:
-                sock.setsockopt(socket.SOL_SOCKET, opt, 1 << 20)
-            except OSError:
-                pass
-        sock.setblocking(False)
-        sock.bind((address, port))
+        if sock is None:
+            sock = bind_udp_socket(address, port)
+        else:
+            sock.setblocking(False)
 
         handle_raw = self._handle_raw
         recvfrom = sock.recvfrom
@@ -1036,12 +1105,33 @@ class DnsServer:
                 finally:
                     self._flush_log()
 
-        loop.add_reader(sock.fileno(), self.event_udp, on_readable)
+        self._read(loop, sock, self.event_udp, on_readable)
         self._udp_socks.append((loop, sock))
         actual = sock.getsockname()[1]
         if announce:
             self.announce_udp(address, actual)
         return actual
+
+    def _read(self, loop, sock: socket.socket, event, callback,
+              *args) -> None:
+        """Register a listener's readiness callback, or keep it for
+        ``start_reading`` while reads are held."""
+        if self._held_reads is not None:
+            self._held_reads.append((loop, sock, event, callback, args))
+        else:
+            loop.add_reader(sock.fileno(), event, callback, *args)
+
+    def hold_reads(self) -> None:
+        """Listeners set up from here on are not read until
+        ``start_reading``: a roll's replacement holds its shard's
+        sockets, which its incumbent still serves, until it is filled."""
+        if self._held_reads is None:
+            self._held_reads = []
+
+    def start_reading(self) -> None:
+        held, self._held_reads = self._held_reads, None
+        for loop, sock, event, callback, args in held or ():
+            loop.add_reader(sock.fileno(), event, callback, *args)
 
     def announce_udp(self, address: str, port: int) -> None:
         self.log.info("UDP DNS service started on %s:%d", address, port)
@@ -1311,36 +1401,17 @@ class DnsServer:
 
     async def listen_tcp(self, address: str, port: int,
                          announce: bool = True,
-                         reuse_port: bool = False) -> int:
+                         sock: Optional[socket.socket] = None) -> int:
+        """``sock`` as in ``listen_udp``: a listener somebody else bound
+        and keeps open."""
         loop = asyncio.get_running_loop()
-        fam = socket.AF_INET6 if ":" in address else socket.AF_INET
-        lsock = socket.socket(fam, socket.SOCK_STREAM)
-        try:
-            lsock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-            if reuse_port:
-                # shard mode: the kernel spreads incoming connections
-                # across every worker listening on this port
-                lsock.setsockopt(socket.SOL_SOCKET,
-                                 socket.SO_REUSEPORT, 1)
+        if sock is None:
+            lsock = bind_tcp_listener(address, port)
+        else:
+            lsock = sock
             lsock.setblocking(False)
-            lsock.bind((address, port))
-            lsock.listen(1024)
-            # accept fast path: wake only when the first frame's bytes
-            # are already in the socket buffer (guarded: not every
-            # platform has the option, and serving must not depend on it)
-            try:
-                lsock.setsockopt(socket.IPPROTO_TCP,
-                                 socket.TCP_DEFER_ACCEPT,
-                                 self.TCP_DEFER_ACCEPT_S)
-            except (AttributeError, OSError):
-                pass
-        except OSError:
-            # bind/listen failure (the pair-bind redraw path): leave no
-            # socket behind
-            lsock.close()
-            raise
-        loop.add_reader(lsock.fileno(), self.event_tcp,
-                        self._on_accept_ready, lsock, loop)
+        self._read(loop, lsock, self.event_tcp, self._on_accept_ready,
+                   lsock, loop)
         self._tcp_listeners.append((loop, lsock))
         if self._tcp_sweep_handle is None and self.tcp_idle_timeout:
             # ONE idle sweep for the whole connection table (vs a timer
@@ -1528,62 +1599,26 @@ class DnsServer:
     # -- lifecycle --
 
     async def quiesce(self, timeout: float = 5.0) -> int:
-        """Graceful stop-accepting for the rolling drain-and-replace
+        """Graceful stop-reading for the rolling drain-and-replace
         cycle (shard supervisor, docs/operations.md "Rolling
-        upgrade"): stop taking NEW work, serve out what is already
-        here, then leave the ``SO_REUSEPORT`` group.
-
-        Order matters: the accept paths close first (new TCP clients
-        re-hash to the surviving group members immediately), then the
-        UDP read loop stops and the datagrams the kernel already
-        queued to this socket — which would be silently dropped at
-        close — are served out synchronously before the socket closes
-        and its hash share moves over.  Finally a bounded wait lets
-        async in-flight queries finish and one settle tick lets the
-        stream lane's write coalescing flush.  Returns the number of
-        in-flight queries still pending at the deadline (0 == clean
-        drain)."""
+        upgrade"): stop taking NEW work and serve out what is already
+        here.  No socket is closed: a shard's sockets are the
+        supervisor's, open for as long as the group serves, and what the
+        kernel queues on them from now on is read by the successor that
+        already reads them.  The listeners and the UDP sockets lose
+        their readers, a bounded wait lets async in-flight queries
+        finish (their answers leave on the same open sockets), and one
+        settle tick lets open stream connections read what they were
+        sent and the stream lane's write coalescing flush.  Returns the
+        number of in-flight queries still pending at the deadline (0 ==
+        clean drain)."""
+        self._held_reads = None
         for loop, lsock in self._tcp_listeners:
-            try:
-                loop.remove_reader(lsock.fileno())
-            except (OSError, ValueError):
-                pass
-            lsock.close()
-        self._tcp_listeners.clear()
+            self._stop_reading(loop, lsock)
         for loop, lsock, _path in self._unix_servers:
-            try:
-                loop.remove_reader(lsock.fileno())
-            except (OSError, ValueError):
-                pass
-            lsock.close()
-        self._unix_servers.clear()
+            self._stop_reading(loop, lsock)
         for loop, sock in self._udp_socks:
-            try:
-                loop.remove_reader(sock.fileno())
-            except (OSError, ValueError):
-                pass
-            while True:
-                try:
-                    data, addr = sock.recvfrom(65535)
-                except (BlockingIOError, InterruptedError):
-                    break
-                except OSError:
-                    break
-
-                def send(wire: bytes, _sock=sock, _addr=addr) -> None:
-                    try:
-                        _sock.sendto(wire, _addr)
-                    except OSError:
-                        pass
-
-                self._handle_raw(data, (addr[0], addr[1]), "udp", send)
-            # leaving the group NOW keeps the unread window to the
-            # microseconds between the drain loop and this close; an
-            # async in-flight UDP answer past this point is best-effort
-            # (its reply socket is gone), matching the sync-dominated
-            # shard serving profile
-            sock.close()
-        self._udp_socks.clear()
+            self._stop_reading(loop, sock)
         deadline = time.monotonic() + timeout
         while self.inflight and time.monotonic() < deadline:
             await asyncio.sleep(0.02)
@@ -1591,29 +1626,27 @@ class DnsServer:
         await asyncio.sleep(0.05)
         return len(self.inflight)
 
+    @staticmethod
+    def _stop_reading(loop, sock: socket.socket) -> None:
+        try:
+            loop.remove_reader(sock.fileno())
+        except (OSError, ValueError):
+            pass
+
     async def close(self) -> None:
         for loop, sock in self._udp_socks:
-            try:
-                loop.remove_reader(sock.fileno())
-            except (OSError, ValueError):
-                pass
+            self._stop_reading(loop, sock)
             sock.close()
         if self._tcp_sweep_handle is not None:
             self._tcp_sweep_handle.cancel()
             self._tcp_sweep_handle = None
         for loop, lsock in self._tcp_listeners:
-            try:
-                loop.remove_reader(lsock.fileno())
-            except (OSError, ValueError):
-                pass
+            self._stop_reading(loop, lsock)
             lsock.close()
         for w in list(self._conns):
             w.close()
         for loop, lsock, path in self._unix_servers:
-            try:
-                loop.remove_reader(lsock.fileno())
-            except (OSError, ValueError):
-                pass
+            self._stop_reading(loop, lsock)
             # note: the path is NOT unlinked here — supervisor SIGTERM
             # semantics own the unlink (main.py), matching the old
             # stream-server behavior callers test against

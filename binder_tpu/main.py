@@ -13,6 +13,7 @@ import asyncio
 import logging
 import os
 import signal
+import socket
 import sys
 from typing import Dict
 
@@ -284,6 +285,9 @@ async def run(options: Dict[str, object]) -> BinderServer:
         nodes = store.read_snapshot()
         log.info("shard %d: snapshot applied (%d node(s))",
                  shard_worker, nodes)
+        if store.attach is None:
+            raise ConfigError("shard worker: the supervisor named no "
+                              "sockets to serve")
     else:
         store = make_store(options, log, collector=collector,
                            recorder=recorder)
@@ -396,10 +400,16 @@ async def run(options: Dict[str, object]) -> BinderServer:
         # (docs/observability.md): on by default like the other
         # production observability
         verify=dict(options.get("verify") or {}),
-        # shard workers share ONE port via SO_REUSEPORT (the kernel
-        # balances) and leave the canonical announce lines to the
-        # supervisor, which prints them once the whole group serves
-        reuse_port=shard_worker is not None,
+        # a shard worker serves the sockets its supervisor bound for
+        # the shard (one port, SO_REUSEPORT: the kernel balances), a
+        # roll's replacement only once it is filled, and leaves the
+        # canonical announce lines to the supervisor, which prints them
+        # once the whole group serves
+        sockets=(None if shard_worker is None else tuple(
+            socket.socket(fileno=int(store.attach[key]))
+            for key in ("udp_fd", "tcp_fd"))),
+        read_when_filled=bool(shard_worker is not None
+                              and store.attach.get("read_when_filled")),
         announce=shard_worker is None,
     )
     # introspection handle (/status federation section, bstat line)
@@ -522,23 +532,38 @@ def _wire_shard_worker(server: BinderServer, store, metrics, collector,
         metrics.port))
     requests = collector.counter("binder_requests_completed")
 
+    def send_stats():
+        try:
+            collector.fold()   # natively counted serves included
+            rrl = getattr(server, "_rrl", None)
+            adm = getattr(server, "_admission", None)
+            store.send(protocol.stats_frame(
+                requests.total(), server.zk_cache.gen,
+                server.zk_cache.epoch, server.zk_cache.is_ready(),
+                len(server.engine.inflight),
+                rrl_dropped=(rrl.dropped if rrl is not None else 0),
+                shed=(sum(adm.shed_counts.values())
+                      if adm is not None else 0),
+                filled=server.filled))
+        except Exception:
+            log.exception("shard stats report failed")
+
     async def stats_loop():
+        walked = -1
         while True:
             await asyncio.sleep(1.0)
-            try:
-                collector.fold()   # natively counted serves included
-                rrl = getattr(server, "_rrl", None)
-                adm = getattr(server, "_admission", None)
-                store.send(protocol.stats_frame(
-                    requests.total(), server.zk_cache.gen,
-                    server.zk_cache.epoch, server.zk_cache.is_ready(),
-                    len(server.engine.inflight),
-                    rrl_dropped=(rrl.dropped if rrl is not None else 0),
-                    shed=(sum(adm.shed_counts.values())
-                          if adm is not None else 0)))
-            except Exception:
-                log.exception("shard stats report failed")
+            if not server.filled and server.fill_progress() != walked:
+                # the startup walks move: a roll waits for `filled`
+                # with a no-progress window, like a start for hello
+                walked = server.fill_progress()
+                store.send(protocol.progress_frame())
+            send_stats()
 
+    # the supervisor promotes a roll's replacement on `filled`: said at
+    # once, not at the next second
+    server.on_filled = send_stats
+    if server.filled:
+        send_stats()
     server._shard_stats_task = loop.create_task(stats_loop())
 
     def on_sigterm():
@@ -546,9 +571,12 @@ def _wire_shard_worker(server: BinderServer, store, metrics, collector,
 
         async def _drain():
             # rolling-drain semantics (docs/operations.md "Rolling
-            # upgrade"): leave the reuseport group and serve out the
+            # upgrade"): stop reading the shard's sockets (they stay
+            # open, the successor reads them) and serve out the
             # in-flight queries BEFORE tearing the serve stack down —
             # stop() cancels whatever quiesce could not finish
+            held = len(server.engine.inflight)
+            pending = held
             try:
                 pending = await server.engine.quiesce()
                 if pending:
@@ -560,8 +588,11 @@ def _wire_shard_worker(server: BinderServer, store, metrics, collector,
                              "served out)", shard)
             except Exception:
                 log.exception("shard %d: quiesce failed", shard)
+            store.send(protocol.drained_frame(held, pending))
             await server.stop()
-            metrics.stop()
+            # (the scrape server's thread goes with the process: its
+            # shutdown would wait out a poll of half a second, which a
+            # roll pays a shard)
             os._exit(0)
 
         loop.create_task(_drain())

@@ -18,7 +18,7 @@ import struct
 import threading
 import time
 from urllib.parse import urlparse as _urlparse
-from typing import Optional
+from typing import Callable, Optional
 
 try:  # native fast path (built by `make -C native`); optional
     from binder_tpu import _binderfastio as _fastio
@@ -195,7 +195,8 @@ class BinderServer:
                  admission: Optional[dict] = None,
                  rrl: Optional[dict] = None,
                  verify: Optional[dict] = None,
-                 reuse_port: bool = False,
+                 sockets: Optional[tuple] = None,
+                 read_when_filled: bool = False,
                  announce: bool = True) -> None:
         self.log = log or logging.getLogger("binder.server")
         # introspection flight recorder (binder_tpu/introspect):
@@ -204,12 +205,24 @@ class BinderServer:
         self.recorder = flight_recorder
         self.host = host
         self.port = port
-        # shard mode (binder_tpu/shard): N workers bind ONE port via
-        # SO_REUSEPORT and the supervisor owns the canonical "service
-        # started" announce lines — workers keep quiet so harnesses
-        # never latch onto a group still forming
-        self.reuse_port = reuse_port
+        # shard mode (binder_tpu/shard): the supervisor binds one UDP
+        # socket and one TCP listener a shard on ONE port
+        # (SO_REUSEPORT) and every incarnation of the shard inherits
+        # the pair (``sockets``: served here, never bound or unbound);
+        # a roll's replacement reads them only once it is filled
+        # (``read_when_filled``), its incumbent serving until then.  The
+        # supervisor also owns the canonical "service started" announce
+        # lines — workers keep quiet so harnesses never latch onto a
+        # group still forming
+        self.sockets = sockets
+        self.read_when_filled = read_when_filled
         self.announce = announce
+        # filled: the startup walks (precompile seed, zone fill) are
+        # done, so every name the native lanes can serve is theirs
+        self.filled = False
+        self.on_filled: Optional[Callable[[], None]] = None
+        self._filled_task = None
+        self._fill_done = 0
         self.dns_domain = dns_domain
         self.balancer_socket = balancer_socket
         self.collector = collector or MetricsCollector()
@@ -627,6 +640,16 @@ class BinderServer:
             self.engine.fastpath_gen = self._epoch_source
             self.engine.fastpath_gate = self._fastpath_active
             self.collector.on_expose(self._fold_fastpath_metrics)
+        # queries answered before this server was filled, every lane's
+        # (after the native fold above): a fresh worker serves through
+        # its fill, a roll's replacement must read 0
+        self._unfilled_child = self.collector.counter(
+            "binder_unfilled_serves_total",
+            "queries answered before the startup zone fill and "
+            "precompile seed were complete").labelled({})
+        self._unfilled_child.inc(0)
+        self._unfilled_folded = 0.0
+        self.collector.on_expose(self._fold_unfilled)
 
         # The query log's one writer (_write_log).  With per-query
         # logging ON (the reference's always-on posture,
@@ -1654,6 +1677,7 @@ class BinderServer:
                     and time.perf_counter() - t0 < self._FILL_BUDGET_S:
                 self._zone_fill_one(domains[i])
                 i += 1
+            self._fill_done = i
             await asyncio.sleep(0)
         self.log.info("zone fill done: %d names in %.1fs", len(domains),
                       time.perf_counter() - started)
@@ -1785,6 +1809,15 @@ class BinderServer:
                     if d > 0:
                         child.inc(d)
                         rfolded[field] = val
+
+    def _fold_unfilled(self) -> None:
+        if self.filled:
+            return
+        with self._fp_fold_lock:
+            delta = self.request_counter.total() - self._unfilled_folded
+            if delta > 0:
+                self._unfilled_child.inc(delta)
+                self._unfilled_folded += delta
 
     def udp_send_drops(self) -> dict:
         """UDP answers dropped at a send buffer still full at the retry,
@@ -2210,22 +2243,36 @@ class BinderServer:
         # started" lines for the port, and a line printed for a draw
         # that is then released advertises a dead port (observed as a
         # CI dnsblast connection-refused failure)
-        try:
-            self.udp_port, self.tcp_port = await bind_port_pair(
-                self.port,
-                lambda: self.engine.listen_udp(
-                    self.host, self.port, announce=False,
-                    reuse_port=self.reuse_port),
-                lambda port: self.engine.listen_tcp(
-                    self.host, port, announce=False,
-                    reuse_port=self.reuse_port),
-                self.engine.close_udp_listener)
-        except OSError:
-            # failed for good (a fixed port taken on UDP or on TCP):
-            # release the balancer listener opened above so the raise
-            # leaves no socket behind
-            await self.engine.close()
-            raise
+        walks = [t for t in (self._zone_fill_task, getattr(
+            self._precompiler, "_seed_task", None)) if t is not None]
+        if not walks:
+            self._set_filled()      # inline walks: filled before query one
+        elif self.read_when_filled:
+            self.engine.hold_reads()
+        if self.sockets is not None:
+            udp, tcp = self.sockets
+            self.udp_port = await self.engine.listen_udp(
+                self.host, self.port, announce=False, sock=udp)
+            self.tcp_port = await self.engine.listen_tcp(
+                self.host, self.port, announce=False, sock=tcp)
+        else:
+            try:
+                self.udp_port, self.tcp_port = await bind_port_pair(
+                    self.port,
+                    lambda: self.engine.listen_udp(
+                        self.host, self.port, announce=False),
+                    lambda port: self.engine.listen_tcp(
+                        self.host, port, announce=False),
+                    self.engine.close_udp_listener)
+            except OSError:
+                # failed for good (a fixed port taken on UDP or on TCP):
+                # release the balancer listener opened above so the
+                # raise leaves no socket behind
+                await self.engine.close()
+                raise
+        if walks:
+            self._filled_task = asyncio.get_running_loop().create_task(
+                self._await_filled(walks))
         if self.announce:
             self.engine.announce_udp(self.host, self.udp_port)
             self.engine.announce_tcp(self.host, self.tcp_port)
@@ -2243,7 +2290,33 @@ class BinderServer:
         if self._verify is not None:
             self._verify.start(asyncio.get_running_loop())
 
+    async def _await_filled(self, walks: list) -> None:
+        await asyncio.gather(*walks, return_exceptions=True)
+        self._set_filled()
+
+    def _set_filled(self) -> None:
+        """The startup walks are done: what was answered until now is
+        ``binder_unfilled_serves_total`` for good, held reads start, and
+        whoever waits for it (a shard worker's supervisor) is told."""
+        if self._fastpath is not None:
+            self._fold_fastpath_metrics()   # the C lanes' serves so far
+        self._fold_unfilled()
+        self.filled = True
+        self.engine.start_reading()
+        if self.on_filled is not None:
+            self.on_filled()
+
+    def fill_progress(self) -> int:
+        """Names the startup walks have passed; grows until ``filled``."""
+        pre = self._precompiler
+        seeded = 0 if pre is None or pre._seed_task is None else \
+            len(self.zk_cache.nodes) - pre._seed_remaining
+        return self._fill_done + seeded
+
     async def stop(self) -> None:
+        if self._filled_task is not None:
+            self._filled_task.cancel()
+            self._filled_task = None
         if self._verify is not None:
             await self._verify.stop()
         if self._policy_task is not None:

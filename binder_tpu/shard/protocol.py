@@ -12,11 +12,19 @@ One UNIX ``socketpair`` per shard carries two ordered streams:
   owner's mirror exactly — which is why a respawned shard catches up
   by simply reading from the top (snapshot + replay on attach).
 - worker -> supervisor: ``progress`` frames while the worker builds
-  its mirror (nothing else moves on the link then, and the supervisor
-  bounds a start by absence of progress), one ``hello`` after the
-  serve stack is up (pid + bound ports), then 1 Hz ``stats`` frames
-  the supervisor folds into the aggregated ``binder_shard_*`` metrics
-  and ``/status``.
+  its mirror and while its startup walks fill its tables (nothing else
+  moves on the link then, and the supervisor bounds a start by absence
+  of progress), one ``hello`` after the serve stack is up (pid + served
+  ports), then 1 Hz ``stats`` frames the supervisor folds into the
+  aggregated ``binder_shard_*`` metrics and ``/status`` (one more at
+  once when the worker turns ``filled``), and a last ``drained`` frame
+  from a worker that leaves on SIGTERM.
+
+The log's first frame is ``attach``: which inherited descriptors are
+the shard's UDP socket and TCP listener (bound by the supervisor once,
+open for as long as the group serves), and whether this incarnation
+reads them from hello on or only once it is filled (a roll's
+replacement, whose incumbent still serves them).
 
 Framing is 4-byte big-endian length + UTF-8 JSON.  Node data rides as
 the owner mirror's *parsed* JSON (re-serialized), not raw znode bytes:
@@ -108,6 +116,15 @@ def path_gone_frame(path: str) -> dict:
     return {"op": "pgone", "p": path}
 
 
+def attach_frame(udp_fd: int, tcp_fd: int, read_when_filled: bool) -> dict:
+    """Supervisor -> worker, the log's first frame: the descriptor
+    numbers (inherited as they are, ``pass_fds``) of the shard's UDP
+    socket and TCP listener, and when this incarnation starts to read
+    them."""
+    return {"op": "attach", "udp_fd": udp_fd, "tcp_fd": tcp_fd,
+            "read_when_filled": read_when_filled}
+
+
 def state_frame(state: str, connected: bool,
                 disconnected_s: Optional[float],
                 establishments: int) -> dict:
@@ -136,15 +153,24 @@ def hello_frame(shard: int, pid: int, udp_port: int, tcp_port: int,
 
 def stats_frame(requests: float, gen: int, epoch: int, ready: bool,
                 inflight: int, rrl_dropped: int = 0,
-                shed: int = 0) -> dict:
+                shed: int = 0, filled: bool = False) -> dict:
     """1 Hz worker report.  ``rrl_dropped``/``shed`` (response-rate-
     limit drops and total admission sheds, both monotonic per worker
     incarnation) fold into ``binder_shard_rrl_dropped`` /
     ``binder_shard_shed`` so a flood's per-shard spread is scrapeable
-    from the supervisor; older workers simply omit them (defaults)."""
+    from the supervisor; older workers simply omit them (defaults).
+    ``filled``: the worker's startup walks (zone fill, precompile seed)
+    are complete; a roll promotes its replacement on it."""
     return {"op": "stats", "requests": requests, "gen": gen,
             "epoch": epoch, "ready": ready, "inflight": inflight,
-            "rrl_dropped": rrl_dropped, "shed": shed}
+            "rrl_dropped": rrl_dropped, "shed": shed, "filled": filled}
+
+
+def drained_frame(inflight: int, unserved: int) -> dict:
+    """Worker -> supervisor, a leaving worker's last frame: the queries
+    it still held in flight when SIGTERM came, and how many of them it
+    still held at the drain deadline (0: all served out)."""
+    return {"op": "drained", "inflight": inflight, "unserved": unserved}
 
 
 def delta_digest(prev: str, frame: dict) -> str:

@@ -15,8 +15,9 @@ deltas, so N shards serve byte-identical answers off one watch load.
 Lifecycle:
 
 - ``read_snapshot()`` (blocking, before the serve stack exists)
-  consumes the attach-time snapshot: a session ``state`` frame, one
-  ``node`` frame per mirrored name, ``snap-end``.  A respawned shard
+  consumes the attach-time snapshot: the ``attach`` frame (the shard's
+  inherited sockets), a session ``state`` frame, one ``node`` frame per
+  mirrored name, ``snap-end``.  A respawned shard
   catches up exactly this way — snapshot + replay IS the recovery
   story.
 - ``start(loop)`` switches the fd to non-blocking delta reading;
@@ -83,6 +84,9 @@ class ReplicaStore(FakeStore):
         self._dg: Optional[str] = None
         self.tracer = None
         self.on_digest: Optional[Callable] = None
+        # the log's first frame: the shard's inherited sockets and when
+        # to read them (protocol.attach_frame)
+        self.attach: Optional[dict] = None
 
     @classmethod
     def from_fd(cls, fd: int, shard: int, **kw) -> "ReplicaStore":
@@ -230,6 +234,8 @@ class ReplicaStore(FakeStore):
             self._apply_state(frame)
         elif op == "digest":
             self._check_digest(frame)
+        elif op == "attach":
+            self.attach = frame
         else:
             self.log.warning("shard %d: unknown mutation-log op %r",
                              self.shard, op)

@@ -5,11 +5,15 @@ processes behind a balancer (PAPER.md L1); ZDNS (arXiv:2309.13495)
 makes the same shared-nothing argument for DNS throughput.  This is the
 rebuild's version of that story with two deliberate twists:
 
-- **Kernel-balanced sockets.**  Every worker binds the SAME UDP+TCP
-  port with ``SO_REUSEPORT``; the kernel's 4-tuple hash spreads
-  clients across shards with zero balancer hops on the hot path.  A
-  dead worker's socket leaves the reuseport group at once, so its
-  share re-hashes to the survivors while the supervisor respawns it.
+- **Kernel-balanced sockets.**  The supervisor binds one UDP socket
+  and one TCP listener a shard, all on the SAME port with
+  ``SO_REUSEPORT``; the kernel's 4-tuple hash spreads clients across
+  shards with zero balancer hops on the hot path.  A shard's pair is
+  bound once and handed to every incarnation of the shard
+  (``pass_fds``): from the group's first hello to its SIGTERM no socket
+  the kernel may deliver a query to is closed, the group's membership
+  and so the hash never change, and what a leaving or dead worker left
+  unread in its shard's sockets is read by its successor.
 - **One mirror owner.**  Only the supervisor holds the ZK session and
   the store mirror, no matter how many shards serve — N shards never
   multiply the watch load on the ensemble.  Mutations fan out over a
@@ -29,15 +33,19 @@ port is in the supervisor snapshot).
 
 Zero-downtime rolling operations (SIGHUP / ``roll_all``,
 docs/operations.md "Rolling upgrade / config reload"): one shard at a
-time, spawn the replacement worker, stream it the attach snapshot,
-wait for it to converge (hello + replica ready) and join the
-``SO_REUSEPORT`` group — at which point the kernel already splits
-load across old AND new — then SIGTERM the old incarnation, which
-quiesces (stops accepting, serves out in-flight) and exits.  A
-replacement that fails to converge aborts the roll with the old
-worker still serving; no client ever sees an empty group.  Config
-reload rides the same cycle: the config file is re-read once up
-front and each replacement spawns with the fresh config.
+time, spawn the replacement worker onto the shard's sockets, stream it
+the attach snapshot, wait for it to converge (hello, a ready replica,
+and *filled*: its zone fill and precompile seed complete, at which
+point it starts to read the sockets beside the incumbent) — then
+SIGTERM the old incarnation, which stops reading, serves out its
+in-flight queries and exits.  A replacement that fails to converge
+aborts the roll with the old worker still serving; no client ever sees
+an unread socket.  Config reload rides the same cycle: the config file
+is re-read once up front and each replacement spawns with the fresh
+config.  What a roll did is the supervisor's to tell, since a rolled
+worker's counters die with its pid: ``binder_shard_roll_phase_seconds``
+(attach, fill, drain), ``binder_shard_roll_inflight_total``,
+``binder_shard_roll_unserved_total``.
 """
 from __future__ import annotations
 
@@ -56,6 +64,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from binder_tpu.dns.server import bind_socket_pair
 from binder_tpu.introspect.status import Introspector
 from binder_tpu.shard import protocol
 from binder_tpu.verify.tracer import PropagationTracer
@@ -76,6 +85,12 @@ MAX_LINK_BUFFER = 256 << 20
 #: quiesces and exits on SIGTERM; stragglers are KILLed)
 ROLL_DRAIN_S = 10.0
 
+#: a roll's three phases a shard: spawn to hello, hello to filled,
+#: SIGTERM to the incumbent's exit
+ROLL_PHASES = ("attach", "fill", "drain")
+ROLL_PHASE_BUCKETS = (0.05, 0.1, 0.25, 0.5, 1.0, 2.0, 5.0, 10.0, 20.0,
+                      40.0, 80.0, 160.0)
+
 SUPERVISOR_SNAPSHOT_VERSION = 1
 
 
@@ -87,7 +102,7 @@ class ShardLink:
                  "last_rrl_dropped", "last_shed",
                  "spawned_mono", "rbuf", "closed",
                  "snap_queue", "snap_sent", "progress_at",
-                 "dg", "skew_pending")
+                 "dg", "skew_pending", "hello_mono", "drained")
 
     def __init__(self, shard: int, proc: subprocess.Popen,
                  sock: socket.socket) -> None:
@@ -125,6 +140,10 @@ class ShardLink:
         # deltas to hash-but-suppress (forcing a detectable mismatch)
         self.dg: Optional[str] = None
         self.skew_pending = 0
+        # when hello came, and the worker's last word when it left on
+        # SIGTERM (protocol.drained_frame)
+        self.hello_mono: Optional[float] = None
+        self.drained: Optional[dict] = None
 
 
 class ShardSupervisor:
@@ -143,9 +162,13 @@ class ShardSupervisor:
         self.n = max(1, int(options.get("shards") or 1))
         self.host = str(options.get("host", "0.0.0.0"))
         self.port = int(options.get("port", 0))
-        # resolved by shard 0's hello when the configured port is 0
+        # resolved by the first shard's bind when the configured port
+        # is 0
         self.udp_port: Optional[int] = self.port or None
         self.tcp_port: Optional[int] = None
+        # one (UDP socket, TCP listener) a shard, bound at start and
+        # open until the group has drained; workers inherit them
+        self._socks: Dict[int, tuple] = {}
         self.links: Dict[int, ShardLink] = {}
         # rolling upgrade state: replacement links catching up while
         # the incumbent still serves (shard -> ShardLink), the roll
@@ -246,6 +269,25 @@ class ShardSupervisor:
             "failed to converge (the old worker kept serving)"
         ).labelled()
         self._m_roll_aborts.inc(0)
+        phase = c.histogram(
+            "binder_shard_roll_phase_seconds",
+            "a rolled shard's phases: attach (spawn to hello), fill "
+            "(hello to filled), drain (SIGTERM to the incumbent's exit)",
+            ROLL_PHASE_BUCKETS)
+        self._m_roll_phase = {p: phase.labelled({"phase": p})
+                              for p in ROLL_PHASES}
+        self._m_roll_inflight = c.counter(
+            "binder_shard_roll_inflight_total",
+            "queries rolled incumbents still held in flight at SIGTERM "
+            "and served out before they left").labelled()
+        self._m_roll_unserved = c.counter(
+            "binder_shard_roll_unserved_total",
+            "queries rolled incumbents still held at the drain "
+            "deadline").labelled()
+        self._m_roll_inflight.inc(0)
+        self._m_roll_unserved.inc(0)
+        self.roll_inflight = 0
+        self.roll_unserved = 0
         self._rrl_drop_children = {}
         self._shed_children = {}
         self._roll_children = {}
@@ -297,14 +339,19 @@ class ShardSupervisor:
     # -- lifecycle --
 
     async def start(self) -> None:
-        """Spawn shard 0 first (it resolves an ephemeral port draw for
-        the whole reuseport group), then the rest concurrently."""
+        """Bind every shard's sockets (the first pair resolves an
+        ephemeral port draw for the whole reuseport group), then spawn
+        shard 0 first and the rest concurrently."""
         self._loop = asyncio.get_running_loop()
         self._tmpdir = tempfile.mkdtemp(prefix="binder-shards-")
-        self._spawn(0, self.port)
-        hello = await self._wait_started(0)
-        self.udp_port = int(hello["udp_port"])
-        self.tcp_port = int(hello["tcp_port"])
+        for i in range(self.n):
+            udp, tcp = bind_socket_pair(self.host, self.udp_port or 0,
+                                        reuse_port=True)
+            self._socks[i] = (udp, tcp)
+            self.udp_port = udp.getsockname()[1]
+            self.tcp_port = tcp.getsockname()[1]
+        self._spawn(0, self.udp_port)
+        await self._wait_started(0)
         for i in range(1, self.n):
             self._spawn(i, self.udp_port)
         for i in range(1, self.n):
@@ -330,16 +377,19 @@ class ShardSupervisor:
     WORKER_QUIET_S = 30.0
 
     async def _wait_converged(self, link: ShardLink,
-                              need_ready: bool = False) -> Optional[str]:
+                              need_filled: bool = False) -> Optional[str]:
         """Wait for *link*'s worker to say hello (and, for a roll's
-        replacement, to report a ready replica over the stats feed).
-        Returns None once it has, else why it was given up on: the
-        process exited, or nothing moved for ``WORKER_QUIET_S``.
-        Stats frames are not progress — a live worker whose replica
-        never turns ready must still run out of window."""
+        replacement, to report over the stats feed a ready replica and
+        that it is *filled*: its zone fill and precompile seed are
+        complete and it reads its shard's sockets).  Returns None once
+        it has, else why it was given up on: the process exited, or
+        nothing moved for ``WORKER_QUIET_S``.  Stats frames are not
+        progress — a live worker whose replica never turns ready, or
+        whose walks stand still, must still run out of window."""
         while True:
-            if link.hello is not None and (
-                    not need_ready or (link.stats or {}).get("ready")):
+            stats = link.stats or {}
+            if link.hello is not None and (not need_filled or (
+                    stats.get("ready") and stats.get("filled"))):
                 return None
             if link.closed or link.proc.poll() is not None:
                 return "worker exited before converging"
@@ -355,7 +405,8 @@ class ShardSupervisor:
         return self.links[i].hello
 
     def _worker_config(self, port: int) -> str:
-        """Write the resolved worker config once per port draw.  The
+        """Write the resolved worker config once (again after a config
+        reload).  The
         store block is STRIPPED — a worker must never open its own
         store session (that is the whole point of the owner) — and so
         are the supervisor-only knobs."""
@@ -369,8 +420,7 @@ class ShardSupervisor:
         path = os.path.join(self._tmpdir, "worker-config.json")
         with open(path, "w") as f:
             json.dump(opts, f)
-        if port:
-            self._cfg_path = path
+        self._cfg_path = path
         return path
 
     def _spawn(self, i: int, port: int) -> None:
@@ -380,9 +430,13 @@ class ShardSupervisor:
                     role: str = "serving") -> ShardLink:
         """Create one worker incarnation WITHOUT installing it as the
         shard's serving link — the rolling upgrade spawns replacements
-        that catch up next to the incumbent before promotion."""
+        that catch up next to the incumbent before promotion.  The
+        worker inherits the shard's sockets; a replacement reads them
+        only once it is filled, any other incarnation from hello on (a
+        fresh or respawned shard has nobody else to answer)."""
         parent, child = socket.socketpair(socket.AF_UNIX,
                                           socket.SOCK_STREAM)
+        udp, tcp = self._socks[i]
         argv = [sys.executable, "-u", "-m", "binder_tpu.main",
                 "-f", self._worker_config(port),
                 "--shard-worker", str(i)]
@@ -392,14 +446,17 @@ class ShardSupervisor:
             os.path.abspath(__file__))))
         env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
         try:
-            proc = subprocess.Popen(argv, pass_fds=(child.fileno(),),
-                                    env=env)
+            proc = subprocess.Popen(
+                argv, pass_fds=(child.fileno(), udp.fileno(),
+                                tcp.fileno()), env=env)
         finally:
             child.close()
         parent.setblocking(False)
         link = ShardLink(i, proc, parent)
         self._loop.add_reader(parent.fileno(), self._on_worker_readable,
                               link)
+        self._send(link, protocol.attach_frame(
+            udp.fileno(), tcp.fileno(), role == "replacement"))
         # attach-time snapshot: the worker replays this, then the
         # delta feed continues seamlessly on the same ordered stream
         self._send_snapshot(link)
@@ -715,7 +772,7 @@ class ShardSupervisor:
                 link.progress_at = time.monotonic()
             elif op == "hello":
                 link.hello = frame
-                link.progress_at = time.monotonic()
+                link.progress_at = link.hello_mono = time.monotonic()
                 self._consec_fail[link.shard] = 0
                 self.log.info(
                     "shard %d serving: pid %d udp %s tcp %s metrics %s",
@@ -725,6 +782,8 @@ class ShardSupervisor:
                 self._fold_stats(link, frame)
             elif op == "digest-report":
                 self._on_digest_report(link, frame)
+            elif op == "drained":
+                link.drained = frame
 
     def _on_digest_report(self, link: ShardLink, frame: dict) -> None:
         """A replica flagged a mutation-log digest mismatch: count the
@@ -972,15 +1031,18 @@ class ShardSupervisor:
         return True
 
     async def roll_shard(self, i: int) -> bool:
-        """One drain-and-replace step.  The incumbent keeps serving
-        until the replacement has (1) replayed the attach snapshot,
-        (2) reported hello — its SO_REUSEPORT sockets are bound, the
-        kernel is already splitting load across both incarnations —
-        and (3) reported a ready replica over the stats feed.  Only
-        then does the incumbent get SIGTERM, quiesce (serve out
-        in-flight), and exit.  Every phase is a ``rolling-upgrade``
-        flight event; failure to converge aborts with the incumbent
-        untouched."""
+        """One drain-and-replace step.  The incumbent keeps serving,
+        alone, until the replacement (spawned onto the shard's own
+        sockets) has (1) replayed the attach snapshot and said hello,
+        (2) reported a ready replica and (3) reported *filled* over the
+        stats feed: its zone fill and precompile seed are complete and
+        it has started to read the sockets.  Only then does the
+        incumbent get SIGTERM, stop reading, serve out its in-flight
+        queries and exit; what it left unread in the sockets is the
+        replacement's.  Every phase is a ``rolling-upgrade`` flight
+        event, the three durations are observed into
+        ``binder_shard_roll_phase_seconds``; failure to converge aborts
+        with the incumbent untouched."""
         if self.udp_port is None or i in self._roll_links \
                 or not 0 <= i < self.n:
             return False
@@ -990,10 +1052,13 @@ class ShardSupervisor:
         if self.recorder is not None:
             self.recorder.record("rolling-upgrade", phase="spawn",
                                  shard=i, old_pid=old_pid)
-        repl = self._spawn_link(i, self.udp_port, role="replacement")
+        # a shard with nobody serving it is not made to wait for a fill
+        alive = old is not None and old.proc.poll() is None
+        repl = self._spawn_link(i, self.udp_port,
+                                role="replacement" if alive else "serving")
         self._roll_links[i] = repl
         try:
-            reason = await self._wait_converged(repl, need_ready=True)
+            reason = await self._wait_converged(repl, need_filled=True)
             if reason is not None:
                 self.roll_aborts += 1
                 self._m_roll_aborts.inc()
@@ -1010,22 +1075,32 @@ class ShardSupervisor:
                 except Exception:
                     pass
                 return False
+            filled = time.monotonic()
+            attach_s = repl.hello_mono - repl.spawned_mono
+            fill_s = filled - repl.hello_mono
             if self.recorder is not None:
                 self.recorder.record(
                     "rolling-upgrade", phase="promote", shard=i,
                     old_pid=old_pid, new_pid=repl.proc.pid,
-                    snapshot_frames=repl.snap_sent)
+                    snapshot_frames=repl.snap_sent,
+                    attach_s=round(attach_s, 3), fill_s=round(fill_s, 3))
             self.links[i] = repl
             if old is not None:
                 await self._drain_incumbent(old)
+            drain_s = time.monotonic() - filled
+            for phase, seconds in zip(ROLL_PHASES,
+                                      (attach_s, fill_s, drain_s)):
+                self._m_roll_phase[phase].observe(seconds)
             self.rolls[i] += 1
             self._roll_children[i].inc()
-            self.log.info("shard %d rolled: pid %s -> %d", i, old_pid,
-                          repl.proc.pid)
+            self.log.info("shard %d rolled: pid %s -> %d (attach %.2fs, "
+                          "fill %.2fs, drain %.2fs)", i, old_pid,
+                          repl.proc.pid, attach_s, fill_s, drain_s)
             if self.recorder is not None:
                 self.recorder.record("rolling-upgrade", phase="done",
                                      shard=i, old_pid=old_pid,
-                                     new_pid=repl.proc.pid)
+                                     new_pid=repl.proc.pid,
+                                     drain_s=round(drain_s, 3))
             return True
         finally:
             self._roll_links.pop(i, None)
@@ -1033,9 +1108,10 @@ class ShardSupervisor:
 
     async def _drain_incumbent(self, link: ShardLink) -> None:
         """SIGTERM the outgoing incarnation and wait bounded: the
-        worker quiesces (leaves the reuseport group, serves out its
-        in-flight queries) and exits clean; a straggler is KILLed at
-        the deadline."""
+        worker stops reading the shard's sockets, serves out its
+        in-flight queries, says what it held (``drained``) and exits
+        clean; a straggler is KILLed at the deadline, and what its last
+        stats frame held in flight counts as unserved."""
         proc = link.proc
         if proc.poll() is None:
             try:
@@ -1044,7 +1120,7 @@ class ShardSupervisor:
                 pass
         deadline = time.monotonic() + ROLL_DRAIN_S
         while proc.poll() is None and time.monotonic() < deadline:
-            await asyncio.sleep(0.05)
+            await asyncio.sleep(0.02)
         if proc.poll() is None:
             self.log.warning("shard %d: outgoing pid %d ignored the "
                              "drain window; killing", link.shard,
@@ -1057,6 +1133,21 @@ class ShardSupervisor:
             proc.wait(timeout=5)
         except Exception:
             pass
+        if not link.closed:
+            self._on_worker_readable(link)      # its last word, if unread
+        last = link.drained or {
+            "inflight": (link.stats or {}).get("inflight", 0),
+            "unserved": (link.stats or {}).get("inflight", 0)}
+        unserved = int(last.get("unserved") or 0)
+        served_out = max(0, int(last.get("inflight") or 0) - unserved)
+        self.roll_inflight += served_out
+        self.roll_unserved += unserved
+        self._m_roll_inflight.inc(served_out)
+        self._m_roll_unserved.inc(unserved)
+        if unserved:
+            self.log.warning("shard %d: outgoing pid %d left %d "
+                             "quer(ies) unserved", link.shard, proc.pid,
+                             unserved)
         self._close_link(link)
 
     async def drain(self, timeout: float = 10.0) -> None:
@@ -1101,6 +1192,11 @@ class ShardSupervisor:
             self._close_link(link)
         self.links.clear()
         self._roll_links.clear()
+        # the shards' sockets go last: no worker is left to answer
+        for pair in self._socks.values():
+            for sock in pair:
+                sock.close()
+        self._socks.clear()
         if self._tmpdir is not None:
             shutil.rmtree(self._tmpdir, ignore_errors=True)
             self._tmpdir = None
@@ -1135,6 +1231,7 @@ class ShardSupervisor:
                 "generation": (stats or {}).get("gen", 0),
                 "epoch": (stats or {}).get("epoch", 0),
                 "ready": bool((stats or {}).get("ready")),
+                "filled": bool((stats or {}).get("filled")),
                 "inflight": (stats or {}).get("inflight", 0),
                 "last_report_age_seconds": (
                     None if link is None or not link.stats_at
@@ -1160,6 +1257,8 @@ class ShardSupervisor:
                 "respawns_total": sum(self.respawns.values()),
                 "rolls_total": sum(self.rolls.values()),
                 "roll_aborts": self.roll_aborts,
+                "roll_inflight": self.roll_inflight,
+                "roll_unserved": self.roll_unserved,
                 "rolling_shard": self._rolling_shard,
                 "digest_checks": self.digest_checks,
                 "digest_violations": self.digest_violations,

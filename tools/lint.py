@@ -868,7 +868,12 @@ _SHARD_FAMILIES = {
     "binder_shard_requests": ("counter", True),
     "binder_shard_rolls_total": ("counter", True),
     "binder_shard_roll_aborts_total": ("counter", False),
+    "binder_shard_roll_inflight_total": ("counter", False),
+    "binder_shard_roll_unserved_total": ("counter", False),
 }
+
+#: a rolled shard's phases, one series each from scrape 1
+_SHARD_ROLL_PHASES = ("attach", "fill", "drain")
 
 
 def validate_shard_metrics(text):
@@ -905,6 +910,14 @@ def validate_shard_metrics(text):
                     errs.append(f"{family}: sample missing the "
                                 f"`shard` label")
                     break
+    family = "binder_shard_roll_phase_seconds"
+    if types.get(family) != "histogram":
+        errs.append(f"{family}: declared {types.get(family)!r}, "
+                    "expected 'histogram'")
+    for phase in _SHARD_ROLL_PHASES:
+        if not any(f'phase="{phase}"' in labels
+                   for labels in samples.get(family + "_count", ())):
+            errs.append(f"{family}: no series for phase {phase!r}")
     return errs
 
 

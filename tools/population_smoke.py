@@ -75,8 +75,8 @@ RRL_RPS, RRL_BURST = 60, 120
 GOODPUT_FLOOR = 0.5
 FP_CEILING = 0.10
 #: freak-packet tolerance for first-try probe timeouts across 4 rolls
-#: (the quiesce drain leaves a sub-millisecond close window); LOST
-#: queries get zero tolerance
+#: (a loaded CI host, not the hand-over: no socket is closed in a roll
+#: since PR 45); LOST queries get zero tolerance
 ROLL_RETRY_TOLERANCE = 3
 
 
