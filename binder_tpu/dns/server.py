@@ -708,9 +708,14 @@ class DnsServer:
         # The query log's writer (installed by BinderServer where lines
         # are rendered ahead of their write: the native ring's and the
         # Python lanes' direct ones); a lane calls it once a readiness
-        # event (the UDP lane once a drain, of which an event holds one
-        # or a chain), after the batch's responses are sent, so one
-        # stream write carries a whole batch of lines (_flush_log).
+        # event (the UDP lane too: one write a callback, whether it
+        # holds one drain or a chain), after the responses are sent, so
+        # one stream write carries the whole event's lines (_flush_log).
+        # Two guarantees are kept: every served query's line is written
+        # before the loop is given back to select, and no answer waits
+        # behind a log write.  One was given up (ISSUE 46): a drain's
+        # lines are written before the next recvmmsg of the same
+        # callback.
         self.log_flush: Optional[Callable[[], None]] = None
         # True while a lane callback runs that ends in _flush_log: a
         # line rendered meanwhile is left to it
@@ -907,9 +912,10 @@ class DnsServer:
             return None
 
     def _flush_log(self) -> None:
-        """The end of a lane's readiness callback, and of every drain
-        of a UDP one: write the query-log lines it produced, the native
-        ring's and the Python lanes' alike, in one write."""
+        """The end of a lane's readiness callback (a UDP one's too,
+        after the last drain of its chain): write the query-log lines
+        it produced, the native ring's and the Python lanes' alike, in
+        one write, before the loop is given back to ``select``."""
         self.log_flush_owed = False
         flush = self.log_flush
         if flush is not None:
@@ -1029,15 +1035,16 @@ class DnsServer:
     # starvation of timers/TCP under sustained UDP flood.
     _UDP_BURST = 128
     # A drain (the socket read until a recvmmsg brings fewer than 64,
-    # the answers sent, their log lines written) that brought this many
-    # datagrams or more is followed by the next in the same callback,
-    # with no select between them: a socket that filled while one batch
+    # the answers sent) that brought this many datagrams or more is
+    # followed by the next in the same callback, with no select and no
+    # log write between them: a socket that filled while one batch
     # was served has most likely filled again, and the queries in it
     # would otherwise wait out a trip through the loop.  One datagram is
     # what a woken, otherwise idle loop finds, and the recvmmsg that
     # would find nothing behind it costs a crossing.  _UDP_BURST bounds
     # the whole chain: a callback starts no recvmmsg once it has taken
-    # that many datagrams.
+    # that many datagrams.  The chain's log lines leave together, in one
+    # write after its last drain's answers.
     _UDP_CHAIN_MIN = 2
 
     async def listen_udp(self, address: str, port: int,
@@ -1214,10 +1221,17 @@ class DnsServer:
 
         A readiness callback holds one *drain* or a chain of them
         (``_UDP_CHAIN_MIN``).  Every drain keeps the order of a lone
-        one: its answers leave before its log lines are written, and
-        its lines are written before the next ``recvmmsg``.  The gate,
-        the generation and the limiter's sampling tick are a drain's,
-        not a callback's."""
+        one: receives, the misses through Python, one ``send_batch``.
+        The gate, the generation and the limiter's sampling tick are a
+        drain's, not a callback's.  The query log is the callback's:
+        one write, after the last drain's answers and before the loop
+        is given back.  Two guarantees are kept: every served query's
+        line is written before the loop is given back to ``select``,
+        and no answer waits behind a log write.  One was given up: a
+        drain's lines are written before the next ``recvmmsg`` of the
+        same callback.  A line is later by at most the rest of one
+        callback, which ``_UDP_BURST`` bounds, and a callback that
+        holds one drain writes exactly where it did."""
         handle_raw = self._handle_raw
         recv_batch = _fastio.recv_batch
         send_batch = _fastio.send_batch
@@ -1271,12 +1285,12 @@ class DnsServer:
             socket read until a ``recvmmsg`` brings fewer than 64 (or
             the callback, which had ``drained`` before, has its
             ``_UDP_BURST``), the misses through Python and their
-            answers in one ``sendmmsg``, then the drain's log lines.
+            answers in one ``sendmmsg``.  Its log lines wait in the
+            ring and in ``_log_pending`` for the callback's one write.
             Returns the datagrams it brought, or -1 where the callback
             has to end here: the socket failed, or a send was short."""
             out: list = []
             batch_out[0] = out
-            self.log_flush_owed = True
             # fast path on/off is decided once per drain — the gate
             # (query-log / probe state) can flip at runtime, and a
             # sampled drain can trip the limiter's hot()
@@ -1361,20 +1375,25 @@ class DnsServer:
                     except OSError as e:
                         log.error("batched UDP send failed: %s", e)
                         short = True
-                # after the batch's responses: no answer waits behind
-                # a log write, and the batch's lines share one
-                self._flush_log()
             return -1 if short else got
 
         def on_readable() -> None:
             # the drains of one socket are chained for as long as the
             # drains themselves say that the socket is being fed, and
             # the send buffer takes what they answer
-            drained = got = drain(0)
-            while got >= chain_min and drained < burst:
-                self.udp_chained_drains += 1
-                got = drain(drained)
-                drained += got
+            self.log_flush_owed = True
+            try:
+                drained = got = drain(0)
+                while got >= chain_min and drained < burst:
+                    self.udp_chained_drains += 1
+                    got = drain(drained)
+                    drained += got
+            finally:
+                # after the last drain's responses, whatever ended the
+                # chain: no answer waits behind a log write, no
+                # recvmmsg of the chain behind one either, and every
+                # drain's lines share the one write
+                self._flush_log()
 
         return on_readable
 

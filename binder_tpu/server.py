@@ -552,9 +552,9 @@ class BinderServer:
         # (DnsServer._UDP_CHAIN_MIN)
         self._chained_child = self.collector.counter(
             "binder_udp_chained_drains_total",
-            "UDP drains (the socket read empty, the answers sent, their "
-            "log lines written) made in the callback of the drain "
-            "before them, with no select between").labelled()
+            "UDP drains (the socket read empty, the answers sent) made "
+            "in the callback of the drain before them, with no select "
+            "and no log write between").labelled()
         self._chained_child.inc(0)       # series exists from scrape 1
         self._chained_folded = 0
         # stream-lane counters (dns/stream.py TcpStats), folded at
@@ -659,9 +659,14 @@ class BinderServer:
         # prefix rendered once: by C into a byte ring, one complete
         # bunyan-style line per native serve, and by _on_after into
         # _log_pending for a Python-lane query.  Ring and pending lines
-        # go out in ONE stream write a drain, after the
-        # batch's responses, onto the same stream the JSON logger
-        # writes to.  Before the ring the fast path stood down
+        # go out in ONE stream write a readiness callback (a UDP one:
+        # after the last drain of its chain), after the responses,
+        # onto the same stream the JSON logger writes to.  Two
+        # guarantees are kept: every served query's line is written
+        # before the loop is given back to select, and no answer waits
+        # behind a log write.  One was given up (ISSUE 46): a drain's
+        # lines are written before the next recvmmsg of the same
+        # callback.  Before the ring the fast path stood down
         # completely under logging, forfeiting ~9x throughput.  A
         # serve that cannot produce its line (ring full, no fragment)
         # DECLINES to the Python path, which logs: pressure degrades
@@ -687,6 +692,16 @@ class BinderServer:
             self.engine.log_flush = self._write_log
             if (self._fastpath is not None
                     and hasattr(_fastio, "fastpath_log_enable")):
+                # The ring holds what one UDP callback serves between
+                # two writes: at most _UDP_BURST + 63 = 191 datagrams
+                # (a drain starts its last recvmmsg of 64 with 127
+                # taken), whether the callback is one drain or a chain.
+                # 191 lines of the hosts zone's 388 bytes are 74 KB; of
+                # the longest a native serve can write (512 of prefix,
+                # 256 of overhead, a fragment of FP_MAX_FRAG 4,096)
+                # 929,024 bytes, under the 1,048,576 here.  A serve that
+                # finds no room declines to Python (70 us in place of
+                # 3), which logs: no line is lost either way.
                 try:
                     _fastio.fastpath_log_enable(
                         self._fastpath, self._log_prefix, 1 << 20)
@@ -2048,9 +2063,9 @@ class BinderServer:
 
     def _log_owed(self) -> None:
         """A line went into ``_log_pending``: see that it is written
-        before the loop next blocks.  A UDP drain writes in its own
-        ``finally``, after its ``send_batch``; every other lane's line
-        arms one ``call_soon``."""
+        before the loop next blocks.  A UDP callback writes in its own
+        ``finally``, after its last drain's ``send_batch``; every other
+        lane's line arms one ``call_soon``."""
         if self._log_soon or self.engine.log_flush_owed:
             return
         try:
@@ -2076,9 +2091,10 @@ class BinderServer:
     def _write_log(self) -> None:
         """The query log's one writer: the native ring's complete lines
         and the pending Python-lane lines, in one write per handler.
-        Called once a readiness event (the UDP lane: once a drain, of
-        which an event holds one or a chain) by the lane that served it, in
-        its ``finally`` after the responses are sent
+        Called once a readiness event (the UDP lane too: once a
+        callback, after the last drain of its chain) by the lane that
+        served it, in its ``finally`` after the responses are sent and
+        before the loop is given back to ``select``
         (``DnsServer._flush_log``), by ``_log_owed``'s ``call_soon``,
         by a record on its way through ``logging``, and by the
         periodic flusher that nets idle tails."""

@@ -644,3 +644,80 @@ def test_other_loggers_and_streams_stay_on_logging(kind):
         assert text.count('"msg": "DNS query"') == n
     if kind == "translating":
         assert text.count("\r\n") == text.count("\n") >= n
+
+
+# -- the ring holds a UDP callback's lines between two writes (ISSUE 46) --
+
+def test_a_callback_of_the_burst_and_63_native_serves_leaves_no_decline(
+        monkeypatch):
+    """One callback of the batched reader takes at most ``_UDP_BURST``
+    + 63 datagrams (a drain starts its last ``recvmmsg`` of 64 with 127
+    taken) and writes the log once, behind the last of them.  That many
+    native serves of the fixture's longest line (the SRV set, three
+    answers and three additional records) leave the ring without a
+    decline: every one is C's, and its line is in the one write."""
+    burst = dns_server.DnsServer._UDP_BURST
+    n = burst + 63
+    brought = []
+    real_drain = fastio.fastpath_drain
+
+    async def run():
+        stream, raw = byte_stream()
+        cli = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+        cli.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 1 << 20)
+        cli.setblocking(False)
+
+        def ask(k):
+            for _ in range(k):
+                cli.send(make_query("_pg._tcp.svc.foo.com", Type.SRV,
+                                    qid=len(brought)).encode())
+
+        def fastpath_drain(fp, fd, gen, cap):
+            got = real_drain(fp, fd, gen, cap)
+            brought.append((len(got[0]), got[1]))
+            if len(brought) == 1:
+                # behind a first drain of 63 the chain's second finds
+                # two full batches: 63 + 64 = 127, then its last 64
+                ask(n - 63)
+            return got
+
+        monkeypatch.setattr(dns_server._fastio, "fastpath_drain",
+                            fastpath_drain)
+        store, cache = fixture_store()
+        server = await start_logged_server(cache, stream)
+        loop = asyncio.get_running_loop()
+        try:
+            cli.connect(("127.0.0.1", server.udp_port))
+            was = fastio.fastpath_stats(server._fastpath)
+            writes = server.io_introspect()["log_writes"]
+            ask(63)
+            answers = []
+            for _ in range(n):
+                answers.append(await asyncio.wait_for(
+                    loop.sock_recv(cli, 4096), 5))
+            await asyncio.sleep(0)
+            now = fastio.fastpath_stats(server._fastpath)
+            return (answers, was, now,
+                    server.io_introspect()["log_writes"] - writes,
+                    server.engine.udp_chained_drains, raw)
+        finally:
+            await server.stop()
+            cli.close()
+
+    answers, was, now, writes, chained, raw = asyncio.run(run())
+    assert brought[:3] == [(0, 63), (0, 64), (0, 64)]   # all C's
+    assert chained >= 1 and writes == 1
+    assert now["log_declines"] == was["log_declines"]
+    assert now["log_lines"] - was["log_lines"] == n
+    decoded = [Message.decode(a) for a in answers]
+    assert all(m.rcode == Rcode.NOERROR and len(m.answers) == 3
+               for m in decoded)
+    lines = query_lines(raw)
+    assert len(lines) == n
+    assert all(ln["query"]["type"] == "SRV" for ln in lines)
+    # the ring's size against the callback's bound: the lines of this
+    # fixture, and the longest line a native serve can write (512 bytes
+    # of prefix, 256 of overhead, a fragment of FP_MAX_FRAG 4,096)
+    longest = max(len(ln) + 1 for ln in raw.getvalue().splitlines())
+    assert n * longest < 1 << 20
+    assert n * (512 + 256 + 4096) < 1 << 20

@@ -1,15 +1,16 @@
 """Chained drains of one UDP socket, and the answers a full send buffer
-costs (ISSUE 43).
+costs (ISSUE 43); the chain's one log write (ISSUE 46).
 
-A *drain* is what a readiness callback of the batched reader did before
-the chain: the socket read (``fastpath_drain`` or, sampled or gated,
-``recv_batch``) until a call brings fewer than 64, the Python lanes'
-answers in one ``send_batch``, then the drain's log lines in one write.
-A drain that brought ``_UDP_CHAIN_MIN`` datagrams or more is followed by
-the next in the same callback.  The chain ends at a drain that brought
-fewer, once the callback has taken ``_UDP_BURST`` datagrams, on a socket
-error and on a short send, which is retried once and whose rest is
-counted by lane (``binder_udp_send_drops_total``).
+A *drain* is the socket read (``fastpath_drain`` or, sampled or gated,
+``recv_batch``) until a call brings fewer than 64, then the Python
+lanes' answers in one ``send_batch``.  A drain that brought
+``_UDP_CHAIN_MIN`` datagrams or more is followed by the next in the same
+callback.  The chain ends at a drain that brought fewer, once the
+callback has taken ``_UDP_BURST`` datagrams, on a socket error and on a
+short send, which is retried once and whose rest is counted by lane
+(``binder_udp_send_drops_total``).  Whatever ends it, the callback then
+writes the query log once: every drain's lines in one write, after the
+last drain's answers and before the loop is given back.
 
 The reader runs over real sockets and the real extension: a loopback UDP
 socket filled before the callback is called, every call of the
@@ -59,14 +60,18 @@ class Recorded:
     """The extension's three calls of the reader, each passed on to the
     real one and written down in order: ``("fp"|"py", brought)``,
     ``("send", offered, taken)``; the engine's log write lands in the
-    same list as ``("flush",)``.  ``on_flush(i)`` runs after the i-th
-    log write: where a test feeds the socket between two drains."""
+    same list as ``("flush",)``.  ``on_send(i)`` runs after the i-th
+    ``send_batch`` (``on_recv(i)`` after the i-th receive, for drains
+    that C answered whole): where a test feeds the socket between two
+    drains."""
 
     def __init__(self):
         self.calls = []
-        self.flushes = 0
-        self.on_flush = None
+        self.sends = self.recvs = 0
+        self.on_send = None
+        self.on_recv = None
         self.recv_error_at = None       # index of the receive that fails
+        self.recv_error = OSError(9, "scripted socket error")
         self.scripted = None            # recv_batch hands these out instead
 
     def receives(self):
@@ -75,7 +80,13 @@ class Recorded:
     def _maybe_fail(self, lane):
         if self.recv_error_at == len(self.receives()):
             self.calls.append((lane, "error"))
-            raise OSError(9, "scripted socket error")
+            raise self.recv_error
+
+    def _received(self, lane, brought):
+        self.calls.append((lane, brought))
+        i, self.recvs = self.recvs, self.recvs + 1
+        if self.on_recv is not None:
+            self.on_recv(i)
 
     def recv_batch(self, fd, cap):
         assert cap == 64
@@ -84,7 +95,7 @@ class Recorded:
             msgs = self.scripted.pop(0) if self.scripted else []
         else:
             msgs = fastio.recv_batch(fd, cap)
-        self.calls.append(("py", len(msgs)))
+        self._received("py", len(msgs))
         return msgs
 
     def fastpath_drain(self, fp, fd, gen, cap):
@@ -92,20 +103,20 @@ class Recorded:
         self._maybe_fail("fp")
         msgs, served, retried, dropped = fastio.fastpath_drain(
             fp, fd, gen, cap)
-        self.calls.append(("fp", len(msgs) + served))
         self.last_send = (retried, dropped)
+        self._received("fp", len(msgs) + served)
         return msgs, served, retried, dropped
 
     def send_batch(self, fd, out):
         taken = fastio.send_batch(fd, out)
         self.calls.append(("send", len(out), taken))
+        i, self.sends = self.sends, self.sends + 1
+        if self.on_send is not None:
+            self.on_send(i)
         return taken
 
     def flush(self):
         self.calls.append(("flush",))
-        i, self.flushes = self.flushes, self.flushes + 1
-        if self.on_flush is not None:
-            self.on_flush(i)
 
 
 def loopback():
@@ -162,14 +173,14 @@ def reader_over(monkeypatch, sock, lane, rrl=None):
 
 def calls_of(drains, lane):
     """What a callback of these drains has to call, in order: each
-    drain's receives, its one ``send_batch`` if it brought anything,
-    its log write."""
+    drain's receives and its one ``send_batch`` if it brought anything,
+    then the callback's one log write."""
     want = []
     for recvs in drains:
         want += [(lane, n) for n in recvs]
         if sum(recvs):
             want.append(("send", sum(recvs), sum(recvs)))
-        want.append(("flush",))
+    want.append(("flush",))
     return want
 
 
@@ -216,6 +227,24 @@ def test_the_drains_of_a_callback_by_what_the_socket_holds(
     cli.close()
 
 
+@pytest.mark.parametrize("lane", ["fp", "py"])
+def test_a_lone_drains_calls_are_what_they_were(monkeypatch, lane):
+    """A callback that holds one drain cannot see ISSUE 46: one receive,
+    one ``send_batch``, one log write, in that order."""
+    srv, cli = loopback()
+    engine, on_readable, rec = reader_over(monkeypatch, srv, lane)
+    for _ in range(3):
+        del rec.calls[:]
+        feed(cli, 1)
+        on_readable()
+        assert rec.calls == [(lane, 1), ("send", 1, 1), ("flush",)]
+        assert not engine.log_flush_owed
+    assert engine.udp_chained_drains == 0
+    assert len(waiting(cli)) == 3
+    srv.close()
+    cli.close()
+
+
 def test_what_a_burst_leaves_is_the_next_callbacks(monkeypatch):
     srv, cli = loopback()
     engine, on_readable, rec = reader_over(monkeypatch, srv, "fp")
@@ -246,7 +275,7 @@ def test_a_chain_follows_the_socket_and_stops_at_the_burst(
     srv, cli = loopback()
     engine, on_readable, rec = reader_over(monkeypatch, srv, lane)
     feed(cli, fed[0])
-    rec.on_flush = lambda i: i + 1 < len(fed) and feed(cli, fed[i + 1])
+    rec.on_send = lambda i: i + 1 < len(fed) and feed(cli, fed[i + 1])
     on_readable()
     assert rec.calls == calls_of(drains, lane)
     assert engine.udp_chained_drains == len(drains) - 1
@@ -269,10 +298,10 @@ def test_one_packets_exception_costs_neither_the_chain_nor_the_drain(
     engine._handle_raw = handle_raw
     on_readable = engine._batched_udp_reader(srv)
     feed(cli, 3)
-    rec.on_flush = lambda i: i == 0 and feed(cli, 2, b"r")
+    rec.on_send = lambda i: i == 0 and feed(cli, 2, b"r")
     on_readable()
-    assert rec.calls == [("fp", 3), ("send", 2, 2), ("flush",),
-                         ("fp", 2), ("send", 2, 2), ("flush",),
+    assert rec.calls == [("fp", 3), ("send", 2, 2),
+                         ("fp", 2), ("send", 2, 2),
                          ("fp", 0), ("flush",)]
     assert sorted(waiting(cli)) == [
         b"answer to q0000", b"answer to q0002",
@@ -282,17 +311,45 @@ def test_one_packets_exception_costs_neither_the_chain_nor_the_drain(
 
 
 @pytest.mark.parametrize("lane", ["fp", "py"])
-def test_a_socket_error_ends_the_chain_after_the_drains_log_write(
+def test_a_socket_error_ends_the_chain_and_the_one_log_write_follows_it(
         monkeypatch, lane):
     srv, cli = loopback()
     engine, on_readable, rec = reader_over(monkeypatch, srv, lane)
     feed(cli, 3)
-    rec.on_flush = lambda i: i == 0 and feed(cli, 4)
+    rec.on_send = lambda i: i == 0 and feed(cli, 4)
     rec.recv_error_at = 1
     on_readable()
-    assert rec.calls == [(lane, 3), ("send", 3, 3), ("flush",),
+    assert rec.calls == [(lane, 3), ("send", 3, 3),
                          (lane, "error"), ("flush",)]
+    assert not engine.log_flush_owed
     assert len(waiting(srv)) == 4       # the next callback's
+    srv.close()
+    cli.close()
+
+
+@pytest.mark.parametrize("lane", ["fp", "py"])
+def test_an_exception_that_escapes_a_drain_still_ends_in_the_log_write(
+        monkeypatch, lane):
+    """Not a socket's ``OSError``, which a drain takes as the chain's
+    end, but a bug: it leaves ``on_readable`` as it came, behind the one
+    write that carries the lines of the drains that served."""
+    srv, cli = loopback()
+    engine, on_readable, rec = reader_over(monkeypatch, srv, lane)
+    feed(cli, 3)
+    rec.on_send = lambda i: i == 0 and feed(cli, 4)
+    rec.recv_error_at = 1
+    rec.recv_error = RuntimeError("a bug in the receive")
+    with pytest.raises(RuntimeError, match="a bug in the receive"):
+        on_readable()
+    assert rec.calls == [(lane, 3), ("send", 3, 3),
+                         (lane, "error"), ("flush",)]
+    assert not engine.log_flush_owed
+    assert len(waiting(cli)) == 3       # the first drain's answers left
+    # the reader is whole: the next callback takes what the socket holds
+    rec.recv_error_at = None
+    del rec.calls[:]
+    on_readable()
+    assert rec.calls == calls_of([[4], [0]], lane)
     srv.close()
     cli.close()
 
@@ -309,8 +366,9 @@ def test_every_eighth_drain_of_a_chain_is_the_sampled_one(monkeypatch):
     engine, on_readable, rec = reader_over(monkeypatch, srv, "fp", rrl)
     drains = 3 * SAMPLE_EVERY
     feed(cli, 2)
-    rec.on_flush = lambda i: i + 1 < drains and feed(cli, 2)
+    rec.on_send = lambda i: i + 1 < drains and feed(cli, 2)
     on_readable()                       # 24 drains of 2, one of none
+    assert rec.calls.count(("flush",)) == 1 and rec.calls[-1] == ("flush",)
     lanes = [lane for lane, n in rec.receives() if n]
     assert len(lanes) == drains and engine.udp_chained_drains == drains
     assert lanes == ["py" if (i + 1) % SAMPLE_EVERY == 0 else "fp"
@@ -383,7 +441,7 @@ def test_a_gate_that_closes_in_a_sampled_drain_keeps_the_next_out_of_c(
     on_readable = engine._batched_udp_reader(srv)
     drains = SAMPLE_EVERY + 2
     feed(cli, 2)
-    rec.on_flush = lambda i: i + 1 < drains and feed(cli, 2)
+    rec.on_send = lambda i: i + 1 < drains and feed(cli, 2)
     on_readable()
     lanes = [lane for lane, n in rec.receives()]
     # seven in C, the sampled one, then Python for as long as the gate
@@ -400,27 +458,33 @@ def test_a_gate_that_closes_in_a_sampled_drain_keeps_the_next_out_of_c(
     cli.close()
 
 
-# -- a drain's answers, its lines, the next recvmmsg: the real server --
+# -- a chain's answers, then its lines in one write: the real server --
 
-def test_each_drains_answers_leave_before_its_lines_and_those_before_the_next_recvmmsg(
-        monkeypatch):
-    """The real ``send_batch`` / ``fastpath_drain`` and the real
-    ``_write_log`` of a started server: at each log write the client
-    already holds every answer of the drain, the stream none of its
-    lines; at each ``recvmmsg`` the stream holds every line of the
-    drains before it."""
-    order = []
-    fed = [3, 2, 1]
+def chained_server(monkeypatch, order, fed, fail_at=None):
+    """A started, logged server whose extension calls, log write and
+    ``on_readable`` each note ``(kind, n, answers the client holds,
+    query lines on the stream)`` in ``order``.  The client asks
+    ``fed[0]`` queries; each ``fastpath_drain`` that returns is followed
+    by the next of ``fed``, so one callback chains them all.  The
+    ``fail_at``-th ``fastpath_drain`` raises instead, which is no
+    socket's error.  Returns what ``run()`` gives: the answers, the
+    chained drains, the stream's lines."""
 
     async def run():
         stream, raw = byte_stream()
         cli = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
         cli.setblocking(False)
         answers = []
+        recvs = [0]
 
         def note(kind, n=None):
             answers.extend(waiting(cli))
             order.append((kind, n, len(answers), len(query_lines(raw))))
+
+        def ask(n):
+            for _ in range(n):
+                cli.send(make_query("web.foo.com", Type.A,
+                                    qid=len(order)).encode())
 
         def recv_batch(fd, cap):
             msgs = fastio.recv_batch(fd, cap)
@@ -429,39 +493,50 @@ def test_each_drains_answers_leave_before_its_lines_and_those_before_the_next_re
 
         def fastpath_drain(fp, fd, gen, cap):
             note("recv-starts")
+            i, recvs[0] = recvs[0], recvs[0] + 1
+            if i == fail_at:
+                raise RuntimeError("a bug in the drain")
             got = fastio.fastpath_drain(fp, fd, gen, cap)
             note("recv", len(got[0]) + got[1])
+            if i + 1 < len(fed):
+                ask(fed[i + 1])
             return got
 
         monkeypatch.setattr(dns_server, "_fastio", types.SimpleNamespace(
             recv_batch=recv_batch, send_batch=fastio.send_batch,
             fastpath_drain=fastpath_drain))
+        real_reader = DnsServer._batched_udp_reader
+
+        def reader(self, sock):
+            on_readable = real_reader(self, sock)
+
+            def noted():
+                try:
+                    on_readable()
+                finally:
+                    note("returned")
+            return noted
+
+        monkeypatch.setattr(DnsServer, "_batched_udp_reader", reader)
         store, cache = fixture_store()
         server = await start_logged_server(cache, stream)
         write_log = server.engine.log_flush
-        writes = [0]
 
         def flush():
             note("write-starts")
             write_log()
             note("written")
-            writes[0] += 1
-            if writes[0] < len(fed):
-                ask(fed[writes[0]])
-
-        def ask(n):
-            for _ in range(n):
-                cli.send(make_query("web.foo.com", Type.A,
-                                    qid=len(order)).encode())
 
         try:
             server.engine.log_flush = flush
             cli.connect(("127.0.0.1", server.udp_port))
             ask(fed[0])
+            want = sum(fed if fail_at is None else fed[:fail_at])
             for _ in range(200):
                 await asyncio.sleep(0.01)
                 answers.extend(waiting(cli))
-                if len(answers) == sum(fed):
+                if len(answers) == want and any(
+                        kind == "returned" for kind, *_ in order):
                     break
             return (answers, server.engine.udp_chained_drains,
                     query_lines(raw))
@@ -470,27 +545,69 @@ def test_each_drains_answers_leave_before_its_lines_and_those_before_the_next_re
             await server.stop()
             cli.close()
 
-    answers, chained, lines = asyncio.run(run())
+    return asyncio.run(run())
+
+
+def first_callback(order):
+    """The notes up to the first return of ``on_readable``."""
+    kinds = [kind for kind, *_ in order]
+    return order[:kinds.index("returned") + 1]
+
+
+def test_a_chains_answers_all_leave_before_its_one_log_write_and_its_lines_before_the_loop_is_given_back(
+        monkeypatch):
+    """The real ``send_batch`` / ``fastpath_drain`` and the real
+    ``_write_log`` of a started server: no ``recvmmsg`` of the chain
+    stands behind a log write; at the callback's one log write the
+    client already holds every answer of every drain, the stream none
+    of their lines; when ``on_readable`` returns the stream holds them
+    all."""
+    order = []
+    fed = [3, 2, 1]
+    answers, chained, lines = chained_server(monkeypatch, order, fed)
     assert len(answers) == len(lines) == sum(fed)
     assert all(Message.decode(a).rcode == Rcode.NOERROR for a in answers)
     assert chained == 2                 # one callback held all three
-    seen = [(kind, n) for kind, n, _, _ in order]
+    callback = first_callback(order)
+    seen = [(kind, n) for kind, n, _, _ in callback]
     assert seen == [("recv-starts", None), ("recv", 3),
-                    ("write-starts", None), ("written", None),
                     ("recv-starts", None), ("recv", 2),
-                    ("write-starts", None), ("written", None),
                     ("recv-starts", None), ("recv", 1),
-                    ("write-starts", None), ("written", None)]
+                    ("write-starts", None), ("written", None),
+                    ("returned", None)]
     done = 0
     for i, n in enumerate(fed):
-        starts, _, write_starts, written = order[4 * i:4 * i + 4]
-        # the lines of the drains before are out before this recvmmsg
-        assert starts[2:] == (done, done)
-        # this drain's answers are with the client, none of its lines
-        # written; then all of them are
-        assert write_starts[2:] == (done + n, done)
-        assert written[2:] == (done + n, done + n)
+        starts, brought = callback[2 * i:2 * i + 2]
+        # the answers of the drains before are with the client at this
+        # recvmmsg, which waited for no write: none of their lines is out
+        assert starts[2:] == (done, 0)
+        assert brought[3] == 0
         done += n
+    write_starts, written, returned = callback[-3:]
+    assert write_starts[2:] == (done, 0)
+    assert written[2:] == returned[2:] == (done, done)
+
+
+def test_an_exception_mid_chain_leaves_the_earlier_drains_lines_on_the_stream(
+        monkeypatch):
+    """The third drain of a chain raises what is no socket's error: the
+    callback's ``finally`` still writes, and the lines of the two drains
+    that served are on the stream when ``on_readable`` is left."""
+    order = []
+    fed = [3, 2, 4]
+    answers, chained, lines = chained_server(monkeypatch, order, fed,
+                                             fail_at=2)
+    callback = first_callback(order)
+    assert [(kind, n) for kind, n, _, _ in callback] == [
+        ("recv-starts", None), ("recv", 3),
+        ("recv-starts", None), ("recv", 2),
+        ("recv-starts", None),
+        ("write-starts", None), ("written", None), ("returned", None)]
+    write_starts, written, returned = callback[-3:]
+    assert write_starts[2:] == (5, 0)
+    assert written[2:] == returned[2:] == (5, 5)
+    assert sorted(ln["req_id"] for ln in lines[:5]) == sorted(
+        Message.decode(a).id for a in answers[:5])
 
 
 # -- sends: a full buffer is retried once, counted, and ends the chain --
@@ -554,8 +671,9 @@ def test_the_native_lanes_short_send_ends_the_chain_and_the_next_callback_serves
     was = native_drops()
     for i in range(10):
         peer.send(query_pkt(qid=i))
-    # a chain would find these behind the first drain
-    rec.on_flush = lambda i: i == 0 and [
+    # a chain would find these behind the first drain, which C
+    # answered whole (no send_batch of the reader's own)
+    rec.on_recv = lambda i: i == 0 and [
         peer.send(query_pkt(qid=100 + k)) for k in range(3)]
     on_readable()
     retried, dropped = rec.last_send
@@ -572,7 +690,7 @@ def test_the_native_lanes_short_send_ends_the_chain_and_the_next_callback_serves
     # the peer has read: the next callback serves what was left, and on
     del rec.calls[:]
     on_readable()
-    assert rec.calls == [("fp", 3), ("flush",), ("fp", 0), ("flush",)]
+    assert rec.calls == [("fp", 3), ("fp", 0), ("flush",)]
     assert len(waiting(peer)) == 3
     assert native_drops() - was == dropped
     ours.close()
@@ -618,7 +736,7 @@ def test_the_python_lanes_short_send_is_retried_counted_and_ends_the_chain(
     # the next callback serves on, and chains again
     del rec.calls[:]
     on_readable()
-    assert rec.calls == [("py", 3), ("send", 3, 3), ("flush",),
+    assert rec.calls == [("py", 3), ("send", 3, 3),
                          ("py", 0), ("flush",)]
     assert engine.udp_send_drops == 9 - taken
     assert waiting(peer) == [answer] * 3
