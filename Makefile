@@ -88,7 +88,7 @@ shard-smoke:
 
 # zone-scale smoke: build a synthetic 100k-name mirror (control: 2k),
 # apply a mutation burst + watch storm through the real mirror ->
-# invalidate -> precompile chain, and assert the million-name
+# invalidate -> drop chain, and assert the million-name
 # representation's invariants: single-name rebuild latency independent
 # of zone size (O(delta)), re-rendered answers byte-identical to fresh
 # engine renders, chunked session rebuild under the loop-lag watchdog
@@ -157,8 +157,8 @@ chip-smoke:
 
 # serving-plane verification smoke: clean soak (zero violations while
 # the checker, audit and propagation tracer all do real work, RSS
-# bounded), then scripted chaos corruptions (corrupt-answer,
-# drop-reverse) each detected within ONE audit cycle and surfaced as
+# bounded), then a scripted chaos corruption (drop-reverse)
+# detected within ONE audit cycle and surfaced as
 # flight event + metric + /status, then a real N=2 supervisor with a
 # skew-replica fault caught by the replica-digest frames
 # (docs/observability.md); BINDER_VERIFY_SECONDS overrides the

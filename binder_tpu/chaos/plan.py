@@ -23,7 +23,6 @@ DSL — one action per line (``;`` also separates), ``#`` comments::
     at 4.7  worker-roll shard=0     # zero-downtime drain-and-replace
     at 4.8  rrl-flood n=400         # spoofed-prefix UDP burst
     at 5.0  restore-session         # plain re-establish
-    at 5.2  corrupt-answer          # flip a byte in a compiled wire
     at 5.4  drop-reverse            # delete one PTR map entry
     at 5.6  skew-replica shard=0    # suppress one worker delta frame
     at 6.0  upstream clear          # all upstream faults off
@@ -80,13 +79,13 @@ Actions
   read — the flood models reflection-attack ammunition, and the
   assertable outcome is on the server: ``binder_rrl_*`` counters move,
   the legit client's goodput survives.
-- ``corrupt-answer [qname=...]`` / ``drop-reverse [ip=...]`` /
-  ``skew-replica [shard=I] [frames=N]`` — verify-plane faults (ISSUE
-  16), dispatched by method name at the driver's ``verify_target``
-  (the :class:`BinderServer` for the table corruptions, the shard
-  supervisor for the mutation-log skew).  Each breaks serving state
-  WITHOUT firing an invalidation — the sampled audit (compiled-bytes,
-  ptr-coherence) and the digest frames (replica-digest) are the only
+- ``drop-reverse [ip=...]`` / ``skew-replica [shard=I] [frames=N]`` —
+  verify-plane faults (ISSUE 16), dispatched by method name at the
+  driver's ``verify_target`` (the :class:`BinderServer` for the
+  reverse-map corruption, the shard supervisor for the mutation-log
+  skew).  Each breaks serving state WITHOUT firing an invalidation —
+  the sampled audit (ptr-coherence) and the digest frames
+  (replica-digest) are the only
   things that can catch them, which is the point: the chaos action
   proves the checker's detection, not the datapath's tolerance.
 
@@ -105,14 +104,14 @@ ACTIONS = ("lose-session", "restore-session", "expire-session",
            "watch-storm", "loop-stall", "upstream",
            "tcp-slow-reader", "tcp-half-close", "tcp-rst",
            "shard-kill", "worker-roll", "rrl-flood",
-           "corrupt-answer", "drop-reverse", "skew-replica")
+           "drop-reverse", "skew-replica")
 STREAM_ACTIONS = ("tcp-slow-reader", "tcp-half-close", "tcp-rst")
 #: spoofed-source /24s the rrl-flood action binds (Linux accepts any
 #: 127/8 address unconfigured) — the SAME prefixes tools/hostile.py
 #: floods from, so one RRL allowlist/bucket story covers both harnesses
 FLOOD_PREFIXES = ("127.66.7", "127.66.8", "127.99.1", "127.99.2")
 #: verify-plane faults, dispatched by method name at ``verify_target``
-VERIFY_ACTIONS = ("corrupt-answer", "drop-reverse", "skew-replica")
+VERIFY_ACTIONS = ("drop-reverse", "skew-replica")
 
 
 class UpstreamFaults:
@@ -246,7 +245,7 @@ class ChaosDriver:
         # worker-roll sink: request_roll(shard) on the supervisor
         # (shard -1 = roll every shard in sequence)
         self.roll_target = roll_target
-        # verify-plane fault sink: corrupt_answer/drop_reverse on a
+        # verify-plane fault sink: drop_reverse on a
         # BinderServer, skew_replica on a shard supervisor — dispatch
         # is by method name, so either (or a test double) fits
         self.verify_target = verify_target
@@ -338,7 +337,7 @@ class ChaosDriver:
             return
         result = fn(**kwargs)
         if result is None:
-            # nothing to corrupt (empty table / no matching entry):
+            # nothing to corrupt (empty map / no matching entry):
             # loud, so a smoke that asserted a detection can tell
             # "not injected" apart from "not detected"
             self.log.warning("chaos: %s found no target state", action)

@@ -1404,7 +1404,7 @@ class DnsServer:
     # asyncio.start_server: protocol/StreamReader/StreamWriter/task
     # creation per connection was the dominant cost of every fresh
     # connection (tcp1 ~137µs, the tc=1 UDP→TCP retry flow 10.8ms p50
-    # in BENCH_r05).  With TCP_DEFER_ACCEPT the first frame normally
+    # on the pre-ledger bench of round 5).  With TCP_DEFER_ACCEPT the first frame normally
     # rides the accept-readiness event, so a one-shot client is served
     # inside the accept callback — one loop iteration end to end.
 

@@ -686,32 +686,6 @@ def wire_walks(raw: bytes) -> bool:
     return off == len(raw)
 
 
-def patch_answer_wire(wire: bytes, qid: Optional[int] = None,
-                      rd: Optional[bool] = None) -> bytes:
-    """ID/flags patch for a precompiled response wire — the query-time
-    half of the mutation-time pipeline (`resolver/precompile.py`).
-
-    Precompiled wires are rendered canonically (id 0, RD clear); serving
-    one to a live query is this patch plus the question-case echo the
-    respond path already applies — never a re-encode.  The EDNS axis is
-    handled by variant selection, not patching: the OPT echo sits at the
-    head of the additionals section (`QueryCtx` appends it at
-    construction, before any answer-derived additionals), so a
-    with-EDNS wire is pre-rendered alongside the without-EDNS one
-    rather than spliced per query.
-    """
-    b = bytearray(wire)
-    if qid is not None:
-        b[0] = (qid >> 8) & 0xFF
-        b[1] = qid & 0xFF
-    if rd is not None:
-        if rd:
-            b[2] |= 0x01
-        else:
-            b[2] &= 0xFE
-    return bytes(b)
-
-
 def make_query(name: str, qtype: int, *, qid: int = 0, rd: bool = False,
                edns_payload: Optional[int] = 1232) -> Message:
     """Build a standard query message (client side / tests)."""

@@ -378,9 +378,6 @@ async def run(options: Dict[str, object]) -> BinderServer:
         cache_size=int(options.get("size", 10000)),
         cache_expiry_ms=int(options.get("expiry", 60000)),
         zone_precompile=bool(options.get("zonePrecompile", True)),
-        answer_precompile=bool(options.get("answerPrecompile", True)),
-        precompile_size=(int(options["precompileSize"])
-                         if "precompileSize" in options else None),
         tcp_idle_timeout=(float(options["tcpIdleTimeout"])
                           if "tcpIdleTimeout" in options else None),
         max_tcp_conns=(int(options["maxTcpConns"])
@@ -463,8 +460,8 @@ async def run(options: Dict[str, object]) -> BinderServer:
                         f"chaos0.{domain}"),
             udp_target=(chaos_host, server.udp_port,
                         f"chaos0.{domain}"),
-            # verify-plane corruption (corrupt-answer / drop-reverse)
-            # mutates the server's own tables behind the checker's back
+            # verify-plane corruption (drop-reverse) mutates the
+            # mirror's own maps behind the checker's back
             verify_target=server,
             recorder=recorder, log=log)
         server.chaos_driver = driver

@@ -16,7 +16,7 @@ fine.  This module is the *policy* for that state, RFC 8767-style:
 
 The cap covers the *cached* lanes too: every transition bumps the
 mirror epoch (``MirrorCache.invalidate_all``), so the Python answer
-cache, the compiled table, the native C caches, and the balancer all
+cache, the native C caches, and the balancer all
 drop answers rendered under the previous mode — an answer rendered
 fresh can never be served into exhaustion, and clamped-TTL stale
 answers never survive recovery.

@@ -5,4 +5,3 @@ from binder_tpu.resolver.engine import (  # noqa: F401
     Resolver,
     SERVICE_CHILD_TYPES,
 )
-from binder_tpu.resolver.precompile import Precompiler  # noqa: F401

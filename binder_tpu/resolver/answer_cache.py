@@ -32,38 +32,22 @@ Correctness properties:
   decide; see ``BinderServer._on_query`` — SERVFAIL means the store is
   unavailable or a record is garbage, conditions that must re-check on
   every query).
-
-The **compiled-answer table** (``put_compiled``/``get_compiled``) is the
-mutation-time precompiler's install target (``resolver/precompile.py``):
-one entry per ``(qtype, qname)``, holding every rotation variant in both
-EDNS postures, probed by the serve paths on a per-key miss.  Compiled
-entries share the tag index — ``invalidate_tag`` drops them in the same
-pass — and the epoch check, but do NOT time-expire: their staleness is
-bounded by tag invalidation + the epoch (every change that could affect
-them arrives as one or the other), and the table is size-bounded by
-insertion-order eviction like the per-key side.
 """
 from __future__ import annotations
 
 import time
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, Optional, Set
 
 from binder_tpu.store import names as _names
 
-#: sentinel marking compiled-table keys inside the shared tag index
-_COMPILED = object()
-
 
 class AnswerCache:
-    __slots__ = ("size", "compiled_size", "expiry_s", "variants_cap",
-                 "_entries", "_compiled", "_by_tag", "hits", "misses",
-                 "invalidations", "neg_hits", "compiled_serves",
-                 "compiled_installs", "_intern")
+    __slots__ = ("size", "expiry_s", "variants_cap", "_entries",
+                 "_by_tag", "hits", "misses", "invalidations",
+                 "neg_hits", "_intern")
 
     def __init__(self, size: int = 10000, expiry_ms: int = 60000,
-                 variants_cap: int = 8,
-                 compiled_size: Optional[int] = None,
-                 intern=None) -> None:
+                 variants_cap: int = 8, intern=None) -> None:
         # canonicalizer for tag/qname strings entering the long-lived
         # indexes: query-decoded names dedup against the mirror's own
         # domain objects (MirrorCache.canon) or the process-wide pool,
@@ -71,38 +55,25 @@ class AnswerCache:
         self._intern = intern if intern is not None \
             else _names.intern_name
         self.size = size
-        #: compiled-table occupancy bound; defaults to the per-key size
-        #: (entries derive 1:1-ish from mirrored names, so operators with
-        #: a large zone raise it with the ``precompileSize`` config key)
-        self.compiled_size = size if compiled_size is None else compiled_size
         self.expiry_s = expiry_ms / 1000.0
         self.variants_cap = variants_cap
         # key -> [epoch, created, next_variant_idx, [value, ...],
-        #         complete, tag, pushed, negative, qkey]
+        #         complete, tag, pushed, negative]
         self._entries: Dict[object, list] = {}
-        # (qtype, qname) -> [epoch, next_variant_idx, variants, rotatable,
-        #                    tag, negative]
-        self._compiled: Dict[Tuple[int, str], list] = {}
-        # dependency tag -> keys whose answers derive from it (per-key
-        # keys verbatim; compiled keys wrapped as (_COMPILED, qtype, name))
+        # dependency tag -> keys whose answers derive from it
         self._by_tag: Dict[str, Set[object]] = {}
         self.hits = 0
         self.misses = 0
         self.invalidations = 0
         self.neg_hits = 0
-        self.compiled_serves = 0
-        self.compiled_installs = 0
 
     def _drop(self, key, e) -> None:
         del self._entries[key]
-        self._drop_tag(e[5], key)
-
-    def _drop_tag(self, tag, tag_key) -> None:
-        keys = self._by_tag.get(tag)
+        keys = self._by_tag.get(e[5])
         if keys is not None:
-            keys.discard(tag_key)
+            keys.discard(key)
             if not keys:
-                del self._by_tag[tag]
+                del self._by_tag[e[5]]
 
     def get(self, key, epoch: int) -> Optional[object]:
         if self.size <= 0:
@@ -130,7 +101,7 @@ class AnswerCache:
 
     def put(self, key, epoch: int, value: object,
             rotatable: bool = False, tag: Optional[str] = None,
-            negative: bool = False, qkey: Optional[tuple] = None) -> bool:
+            negative: bool = False) -> bool:
         """Record a freshly resolved value.  ``rotatable`` says that
         another resolve of this key may give other bytes (the wire
         carries a set of several records, shuffled a resolve): such an
@@ -142,9 +113,7 @@ class AnswerCache:
         caller);
         ``negative`` marks NXDOMAIN/NODATA answers for the separate
         accounting (never SERVFAIL — callers must not put those at
-        all); ``qkey`` is the ``(qtype, qname)`` question identity, kept
-        so tag invalidation can tell the precompiler exactly which
-        question shapes it dropped.  Returns True exactly when the entry
+        all).  Returns True exactly when the entry
         just became *complete* (non-rotatable, or the full variant set
         collected): from then on ``get`` serves it, and its first hit
         promotes it to the native fast path (``take_push``)."""
@@ -164,10 +133,8 @@ class AnswerCache:
             self._drop(old_key, self._entries[old_key])
         if tag is not None:
             tag = self._intern(tag)
-        if qkey is not None:
-            qkey = (qkey[0], self._intern(qkey[1]))
         self._entries[key] = [epoch, time.monotonic(), 0, [value],
-                              not rotatable, tag, False, negative, qkey]
+                              not rotatable, tag, False, negative]
         self._by_tag.setdefault(tag, set()).add(key)
         return not rotatable
 
@@ -185,103 +152,16 @@ class AnswerCache:
         e[6] = True
         return e[3], e[5]
 
-    # -- the compiled-answer table (mutation-time precompiler) --
-
-    def put_compiled(self, qtype: int, qname: str, epoch: int,
-                     variants: List[object], rotatable: bool,
-                     tag: Optional[str], negative: bool = False,
-                     evidence_at: Optional[float] = None) -> None:
-        """Install (or replace) the precompiled answer set for one
-        question.  ``variants`` is the full rotation set, rendered at
-        mutation time — the entry is born complete, so the very next
-        query for the name serves from it.
-
-        ``evidence_at`` is the monotonic instant of the most recent
-        QUERY evidence for this shape (propagated verbatim through
-        drop→re-render cycles; refreshed only by an actual serve) —
-        None for speculative installs (the startup seed).  Invalidation
-        reports the shape for re-render only while that evidence is
-        younger than the expiry window, so a name queried once on a
-        hot-churning record stops being re-rendered one window later
-        instead of forever."""
-        if self.compiled_size <= 0 or not variants:
-            return
-        qname = self._intern(qname)
-        if tag is not None:
-            tag = self._intern(tag)
-        ckey = (qtype, qname)
-        old = self._compiled.get(ckey)
-        if old is not None:
-            self._drop_tag(old[4], (_COMPILED,) + ckey)
-        elif len(self._compiled) >= self.compiled_size:
-            old_key = next(iter(self._compiled))
-            self._drop_compiled(old_key, self._compiled[old_key])
-        self._compiled[ckey] = [epoch, 0, variants, rotatable, tag,
-                                negative, evidence_at]
-        self._by_tag.setdefault(tag, set()).add((_COMPILED,) + ckey)
-        self.compiled_installs += 1
-
-    def compiled_full(self) -> bool:
-        """True when the next new shape's ``put_compiled`` would evict
-        (or, with a table of none, be dropped): the startup seed stops
-        rendering here (``Precompiler.seed_mirror``)."""
-        return len(self._compiled) >= self.compiled_size
-
-    def get_compiled(self, qtype: int, qname: str, epoch: int):
-        """Probe the compiled table: ``(variant, rotatable, tag,
-        negative)`` with the rotation cursor advanced, or None.  No time
-        expiry — coherence comes from the tag index and the epoch."""
-        e = self._compiled.get((qtype, qname))
-        if e is None:
-            return None
-        if e[0] != epoch:
-            self._drop_compiled((qtype, qname), e)
-            return None
-        variants = e[2]
-        idx = e[1]
-        e[1] = (idx + 1) % len(variants)
-        e[6] = time.monotonic()   # fresh serving evidence
-        self.compiled_serves += 1
-        if e[5]:
-            self.neg_hits += 1
-        return variants[idx], e[3], e[4], e[5]
-
-    def _drop_compiled(self, ckey, e) -> None:
-        del self._compiled[ckey]
-        self._drop_tag(e[4], (_COMPILED,) + ckey)
-
-    def invalidate_tag(self, tag: str,
-                       dropped: Optional[list] = None) -> int:
-        """Drop every entry — per-key and compiled — whose answer
-        derives from ``tag``; returns how many were dropped.  When
-        ``dropped`` is given, ``(qtype, qname, evidence_at)`` triples
-        for the dropped entries with QUERY EVIDENCE inside the expiry
-        window are appended to it — the precompiler's re-render work
-        list.  A per-key entry's evidence is its creation time (a query
-        made it); a compiled entry carries its propagated evidence
-        timestamp.  Shapes without recent evidence die silently — churn
-        on names nobody queries must cost nothing."""
+    def invalidate_tag(self, tag: str) -> int:
+        """Drop every entry whose answer derives from ``tag``; returns
+        how many were dropped."""
         keys = self._by_tag.pop(tag, None)
         if not keys:
             return 0
         n = 0
-        now = time.monotonic() if dropped is not None else 0.0
         for key in keys:
-            if (type(key) is tuple and len(key) == 3
-                    and key[0] is _COMPILED):
-                ckey = key[1:]
-                e = self._compiled.pop(ckey, None)
-                if e is not None:
-                    n += 1
-                    if (dropped is not None and e[6] is not None
-                            and now - e[6] <= self.expiry_s):
-                        dropped.append(ckey + (e[6],))
-            else:
-                e = self._entries.pop(key, None)
-                if e is not None:
-                    n += 1
-                    if dropped is not None and e[8] is not None:
-                        dropped.append(e[8] + (e[1],))
+            if self._entries.pop(key, None) is not None:
+                n += 1
         self.invalidations += n
         return n
 
@@ -309,12 +189,8 @@ class AnswerCache:
             "invalidations": self.invalidations,
             "expiry_ms": self.expiry_s * 1000.0,
             "neg_hits": self.neg_hits,
-            "compiled_entries": len(self._compiled),
-            "compiled_serves": self.compiled_serves,
-            "compiled_installs": self.compiled_installs,
         }
 
     def clear(self) -> None:
         self._entries.clear()
-        self._compiled.clear()
         self._by_tag.clear()

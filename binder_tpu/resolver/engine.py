@@ -34,17 +34,18 @@ import re
 from typing import Optional
 from urllib.parse import urlparse
 
-from binder_tpu.dns.query import QueryCtx
+from binder_tpu.dns.query import _ECHO_OPT, QueryCtx
 from binder_tpu.dns.wire import (
     ARecord,
+    Message,
     PTRRecord,
+    Question,
     Rcode,
     SOARecord,
     SRVRecord,
     Type,
     ip_from_reverse_name,
 )
-from binder_tpu.resolver.precompile import Precompiler
 from binder_tpu.store.cache import MirrorCache
 from binder_tpu.store.names import rec_parts as _rec_parts
 
@@ -66,6 +67,14 @@ HOST_LIKE_TYPES = frozenset({
 })
 
 DEFAULT_TTL = 30  # reference lib/server.js:270 (the ZK session timeout)
+
+#: Answer-set size above which a rotatable set is a *lazy render*: its
+#: plan, records and encode are timed as one stage (``Resolver._finish``)
+#: and the native zone fill leaves a service's plain-A rotation of more
+#: members to the Python lanes (``BinderServer._zone_push_service_a``).
+#: Eight rotations of a set of hundreds cost hundreds of ms to render,
+#: and its wire passes every UDP payload anyway.
+MAX_SET_RECORDS = 64
 
 #: The one statement of which questions the engine resolves at all
 #: (lib/server.js:491-506): ``(served types, rcode of every other
@@ -105,15 +114,13 @@ class AnswerPlan:
 
     - the query path (``Resolver.resolve``/``resolve_ptr``): plan, then
       apply to the live QueryCtx (shuffle rotatable groups, respond);
-    - the mutation-time precompiler (``resolver/precompile.py``): plan
-      once per affected name when the mirror changes, render every
-      rotation variant to wire, and install the finished answers so
-      post-churn queries never pay a resolve.
+    - the reference render (``render_plan``): tests and probes hold a
+      served wire to the plan's own encode, with no query in hand.
 
     ``groups`` is the rotation unit list: each element is
     ``(answers, additionals)`` for one service member (or the single
     answer for non-service shapes).  The query path shuffles groups
-    (round-robin); the precompiler renders cyclic rotations of them.
+    (round-robin).
 
     Known deviation from the pre-split engine: a service with an
     invalid member record still answers SERVFAIL, but with an empty
@@ -142,7 +149,7 @@ class AnswerPlan:
 
     def records(self) -> int:
         """Answer and additional records over all groups: what the
-        precompiler holds against ``MAX_SET_RECORDS``."""
+        lazy render is decided on (``MAX_SET_RECORDS``)."""
         return sum(len(answers) + len(additionals)
                    for answers, additionals in self.groups)
 
@@ -153,6 +160,22 @@ class AnswerPlan:
         is never cached at all)."""
         return (self.rcode == Rcode.NXDOMAIN
                 or (self.rcode == Rcode.NOERROR and not self.groups))
+
+
+def render_plan(qname: str, qtype: int, plan: AnswerPlan,
+                edns: bool = False) -> bytes:
+    """The canonical response wire of a plan (id 0, RD clear, groups in
+    plan order) — byte-identical to what ``QueryCtx.respond`` encodes
+    for it, because it IS the same ``Message.encode``: qr/aa set, the
+    EDNS echo (when present) at the head of the additionals, full name
+    compression."""
+    adds = [r for g in plan.groups for r in g[1]]
+    return Message(
+        id=0, qr=True, aa=True, rd=False, rcode=plan.rcode,
+        questions=[Question(name=qname, qtype=qtype)],
+        answers=[r for g in plan.groups for r in g[0]],
+        authorities=list(plan.authorities),
+        additionals=([_ECHO_OPT] + adds) if edns else adds).encode()
 
 
 class Resolver:
@@ -198,8 +221,8 @@ class Resolver:
 
     # -- forward resolution (lib/server.js:136-429) --
     #
-    # resolve() = plan() + apply: plan is the PURE resolution (also the
-    # mutation-time precompiler's entry point); apply handles the live
+    # resolve() = plan() + apply: plan is the PURE resolution (also
+    # what ``render_plan`` encodes); apply handles the live
     # query's concerns — log context, attribution stamps, the recursion
     # handoff (RD-dependent, so it cannot live in the plan), round-robin
     # shuffle, and the respond.
@@ -456,11 +479,11 @@ class Resolver:
             query.dep_domain = plan.dep_domain
         if plan.stale:
             query.log_ctx["stale"] = True
-        # a set the precompiler declines as oversize is rendered here,
-        # at query time, whole: its plan, its records and its encode go
-        # under one stage of their own, `lazy-render`, stamped after the
-        # respond in place of `store-lookup` and `pre-resp`
-        lazy = plan.rotatable and plan.records() > Precompiler.MAX_SET_RECORDS
+        # an oversize set is rendered here, at query time, whole: its
+        # plan, its records and its encode go under one stage of their
+        # own, `lazy-render`, stamped after the respond in place of
+        # `store-lookup` and `pre-resp`
+        lazy = plan.rotatable and plan.records() > MAX_SET_RECORDS
         if not lazy:
             # decode→policy→mirror probe→plan, on the attribution timeline
             query.stamp("store-lookup")

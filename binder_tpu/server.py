@@ -28,7 +28,6 @@ except ImportError:
 from binder_tpu.dns.query import QueryCtx
 from binder_tpu.dns.server import DnsServer, bind_port_pair
 from binder_tpu.dns.wire import (
-    MAX_UDP_PAYLOAD,
     ARecord,
     OPTRecord,
     PTRRecord,
@@ -38,7 +37,6 @@ from binder_tpu.dns.wire import (
     WireError,
     encode_name,
     ip_from_reverse_name,
-    patch_answer_wire,
     reverse_name_for_ip,
 )
 from binder_tpu.introspect.ledger import (
@@ -55,10 +53,10 @@ from binder_tpu.metrics.collector import (
     MetricsCollector,
 )
 from binder_tpu.resolver.answer_cache import AnswerCache
-from binder_tpu.resolver.precompile import Precompiler
 from binder_tpu.store.names import rec_parts as _names_rec_parts
 from binder_tpu.resolver.engine import (
     DEFAULT_TTL,
+    MAX_SET_RECORDS,
     Resolver,
     SERVICE_CHILD_TYPES as _SERVICE_CHILD_TYPES,
     TYPE_RULE,
@@ -184,8 +182,6 @@ class BinderServer:
                  cache_size: int = 10000,
                  cache_expiry_ms: int = 60000,
                  zone_precompile: bool = True,
-                 answer_precompile: bool = False,
-                 precompile_size: Optional[int] = None,
                  tcp_idle_timeout: Optional[float] = None,
                  max_tcp_conns: Optional[int] = None,
                  max_tcp_write_buffer: Optional[int] = None,
@@ -217,8 +213,8 @@ class BinderServer:
         self.sockets = sockets
         self.read_when_filled = read_when_filled
         self.announce = announce
-        # filled: the startup walks (precompile seed, zone fill) are
-        # done, so every name the native lanes can serve is theirs
+        # filled: the startup zone fill is done, so every name the
+        # native lanes can serve is theirs
         self.filled = False
         self.on_filled: Optional[Callable[[], None]] = None
         self._filled_task = None
@@ -233,7 +229,6 @@ class BinderServer:
         self.zk_cache = zk_cache
         self.answer_cache = AnswerCache(
             size=cache_size, expiry_ms=cache_expiry_ms,
-            compiled_size=precompile_size,
             # tag/qname strings dedup against the mirror's own domain
             # objects (the interned-name pool architecture, ISSUE 7)
             intern=getattr(zk_cache, "canon", None))
@@ -369,7 +364,7 @@ class BinderServer:
                 log=self.log)
             # answers rendered under one staleness mode must never be
             # served under another: every transition flushes all cached
-            # lanes (Python, compiled, native, balancer) via the epoch
+            # lanes (Python, native, balancer) via the epoch
             self._policy.on_transition(self._on_degradation_transition)
             self.resolver.policy = self._policy
         self._admission = None
@@ -446,7 +441,7 @@ class BinderServer:
 
         # Serving-plane verification (binder_tpu/verify, ISSUE 16):
         # incremental invariant checks off the same per-name
-        # invalidation feed the precompiler drains, a sampled
+        # invalidation feed the zone drain takes, a sampled
         # budgeted full-zone audit, and mutation-to-glass propagation
         # tracing.  Same config convention as admission/rrl: None
         # disables (direct construction / tests), a config block
@@ -458,41 +453,13 @@ class BinderServer:
         self._zone_trace: dict = {}
         if verify is not None and verify.get("enabled", True):
             self._verify = Verifier(
-                zk_cache=zk_cache, answer_cache=self.answer_cache,
-                resolver=self.resolver,
-                policy_mode=(self._policy.mode
-                             if self._policy is not None else None),
-                config=verify, collector=self.collector,
-                recorder=flight_recorder, log=self.log)
+                zk_cache=zk_cache, config=verify,
+                collector=self.collector, recorder=flight_recorder,
+                log=self.log)
             # the mirror stamps each mutation's trace context at
             # bump_gen and marks mirror-apply at invalidation fan-out
             zk_cache.tracer = self._verify.tracer
 
-        # Mutation-time answer precompilation (resolver/precompile.py):
-        # store mutations eagerly re-render the affected names' answers
-        # into the AnswerCache's compiled table, so post-churn (and
-        # seeded cold) queries are a dict probe + ID/flags patch instead
-        # of an engine.resolve() pass.  Off by default at this layer —
-        # main.py turns it on from config (`answerPrecompile`, default
-        # true) like the other production knobs.
-        self._precompiler: Optional[Precompiler] = None
-        if answer_precompile and cache_size > 0:
-            self._precompiler = Precompiler(
-                resolver=self.resolver, answer_cache=self.answer_cache,
-                zk_cache=zk_cache, summarize=self._summarize,
-                collector=self.collector, recorder=flight_recorder,
-                log=self.log, native_put=self._precompile_native_put,
-                tracer=(self._verify.tracer
-                        if self._verify is not None else None))
-        if self._verify is not None:
-            # the checker re-renders through the precompiler for the
-            # compiled-bytes invariant (None: skip-counted, not silent)
-            self._verify.precompiler = self._precompiler
-        self._precompile_serve_child = self.collector.counter(
-            "binder_precompile_serves",
-            "queries answered from mutation-time precompiled entries"
-        ).labelled()
-        self._precompile_serve_child.inc(0)   # series exists from scrape 1
         self.engine = DnsServer(log=self.log, name=name,
                                 tcp_idle_timeout=tcp_idle_timeout,
                                 max_tcp_conns=max_tcp_conns,
@@ -645,8 +612,8 @@ class BinderServer:
         # its fill, a roll's replacement must read 0
         self._unfilled_child = self.collector.counter(
             "binder_unfilled_serves_total",
-            "queries answered before the startup zone fill and "
-            "precompile seed were complete").labelled({})
+            "queries answered before the startup zone fill was "
+            "complete").labelled({})
         self._unfilled_child.inc(0)
         self._unfilled_folded = 0.0
         self.collector.on_expose(self._fold_unfilled)
@@ -728,8 +695,8 @@ class BinderServer:
         # C gives it with no name in its key and no first sight in
         # Python per name.  In the logged posture the row carries the
         # one fragment a Python-lane first sight of a declined question
-        # logs: no `cached`, no `precompiled` — the native answer IS
-        # the engine's decision, not a replay of it.  `_type_row` is
+        # logs: no `cached` — the native answer IS the engine's
+        # decision, not a replay of it.  `_type_row` is
         # the served types while the row is installed, else None.
         self._type_row: Optional[frozenset] = None
         if self._zone_enabled and hasattr(_fastio, "fastpath_type_row"):
@@ -815,8 +782,8 @@ class BinderServer:
 
     def _on_degradation_transition(self, old: str, new: str) -> None:
         """Degradation state edge: flush every cached answer lane.  The
-        epoch bump invalidates the Python answer cache, the compiled
-        table, the native C caches, and (via the generation frame) the
+        epoch bump invalidates the Python answer cache, the native C
+        caches, and (via the generation frame) the
         balancer — so a wire rendered fresh is never served into
         exhaustion and clamped-TTL stale wires never survive recovery."""
         self.zk_cache.invalidate_all(
@@ -886,12 +853,6 @@ class BinderServer:
                     self._fastpath_push(key, self.zk_cache.epoch, query)
                 return None
 
-        # Mutation-time precompiled probe: a per-key miss whose answer
-        # was re-rendered at mutation time (or seeded at start) serves
-        # as a dict probe + ID/flags patch — the engine never runs.
-        if key is not None and self._serve_compiled(query, key, q0):
-            return None
-
         pending = self.resolver.handle(query)
 
         answered = (pending is None and query.responded
@@ -933,110 +894,8 @@ class BinderServer:
                 # excluded above — the never-cache rule
                 negative=(rcode == Rcode.NXDOMAIN
                           or (rcode == Rcode.NOERROR
-                              and not query.response.answers)),
-                qkey=(q0.qtype, q0.name))
+                              and not query.response.answers)))
         return pending
-
-    #: the client postures precompiled answers are installed under in
-    #: the NATIVE answer cache: (rd, edns, effective payload).  These
-    #: are the request shapes resolvers actually send (EDNS at the
-    #: 1232 safe default, classic 512 without); anything else (odd
-    #: payload advertisements, options) falls to the Python compiled
-    #: probe, which serves every posture by patching.
-    _NATIVE_POSTURES = ((False, False, MAX_UDP_PAYLOAD),
-                        (True, False, MAX_UDP_PAYLOAD),
-                        (False, True, 1232),
-                        (True, True, 1232))
-
-    def _precompile_native_put(self, qtype: int, qname: str, variants,
-                               tag: str, rcode: int) -> None:
-        """Install a precompiled answer set into the NATIVE answer
-        cache, one entry per canonical client posture — the
-        mutation-time analog of promote-on-first-hit.  The hit path IS
-        the C drain; installing at mutation time makes the post-churn
-        (and seeded cold) miss path take it from query one.  Pure
-        optimization: every failure path simply leaves the name to the
-        Python compiled probe.  Unlike query-path promotion, the push
-        cost lands on the mutation drain, never on a query."""
-        if self._fastpath is None:
-            return
-        qn = self._qname_wire(qname)
-        tag_wire = self._qname_wire(tag)
-        if qn is None or tag_wire is None:
-            return
-        # the C key builder only produces hostname-charset keys; an
-        # install outside that set could never be probed
-        i = 0
-        while qn[i]:
-            ll = qn[i]
-            if not _FP_NAME_OK.issuperset(qn[i + 1:i + 1 + ll]):
-                return
-            i += 1 + ll
-        frags = None
-        if self._log_ring:
-            # native serves must produce the same log line the Python
-            # compiled serve would ({"precompiled": true} + summaries)
-            frags = [self._log_frag({"precompiled": True}, rcode,
-                                    v[2], v[3]) for v in variants]
-            if any(f is None for f in frags):
-                return                  # unloggable: stays in Python
-        epoch = self.zk_cache.epoch
-        for rd, edns, payload in self._NATIVE_POSTURES:
-            wires = [patch_answer_wire(v[1] if edns else v[0], rd=rd)
-                     for v in variants]
-            if any(len(w) > payload for w in wires):
-                continue    # truncation shapes: the generic path owns TC
-            ckey = _fastpath_key_parts(rd, edns, payload, qtype, 1, qn)
-            try:
-                if frags is not None:
-                    _fastio.fastpath_put(self._fastpath, ckey, qtype,
-                                         epoch, wires, -1, tag_wire,
-                                         frags)
-                else:
-                    _fastio.fastpath_put(self._fastpath, ckey, qtype,
-                                         epoch, wires, -1, tag_wire)
-            except (TypeError, ValueError, MemoryError) as e:
-                self.log.debug("precompile native push skipped: %s", e)
-                return
-
-    def _serve_compiled(self, query: QueryCtx, key, q0) -> bool:
-        """Serve one query from the compiled-answer table, if present:
-        select the EDNS posture's pre-rendered wire, patch the RD bit
-        (the ID and question case are patched by respond_raw as for any
-        cached wire), respond, and install the result under the query's
-        exact key so repeats take the plain hit path (and promote to the
-        native fast path on their first hit, same economics as lazy
-        entries).  Declines (False) when the table has no entry or the
-        wire would need UDP truncation: the generic path owns those,
-        and ``_on_query`` stores the header it sends as one variant."""
-        if q0.qclass != 1:
-            return False
-        epoch = self.zk_cache.epoch
-        hit = self.answer_cache.get_compiled(q0.qtype, q0.name, epoch)
-        if hit is None:
-            return False
-        (w0, w1, ans, add), rotatable, tag, negative = hit
-        req = query.request
-        wire = w1 if req.edns is not None else w0
-        if query.udp_semantics and len(wire) > req.max_udp_payload():
-            return False
-        if req.rd:
-            wire = patch_answer_wire(wire, rd=True)
-        query.response.rcode = wire[3] & 0x0F   # for metrics/logs
-        query.log_ctx["precompiled"] = True
-        query.cached_summary = (ans, add)
-        query.stamp("precompile-hit")   # decode→probe→patch, whole serve
-        query.respond_raw(wire)
-        self._precompile_serve_child.inc()
-        try:
-            self.answer_cache.put(
-                key, epoch, (wire, ans, add), rotatable=rotatable,
-                tag=tag, negative=negative, qkey=(q0.qtype, q0.name))
-        except Exception:
-            # response already sent: bookkeeping must not re-raise into
-            # the dispatch path (it would SERVFAIL a served query)
-            self.log.exception("compiled-serve bookkeeping failed")
-        return True
 
     @staticmethod
     def _qname_wire(name: str) -> Optional[bytes]:
@@ -1065,11 +924,8 @@ class BinderServer:
         name's refresh runs, its queries resolve through the Python
         lanes (_on_query) — slower, never stale."""
         wires = []
-        # question shapes the drops touched — the precompiler's exact
-        # re-render work list (concrete negative SRV qnames, postures)
-        dropped: list = []
         for tag in tags:
-            self.answer_cache.invalidate_tag(tag, dropped=dropped)
+            self.answer_cache.invalidate_tag(tag)
             wire = self._qname_wire(tag)
             if wire is not None:
                 wires.append(wire)
@@ -1084,17 +940,10 @@ class BinderServer:
                 pass
         if wires:
             self.engine.notify_invalidate(wires)
-        if self._precompiler is not None and dropped:
-            # refill work, deferred and bounded like the zone drain; the
-            # DROPS above were synchronous, so until a name's re-render
-            # runs its queries resolve lazily — slower, never stale.
-            # Only shapes with serving evidence (the dropped keys) are
-            # re-rendered: churn on unqueried names costs nothing here.
-            self._precompiler.enqueue(dropped)
         if self._verify is not None:
             # incremental verification rides the same feed (after the
-            # drops and re-render enqueue: the checker sees the
-            # post-mutation tables, never the stale ones)
+            # drops: the checker sees the post-mutation tables, never
+            # the stale ones)
             self._verify.enqueue_tags(tags)
             ctx = self._verify.tracer.current
             if ctx is not None and self._zone_enabled:
@@ -1186,36 +1035,13 @@ class BinderServer:
             # the zone table should serve)
             self._verify.tracer.observe("native-install", ctx)
 
-    # -- chaos injection hooks (chaos/plan.py corrupt-answer /
-    # drop-reverse; the driver dispatches on these method names) --
-
-    def corrupt_answer(self, qname: Optional[str] = None):
-        """Flip one byte mid-wire in a compiled-table entry's first
-        rotation variant.  Direct table corruption fires NO
-        invalidation — only the verify audit's compiled-bytes walk can
-        find it, which is exactly what the chaos action exists to
-        prove.  Returns the corrupted ``(qtype, qname)`` or None."""
-        for ckey, e in self.answer_cache._compiled.items():
-            if qname is not None and ckey[1] != qname:
-                continue
-            variants = e[2]
-            if not variants:
-                continue
-            v = variants[0]
-            if len(v[0]) <= 12:
-                continue                # header-only wire: nothing to flip
-            w0 = bytearray(v[0])
-            w0[len(w0) // 2] ^= 0xFF
-            variants[0] = (bytes(w0),) + tuple(v[1:])
-            self.log.warning("chaos: corrupted compiled answer for %s",
-                             ckey[1])
-            return ckey
-        return None
+    # -- chaos injection hook (chaos/plan.py drop-reverse; the driver
+    # dispatches on the method name) --
 
     def drop_reverse(self, ip: Optional[str] = None):
         """Delete one reverse-map entry without touching the forward
         node — the forward/reverse coherence break the ptr-coherence
-        audit must catch (no invalidation fires here either).
+        audit must catch (no invalidation fires here).
         Returns the dropped address or None."""
         rl = self.zk_cache.rev_lookup
         if ip is None:
@@ -1430,8 +1256,8 @@ class BinderServer:
         members = self._zone_service_members(node, ttl)
         if not members:
             return                      # NODATA shape: Python answers
-        if len(members) > Precompiler.MAX_SET_RECORDS:
-            return      # oversize rotation set: lazy (see precompile.py)
+        if len(members) > MAX_SET_RECORDS:
+            return      # oversize rotation set: the engine's lazy render
         answers = [
             (b"\xc0\x0c\x00\x01\x00\x01"
              + struct.pack(">IH", min(ttl, rttl) & 0xFFFFFFFF, 4)
@@ -1624,8 +1450,13 @@ class BinderServer:
                                       self.zk_cache.epoch, ancount,
                                       bodies, tag, arcount)
 
-    #: per-pass wall budget for the chunked zone fill / seed walks
+    #: per-pass wall budget for the chunked zone fill
     _FILL_BUDGET_S = 0.002
+    #: a mirror of this many names or fewer fills inline at start (filled
+    #: before query one, what every small-zone test relies on); a larger
+    #: one fills from a chunked background task, so a million-name zone
+    #: serves at once and fills in behind the traffic
+    _FILL_INLINE_MAX = 20000
 
     def _zone_fill(self) -> None:
         """Walk the mirror and push every eligible precompiled answer —
@@ -1653,7 +1484,7 @@ class BinderServer:
                 reserve(self._fastpath, 2 * len(nodes))
             except (TypeError, ValueError, MemoryError) as e:
                 self.log.debug("zone-table reserve skipped: %s", e)
-        if loop is not None and len(nodes) > Precompiler.SEED_INLINE_MAX:
+        if loop is not None and len(nodes) > self._FILL_INLINE_MAX:
             self._zone_fill_task = loop.create_task(
                 self._zone_fill_chunked())
             return
@@ -2242,12 +2073,6 @@ class BinderServer:
     # -- lifecycle (lib/server.js:609-657) --
 
     async def start(self) -> None:
-        if self._precompiler is not None:
-            # compile the already-mirrored names, as many shapes as the
-            # compiled table keeps (mirrors built before this server
-            # subscribed to invalidation events); mutation events keep
-            # the table fresh from here on
-            self._precompiler.seed_mirror()
         self._zone_fill()
         if self.balancer_socket:
             await self.engine.listen_balancer(self.balancer_socket)
@@ -2259,10 +2084,8 @@ class BinderServer:
         # started" lines for the port, and a line printed for a draw
         # that is then released advertises a dead port (observed as a
         # CI dnsblast connection-refused failure)
-        walks = [t for t in (self._zone_fill_task, getattr(
-            self._precompiler, "_seed_task", None)) if t is not None]
-        if not walks:
-            self._set_filled()      # inline walks: filled before query one
+        if self._zone_fill_task is None:
+            self._set_filled()      # inline fill: filled before query one
         elif self.read_when_filled:
             self.engine.hold_reads()
         if self.sockets is not None:
@@ -2286,9 +2109,9 @@ class BinderServer:
                 # raise leaves no socket behind
                 await self.engine.close()
                 raise
-        if walks:
+        if self._zone_fill_task is not None:
             self._filled_task = asyncio.get_running_loop().create_task(
-                self._await_filled(walks))
+                self._await_filled(self._zone_fill_task))
         if self.announce:
             self.engine.announce_udp(self.host, self.udp_port)
             self.engine.announce_tcp(self.host, self.tcp_port)
@@ -2306,12 +2129,12 @@ class BinderServer:
         if self._verify is not None:
             self._verify.start(asyncio.get_running_loop())
 
-    async def _await_filled(self, walks: list) -> None:
-        await asyncio.gather(*walks, return_exceptions=True)
+    async def _await_filled(self, fill) -> None:
+        await asyncio.wait([fill])
         self._set_filled()
 
     def _set_filled(self) -> None:
-        """The startup walks are done: what was answered until now is
+        """The startup zone fill is done: what was answered until now is
         ``binder_unfilled_serves_total`` for good, held reads start, and
         whoever waits for it (a shard worker's supervisor) is told."""
         if self._fastpath is not None:
@@ -2323,11 +2146,8 @@ class BinderServer:
             self.on_filled()
 
     def fill_progress(self) -> int:
-        """Names the startup walks have passed; grows until ``filled``."""
-        pre = self._precompiler
-        seeded = 0 if pre is None or pre._seed_task is None else \
-            len(self.zk_cache.nodes) - pre._seed_remaining
-        return self._fill_done + seeded
+        """Names the zone fill has passed; grows until ``filled``."""
+        return self._fill_done
 
     async def stop(self) -> None:
         if self._filled_task is not None:

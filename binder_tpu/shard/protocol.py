@@ -159,8 +159,8 @@ def stats_frame(requests: float, gen: int, epoch: int, ready: bool,
     incarnation) fold into ``binder_shard_rrl_dropped`` /
     ``binder_shard_shed`` so a flood's per-shard spread is scrapeable
     from the supervisor; older workers simply omit them (defaults).
-    ``filled``: the worker's startup walks (zone fill, precompile seed)
-    are complete; a roll promotes its replacement on it."""
+    ``filled``: the worker's startup zone fill is complete; a roll
+    promotes its replacement on it."""
     return {"op": "stats", "requests": requests, "gen": gen,
             "epoch": epoch, "ready": ready, "inflight": inflight,
             "rrl_dropped": rrl_dropped, "shed": shed, "filled": filled}

@@ -9,7 +9,7 @@ unchanged) whose tree is mutated ONLY by mutation-log frames read from
 the supervisor socketpair.  The worker's own ``MirrorCache`` sits on
 top and re-derives everything a single-process binder would — TreeNode
 tree, reverse (PTR) map, generation bumps, per-name invalidation tags
-feeding the precompiler and the native caches — from the replayed
+feeding the answer caches and the zone table — from the replayed
 deltas, so N shards serve byte-identical answers off one watch load.
 
 Lifecycle:

@@ -20,8 +20,9 @@ rebuild's version of that story with two deliberate twists:
   per-shard UNIX socketpair mutation log (``shard/protocol.py``):
   snapshot + replay on attach, per-name deltas from the owner
   MirrorCache's invalidation events afterwards.  Each worker's
-  precompiler re-renders from that same delta feed, so shard answers
-  stay byte-identical (modulo ID/rotation) to the single-process path.
+  caches drop and its zone table refills from that same delta feed, so
+  shard answers stay byte-identical (modulo ID/rotation) to the
+  single-process path.
 
 The supervisor also owns the operational surface: it respawns crashed
 shards (exponential backoff, snapshot catch-up), drains on SIGTERM
@@ -35,7 +36,7 @@ Zero-downtime rolling operations (SIGHUP / ``roll_all``,
 docs/operations.md "Rolling upgrade / config reload"): one shard at a
 time, spawn the replacement worker onto the shard's sockets, stream it
 the attach snapshot, wait for it to converge (hello, a ready replica,
-and *filled*: its zone fill and precompile seed complete, at which
+and *filled*: its zone fill complete, at which
 point it starts to read the sockets beside the incumbent) — then
 SIGTERM the old incarnation, which stops reading, serves out its
 in-flight queries and exits.  A replacement that fails to converge
@@ -380,8 +381,8 @@ class ShardSupervisor:
                               need_filled: bool = False) -> Optional[str]:
         """Wait for *link*'s worker to say hello (and, for a roll's
         replacement, to report over the stats feed a ready replica and
-        that it is *filled*: its zone fill and precompile seed are
-        complete and it reads its shard's sockets).  Returns None once
+        that it is *filled*: its zone fill is complete and it reads
+        its shard's sockets).  Returns None once
         it has, else why it was given up on: the process exited, or
         nothing moved for ``WORKER_QUIET_S``.  Stats frames are not
         progress — a live worker whose replica never turns ready, or
@@ -1035,8 +1036,8 @@ class ShardSupervisor:
         alone, until the replacement (spawned onto the shard's own
         sockets) has (1) replayed the attach snapshot and said hello,
         (2) reported a ready replica and (3) reported *filled* over the
-        stats feed: its zone fill and precompile seed are complete and
-        it has started to read the sockets.  Only then does the
+        stats feed: its zone fill is complete and it has started to
+        read the sockets.  Only then does the
         incumbent get SIGTERM, stop reading, serve out its in-flight
         queries and exit; what it left unread in the sockets is the
         replacement's.  Every phase is a ``rolling-upgrade`` flight

@@ -541,8 +541,8 @@ class MirrorCache:
         """The canonical object for *name*: the mirror's own domain
         string when the name is mirrored (the nodes index is the
         canonical home for mirrored names), else the process-wide
-        interned-name pool.  The answer cache's tag index and the
-        compiled-answer table intern through this, so a name is ONE
+        interned-name pool.  The answer cache's tag index interns
+        through this, so a name is ONE
         object no matter how many layers index it."""
         node = self.nodes.get(name)
         if node is not None:
@@ -561,7 +561,7 @@ class MirrorCache:
 
     def invalidate_all(self, reason: str = "") -> None:
         """Epoch bump OUTSIDE a rebuild: every answer cached anywhere
-        (Python answer cache, compiled table, native C caches, the
+        (Python answer cache, native C caches, the
         balancer) must revalidate.  Used by the degradation policy at
         state transitions — an answer rendered under one staleness mode
         must never be served under another (e.g. a fresh-rendered wire

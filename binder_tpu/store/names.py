@@ -2,8 +2,8 @@
 representation.
 
 Every structure that touches a DNS name — the mirror's node index, the
-reverse (PTR) map, the answer cache's dependency-tag index, the
-compiled-answer table, the shard mutation log — used to hold its own
+reverse (PTR) map, the answer cache's dependency-tag index, the shard
+mutation log — used to hold its own
 copy of the same strings, and every mirrored znode held a freshly
 parsed JSON dict whose *keys* alone ("type", "host", "address")
 dominated per-name RSS at scale (json.loads memoizes keys within one
@@ -121,7 +121,7 @@ class NamePool:
 
 
 #: THE pool.  One per process on purpose: the mirror, the answer
-#: cache's tag index, the compiled-answer table, and a shard worker's
+#: cache's tag index and a shard worker's
 #: replica feed all intern through here, which is what makes a name
 #: ONE object no matter how many layers index it.
 POOL = NamePool()

@@ -2,7 +2,7 @@
 
 Janus-style (arXiv:2511.02559) incremental verification: instead of
 re-proving the whole zone after every change, the checker hangs off the
-SAME per-name invalidation feed the precompiler drains
+SAME per-name invalidation feed the zone drain takes
 (``MirrorCache.invalidate`` → ``BinderServer._on_store_invalidate``)
 and re-verifies only what a mutation can have affected.  Invariants:
 
@@ -13,33 +13,25 @@ and re-verifies only what a mutation can have affected.  Invariants:
   agree in both directions — a host-like node's address has a reverse
   entry that points back at a node carrying that address, and no
   reverse entry maps an address its node no longer owns;
-- ``compiled-bytes``: a compiled-table entry's wires are byte-identical
-  to a fresh engine render of the same plan (id 0 / RD clear are the
-  canonical form on both sides; rotation variants compare in their
-  deterministic order).  Only checked while the degradation policy is
-  ``fresh`` — stale serving clamps TTLs in the rendered bytes;
 - ``replica-digest``: shard replicas apply the same mutation log the
   owner sent, proven by rolling per-generation digest frames (see
   ``shard/protocol.delta_digest``; the supervisor/replica own the
   wire halves, violations are counted under this invariant on both
-  sides);
-- ``stale-epoch``: no pre-transition epoch survives a
-  degradation-policy flush — after an ``invalidate_all`` the checker
-  sweeps the compiled table (time-budgeted), and any old-epoch entry
-  found AFTER the sweep completed is a violation (the bug class where
-  a re-render captures its epoch before a flush and installs after).
+  sides).
+
+No invariant reads a byte of the native zone table or the native answer
+cache (ROADMAP D18).
 
 Violations surface three ways at once: a ``verify-violation`` flight
 event, the ``binder_verify_violations_total{invariant}`` counter, and
 the ``recent_violations`` table in ``/status verify``.  Work the
-checker cannot do soundly (stale mode, store not ready, queue
-overflow) is counted as ``binder_verify_skipped_total`` — silence is
-never ambiguous.
+checker sheds (queue overflow) is counted as
+``binder_verify_skipped_total`` — silence is never ambiguous.
 
 Everything is time-budgeted at 2 ms per event-loop pass (the PR 7
 chunked-rebuild discipline), including the sampled full-zone
 background audit that catches drift the delta feed cannot see —
-corruption injected directly into tables (chaos ``corrupt-answer`` /
+corruption injected directly into the mirror's maps (chaos
 ``drop-reverse``) never fires an invalidation, so only the audit walk
 finds it.
 """
@@ -51,8 +43,7 @@ import time
 from collections import deque
 from typing import Optional
 
-from binder_tpu.dns.wire import Rcode, Type, ip_from_reverse_name
-from binder_tpu.resolver.answer_cache import _COMPILED
+from binder_tpu.dns.wire import ip_from_reverse_name
 from binder_tpu.verify.tracer import PropagationTracer
 
 #: the invariant catalog — the ``{invariant=...}`` label values of the
@@ -61,9 +52,7 @@ from binder_tpu.verify.tracer import PropagationTracer
 INVARIANTS = (
     "dangling-srv",
     "ptr-coherence",
-    "compiled-bytes",
     "replica-digest",
-    "stale-epoch",
 )
 
 #: skip accounting for delta work shed under queue pressure (a series
@@ -76,8 +65,8 @@ class Verifier:
     sampled, budgeted background audit, and the owner of the process's
     :class:`~binder_tpu.verify.tracer.PropagationTracer`."""
 
-    #: per-pass wall budget for the delta drain, the epoch sweep and
-    #: each audit slice — same discipline as the chunked mirror rebuild
+    #: per-pass wall budget for the delta drain and each audit slice —
+    #: same discipline as the chunked mirror rebuild
     BUDGET_S = 0.002
     MIN_CHUNK = 1
     #: delta-queue bound: overflow degrades to the audit (counted as
@@ -86,21 +75,16 @@ class Verifier:
     #: violations retained for the /status table
     RECENT_VIOLATIONS = 16
 
-    def __init__(self, *, zk_cache, answer_cache=None, resolver=None,
-                 precompiler=None, policy_mode=None, config=None,
-                 collector=None, recorder=None,
+    def __init__(self, *, zk_cache, config=None, collector=None,
+                 recorder=None,
                  log: Optional[logging.Logger] = None) -> None:
         cfg = dict(config or {})
         self.zk_cache = zk_cache
-        self.answer_cache = answer_cache
-        self.resolver = resolver
-        self.precompiler = precompiler
-        self._policy_mode = policy_mode or (lambda: "fresh")
         self.recorder = recorder
         self.log = log or logging.getLogger("binder.verify")
         self.audit_interval_s = float(
             cfg.get("auditIntervalSeconds", 0.25))
-        #: check every Nth name/entry per audit pass; successive passes
+        #: check every Nth name per audit pass; successive passes
         #: rotate the residue so N passes cover the whole zone
         self.audit_sample = max(1, int(cfg.get("auditSample", 1)))
         self.tracer = PropagationTracer(collector=collector,
@@ -117,10 +101,6 @@ class Verifier:
         # delta queue: insertion-ordered tag set (dict keys)
         self._queue: dict = {}
         self._drain_scheduled = False
-        # stale-epoch sweep state (see _maybe_epoch_sweep)
-        self._epoch_seen = zk_cache.epoch
-        self._sweep_keys: list = []
-        self._sweep_done = True
         # audit cursor
         self._audit_work: list = []
         self._audit_residue = 0
@@ -135,8 +115,7 @@ class Verifier:
                 "serving-plane invariant violations detected")
             skipped = collector.counter(
                 "binder_verify_skipped_total",
-                "invariant checks skipped (unsound mode, store not "
-                "ready, or delta-queue overflow)")
+                "invariant checks skipped (delta-queue overflow)")
             self._m_checks = {
                 inv: checks.labelled({"invariant": inv})
                 for inv in INVARIANTS}
@@ -212,7 +191,7 @@ class Verifier:
             loop = asyncio.get_running_loop()
         except RuntimeError:
             # no loop (synchronous stores, tests): drain inline
-            while self._queue or not self._sweep_done:
+            while self._queue:
                 self._drain(reschedule=False)
             return
         self._drain_scheduled = True
@@ -221,7 +200,6 @@ class Verifier:
     def _drain(self, reschedule: bool = True) -> None:
         self._drain_scheduled = False
         t0 = time.perf_counter()
-        self._maybe_epoch_sweep(t0)
         n = 0
         q = self._queue
         while q:
@@ -236,7 +214,7 @@ class Verifier:
             if (n >= self.MIN_CHUNK
                     and time.perf_counter() - t0 >= self.BUDGET_S):
                 break
-        if reschedule and (q or not self._sweep_done):
+        if reschedule and q:
             self._drain_scheduled = False
             try:
                 loop = asyncio.get_running_loop()
@@ -256,7 +234,6 @@ class Verifier:
             node = self.zk_cache.nodes.get(tag)
             if node is not None:
                 self._check_node(node)
-        self._check_compiled_for_tag(tag)
 
     def _check_reverse_entry(self, ip: str) -> None:
         """One reverse-map entry's coherence: if the map still claims
@@ -303,103 +280,6 @@ class Verifier:
                     self._violation("dangling-srv", service=node.domain,
                                     target=kid)
 
-    # -- compiled-table checks --
-
-    def _check_compiled_for_tag(self, tag: str) -> None:
-        ac = self.answer_cache
-        if ac is None:
-            return
-        keys = ac._by_tag.get(tag)
-        if not keys:
-            return
-        for key in list(keys):
-            if type(key) is tuple and len(key) == 3 \
-                    and key[0] is _COMPILED:
-                self._check_compiled(key[1:])
-
-    def _check_compiled(self, ckey) -> None:
-        ac = self.answer_cache
-        e = ac._compiled.get(ckey)
-        if e is None:
-            return
-        epoch = self.zk_cache.epoch
-        self._check("stale-epoch")
-        if e[0] != epoch:
-            # during the post-flush sweep window old-epoch entries are
-            # EXPECTED (the flush invalidated them wholesale) — purge;
-            # after the sweep declared the table clean, survival is the
-            # violation
-            if self._sweep_done:
-                self._violation("stale-epoch", qname=ckey[1],
-                                qtype=ckey[0], entry_epoch=e[0],
-                                epoch=epoch)
-            ac._drop_compiled(ckey, e)
-            return
-        if self._policy_mode() != "fresh":
-            # stale serving clamps TTLs in the rendered bytes: a
-            # re-render would false-positive against a fresh-rendered
-            # entry (and vice versa)
-            self._skip("compiled-bytes")
-            return
-        pc, rz = self.precompiler, self.resolver
-        if pc is None or rz is None:
-            self._skip("compiled-bytes")
-            return
-        qtype, qname = ckey
-        if qtype == Type.PTR:
-            plan = rz.plan_ptr(qname)
-        else:
-            plan = rz.plan(qname, qtype)
-        self._check("compiled-bytes")
-        if plan.rcode == Rcode.SERVFAIL:
-            self._skip("compiled-bytes")
-            return
-        if plan.miss:
-            self._violation("compiled-bytes", qname=qname, qtype=qtype,
-                            detail="compiled entry for a missing name")
-            return
-        fresh = pc.render_variants(qname, qtype, plan)
-        if fresh is None:
-            self._skip("compiled-bytes")  # oversize/unencodable: lazy
-            return
-        have = e[2]
-        if len(fresh) != len(have):
-            self._violation("compiled-bytes", qname=qname, qtype=qtype,
-                            detail="variant count %d != fresh %d"
-                                   % (len(have), len(fresh)))
-            return
-        for i, (hv, fv) in enumerate(zip(have, fresh)):
-            if hv[0] != fv[0] or hv[1] != fv[1]:
-                self._violation(
-                    "compiled-bytes", qname=qname, qtype=qtype,
-                    variant=i,
-                    detail="compiled wire differs from fresh render")
-                return
-
-    # -- stale-epoch sweep --
-
-    def _maybe_epoch_sweep(self, t0: float) -> None:
-        ac = self.answer_cache
-        if ac is None:
-            return
-        epoch = self.zk_cache.epoch
-        if epoch != self._epoch_seen:
-            self._epoch_seen = epoch
-            self._sweep_keys = list(ac._compiled)
-            self._sweep_done = not self._sweep_keys
-        if self._sweep_done:
-            return
-        keys = self._sweep_keys
-        while keys:
-            ckey = keys.pop()
-            e = ac._compiled.get(ckey)
-            if e is not None and e[0] != epoch:
-                self._check("stale-epoch")
-                ac._drop_compiled(ckey, e)
-            if time.perf_counter() - t0 >= self.BUDGET_S:
-                return
-        self._sweep_done = True
-
     # -- the sampled background audit --
 
     def start(self, loop) -> None:
@@ -436,9 +316,6 @@ class Verifier:
         zk = self.zk_cache
         work = [("name", d) for d in list(zk.nodes)[r::n]]
         work += [("rev", ip) for ip in list(zk.rev_lookup)[r::n]]
-        if self.answer_cache is not None:
-            work += [("ckey", k)
-                     for k in list(self.answer_cache._compiled)[r::n]]
         self._audit_work = work
         self.audit_passes += 1
 
@@ -446,7 +323,6 @@ class Verifier:
         """One time-budgeted audit slice: resumes the in-flight pass or
         snapshots a new one.  Synchronous — tests drive it directly."""
         t0 = time.perf_counter()
-        self._maybe_epoch_sweep(t0)
         if not self._audit_work:
             self._audit_refill()
         work = self._audit_work
@@ -458,10 +334,8 @@ class Verifier:
                     node = self.zk_cache.nodes.get(item)
                     if node is not None:
                         self._check_node(node)
-                elif kind == "rev":
-                    self._check_reverse_entry(item)
                 else:
-                    self._check_compiled(item)
+                    self._check_reverse_entry(item)
             except Exception:  # noqa: BLE001 — see _drain
                 self.log.exception("verify audit failed for %s %s",
                                    kind, item)

@@ -11,9 +11,6 @@ the elapsed time against that SAME t0:
 - ``replica-apply``: a worker's replica store applied the delta (the
   frame carries the owner's trace id and t0 — ``time.monotonic`` is
   CLOCK_MONOTONIC on Linux, comparable across processes on one box);
-- ``precompile-render`` / ``compiled-install``: the precompiler
-  re-rendered the affected answers and installed them in the compiled
-  table;
 - ``native-install``: the zone lane re-installed the answer in the
   native fast path.
 
@@ -45,8 +42,6 @@ STAGES = (
     "mirror-apply",
     "shard-frame",
     "replica-apply",
-    "precompile-render",
-    "compiled-install",
     "native-install",
 )
 
@@ -72,7 +67,7 @@ class PropagationTracer:
     One instance per process; the owner-side instance lives on the
     serving plane's :class:`~binder_tpu.verify.checker.Verifier` (the
     shard supervisor builds a bare one — it has no answer plane), and
-    the mirror/precompiler/server reach it through duck-typed
+    the mirror and the server reach it through duck-typed
     ``tracer`` attributes so every hook stays optional.
     """
 

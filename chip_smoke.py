@@ -346,7 +346,7 @@ def write_config(out_dir: str, hosts: int) -> tuple:
 
 
 def http_get(port: int, path: str) -> bytes:
-    # a worker busy seeding a million names answers a scrape in ~15 s
+    # a worker busy filling a million names answers a scrape in ~15 s
     with urllib.request.urlopen(f"http://127.0.0.1:{port}{path}",
                                 timeout=60) as r:
         return r.read()
@@ -372,7 +372,6 @@ def worker_state(worker: dict) -> dict:
             "native_serves": int(total("binder_zone_serves")
                                  + total("binder_answer_cache_hits")
                                  - status["answer_cache"]["hits"]),
-            "seed_remaining": status["precompile"]["seed_remaining"],
             "store": status["store"]["backend"]}
 
 
@@ -506,18 +505,16 @@ def check_mutation(server: Server, udp: int, wire: bytes, pids: list,
 
 
 def wait_settled(workers: list, hosts: int) -> None:
-    """Above 20k names the precompile seed and the native zone fill
-    run in the background after ready, and hold each worker's loop
-    while they do.  The load pass wants the settled state: seed
-    drained, every host's zone entry in, and the zone gauges the same
-    on two readings two seconds apart."""
+    """Above 20k names the native zone fill runs in the background
+    after ready, and holds each worker's loop while it does.  The load
+    pass wants the settled state: every host's zone entry in, and the
+    zone gauges the same on two readings two seconds apart."""
     deadline = time.monotonic() + SETTLE_TIMEOUT_S
     last = None
     while True:
         states = [worker_state(w) for w in workers]
         entries = [s["zone_entries"] for s in states]
-        if (entries == last and min(entries) >= hosts
-                and not any(s["seed_remaining"] for s in states)):
+        if entries == last and min(entries) >= hosts:
             return
         if time.monotonic() > deadline:
             fail("serve", f"not settled after {SETTLE_TIMEOUT_S:.0f}s: "
@@ -618,8 +615,6 @@ def serve(args, out_dir: str) -> dict:
         "time_to_ready_s": round(ready_s, 1),
         "time_to_settled_s": round(settled_s, 1),
         "background_zone_fill_s": server.background_s("zone fill"),
-        "background_precompile_seed_s":
-            server.background_s("precompile seed"),
         "rss_mb_at_ready": rss_ready,
         "worker_pids": pids, "worker_store": "ReplicaStore",
         "checks_passed": checks.passed,

@@ -8,8 +8,7 @@ Pins the contracts the hostile plane must keep:
   ``tools/hostile.py --write-corpus``) produces FORMERR-or-drop on
   every serve lane — the UDP ingress, the TCP generic path, and the
   live TCP stream lane — never an exception and
-  never a deposit into the decode cache, answer cache, or compiled
-  table;
+  never a deposit into the decode cache or the answer cache;
 - **RRL mechanics** — slip replies are TC=1 echoes no larger than the
   query, prefixes aggregate (/24 v4), the bucket table is LRU-capped,
   ``hot()`` trips on limiting and feeds the fastpath gate, and
@@ -86,7 +85,6 @@ def assert_no_deposits(server):
     assert not server.engine._decode_cache, "decode-cache deposit"
     stats = server.answer_cache.stats()
     assert stats["entries"] == 0, "answer-cache deposit"
-    assert stats["compiled_entries"] == 0, "compiled-table deposit"
 
 
 class TestCorpus:

@@ -646,10 +646,7 @@ class TestSnapshotValidator:
             "answer_cache": {"size": 10, "entries": 0, "hits": 0,
                              "misses": 0, "hit_ratio": 0.0,
                              "invalidations": 0, "expiry_ms": 1000.0,
-                             "neg_hits": 0, "compiled_entries": 0,
-                             "compiled_serves": 0,
-                             "compiled_installs": 0,
-                             "type_row_serves": 0,
+                             "neg_hits": 0, "type_row_serves": 0,
                              "zone_put_skips": {"size": 0, "bytes": 0}},
             "inflight": {"count": 0, "queries": []},
             "tcp": {"open_conns": 0, "max_conns": 1024,
@@ -661,7 +658,8 @@ class TestSnapshotValidator:
                     "slow_reader_drops": 0, "coalesced_writes": 0,
                     "coalesced_frames": 0, "half_closes": 0,
                     "rst_drops": 0, "udp_truncated": 0},
-            "recursion": None, "precompile": None, "loop": None,
+            "recursion": None, "precompile": {"seed_remaining": 0},
+            "loop": None,
             "flight_recorder": None, "policy": None, "verify": None,
             "io": None,
         }

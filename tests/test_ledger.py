@@ -649,7 +649,7 @@ def service_cache(members):
 ], ids=["A-64", "A-65", "SRV-64", "SRV-66"])
 def test_lazy_render_fires_above_64_records_and_not_at_64(
         qname, qtype, members, lazy):
-    """A set the precompiler declines (``MAX_SET_RECORDS``) is rendered
+    """A set above ``engine.MAX_SET_RECORDS`` is rendered
     at query time under a stage of its own, in place of ``store-lookup``
     and ``pre-resp``; a set of 64 records keeps those two."""
     async def run():
@@ -694,11 +694,11 @@ def test_ring_keeps_a_forced_block_with_its_instant():
 
     dog, start, end = asyncio.run(run())
     ring = dog.snapshot()["stalls"]
-    assert len(ring) == 1, ring
-    assert ring[0]["lag_s"] >= 0.05
     # the instant is the late wake-up: at the block's end, on the clock
-    # every process of the machine shares
-    assert start <= ring[0]["t_mono"] <= end + 0.02
+    # every process of the machine shares (a busy machine may add
+    # stalls of its own beside the forced one)
+    assert any(s["lag_s"] >= 0.05 and start <= s["t_mono"] <= end + 0.02
+               for s in ring), ring
     assert dog.snapshot()["stall_events"] == 0      # under 0.25 s
 
 

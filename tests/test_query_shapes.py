@@ -1,16 +1,15 @@
 """The Python lanes' answer for every query shape, held to a reference.
 
 A datagram that the native lanes did not answer takes one path:
-``_decode_query`` then ``_on_query`` (answer cache, compiled table,
-resolver).  These tests drive that path with the query log off (so no
+``_decode_query`` then ``_on_query`` (answer cache, resolver).  These tests drive that path with the query log off (so no
 log line stands between a query and its answer):
 
 - every shape of ``QUERY_SHAPES``, the store-down shape and two
   malformed-looking shapes are asked twice of a server with its caches
   on (the first sight is a resolve, the second an answer-cache hit
   wherever the answer may be cached) and each answer must be byte for
-  byte what a reference server renders (no answer cache, no compiled
-  table, no zone table: every answer a resolve), the id apart;
+  byte what a reference server renders (no answer cache, no zone
+  table: every answer a resolve), the id apart;
 - what the caches must keep: each requester's own question case, a
   mutation's invalidation, rotation of service answers, metrics with
   the log off, one cache key a transport.
@@ -73,9 +72,8 @@ def new_server(cache, **kw):
 
 def reference_server(cache):
     """A server whose every answer is a resolve: no answer cache, no
-    compiled table, no zone table."""
-    return new_server(cache, cache_size=0, answer_precompile=False,
-                      zone_precompile=False)
+    zone table."""
+    return new_server(cache, cache_size=0, zone_precompile=False)
 
 
 def responses(server, wire: bytes, protocol: str = "udp",

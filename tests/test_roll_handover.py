@@ -46,8 +46,8 @@ from reference import Zone, compare  # noqa: E402
 
 DOMAIN = "roll.test"
 LIMIT_S = 120.0
-#: over ``Precompiler.SEED_INLINE_MAX`` (20,000 mirrored names), so a
-#: worker's zone fill and precompile seed are chunked walks
+#: over ``BinderServer._FILL_INLINE_MAX`` (20,000 mirrored names), so a
+#: worker's zone fill is a chunked walk
 HOSTS = 20600
 ZONE = Zone({"hosts": HOSTS, "racks": 0, "subtree": "zs",
              "services": {"count": 0, "srvce": "_http", "proto": "_tcp",
@@ -459,8 +459,7 @@ async def plain_server():
     server = BinderServer(
         zk_cache=cache, dns_domain=DOMAIN, datacenter_name="dc0",
         host="127.0.0.1", port=0, collector=MetricsCollector(),
-        query_log=False, cache_size=0, zone_precompile=False,
-        answer_precompile=False)
+        query_log=False, cache_size=0, zone_precompile=False)
     await server.start()
     return server
 

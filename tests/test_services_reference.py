@@ -4,7 +4,7 @@ zone whose set sizes straddle every edge the program has (ISSUE 26).
 ``benchmark/reference.py`` resolves from the zone's description alone and
 imports nothing of ``binder_tpu``; here its fixture is loaded into a fake
 store under a server in the production posture's serving shape (zone
-table, answer precompile, query log through the native ring), and every
+table, query log through the native ring), and every
 service is asked the way the cell ``services_srv_open60`` asks: SRV
 ``_http._tcp.<service>`` over UDP without an OPT record, over UDP with OPT
 1232, and over TCP.  A UDP answer with TC=1 is held to its header and its
@@ -24,8 +24,8 @@ section) on an answer to a question that had one, none otherwise.
 
 The sizes: 6/7 is where 512 bytes run out, 8/9 the edge between the
 deployment's ``small`` and ``medium`` classes, 16/17 where 1232 bytes run
-out, 32/33 the precompiler's 64 *records* for an SRV set with glue
-(``Precompiler.MAX_SET_RECORDS``), 64/65 what was the zone table's
+out, 32/33 the lazy render's 64 *records* for an SRV set with glue
+(``engine.MAX_SET_RECORDS``), 64/65 what was the zone table's
 64-*member* rule until ISSUE 39 (its SRV entries now hold every set a
 TCP message carries), 250 the deployment's largest set.
 """
@@ -51,7 +51,7 @@ fastio = pytest.importorskip(
 sys.path.append(os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark"))
 import dnswire  # noqa: E402
-from reference import Zone, compare  # noqa: E402
+from reference import Zone, compare, whole_size  # noqa: E402
 
 DOMAIN = "foo.com"
 SIZES = (2, 6, 7, 8, 9, 16, 17, 32, 33, 64, 65, 250)
@@ -98,7 +98,7 @@ class Served:
             host="127.0.0.1", port=0, collector=MetricsCollector(),
             log=make_logger("binder-services-test",
                             stream=byte_stream()[0]),
-            query_log=True, zone_precompile=True, answer_precompile=True)
+            query_log=True, zone_precompile=True)
         await server.start()
         return server
 
@@ -171,12 +171,15 @@ def test_srv_set_equals_the_reference(served, size, transport):
         if transport == "tcp":
             continue
         udp = dnswire.Answer(served.ask_udp(wire))
-        # TC=1 exactly when the whole set does not fit the limit; held
-        # to its header then, and its retry (above) to the whole set
-        fits = len(whole) <= limit
+        # TC=1 exactly when the whole set does not fit the limit, by the
+        # reference's own plain wire (the zone table's stream frame
+        # spells glue owners out and is longer); held to its header
+        # then, and its retry (above) to the whole set
+        want = zone.expected(qname, dnswire.SRV, payload=payload or 0)
+        fits = whole_size(qname, want) <= limit
         assert udp.tc == (not fits), (size, len(whole), limit)
         assert compare(udp, qname, dnswire.SRV, want,
-                       whole=not udp.tc) == []
+                       whole=not udp.tc, truncated=udp.tc) == []
         # and a member's own A record, the glue's source
         label, address = service.members[qid % size]
         member = f"{label}.{service.label}.{DOMAIN}"

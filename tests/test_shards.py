@@ -14,7 +14,7 @@ What ISSUE 6 pins here:
   record shapes (host A, PTR, REFUSED policy, rotated service sets);
 - the ``binder_shard_*`` exposition passes
   ``tools/lint.py validate_shard_metrics`` (this is the family's
-  tier-1 wiring, like the tcp/precompile validators);
+  tier-1 wiring, like the tcp validator);
 - the chaos DSL's ``shard-kill`` action parses and dispatches to the
   driver's ``shard_target``.
 
@@ -373,8 +373,7 @@ class TestLargeSnapshotAttach:
 
     def test_50k_snapshot_heartbeats_convergence_parity(self):
         from binder_tpu.metrics.collector import MetricsCollector
-        from binder_tpu.resolver.engine import Resolver
-        from binder_tpu.resolver.precompile import Precompiler
+        from binder_tpu.resolver.engine import Resolver, render_plan
         from binder_tpu.shard import ReplicaStore
         from binder_tpu.shard.supervisor import ShardLink, ShardSupervisor
         from binder_tpu.store import FakeStore, MirrorCache
@@ -382,10 +381,7 @@ class TestLargeSnapshotAttach:
 
         def render(cache, qname):
             plan = Resolver(cache, dns_domain=DOMAIN).plan(qname, Type.A)
-            answers = [r for g in plan.groups for r in g[0]]
-            adds = [r for g in plan.groups for r in g[1]]
-            return Precompiler._render(qname, Type.A, plan, answers,
-                                       adds, False)
+            return render_plan(qname, Type.A, plan)
 
         async def run():
             store = FakeStore()

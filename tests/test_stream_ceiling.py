@@ -19,8 +19,8 @@ Two layers, as ``test_fastpath.py`` has them:
 - C-unit: ``fastpath_zone_put`` / ``fastpath_put`` on a bare cache, and
   the three serving entries over the same bytes;
 - served: a ``BinderServer`` in the production posture's serving shape
-  (zone table, answer precompile, query log through the native ring)
-  beside the generic path (no cache, no compiled table, no zone table)
+  (zone table, query log through the native ring)
+  beside the generic path (no cache, no zone table)
   over one zone of SRV sets with glue, frame for frame.
 
 The zone table's SRV bodies spell the glue's owner names out (the fill
@@ -542,8 +542,7 @@ ALL_SIZES = tuple(sorted(set(NATIVE_SIZES + EDGE_SIZES + STREAM_SIZES))
                   ) + (ABOVE_STREAM,)
 VARIANTS = 8
 #: the fields of a query-log line that name the lane or the moment
-LANE_FIELDS = ("time", "latency", "timers", "trace", "cached",
-               "precompiled")
+LANE_FIELDS = ("time", "latency", "timers", "trace", "cached")
 
 
 class Rotation:
@@ -594,10 +593,8 @@ class Pair:
 
         raws = []
         self.stores = []
-        self.served = server(raws, zone_precompile=True,
-                             answer_precompile=True)
-        self.generic = server(raws, zone_precompile=False,
-                              answer_precompile=False, cache_size=0)
+        self.served = server(raws, zone_precompile=True)
+        self.generic = server(raws, zone_precompile=False, cache_size=0)
         self.served_raw, self.generic_raw = raws
         self.generic.resolver.rng = self.rotation
         await self.served.start()
@@ -690,13 +687,9 @@ def fields(wire):
 def line_differences(native, python):
     """The fields two lines differ in, those that name the lane or the
     moment aside."""
-    # the line of an entry the seed installed in the native answer
-    # cache is the compiled serve's, C's or Python's: no ``query``
-    # object; a zone serve's has the resolve's
-    drop = LANE_FIELDS + (("query",) if native.get("precompiled") else ())
     return {k: (native.get(k), python.get(k))
             for k in set(native) | set(python)
-            if k not in drop and native.get(k) != python.get(k)}
+            if k not in LANE_FIELDS and native.get(k) != python.get(k)}
 
 
 def fragment_len(line):

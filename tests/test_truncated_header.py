@@ -6,12 +6,11 @@ stores the wire it sent as an entry of one variant: complete in the
 answer cache from its first sight.  The second sight is a plain
 answer-cache hit, which promotes the entry to the native answer cache,
 and the third is replayed by C.  (Before, the entry waited for eight
-resolves of eight byte-identical headers.)  The compiled probe still
-declines a wire that would need truncation: the first sight is a
-resolve, whether or not the compiled table holds the set.
+resolves of eight byte-identical headers.)  The first sight is a
+resolve.
 
 Held here: every byte on the wire against the generic path (a server
-with no compiled table and no answer cache, whose every answer is
+with no zone table and no answer cache, whose every answer is
 ``resolver.handle`` + ``QueryCtx.respond``), over the postures a client
 can take; which answers are truncated; rotation of every answer that
 carries records; invalidation by tag and by epoch; the two counters; the
@@ -39,7 +38,7 @@ from tests.test_server import read_data_frame
 
 DOMAIN = "foo.com"
 #: members of a set: 7 is where 512 bytes run out and 17 where 1232 do
-#: (a member costs 73 bytes here); 32/33 straddle the precompiler's 64
+#: (a member costs 73 bytes here); 32/33 straddle the lazy render's 64
 #: records for an SRV set with glue; 250 is the service cell's largest
 SIZES = (7, 17, 32, 33, 250)
 PROTOCOLS = ("udp", "balancer")
@@ -101,9 +100,9 @@ def records(wire: bytes):
 
 class Pair:
     """The server under test (the production posture's serving shape:
-    zone table, answer precompile, query log through the native ring, a
-    balancer socket) and the generic path beside it (no compiled table,
-    no answer cache, no zone table), over one zone, on a loop of their
+    zone table, query log through the native ring, a balancer socket)
+    and the generic path beside it (no answer cache, no zone table),
+    over one zone, on a loop of their
     own thread, for blocking asks."""
 
     def __init__(self, sock_path):
@@ -131,13 +130,12 @@ class Pair:
             zk_cache=zone(), dns_domain=DOMAIN, datacenter_name="coal",
             host="127.0.0.1", port=0, collector=MetricsCollector(),
             log=make_logger("binder-tc-test", stream=self.stream),
-            query_log=True, zone_precompile=True, answer_precompile=True,
+            query_log=True, zone_precompile=True,
             balancer_socket=sock_path)
         self.generic = BinderServer(
             zk_cache=zone(), dns_domain=DOMAIN, datacenter_name="coal",
             host="127.0.0.1", port=0, collector=MetricsCollector(),
-            query_log=False, zone_precompile=False,
-            answer_precompile=False, cache_size=0)
+            query_log=False, zone_precompile=False, cache_size=0)
         await self.server.start()
         await self.generic.start()
         self.reader, self.writer = \
@@ -206,9 +204,8 @@ def pair(tmp_path_factory):
 def test_a_udp_answer_equals_the_generic_paths(pair, size, protocol, opt,
                                                rd):
     """Three sights of one question in one posture, each in a question
-    case of its own: the first is a resolve (the compiled probe declines
-    a wire over the payload, and sets above 64 records have no entry),
-    the second is the answer cache's, the third the native cache's where
+    case of its own: the first is a resolve, the second is the answer
+    cache's, the third the native cache's where
     it took the entry.  Each equals what the generic path answers to the same bytes:
     byte for byte where it is truncated, record for record where the
     whole set fits and rotates."""
@@ -285,7 +282,6 @@ def small_zone(members=4, label="few"):
 
 async def start_server(cache, **kw):
     kw.setdefault("query_log", False)
-    kw.setdefault("answer_precompile", True)
     server = BinderServer(zk_cache=cache, dns_domain=DOMAIN,
                           datacenter_name="coal", host="127.0.0.1", port=0,
                           collector=MetricsCollector(), **kw)
@@ -305,17 +301,13 @@ def entry_of(server, qname, payload=None, rd=False):
     return server.answer_cache._entries.get(key)
 
 
-@pytest.mark.parametrize("precompile", [False, True],
-                         ids=["resolved", "compiled"])
-def test_an_answer_that_fits_keeps_rotatable_and_its_eight_variants(
-        precompile):
-    """Nine members under a payload of 1400 (no posture the seed installs
-    natively, so every sight is the Python lanes'): the set fits, and
-    its entry serves no hit before it holds eight rotations."""
+def test_an_answer_that_fits_keeps_rotatable_and_its_eight_variants():
+    """Nine members under a payload of 1400 (no zone table, so every
+    sight is the Python lanes'): the set fits, and its entry serves no
+    hit before it holds eight rotations."""
     async def run():
         store, cache = small_zone(9)
-        server = await start_server(cache, zone_precompile=False,
-                                    answer_precompile=precompile)
+        server = await start_server(cache, zone_precompile=False)
         qname = f"_http._tcp.few.{DOMAIN}"
         wire = make_query(qname, Type.SRV, qid=5,
                           edns_payload=1400).encode()
@@ -327,24 +319,21 @@ def test_an_answer_that_fits_keeps_rotatable_and_its_eight_variants(
             e = entry_of(server, qname, payload=1400)
             seen.append((server.answer_cache.hits, e[4], len(e[3])))
         made = renders_of(server)
-        compiled = server.answer_cache._compiled.get((Type.SRV, qname))
         await server.stop()
-        return seen, made, compiled
+        return seen, made
 
-    seen, made, compiled = asyncio.run(run())
+    seen, made = asyncio.run(run())
     # eight sights each store a variant and none is a hit; the ninth is
     assert [n for _, _, n in seen] == [1, 2, 3, 4, 5, 6, 7, 8, 8]
     assert [hits for hits, _, _ in seen] == [0] * 8 + [1]
     assert not any(complete for _, complete, _ in seen)
     assert made == 0
-    if precompile:
-        assert compiled[3] and len(compiled[2]) == 8    # rotatable, 8
 
 
-@pytest.mark.parametrize("members", [7, 33], ids=["compiled", "above-64"])
+@pytest.mark.parametrize("members", [7, 33], ids=["under-64", "above-64"])
 def test_a_truncated_wire_is_complete_from_its_first_sight(members):
-    """Whether the compiled table holds the set (7 members) or not (33,
-    above ``Precompiler.MAX_SET_RECORDS`` with its glue)."""
+    """Whether the set's first sight is a plain resolve (7 members) or a
+    lazy render (33, above ``engine.MAX_SET_RECORDS`` with its glue)."""
     async def run():
         store, cache = small_zone(members)
         server = await start_server(cache, zone_precompile=False)
@@ -467,19 +456,16 @@ def test_one_render_then_the_caches_and_c_replays_it_truncated():
     assert len(lines) == 6
     whole, first, hits = lines[0], lines[1], lines[2:]
     # the stream answer is the zone table's, whole, in C's line (no
-    # lane field, no timers; the compiled table's without the
-    # extension); the first UDP sight is a resolve (the probe declines
-    # what it would have to truncate), and its line summarizes the set
+    # lane field, no timers; a resolve's without the extension); the
+    # first UDP sight is a resolve, and its line summarizes the set
     # that was rendered
     assert whole["rcode"] == "NOERROR" and whole["port"].endswith("/tcp")
     if native:
-        assert "precompiled" not in whole and whole["timers"] == {}
-    else:
-        assert whole["precompiled"] is True
-    assert "precompiled" not in first and "cached" not in first
+        assert whole["timers"] == {}
+    assert "cached" not in whole and "cached" not in first
     assert len(first["answers"]) == len(first["additional"]) == 7
     for line in [first] + hits:
         assert sorted(line["answers"]) == sorted(whole["answers"])
         assert sorted(line["additional"]) == sorted(whole["additional"])
     for line in hits:
-        assert line["cached"] is True and "precompiled" not in line
+        assert line["cached"] is True

@@ -324,7 +324,7 @@ class Pair:
         raws, self.streams = [], []
         self.served = server(raws)
         self.reference = server(raws, zone_precompile=False,
-                                answer_precompile=False, cache_size=0)
+                                cache_size=0)
         self.served_raw, self.reference_raw = raws
         await self.served.start()
         await self.reference.start()
@@ -423,7 +423,7 @@ def test_a_native_answer_is_the_engines_byte_for_byte_and_line_for_line(
         assert (after["hits"], after["entries"]) == (
             before["hits"], before["entries"])
         # one line, the Python lane's first-sight line: no `cached`, no
-        # `precompiled`, no `query` (the engine never planned)
+        # `query` (the engine never planned)
         (line,), (want,) = lines, want_lines
         if lane == "drain":     # two sockets, two source ports
             assert line.pop("port").endswith("/udp")
@@ -433,7 +433,7 @@ def test_a_native_answer_is_the_engines_byte_for_byte_and_line_for_line(
         assert (line["rcode"], line["answers"], line["additional"]) \
             == ("NOTIMP", [], [])
         assert line["edns"] is (OPTS[opt] is not None)
-        assert not {"cached", "precompiled", "query"} & set(line)
+        assert not {"cached", "query"} & set(line)
 
 
 @pytest.mark.parametrize("lane", LANES)

@@ -4,9 +4,7 @@ What this pins down:
 
 - the incremental checker catches each scripted corruption through the
   invariant that owns it: a dropped reverse entry (ptr-coherence), a
-  missing service member (dangling-srv), a byte flipped mid-wire in a
-  compiled answer (compiled-bytes), an old-epoch entry surviving past
-  the post-flush sweep (stale-epoch), and a skewed mutation log
+  missing service member (dangling-srv), and a skewed mutation log
   (replica-digest);
 - a violation is surfaced everywhere at once: flight-recorder event,
   ``binder_verify_violations_total`` counter, and the ``/status``
@@ -15,8 +13,8 @@ What this pins down:
 - the delta queue sheds (counted, never unbounded) past MAX_QUEUE;
 - the propagation tracer: distinct trace ids per store event, handed-
   down contexts consumed exactly once, stage latencies folded into the
-  introspected p50/p99, and the mutation->render->install chain
-  observed end to end through a live server;
+  introspected p50/p99, and the mutation->mirror->native-install
+  chain observed end to end through a live server;
 - replica-digest mechanics: the rolling digest is deterministic over
   the replicated substance and blind to trace freight; a replica
   flags a divergence exactly once and resyncs; digests stay in parity
@@ -80,7 +78,6 @@ async def start_server(recorder, collector, **kw):
                           port=0, collector=collector,
                           query_log=kw.pop("query_log", False),
                           flight_recorder=recorder,
-                          answer_precompile=True,
                           verify={"auditIntervalSeconds": 0.05}, **kw)
     await server.start()
     return server, store
@@ -174,36 +171,30 @@ class TestIncrementalChecker:
         assert ev[-1]["have"] == "aaaa"
 
 
-# -- compiled-table invariants + the full surfacing round trip --
+# -- the audit's find + the full surfacing round trip --
 
 class TestViolationRoundTrip:
-    def run(self, coro):
-        return asyncio.run(coro)
-
-    def test_corrupt_answer_to_flight_metrics_status(self):
+    def test_drop_reverse_to_flight_metrics_status(self):
         async def go():
             recorder = FlightRecorder(capacity=256)
             collector = MetricsCollector()
             server, store = await start_server(recorder, collector)
             vf = server._verify
             try:
-                # query evidence keeps the shape in the compiled table
-                msg = await udp_ask(server.udp_port, f"w0.{DOMAIN}",
-                                    Type.A)
-                assert msg.rcode == Rcode.NOERROR and msg.answers
-                ckey = server.corrupt_answer()
-                assert ckey is not None
+                # no invalidation fires: only the audit can find it
+                ip = server.drop_reverse()
+                assert ip is not None
                 vf.audit_cycle()
-                assert vf.violations["compiled-bytes"] >= 1
+                assert vf.violations["ptr-coherence"] >= 1
 
                 # flight recorder
                 ev = [e for e in recorder.events()
                       if e["type"] == "verify-violation"]
-                assert any(e["invariant"] == "compiled-bytes"
+                assert any(e["invariant"] == "ptr-coherence"
                            for e in ev)
                 # metrics: counter advanced, full family validates
                 text = collector.expose()
-                assert 'invariant="compiled-bytes"' in text
+                assert 'invariant="ptr-coherence"' in text
                 assert validate_verify_metrics(text) == []
                 # /status: section present, snapshot schema holds
                 intro = Introspector(server=server, recorder=recorder,
@@ -212,8 +203,8 @@ class TestViolationRoundTrip:
                 snap = intro.snapshot()
                 assert validate_status_snapshot(snap) == []
                 sec = snap["verify"]
-                assert sec["violations"]["compiled-bytes"] >= 1
-                assert any(v["invariant"] == "compiled-bytes"
+                assert sec["violations"]["ptr-coherence"] >= 1
+                assert any(v["invariant"] == "ptr-coherence"
                            for v in sec["recent_violations"])
                 # and the operator CLI renders it loudly
                 loader = importlib.machinery.SourceFileLoader(
@@ -226,60 +217,11 @@ class TestViolationRoundTrip:
                 bstat = importlib.util.module_from_spec(spec)
                 loader.exec_module(bstat)
                 out = bstat.render(snap)
-                assert "VIOLATION compiled-bytes" in out
+                assert "VIOLATION ptr-coherence" in out
             finally:
                 await server.stop()
 
-        self.run(go())
-
-    def test_drop_reverse_detected_by_audit(self):
-        async def go():
-            recorder = FlightRecorder(capacity=256)
-            collector = MetricsCollector()
-            server, store = await start_server(recorder, collector)
-            vf = server._verify
-            try:
-                ip = server.drop_reverse()
-                assert ip is not None
-                vf.audit_cycle()
-                assert vf.violations["ptr-coherence"] >= 1
-            finally:
-                await server.stop()
-
-        self.run(go())
-
-    def test_stale_epoch_survivor_past_sweep(self):
-        async def go():
-            recorder = FlightRecorder(capacity=256)
-            collector = MetricsCollector()
-            server, store = await start_server(recorder, collector)
-            vf = server._verify
-            cache = server.zk_cache
-            ac = server.answer_cache
-            try:
-                # flush: epoch bump invalidates everything compiled;
-                # the sweep purges old-epoch entries WITHOUT violating
-                # (they are expected in the window)
-                cache.invalidate_all("test-flush")
-                vf.audit_cycle()
-                assert vf._sweep_done
-                assert vf.violations["stale-epoch"] == 0
-                assert all(e[0] == cache.epoch
-                           for e in ac._compiled.values())
-                # an old-epoch entry AFTER the table was declared
-                # clean is the violation
-                ac.put_compiled(Type.A, f"w3.{DOMAIN}",
-                                cache.epoch - 1,
-                                [(b"\x00" * 24, 0)], False,
-                                f"w3.{DOMAIN}")
-                vf.audit_cycle()
-                assert vf.violations["stale-epoch"] == 1
-                # and the zombie was purged, not just reported
-                assert (Type.A, f"w3.{DOMAIN}") not in ac._compiled
-            finally:
-                await server.stop()
-
-        self.run(go())
+        asyncio.run(go())
 
 
 # -- propagation tracing --
@@ -328,29 +270,18 @@ class TestPropagationTracer:
         async def go():
             recorder = FlightRecorder(capacity=256)
             collector = MetricsCollector()
-            # the evidence query must surface in Python (only
-            # evidenced shapes re-render on mutation): with the native
-            # extension built, the precompile seed fills the C caches
-            # too and a default server answers entirely in C.
-            # query_log on without the JSON log ring stands the native
-            # tier down (_fastpath_active), the documented way to make
-            # every query surface
-            server, store = await start_server(recorder, collector,
-                                               zone_precompile=False,
-                                               query_log=True)
+            server, store = await start_server(recorder, collector)
             vf = server._verify
             try:
-                # query evidence first: only evidenced shapes re-render
                 msg = await udp_ask(server.udp_port, f"w1.{DOMAIN}",
                                     Type.A)
                 assert msg.rcode == Rcode.NOERROR
                 store.put_json(domain_to_path(f"w1.{DOMAIN}"),
                                {"type": "host",
                                 "host": {"address": "10.77.0.99"}})
-                # deadline poll, not a fixed sleep: the precompiler
-                # drains its queue in budgeted loop passes
-                want = ("mirror-apply", "precompile-render",
-                        "compiled-install")
+                # deadline poll, not a fixed sleep: the zone drain
+                # re-pushes in bounded batches between loop passes
+                want = ("mirror-apply", "native-install")
                 deadline = time.monotonic() + 5.0
                 while time.monotonic() < deadline:
                     prop = vf.introspect()["propagation"]
@@ -360,7 +291,7 @@ class TestPropagationTracer:
                     await asyncio.sleep(0.02)
                 for stage in want:
                     assert prop["stages"][stage]["count"] >= 1, stage
-                assert prop["observed"] >= 3
+                assert prop["observed"] >= 2
             finally:
                 await server.stop()
 
@@ -568,30 +499,25 @@ class TestAuditScale:
 class TestChaosVerifyActions:
     def test_parse_actions_with_string_selectors(self):
         plan = FaultPlan.parse(
-            "at 0.5 corrupt-answer qname=web.foo.com\n"
+            "at 0.5 rrl-flood qname=web.foo.com\n"
             "at 1.0 drop-reverse ip=10.0.0.1\n"
             "at 1.5 skew-replica shard=0 frames=2\n"
-            "at 2.0 corrupt-answer")
+            "at 2.0 drop-reverse")
         acts = [(t, a, kw) for t, a, kw in plan.timeline]
-        assert acts[0] == (0.5, "corrupt-answer",
-                           {"qname": "web.foo.com"})
+        assert acts[0] == (0.5, "rrl-flood", {"qname": "web.foo.com"})
         assert acts[1] == (1.0, "drop-reverse", {"ip": "10.0.0.1"})
         assert acts[2] == (1.5, "skew-replica",
                            {"shard": 0, "frames": 2})
-        assert acts[3] == (2.0, "corrupt-answer", {})
+        assert acts[3] == (2.0, "drop-reverse", {})
 
     def test_parse_rejects_empty_selector(self):
         with pytest.raises(ValueError):
-            FaultPlan.parse("at 1 corrupt-answer qname=")
+            FaultPlan.parse("at 1 drop-reverse ip=")
 
     def test_driver_dispatches_to_verify_target(self):
         calls = []
 
         class Target:
-            def corrupt_answer(self, qname=None):
-                calls.append(("corrupt", qname))
-                return (1, qname)
-
             def drop_reverse(self, ip=None):
                 calls.append(("drop", ip))
                 return ip
@@ -601,21 +527,19 @@ class TestChaosVerifyActions:
                 return shard
 
         plan = (FaultPlan()
-                .at(0.0, "corrupt-answer", qname="a.b")
                 .at(0.0, "drop-reverse", ip="10.9.9.9")
                 .at(0.0, "skew-replica", shard=1, frames=3))
         recorder = FlightRecorder(capacity=64)
         driver = ChaosDriver(plan, verify_target=Target(),
                              recorder=recorder)
         asyncio.run(driver.run())
-        assert ("corrupt", "a.b") in calls
         assert ("drop", "10.9.9.9") in calls
         assert ("skew", 1, 3) in calls
         injected = [e for e in recorder.events()
                     if e["type"] == "chaos-inject"]
-        assert len(injected) == 3
+        assert len(injected) == 2
 
     def test_missing_target_or_hook_is_skipped_not_fatal(self):
-        plan = FaultPlan().at(0.0, "corrupt-answer")
+        plan = FaultPlan().at(0.0, "drop-reverse")
         asyncio.run(ChaosDriver(plan).run())
         asyncio.run(ChaosDriver(plan, verify_target=object()).run())

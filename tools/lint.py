@@ -462,9 +462,6 @@ _SNAPSHOT_SCHEMA = {
         "hits": (int, False), "misses": (int, False),
         "hit_ratio": (_NUM, False), "invalidations": (int, False),
         "expiry_ms": (_NUM, False), "neg_hits": (int, False),
-        "compiled_entries": (int, False),
-        "compiled_serves": (int, False),
-        "compiled_installs": (int, False),
         "type_row_serves": (int, False),
         "zone_put_skips": (dict, False),
     },
@@ -615,13 +612,10 @@ def validate_status_snapshot(snap):
         for key in ("pending", "chunks", "last_duration_seconds"):
             if key not in mirror["rebuild"]:
                 errs.append(f"mirror.rebuild: missing {key!r}")
+    # the one key the benchmark harness still waits on (ROADMAP D13)
     pc = snap.get("precompile")
-    if isinstance(pc, dict):
-        for key in ("queue_depth", "max_pending", "batch", "compiled",
-                    "declined", "shed", "seed_remaining", "seeded",
-                    "seed_skipped"):
-            if key not in pc:
-                errs.append(f"precompile: missing {key!r}")
+    if isinstance(pc, dict) and "seed_remaining" not in pc:
+        errs.append("precompile: missing 'seed_remaining'")
     vf = snap.get("verify")
     if isinstance(vf, dict):
         for key in ("enabled", "checks", "violations", "skipped",
@@ -674,50 +668,6 @@ def validate_status_snapshot(snap):
                         "false_positives"):
                 if key not in rrl:
                     errs.append(f"policy.rrl: missing {key!r}")
-    return errs
-
-
-# ---- mutation-time precompiler metrics validator ----
-#
-# The precompiler's operational story lives in its metrics: compiled /
-# declined / shed counters plus the live queue-depth gauge.  An exporter
-# bug that silently dropped one of them would leave storm shedding
-# invisible — exactly the failure mode the bounded queue exists to
-# surface.  validate_precompile_metrics() checks a scrape exposition for
-# the full binder_precompile_* family with the right TYPEs.  Wired into
-# tier-1 via tests/test_precompile.py alongside validate_exposition.
-
-_PRECOMPILE_FAMILIES = {
-    "binder_precompile_compiled": "counter",
-    "binder_precompile_declined": "counter",
-    "binder_precompile_shed": "counter",
-    "binder_precompile_queue_depth": "gauge",
-    "binder_precompile_serves": "counter",
-}
-
-
-def validate_precompile_metrics(text):
-    """Validate that a Prometheus exposition carries the complete
-    ``binder_precompile_*`` family (correct TYPE declarations and at
-    least one sample each).  Returns error strings; empty == valid."""
-    errs = list(validate_exposition(text))
-    types = {}
-    sampled = set()
-    for line in text.splitlines():
-        parts = line.split()
-        if line.startswith("# TYPE") and len(parts) >= 4:
-            types[parts[2]] = parts[3]
-        elif line and not line.startswith("#") and parts:
-            name = parts[0].split("{", 1)[0]
-            sampled.add(name)
-    for family, kind in _PRECOMPILE_FAMILIES.items():
-        if family not in types:
-            errs.append(f"{family}: missing # TYPE declaration")
-        elif types[family] != kind:
-            errs.append(f"{family}: declared {types[family]!r}, "
-                        f"expected {kind!r}")
-        if family not in sampled:
-            errs.append(f"{family}: no samples in exposition")
     return errs
 
 
@@ -1144,11 +1094,9 @@ _VERIFY_FAMILIES = {
 #: the invariant catalog (binder_tpu/verify/checker.py INVARIANTS) —
 #: every value pinned on all three counters; the skip counter also
 #: carries the queue-shed series
-_VERIFY_INVARIANTS = ("dangling-srv", "ptr-coherence", "compiled-bytes",
-                      "replica-digest", "stale-epoch")
+_VERIFY_INVARIANTS = ("dangling-srv", "ptr-coherence", "replica-digest")
 #: the propagation stage catalog (binder_tpu/verify/tracer.py STAGES)
 _VERIFY_STAGES = ("mirror-apply", "shard-frame", "replica-apply",
-                  "precompile-render", "compiled-install",
                   "native-install")
 
 

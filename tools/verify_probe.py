@@ -6,19 +6,19 @@ subprocess per zone size (like tools/zone_probe.py, whose answer-path
 harness it reuses) so the sizes never pollute each other's RSS.
 
 Builds a synthetic zone, wires the zone_probe Harness (mirror →
-invalidate → precompile, the BinderServer answer path minus
-transports), and measures:
+invalidate → drop, the BinderServer answer path minus transports),
+and measures:
 
 - a control mutation burst with NO verifier wired: the baseline
   single-name mutation latency (p50/p99) at this zone size;
 - the same burst with the full verify plane wired — propagation
-  tracer on the mirror + precompiler, incremental checker fed by the
+  tracer on the mirror, incremental checker fed by the
   per-name invalidation tags (no event loop, so the checker drains
   INLINE and its entire cost lands in the measured latency — the
   honest worst case; in the server it amortizes across loop passes);
 - the per-stage mutation→glass propagation figures off the tracer
-  itself (`mirror-apply` / `precompile-render` / `compiled-install`;
-  every figure end-to-end from the store event, exactly what
+  itself (`mirror-apply`: the harness has no native lane to install
+  into; every figure end-to-end from the store event, exactly what
   `binder_propagation_seconds` records in production) — the
   O(delta) claim is these staying flat from 10k to 1M names;
 - one full background-audit pass: wall time, slice count, the worst
@@ -84,21 +84,17 @@ def probe(n: int, mutations: int = 400, sample: int = 0) -> dict:
             lat.append((time.perf_counter() - t0) * 1e6)
         return lat
 
-    # control: the bare mirror → invalidate → re-render chain
+    # control: the bare mirror → invalidate → drop chain
     p50, p99 = _pcts(burst(210))
     out["mutation_p50_us"] = p50
     out["mutation_p99_us"] = p99
     out["mutation_samples"] = len(idx)
 
     # wire the verify plane the way BinderServer does (server.py):
-    # tracer on the mirror (store-event stamp + mirror-apply) and on
-    # the precompiler (render/install stages), checker fed by the
-    # same invalidation tags the answer cache drops
-    vf = Verifier(zk_cache=cache, answer_cache=h.answer_cache,
-                  resolver=h.resolver, precompiler=h.pc,
-                  config={"auditSample": sample})
+    # tracer on the mirror (store-event stamp + mirror-apply), checker
+    # fed by the same invalidation tags the answer cache drops
+    vf = Verifier(zk_cache=cache, config={"auditSample": sample})
     cache.tracer = vf.tracer
-    h.pc.tracer = vf.tracer
     cache.on_invalidate(vf.enqueue_tags)
 
     p50v, p99v = _pcts(burst(211))

@@ -3,21 +3,21 @@
 
 Two harnesses back to back (ISSUE 16 acceptance):
 
-**In-process** — boots a full binder (fake store + mutation-time
-precompile + the verify subsystem) and runs two phases:
+**In-process** — boots a full binder (fake store + the verify
+subsystem) and runs two phases:
 
 - *clean soak*: continuous churn + queries; the incremental checker
   and the sampled audit must evaluate real work (checks advance, audit
-  passes complete, every propagation stage from ``mirror-apply`` to
-  ``compiled-install`` observes) while firing ZERO violations — a
+  passes complete, the propagation stages ``mirror-apply`` and
+  ``native-install`` observe) while firing ZERO violations — a
   checker that cries wolf on a healthy binder is worse than none; the
   scrape passes ``validate_verify_metrics`` and the snapshot passes
   ``validate_status_snapshot``; process RSS growth stays bounded;
-- *scripted corruption*: chaos ``corrupt-answer`` and ``drop-reverse``
-  (table corruption that fires NO invalidation — only the audit can
-  see it), then one audit cycle.  Each corruption must be detected
-  within that single cycle, and every violation must surface all three
-  ways at once: ``verify-violation`` flight event, the
+- *scripted corruption*: chaos ``drop-reverse`` (map corruption that
+  fires NO invalidation — only the audit can see it), then one audit
+  cycle.  The corruption must be detected within that single cycle,
+  and the violation must surface all three ways at once:
+  ``verify-violation`` flight event, the
   ``binder_verify_violations_total{invariant}`` counter, and the
   ``recent_violations`` table in ``/status verify``.
 
@@ -133,15 +133,12 @@ async def _run_inprocess(duration: float) -> dict:
     store.start_session()
 
     # query_log on (without the JSON log ring) stands the native tier
-    # down (_fastpath_active), so every query surfaces in Python and
-    # leaves re-render evidence — with the C path active, the seed
-    # fills the native caches and churned names would propagate
-    # mirror-apply → native-install only, never exercising the
-    # precompile-render/compiled-install stages this smoke asserts
+    # down (_fastpath_active), so every query surfaces in Python; the
+    # zone drain still re-pushes each churned name (native-install)
     server = BinderServer(
         zk_cache=cache, dns_domain=DOMAIN, datacenter_name="dc0",
         host="127.0.0.1", port=0, collector=collector, query_log=True,
-        flight_recorder=recorder, answer_precompile=True,
+        flight_recorder=recorder,
         verify={"auditIntervalSeconds": 0.05})
     await server.start()
     intro = Introspector(server=server, recorder=recorder,
@@ -189,8 +186,7 @@ async def _run_inprocess(duration: float) -> dict:
             raise Violation(f"clean soak fired violations: {fired}")
         if not sum(vf.checks.values()):
             raise Violation("checker evaluated no invariants")
-        for inv in ("ptr-coherence", "compiled-bytes", "dangling-srv",
-                    "stale-epoch"):
+        for inv in ("ptr-coherence", "dangling-srv"):
             if not vf.checks[inv]:
                 raise Violation(f"invariant {inv} never checked")
         if vf.audit_passes < 1:
@@ -198,8 +194,7 @@ async def _run_inprocess(duration: float) -> dict:
         prop = vf.tracer.introspect()
         if not prop["observed"]:
             raise Violation("no propagation stages observed")
-        for stage in ("mirror-apply", "precompile-render",
-                      "compiled-install"):
+        for stage in ("mirror-apply", "native-install"):
             if not prop["stages"][stage]["count"]:
                 raise Violation(f"propagation stage {stage} never "
                                 f"observed under churn")
@@ -208,35 +203,27 @@ async def _run_inprocess(duration: float) -> dict:
             raise Violation(f"verify metrics: {errs[:3]}")
 
         # -- phase 2: scripted corruption, detected within ONE cycle --
-        if not server.answer_cache._compiled:
-            raise Violation("no compiled entries to corrupt")
-        plan = FaultPlan(seed=3) \
-            .at(0.05, "corrupt-answer") \
-            .at(0.15, "drop-reverse")
+        plan = FaultPlan(seed=3).at(0.05, "drop-reverse")
         driver = ChaosDriver(plan, store=store, verify_target=server,
                              recorder=recorder)
         await driver.run()
         vf.audit_cycle()
-        if vf.violations["compiled-bytes"] < 1:
-            raise Violation("corrupt-answer not detected within one "
-                            "audit cycle")
         if vf.violations["ptr-coherence"] < 1:
             raise Violation("drop-reverse not detected within one "
                             "audit cycle")
         # the violation -> flight event -> metrics -> /status round trip
-        if recorder.by_type.get("verify-violation", 0) < 2:
+        if recorder.by_type.get("verify-violation", 0) < 1:
             raise Violation("violations missing from the flight "
                             "recorder")
-        text = collector.expose()
-        for inv in ("compiled-bytes", "ptr-coherence"):
-            if _invariant_counter(
-                    text, "binder_verify_violations_total", inv) < 1:
-                raise Violation(f"violations counter for {inv} did "
-                                f"not advance")
+        if _invariant_counter(
+                collector.expose(), "binder_verify_violations_total",
+                "ptr-coherence") < 1:
+            raise Violation("violations counter for ptr-coherence did "
+                            "not advance")
         snap = intro.snapshot()
         recent = {v["invariant"]
                   for v in snap["verify"]["recent_violations"]}
-        if not {"compiled-bytes", "ptr-coherence"} <= recent:
+        if "ptr-coherence" not in recent:
             raise Violation(f"/status recent_violations missing "
                             f"invariants: has {sorted(recent)}")
         errs = validate_status_snapshot(snap)

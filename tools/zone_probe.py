@@ -7,16 +7,16 @@ as a script, one process a zone size keeps measurements out of each
 other's RSS.
 
 Builds a synthetic zone (``store.fake.populate_synthetic``) in a fake
-store, mirrors it, wires the answer-cache + mutation-time precompiler
-the way BinderServer does, and measures:
+store, mirrors it, wires the answer cache to the mirror's invalidation
+feed the way BinderServer does, and measures:
 
 - store/mirror build wall time and RSS delta (→ bytes per name);
-- single-name mutation → re-rendered compiled answer latency
+- single-name mutation latency: mirror → drop of the served answer
   (p50/p99 over a sample spread across the zone), with a byte-parity
-  check of every re-rendered wire against a fresh engine render;
+  check of the next ask's wire (a lazy resolve) against a fresh engine
+  render of the new data;
 - watch-storm recovery: a burst of mutations against served names,
-  time until the precompile backlog drains (event-loop mode, so the
-  bounded drain is what's being measured);
+  time until every one of them serves its new answer again;
 - chunked session rebuild: wall time, chunk count, the worst
   event-loop stall observed while it streamed, and proof that lookups
   kept serving mid-rebuild;
@@ -36,8 +36,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from binder_tpu.resolver.answer_cache import AnswerCache  # noqa: E402
-from binder_tpu.resolver.engine import Resolver  # noqa: E402
-from binder_tpu.resolver.precompile import Precompiler  # noqa: E402
+from binder_tpu.resolver.engine import Resolver, render_plan  # noqa: E402
 from binder_tpu.dns.wire import Type  # noqa: E402
 from binder_tpu.store import FakeStore, MirrorCache  # noqa: E402
 from binder_tpu.store.fake import populate_synthetic  # noqa: E402
@@ -63,47 +62,38 @@ def host_name(i: int, racks: int) -> str:
 
 
 class Harness:
-    """The answer-path wiring of BinderServer, minus transports: an
-    AnswerCache + Resolver + Precompiler fed by the mirror's per-name
-    invalidation events, so a store mutation exercises the REAL
-    mirror → drop → re-render chain."""
+    """The Python lanes' answer path of BinderServer, minus transports:
+    an AnswerCache + Resolver fed by the mirror's per-name invalidation
+    events, so a store mutation exercises the REAL mirror → drop chain
+    and the next ask is a lazy resolve."""
 
     def __init__(self, cache: MirrorCache, cache_size: int = 65536):
         self.cache = cache
         self.answer_cache = AnswerCache(size=cache_size,
-                                        compiled_size=cache_size,
                                         intern=cache.canon)
         self.resolver = Resolver(cache, dns_domain=DOMAIN)
-        self.pc = Precompiler(resolver=self.resolver,
-                              answer_cache=self.answer_cache,
-                              zk_cache=cache, summarize=str)
-        self.pc.MAX_PENDING_CAP = cache_size
         cache.on_invalidate(self._on_invalidate)
 
     def _on_invalidate(self, tags) -> None:
-        dropped = []
         for tag in tags:
-            self.answer_cache.invalidate_tag(tag, dropped=dropped)
-        if dropped:
-            self.pc.enqueue(dropped)
+            self.answer_cache.invalidate_tag(tag)
 
-    def prime(self, qname: str) -> None:
-        """Install serving evidence for a name (what a real query
-        would do), so its mutations are eagerly re-rendered."""
-        self.pc._compile_one((Type.A, qname),
-                             evidence_at=time.monotonic())
+    def served_wire(self, qname: str) -> bytes:
+        """What the ladder serves for the name's A: the cached wire,
+        else a resolve whose wire is cached under the name's tag."""
+        key, epoch = (Type.A, qname), self.cache.epoch
+        wire = self.answer_cache.get(key, epoch)
+        if wire is None:
+            wire = self.engine_wire(qname)
+            self.answer_cache.put(key, epoch, wire, tag=qname)
+        return wire
 
-    def compiled_wire(self, qname: str):
-        hit = self.answer_cache.get_compiled(Type.A, qname,
-                                             self.cache.epoch)
-        return None if hit is None else hit[0][0]
+    #: a first ask: the name has a served answer for a mutation to drop
+    prime = served_wire
 
-    def engine_wire(self, qname: str):
-        plan = self.resolver.plan(qname, Type.A)
-        answers = [r for g in plan.groups for r in g[0]]
-        adds = [r for g in plan.groups for r in g[1]]
-        return Precompiler._render(qname, Type.A, plan, answers, adds,
-                                   False)
+    def engine_wire(self, qname: str) -> bytes:
+        return render_plan(qname, Type.A,
+                           self.resolver.plan(qname, Type.A))
 
 
 def probe(n: int, mutations: int = 200, storm: int = 2000) -> dict:
@@ -133,9 +123,9 @@ def probe(n: int, mutations: int = 200, storm: int = 2000) -> dict:
 
     h = Harness(cache)
 
-    # single-name mutation -> re-rendered answer, sampled across the
-    # zone; inline (no loop), so the timing is the full synchronous
-    # mirror -> invalidate -> re-render chain and nothing else
+    # single-name mutation -> dropped answer, sampled across the
+    # zone; the timing is the full synchronous mirror -> invalidate ->
+    # drop chain and nothing else
     step = max(1, n // max(1, mutations))
     sample = list(range(0, n, step))[:mutations]
     for i in sample:
@@ -150,8 +140,7 @@ def probe(n: int, mutations: int = 200, storm: int = 2000) -> dict:
         store.set_data(host_path(i, racks), body)
         lat_us.append((time.perf_counter() - t0) * 1e6)
         name = host_name(i, racks)
-        cw = h.compiled_wire(name)
-        if cw is None or cw != h.engine_wire(name):
+        if h.served_wire(name) != h.engine_wire(name):
             parity_failures += 1
     lat_us.sort()
     out["mutation_p50_us"] = round(lat_us[len(lat_us) // 2], 1)
@@ -160,8 +149,8 @@ def probe(n: int, mutations: int = 200, storm: int = 2000) -> dict:
     out["mutation_samples"] = len(sample)
     out["parity_failures"] = parity_failures
 
-    # watch storm + chunked rebuild need a live event loop (the
-    # bounded drains are the thing being measured)
+    # the chunked rebuild needs a live event loop (its bounded chunks
+    # are the thing being measured)
     async def loop_phase():
         res = {}
         burst = min(storm, n)
@@ -176,11 +165,10 @@ def probe(n: int, mutations: int = 200, storm: int = 2000) -> dict:
                 b'{"type": "host", "host": {"address": "10.201.%d.%d"}}'
                 % ((j >> 8) & 255, j & 255))
         res["storm_mutate_s"] = round(time.perf_counter() - t0, 3)
-        while h.pc._pending:
-            await asyncio.sleep(0)
+        for i in burst_idx:
+            h.served_wire(host_name(i, racks))
         res["storm_recovery_s"] = round(time.perf_counter() - t0, 3)
         res["storm_burst"] = len(burst_idx)
-        res["storm_shed"] = h.pc.shed
 
         # chunked session rebuild: serving continues, loop stays live
         loop = asyncio.get_running_loop()
@@ -219,7 +207,6 @@ def probe(n: int, mutations: int = 200, storm: int = 2000) -> dict:
 
     out.update(asyncio.run(loop_phase()))
     out["pool"] = POOL.stats()
-    out["compiled"] = h.pc.compiled
     return out
 
 

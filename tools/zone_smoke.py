@@ -5,16 +5,16 @@ to end, as a CI gate (ISSUE 7).
 Builds a synthetic mirror at a small CONTROL size and at the smoke size
 (``BINDER_ZONE_NAMES``, default 100k; ``make ci`` runs a trimmed 20k),
 applies a mutation burst + watch storm through the real
-mirror → invalidate → precompile chain (tools/zone_probe.py), and
+mirror → invalidate → drop chain (tools/zone_probe.py), and
 asserts:
 
 - single-name rebuild latency is independent of zone size
   (p50 at the smoke size within ``LAT_RATIO_MAX`` of the control —
   O(delta), not O(zone));
-- every re-rendered compiled answer is byte-identical to a fresh
-  engine render (answers stay engine-parity through the compact
-  representation);
-- the watch storm drains without wedging (bounded backpressure);
+- the ask after every mutation serves the bytes of a fresh engine
+  render of the new data, never the dropped answer (answers stay
+  engine-parity through the compact representation);
+- the watch storm's names all serve their new answers again;
 - the chunked session rebuild never stalls the event loop past the
   loop-lag watchdog threshold, and lookups keep serving throughout;
 - the in-process metrics surface passes ``validate_mirror_metrics``
@@ -82,8 +82,8 @@ def main() -> int:
 
     parity = control["parity_failures"] + smoke["parity_failures"]
     if parity:
-        failures.append(f"{parity} re-rendered answer(s) diverged "
-                        "from a fresh engine render")
+        failures.append(f"{parity} answer(s) served after a mutation "
+                        "diverged from a fresh engine render")
 
     if smoke["rebuild_max_loop_lag_ms"] > STALL_THRESHOLD_MS:
         failures.append(
@@ -97,7 +97,7 @@ def main() -> int:
     if smoke["rebuild_chunks"] < 2:
         failures.append("rebuild at smoke size did not chunk")
 
-    # storm drained (probe would have hung otherwise) — pin the figure
+    # storm recovered (every name re-resolved) — pin the figure
     results["storm_recovery_s"] = smoke["storm_recovery_s"]
 
     lint_errs = scrape_mirror_metrics()
