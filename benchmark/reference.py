@@ -94,6 +94,9 @@ class Zone:
         #: whether an answer to a question with an OPT record carries one:
         #: true but under the control ``--break reference-opt``
         self.opt_echoed = True
+        #: empty but under the control ``--break reference-address`` in a
+        #: zone without services: a host's name -> another address
+        self.altered = {}
         self._host_rx = re.compile(
             r"^h(\d{6})\.r(\d{4})\.%s\.%s$" % (re.escape(self.subtree),
                                                 re.escape(domain)))
@@ -253,6 +256,8 @@ class Zone:
                   glue=targets)
 
     def _address_of(self, name: str):
+        if name in self.altered:
+            return self.altered[name]
         m = self._host_rx.match(name)
         if m:
             i = int(m.group(1))

@@ -66,7 +66,10 @@ def test_the_manifest_states_what_the_reader_states():
     assert entry == {"name": "log_direct_share", "unit": module.UNIT,
                      "better": "higher", "source": "program_counter",
                      "layer": module.LAYER, "moves": module.MOVES,
+                     # (the log's one writer serves every lane, the
+                     # balancer's link too)
                      "workloads": ["hosts_zipf_open60",
                                    "services_srv_open60",
                                    "hosts_a_aaaa_open60",
-                                   "services_srv_edns"]}
+                                   "services_srv_edns",
+                                   "hosts_zipf_balancer_open60"]}

@@ -64,13 +64,18 @@ def test_nothing_to_read_is_none(empty):
 def test_the_manifest_states_what_the_reader_states():
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    entry = manifest["per_layer"][-1]       # appended, nothing moved
+    (entry,) = [m for m in manifest["per_layer"]
+                if m["name"] == "log_write_bytes_mean"]    # by name
     module = reader()
-    assert entry == {"name": "log_write_bytes_mean", "unit": module.UNIT,
-                     "better": "higher", "source": "program_counter",
-                     "layer": module.LAYER, "moves": module.MOVES,
-                     "workloads": ["hosts_zipf_open60",
-                                   "services_srv_open60",
-                                   "hosts_a_aaaa_open60",
-                                   "services_srv_edns"]}
+    # the four steady cells of a reuseport group first, as PR 46 listed
+    # them; behind them whatever cell a later PR found the log's one writer
+    # in (the instances behind the balancer write it the same way)
+    assert entry["workloads"][:4] == [
+        "hosts_zipf_open60", "services_srv_open60", "hosts_a_aaaa_open60",
+        "services_srv_edns"]
+    assert "hosts_zipf_rolling" not in entry["workloads"]
+    assert dict(entry, workloads=None) == {
+        "name": "log_write_bytes_mean", "unit": module.UNIT,
+        "better": "higher", "source": "program_counter",
+        "layer": module.LAYER, "moves": module.MOVES, "workloads": None}
     assert module.LAYER == "query log" and module.UNIT == "bytes"

@@ -170,7 +170,7 @@ def test_an_event_that_is_not_built_is_refused():
                    [{"at_s": 2, "signal": "SIGHUP", "to": "supervisor"},
                     {"at_s": 1, "signal": "SIGHUP", "to": "supervisor"}]):
         with pytest.raises(SystemExit):
-            run.Events(events, None, "/nonexistent")
+            run.Events(events, run.SupervisorGroup, "/nonexistent")
 
 
 # -- the manifest --

@@ -94,11 +94,17 @@ def test_the_new_metrics_list_the_cell_alone_and_the_old_ones_gain_it():
     for name in ("busy_unnamed_share", "syscalls_per_answer",
                  "socket_us_per_answer", "python_us_per_query"):
         assert CELL in by_name[name]["workloads"]
-    # as the manifest stands: PR 26's eight, PR 27's and PR 29's one each
-    # (the services cells alone); shared with the hosts cells: the 21 of
-    # PR 23 to 25, PR 37's nine, PR 43's three and PR 44's two
-    assert sum(CELL in p["workloads"] for p in m["per_layer"]) \
-        == len(NEW) + 2 + 21 + 9 + 3 + 2
+    # by name, whatever later PRs appended: PR 26's eight, PR 27's and
+    # PR 29's one each list the services cells alone; every metric that the
+    # hosts cell lists and that is not of its own topology or mix is the
+    # services cell's too
+    listed = {p["name"] for p in m["per_layer"] if CELL in p["workloads"]}
+    assert set(NEW) | {"tc_render_share", "tcp_native_share"} <= listed
+    for p in m["per_layer"]:
+        if p["name"] in NEW + ("tc_render_share", "tcp_native_share"):
+            assert not any(w.startswith("hosts_") for w in p["workloads"])
+        elif "hosts_zipf_open60" in p["workloads"]:
+            assert p["name"] in listed, p["name"]
 
 
 @pytest.mark.parametrize("seed", [1, 2**31 + 7])
